@@ -788,7 +788,20 @@ class ControlStore:
             # 1000-node burst on a single event-loop tick. Each node is
             # still visited about once per period.
             nshards = max(1, min(8, (len(self.node_last_beat) + 127) // 128))
+            asleep_at = time.monotonic()
             await asyncio.sleep(period / nshards)
+            # Oversleeping means this process was not running and could not
+            # have heard a beat — a starved loop, or a frozen host: four
+            # processes attaching to their TPU chips at once freeze every
+            # process on a v5e host for 10-12 s. That time is not the
+            # nodes' silence; a daemon frozen with us beats again at once.
+            stall = time.monotonic() - asleep_at - period / nshards
+            if stall > period:
+                logger.warning(
+                    "health loop overslept %.1fs (host stall); not counting "
+                    "it against node liveness", stall)
+                for node_id in self.node_last_beat:
+                    self.node_last_beat[node_id] += stall
             shard = (shard + 1) % nshards
             self._sweep_preempt_notices()
             now = time.monotonic()

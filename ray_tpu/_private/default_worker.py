@@ -87,7 +87,24 @@ def amain():
     asyncio.run(run())
 
 
+# The one place the JAX persistent compile cache is placed. Its path is part
+# of the cache key's world: a directory that moves (session dir, tempfile,
+# pid, timestamp) never hits, so the default is fixed inside the checkout.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """Set from outside, the variable is used as it stands; otherwise the
+    fixed default. JAX reads it when first imported, which in a worker is
+    after start-up."""
+    return os.environ.setdefault(COMPILE_CACHE_ENV, DEFAULT_COMPILE_CACHE_DIR)
+
+
 def main():
+    place_compile_cache()
     logging.basicConfig(
         level=os.environ.get("RT_LOG_LEVEL", "INFO"),
         format="%(asctime)s %(levelname)s worker %(message)s",

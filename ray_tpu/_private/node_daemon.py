@@ -154,19 +154,16 @@ class NodeDaemon:
         # {"spot": "!true"}) can keep coordination actors off it
         if res.get("spot"):
             self.labels.setdefault("spot", "true")
-        if "TPU" not in res and os.environ.get("RT_TPU_AUTODETECT"):
-            # env-only detection: the daemon must not touch libtpu (that
-            # would claim the chips workers need). Opt-in: on shared-sandbox
-            # hosts several fake daemons coexist with one real chip.
+        if "TPU" not in res:
+            # chips are counted from device files: the daemon must not load
+            # libtpu (that would claim the chips its workers need). An
+            # explicit resources={"TPU": n} wins.
             from ray_tpu.tpu.accelerator import TpuAcceleratorManager
 
-            info = TpuAcceleratorManager.detect(allow_jax_probe=False)
-            if info is not None:
-                tpu_res, tpu_labels = (
-                    TpuAcceleratorManager.node_resources_and_labels(info)
-                )
-                res.update(tpu_res)
-                self.labels.update(tpu_labels)
+            tpu_res, tpu_labels = (
+                TpuAcceleratorManager.node_resources_and_labels())
+            res.update(tpu_res)
+            self.labels.update(tpu_labels)
         self.total_resources = ResourceSet(res)
         self.available = ResourceSet(res)
         # Free TPU chip ids (reference: tpu.py:42-55 visibility semantics —
@@ -876,10 +873,18 @@ class NodeDaemon:
         cwd = None
         if env_key and runtime_env:
             python_exe, cwd = await self._build_worker_env(runtime_env)
-        if tpu_chips:
-            from ray_tpu.tpu.accelerator import TpuAcceleratorManager
+        # one process per chip: a granted worker sees exactly its chips and
+        # must get the TPU backend; an ungranted one is pinned to the CPU
+        from ray_tpu.tpu import accelerator as tpu_accel
 
-            TpuAcceleratorManager.set_visible_chips_env(
+        platform = tpu_accel.worker_platform(granted=bool(tpu_chips))
+        if platform is not None:
+            env["JAX_PLATFORMS"] = platform
+        env.pop(tpu_accel.GRANTED_CHIPS_ENV, None)
+        if tpu_chips:
+            env[tpu_accel.GRANTED_CHIPS_ENV] = ",".join(
+                str(c) for c in tpu_chips)
+            tpu_accel.TpuAcceleratorManager.set_visible_chips_env(
                 env, list(tpu_chips), self._tpu_chips_per_host
             )
         try:

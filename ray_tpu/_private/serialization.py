@@ -213,15 +213,6 @@ class _PinnedBuffer:
         return memoryview(self._mv)
 
 
-# Pure-Python buffer exporters (PEP 688 __buffer__) only exist on CPython
-# 3.12+. Older interpreters can't tie a store pin to array lifetime, so
-# they must COPY out-of-band buffers and release the pin eagerly — correct
-# reads at the cost of zero-copy (a _PinnedBuffer handed to np.frombuffer
-# on 3.10 is a TypeError, and handing the raw shm view instead would free
-# the pin while arrays still alias the segment).
-_CAN_PIN_BUFFERS = sys.version_info >= (3, 12)
-
-
 def deserialize(data, copy_buffers: bool = False, release=None) -> Any:
     """Deserialize from bytes/memoryview produced by SerializedObject.
 
@@ -233,11 +224,6 @@ def deserialize(data, copy_buffers: bool = False, release=None) -> Any:
     references `data` (immediately when everything was copied in-band, or when
     the last aliasing array is GC'd otherwise).
     """
-    if release is not None and not copy_buffers and not _CAN_PIN_BUFFERS:
-        # a pin would be needed but this interpreter can't export buffers
-        # from Python (see _CAN_PIN_BUFFERS): copy + eager release instead.
-        # Pin-less zero-copy over plain bytes (inline objects) stays.
-        copy_buffers = True
     mv = memoryview(data)
     nbuf, inband_len = _HEADER.unpack_from(mv, 0)
     off = _HEADER.size

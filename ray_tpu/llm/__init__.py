@@ -51,7 +51,11 @@ class LLMConfig:
         import jax
 
         from ray_tpu.models.llama import LlamaConfig, init_params
+        from ray_tpu.tpu.accelerator import check_granted_devices
 
+        # a worker holding a chip grant must see exactly those chips on the
+        # TPU backend before anything is built on it
+        check_granted_devices()
         preset = getattr(LlamaConfig, self.model)
         cfg = preset(**self.model_overrides)
         assert cfg.vocab_size >= ByteTokenizer.vocab_size, (
@@ -62,7 +66,13 @@ class LLMConfig:
             with open(self.checkpoint_path, "rb") as f:
                 params = jax.device_put(pickle.load(f))
         else:
-            params = init_params(cfg, jax.random.PRNGKey(self.seed))
+            # under jit: one compile instead of one per eager op, and the
+            # float32 draws fuse into the cast to param_dtype instead of
+            # materialising at full size beside the weights
+            import functools
+
+            params = jax.jit(functools.partial(init_params, cfg))(
+                jax.random.PRNGKey(self.seed))
         return cfg, params
 
 
@@ -198,12 +208,14 @@ class LLMEngine:
     def __init__(self, config: LLMConfig, engine_config=None):
         from ray_tpu.llm._engine import EngineConfig, PagedEngine
 
+        t0 = time.monotonic()
         self.config = config
         self.tokenizer = ByteTokenizer()
         cfg, params = config.build_model()
         self.engine = PagedEngine(
             cfg, params, engine_config or EngineConfig(), eos_id=EOS)
         self._t0 = None
+        self._init_s = time.monotonic() - t0
 
     @_rt.method(num_returns="streaming")
     async def completions_stream(self, prompt: str,
@@ -257,6 +269,32 @@ class LLMEngine:
         async for tok in gen:
             yield int(tok)
 
+    async def device_info(self) -> Dict[str, Any]:
+        """What JAX shows this engine's process, next to what the node
+        daemon granted it. A process numbers its own devices from 0, so
+        engines on different chips are told apart by `granted_chips`."""
+        import jax
+
+        from ray_tpu.tpu.accelerator import granted_chips
+
+        devices = jax.local_devices()
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "granted_chips": ",".join(granted_chips()),
+            "init_s": round(self._init_s, 2),
+        }
+
+    async def check_prefill(self, prompt: str) -> Dict[str, Any]:
+        """Prefill's last-position logits against the reference forward on
+        the same prompt, computed here where the weights are (off the event
+        loop: it compiles)."""
+        import asyncio
+
+        return await asyncio.to_thread(
+            self.engine.check_prefill, self.tokenizer.encode(prompt))
+
     async def stats(self) -> Dict[str, Any]:
         s = self.engine.stats()
         elapsed = max(time.monotonic() - (self._t0 or time.monotonic()),
@@ -279,10 +317,13 @@ def build_openai_app(config: LLMConfig, *, deployment_name: str = "v1"):
     (reference: build_openai_app core/ingress/builder.py:213 — the HTTP
     route is POST /<deployment_name>, our proxy's path convention)."""
     from ray_tpu import serve
+    from ray_tpu.tpu.accelerator import chip_options
 
     deployment = serve.Deployment(
         LLMServer, deployment_name,
         num_replicas=config.num_replicas,
+        # each replica builds the model, so each asks for its chip
+        ray_actor_options=chip_options(),
         init_args=(config,),
     )
     return serve.run(deployment)

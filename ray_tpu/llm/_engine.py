@@ -208,6 +208,31 @@ def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     return prefill
 
 
+def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
+                       prompt_ids: List[int]):
+    """Run the jitted `prefill` over one prompt into a pool sized to exactly
+    that prompt (+ trash block 0). Returns (last_logits, kc, vc, nb) with
+    the prompt's KV in blocks 1..nb."""
+    import jax.numpy as jnp
+
+    p = list(prompt_ids) or [0]
+    plen = len(p)
+    bs = ecfg.kv_block_size
+    nb = -(-plen // bs)
+    S = max(8, 1 << (plen - 1).bit_length())
+    kc = jnp.zeros((cfg.n_layers, nb + 1, bs, cfg.n_kv_heads, cfg.head_dim),
+                   cfg.dtype)
+    vc = jnp.zeros_like(kc)
+    table = np.zeros((max(nb, 1),), np.int32)
+    table[:nb] = np.arange(1, nb + 1)
+    prompt = np.zeros((S,), np.int32)
+    prompt[:plen] = p
+    logits, kc, vc = prefill(
+        S, params, kc, vc, jnp.asarray(table), jnp.asarray(prompt),
+        jnp.int32(plen))
+    return logits, kc, vc, nb
+
+
 def _make_suffix_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     """Jitted prefill of a prompt SUFFIX over a cached prefix: the first
     ``cached_len`` tokens' KV already sit in the request's table blocks
@@ -758,6 +783,30 @@ class PagedEngine:
             # slot + blocks at its next step boundary; without this flag a
             # cancelled stream leaked its KV blocks until pool exhaustion.
             req.aborted = True
+
+    def check_prefill(self, prompt_ids: List[int]) -> Dict[str, Any]:
+        """Prefill's last-position logits against `models.llama.forward` on
+        the same prompt: the paged prefill step and the reference forward
+        are two writings of one model and must agree. Runs on a pool of its
+        own, so the live cache is untouched."""
+        import functools
+
+        import jax
+
+        from ray_tpu.models.llama import forward
+
+        got, _, _, _ = prefill_fresh_pool(
+            self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
+        ref = jax.jit(functools.partial(forward, self.cfg))(
+            self.params, np.asarray([list(prompt_ids)], np.int32))[0, -1]
+        got, ref = np.asarray(got), np.asarray(ref)
+        return {
+            "prompt_tokens": len(prompt_ids),
+            "finite": bool(np.isfinite(got).all()),
+            "max_abs_diff": float(np.abs(got - ref).max()),
+            "max_abs_ref": float(np.abs(ref).max()),
+            "argmax_equal": bool(got.argmax() == ref.argmax()),
+        }
 
     def _publish_metrics(self):
         """Engine telemetry on the metrics plane (constructors are

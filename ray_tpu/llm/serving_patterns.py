@@ -32,7 +32,10 @@ import numpy as np
 
 import ray_tpu
 from ray_tpu.llm import EOS, ByteTokenizer, LLMConfig
-from ray_tpu.llm._engine import EngineConfig
+from ray_tpu.llm._engine import (
+    EngineConfig, _make_prefill, prefill_fresh_pool,
+)
+from ray_tpu.tpu.accelerator import chip_options
 
 
 @ray_tpu.remote
@@ -45,31 +48,12 @@ class PrefillWorker:
         self.config = config
         self.ecfg = EngineConfig(**(engine_config or {}))
         self.cfg, self.params = config.build_model()
-        from ray_tpu.llm._engine import _make_prefill
-
         self._prefill = _make_prefill(self.cfg, self.ecfg)
         self._served = 0
 
     def prefill(self, prompt_ids: List[int]) -> Dict[str, Any]:
-        import jax.numpy as jnp
-
-        p = list(prompt_ids) or [0]
-        plen = len(p)
-        bs = self.ecfg.kv_block_size
-        nb = -(-plen // bs)
-        S = max(8, 1 << (plen - 1).bit_length())
-        # pool sized to exactly this prompt (+ trash block 0)
-        hd = self.cfg.head_dim
-        kc = jnp.zeros((self.cfg.n_layers, nb + 1, bs, self.cfg.n_kv_heads,
-                        hd), self.cfg.dtype)
-        vc = jnp.zeros_like(kc)
-        table = np.zeros((max(nb, 1),), np.int32)
-        table[:nb] = np.arange(1, nb + 1)
-        prompt = np.zeros((S,), np.int32)
-        prompt[:plen] = p
-        logits, kc, vc = self._prefill(
-            S, self.params, kc, vc, jnp.asarray(table), jnp.asarray(prompt),
-            jnp.int32(plen))
+        logits, kc, vc, nb = prefill_fresh_pool(
+            self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
         self._served += 1
         return {
             "k": np.asarray(kc[:, 1:nb + 1]),
@@ -132,10 +116,13 @@ class PrefillDecodeIngress:
         self.tokenizer = ByteTokenizer()
         ecfg = dict(engine_config or {})
         self.block = int(ecfg.get("kv_block_size", 16))
+        # every actor that builds the model asks for its chip
+        chip = chip_options()
         self.prefill_workers = [
-            PrefillWorker.remote(config, ecfg) for _ in range(num_prefill)]
+            PrefillWorker.options(**chip).remote(config, ecfg)
+            for _ in range(num_prefill)]
         self.decoders = [
-            LLMEngine.remote(config, EngineConfig(**ecfg))
+            LLMEngine.options(**chip).remote(config, EngineConfig(**ecfg))
             for _ in range(num_decode)]
         self.router = KvAwareRouter(num_decode, self.block)
         self._pf_rr = 0
@@ -235,13 +222,31 @@ class DPEngineGroup:
         self.config = config
         self.tokenizer = ByteTokenizer()
         ecfg = EngineConfig(**(engine_config or {}))
+        # one engine per chip: each asks for its own
+        chip = chip_options()
         self.engines = [
             LLMEngine.options(runtime_env={"env_vars": {
                 "RT_DP_RANK": str(r), "RT_DP_SIZE": str(dp_size)}},
+                **chip,
             ).remote(config, ecfg)
             for r in range(dp_size)
         ]
         self.load = [0] * dp_size
+
+    async def stats(self) -> List[Dict[str, Any]]:
+        """Per-rank engine stats, each with the device its process holds."""
+        import asyncio
+
+        async def one(e):
+            s, device = await asyncio.gather(
+                e.stats.remote(), e.device_info.remote())
+            return {**s, "device": device}
+
+        return list(await asyncio.gather(*map(one, self.engines)))
+
+    async def check_prefill(self, prompt: str) -> Dict[str, Any]:
+        """Rank 0's prefill-against-forward logits check (see LLMEngine)."""
+        return await self.engines[0].check_prefill.remote(prompt)
 
     async def __call__(self, payload: Dict[str, Any]):
         prompt = payload.get("prompt", "")
@@ -265,7 +270,9 @@ class DPEngineGroup:
         return {
             "object": "text_completion",
             "model": self.config.model_id,
-            "choices": [{"index": 0, "text": text,
+            # token_ids: the byte tokenizer's text drops every id >= 256,
+            # so only the ids say what the model produced
+            "choices": [{"index": 0, "text": text, "token_ids": toks,
                          "finish_reason": "stop" if len(toks) < max_new
                          else "length"}],
             "usage": {"completion_tokens": len(toks), "dp_rank": i},

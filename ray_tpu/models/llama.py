@@ -53,21 +53,25 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    # presets: published widths; keyword arguments override any field (the
+    # chip runs cut depth and set dtypes this way)
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
-        return cls(dim=4096, n_layers=32, n_heads=32, n_kv_heads=32,
-                   ffn_dim=11008, **kw)
+        return cls(**{**dict(dim=4096, n_layers=32, n_heads=32,
+                             n_kv_heads=32, ffn_dim=11008), **kw})
 
     @classmethod
     def llama3_8b(cls, **kw) -> "LlamaConfig":
-        return cls(vocab_size=128256, dim=4096, n_layers=32, n_heads=32,
-                   n_kv_heads=8, ffn_dim=14336, rope_theta=500000.0, **kw)
+        return cls(**{**dict(vocab_size=128256, dim=4096, n_layers=32,
+                             n_heads=32, n_kv_heads=8, ffn_dim=14336,
+                             rope_theta=500000.0), **kw})
 
     @classmethod
     def tiny(cls, **kw) -> "LlamaConfig":
         """Test/CI-size config."""
-        return cls(vocab_size=512, dim=128, n_layers=2, n_heads=4,
-                   n_kv_heads=2, ffn_dim=256, max_seq_len=256, **kw)
+        return cls(**{**dict(vocab_size=512, dim=128, n_layers=2, n_heads=4,
+                             n_kv_heads=2, ffn_dim=256, max_seq_len=256),
+                      **kw})
 
     def num_params(self) -> int:
         hd = self.head_dim
@@ -217,10 +221,6 @@ def attention(cfg: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
         from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 
         return ulysses_attention_sharded(q, k, v, mesh, causal=True)
-    if cfg.attention_impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True)
     return _attention_xla(q, k, v, causal=True)
 
 
@@ -295,7 +295,16 @@ def _layer(cfg: LlamaConfig, mesh: Optional[Mesh], h, layer_params, cos, sin,
         v = jnp.einsum("bsd,dhk->bhsk", x, wv)
         q = apply_rope_bhsd(q, cos, sin)
         k = apply_rope_bhsd(k, cos, sin)
-        o = flash_attention_bhsd(q, k, v, causal=True)
+        attn_fn = partial(flash_attention_bhsd, causal=True)
+        if mesh is not None and mesh.size > 1 and mesh.shape["pp"] == 1:
+            # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+            # shard_map"): run it per shard — batch over the data axes,
+            # heads over tp, every shard holding whole sequences
+            spec = P(BATCH_AXES, "tp", None, None)
+            attn_fn = jax.shard_map(attn_fn, mesh=mesh,
+                                    in_specs=(spec, spec, spec),
+                                    out_specs=spec)
+        o = attn_fn(q, k, v)
         wo = _use(mesh, p["wo"].astype(dt), P("tp", None)).reshape(
             cfg.n_heads, hd, cfg.dim)
         attn = jnp.einsum("bhsk,hkd->bsd", o, wo)

@@ -19,8 +19,10 @@ kernel. A (batch, seq, heads, head_dim) wrapper is kept for callers that use
 the attention-standard layout. GQA is handled in the BlockSpec index maps
 (query heads sharing a kv head read the same k/v block).
 
-On non-TPU backends (CPU tests) everything transparently falls back to a
-fused XLA implementation with identical semantics.
+Off-TPU (CPU tests) the public entry points run a fused XLA implementation
+with identical semantics — it is the reference the kernels are checked
+against. On a TPU backend they run the compiled kernels or raise naming the
+shape constraint that failed; they never quietly become the XLA path.
 
 Reference gap: the reference has no attention kernels at all (delegated to
 vLLM/torch — SURVEY §2b); pallas_guide.md is the kernel playbook used here.
@@ -35,18 +37,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-_INTERPRET = False  # set True to debug kernels on CPU interpreter
+_INTERPRET = False  # test-only: run the kernels in the Pallas interpreter
 
 NEG_INF = -1e30
 
 
-def _compiler_params_cls(pltpu):
-    # jax >= 0.8 spells it CompilerParams; the 0.4.x era TPUCompilerParams
-    return getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+def _out(shape, dtype, *operands):
+    """A pallas_call out_shape entry that varies over the manual mesh axes
+    its operands vary over. Inside shard_map the type system needs that
+    stated (a Mosaic kernel only runs on a multi-chip mesh inside one: GSPMD
+    cannot partition it); outside, the set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ---------------------------------------------------------------------------
-# XLA fallback (CPU tests / unsupported shapes)
+# XLA reference (the only path off-TPU)
 # ---------------------------------------------------------------------------
 
 
@@ -78,10 +84,9 @@ def _kv_streamer(stream, block_k, bi, kh, k_src, v_src, scratch):
     """Returns (warmup, prefetch, load) for the per-iteration K/V tiles.
 
     stream=False: k_src/v_src are whole-s VMEM refs — direct slices, the
-    BlockSpec auto-pipeline overlaps the HBM traffic (fastest; fits scoped
-    VMEM through s=8192). stream=True: k_src/v_src stay in HBM and tiles
-    move through double-buffered VMEM scratch — O(block) VMEM at any
-    seq_len (whole-s refs overflow scoped VMEM at 16k+)."""
+    BlockSpec auto-pipeline overlaps the HBM traffic. stream=True:
+    k_src/v_src stay in HBM and tiles move through double-buffered VMEM
+    scratch — O(block) VMEM at any seq_len."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -168,10 +173,13 @@ def _fwd_kernel(q_ref, k_src, v_src, o_ref, lse_ref, *scratch, causal,
     lse_ref[0, 0] = m + jnp.log(l)
 
 
-# whole-s VMEM refs beat manual streaming while they fit under the 16MB
-# scoped-VMEM ceiling (the BlockSpec auto-pipeline overlaps grid steps);
-# measured cliffs on v5e with 512-blocks, hd=128: fwd/dq whole-s k/v holds
-# through s=8192, dkv whole-s q/do through s=4096
+# Whole-s VMEM refs (the BlockSpec auto-pipeline overlaps grid steps) while
+# they fit scoped VMEM, manual streaming above. What the compiler accepts,
+# from an ahead-of-time compile for "TPU v5 lite" (libtpu 0.0.34, 512-blocks,
+# hd=128, bf16): whole-s fwd compiles at s=8192 and overflows the 16 MB
+# scoped limit at 16384 (16.5 MB); whole-s dq+dkv compile at 16384 and
+# overflow at 32768. The dkv threshold is therefore conservative; which side
+# of either threshold is faster has not been measured.
 _STREAM_KV_ELEMS = 8192 * 128    # fwd + dq: stream k/v above this s*hd
 _STREAM_QDO_ELEMS = 4096 * 128   # dkv: stream q/do above this s*hd
 
@@ -182,8 +190,8 @@ def _qdo_specs(stream, s, hd, block_q, qdt, gdt):
     from jax.experimental.pallas import tpu as pltpu
 
     if stream:
-        specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
+        specs = [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
         scratch = [
             pltpu.VMEM((2, block_q, hd), qdt),
             pltpu.VMEM((2, block_q, hd), gdt),
@@ -204,8 +212,8 @@ def _kv_specs(stream, s, hd, block_k, kdt, vdt, rep):
     from jax.experimental.pallas import tpu as pltpu
 
     if stream:
-        specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
+        specs = [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
         scratch = [
             pltpu.VMEM((2, block_k, hd), kdt),
             pltpu.VMEM((2, block_k, hd), vdt),
@@ -241,8 +249,8 @@ def _flash_fwd_tpu(q, k, v, causal, block_q, block_k):
     o, lse = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
+            _out((b, h, s, hd), q.dtype, q, k, v),
+            _out((b, h, s, 1), jnp.float32, q, k, v),
         ),
         grid=grid,
         in_specs=[
@@ -254,7 +262,7 @@ def _flash_fwd_tpu(q, k, v, causal, block_q, block_k):
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
         ),
         scratch_shapes=kv_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -322,9 +330,7 @@ def _dkv_kernel(q_src, k_ref, v_ref, do_src, lse_ref, delta_ref,
                 seq_len, rep, stream):
     """dk/dv for one (batch, query-head, k-block). stream=True moves q/do
     tiles from HBM through double-buffered VMEM scratch (O(block) VMEM at
-    any seq_len — the whole-s q/do BlockSpec was the 8k/16k compile
-    failure); stream=False keeps them whole-s in VMEM (faster when they
-    fit). lse/delta always arrive as (b, h, 1, s) LANE-major rows, whole-s
+    any seq_len); stream=False keeps them whole-s in VMEM. lse/delta always arrive as (b, h, 1, s) LANE-major rows, whole-s
     in VMEM: that layout pads only the sublane dim (8·s·4B, vs 128·s·4B
     for (s, 1) columns); each q-tile's rows are relayouted to a
     (block_q, 1) column in-kernel, which Mosaic supports."""
@@ -433,7 +439,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
                                      v.dtype, rep)
     dq = pl.pallas_call(
         dq_kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
+        out_shape=_out((b, h, s, hd), q.dtype, q, k, v, g),
         grid=(b, h, s // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -445,7 +451,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         scratch_shapes=kv_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -467,8 +473,8 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
     dk, dv = pl.pallas_call(
         dkv_kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, s, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s, hd), jnp.float32),
+            _out((b, h, s, hd), jnp.float32, q, k, v, g),
+            _out((b, h, s, hd), jnp.float32, q, k, v, g),
         ),
         grid=(b, h, s // dkv_block_k),
         in_specs=[
@@ -484,7 +490,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
             pl.BlockSpec((1, 1, dkv_block_k, hd), lambda bi, hi, ki: (bi, hi, ki, 0)),
         ),
         scratch_shapes=qdo_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -603,9 +609,9 @@ def _flash_chunk_tpu(q, k, v, o, m, l, causal, block_q, block_k):
     return pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, sq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+            _out((b, h, sq, hd), jnp.float32, q, k, v, o, m, l),
+            _out((b, h, sq, 1), jnp.float32, q, k, v, o, m, l),
+            _out((b, h, sq, 1), jnp.float32, q, k, v, o, m, l),
         ),
         grid=(b, h, sq // block_q),
         in_specs=[
@@ -621,7 +627,7 @@ def _flash_chunk_tpu(q, k, v, o, m, l, causal, block_q, block_k):
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
         ),
         scratch_shapes=kv_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -634,16 +640,29 @@ def _flash_chunk_tpu(q, k, v, o, m, l, causal, block_q, block_k):
     )(q, k, v, o, m, l)
 
 
-def _chunk_supported(q, k, block_q, block_k):
-    sq, hd = q.shape[2], q.shape[3]
-    sk = k.shape[2]
-    return (
-        jax.default_backend() == "tpu"
-        and sq % min(block_q, sq) == 0
-        and sk % min(block_k, sk) == 0
-        and hd % 128 == 0
-        and q.shape[1] % k.shape[1] == 0
-    )
+def _kernel_path(what, q, k, block_q, block_k) -> bool:
+    """Whether `what` runs its Pallas kernel on q (b, h, sq, hd) against
+    k (b, kvh, sk, hd). Off-TPU: False, the XLA reference runs (unless a
+    test switched the interpreter on). On a TPU backend: True, or ValueError
+    naming every violated constraint — an unsupported shape must not
+    quietly become the XLA path there."""
+    if jax.default_backend() != "tpu" and not _INTERPRET:
+        return False
+    (h, sq, hd), (kvh, sk) = q.shape[1:], k.shape[1:3]
+    need = []
+    if hd % 128:
+        need.append(f"head_dim % 128 == 0 (head_dim={hd})")
+    if h % kvh:
+        need.append(f"heads % kv_heads == 0 ({h} % {kvh})")
+    if sq % block_q:
+        need.append(f"q_len % block_q == 0 ({sq} % {block_q})")
+    if sk % block_k:
+        need.append(f"kv_len % block_k == 0 ({sk} % {block_k})")
+    if need:
+        raise ValueError(
+            f"{what}: no Pallas kernel for this shape on TPU; it needs "
+            + "; ".join(need))
+    return True
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -657,10 +676,10 @@ def flash_chunk_bhsd(q, k, v, o, m, l, causal=False,
     O(s·d) per hop instead of the O(s²/sp) probability blocks JAX autodiff
     would save.
     """
-    if _chunk_supported(q, k, block_q, block_k):
-        return _flash_chunk_tpu(q, k, v, o, m, l, causal,
-                                min(block_q, q.shape[2]),
-                                min(block_k, k.shape[2]))
+    bq = min(block_q, q.shape[2])
+    bk = min(block_k, k.shape[2])
+    if _kernel_path("flash_chunk_bhsd", q, k, bq, bk):
+        return _flash_chunk_tpu(q, k, v, o, m, l, causal, bq, bk)
     return _chunk_xla(q, k, v, o, m, l, causal)
 
 
@@ -742,7 +761,7 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
                                      v.dtype, rep)
     dq = pl.pallas_call(
         dq_kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, hd), jnp.float32),
+        out_shape=_out((b, h, sq, hd), jnp.float32, q, k, v, g),
         grid=(b, h, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -754,7 +773,7 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, 1, block_q, hd),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         scratch_shapes=kv_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_INTERPRET,
@@ -770,8 +789,8 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
     dk, dv = pl.pallas_call(
         dkv_kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, sk, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sk, hd), jnp.float32),
+            _out((b, h, sk, hd), jnp.float32, q, k, v, g),
+            _out((b, h, sk, hd), jnp.float32, q, k, v, g),
         ),
         grid=(b, h, sk // dkv_block_k),
         in_specs=[
@@ -787,7 +806,7 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
             pl.BlockSpec((1, 1, dkv_block_k, hd), lambda bi, hi, ki: (bi, hi, ki, 0)),
         ),
         scratch_shapes=qdo_scratch,
-        compiler_params=_compiler_params_cls(pltpu)(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_INTERPRET,
@@ -804,8 +823,7 @@ def flash_hop_bwd(q, k, v, g, lse, delta, causal,
     """Backward of one ring-attention hop given global lse/delta rows."""
     bq = min(block_q, q.shape[2])
     bk = min(block_k, k.shape[2])
-    if _chunk_supported(q, k, bq, bk):
-        # streamed dq/dkv kernels: O(block) VMEM at any per-shard length
+    if _kernel_path("flash_hop_bwd", q, k, bq, bk):
         return _hop_bwd_tpu(q, k, v, g, lse, delta, causal, bq, bk,
                             dkv_block_q=bq, dkv_block_k=bk)
     return _hop_bwd_xla(q, k, v, g, lse, delta, causal)
@@ -816,31 +834,16 @@ def flash_hop_bwd(q, k, v, g, lse, delta, causal,
 # ---------------------------------------------------------------------------
 
 
-def _supported_on_tpu(q, k, block_q, block_k):
-    # NOTE: the dkv kernel's causal start block `(ki*block_k)//block_q` is a
-    # floor and stays correct for ANY block_q/block_k combination (including
-    # the mismatched 512/256 long-context backward blocks), so no
-    # divisibility constraint between the two is required.
-    b, h, s, hd = q.shape
-    return (
-        jax.default_backend() == "tpu"
-        and s % block_q == 0
-        and s % block_k == 0
-        and hd % 128 == 0
-        and h % k.shape[1] == 0
-    )
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_bhsd(q, k, v, causal, block_q, block_k, bwd_block_q, bwd_block_k):
-    if _supported_on_tpu(q, k, block_q, block_k):
+    if _kernel_path("flash_attention", q, k, block_q, block_k):
         return _flash_fwd_tpu(q, k, v, causal, block_q, block_k)[0]
     return _xla_attention_bhsd(q, k, v, causal)
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, bwd_block_q,
                     bwd_block_k):
-    if _supported_on_tpu(q, k, block_q, block_k):
+    if _kernel_path("flash_attention", q, k, block_q, block_k):
         o, lse = _flash_fwd_tpu(q, k, v, causal, block_q, block_k)
         return o, (q, k, v, o, lse)
     return _xla_attention_bhsd(q, k, v, causal), (q, k, v, None, None)

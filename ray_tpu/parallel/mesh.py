@@ -126,17 +126,9 @@ def constrain(x, mesh: Mesh, spec: P):
 
 
 def to_varying(x, axes):
-    """Mark `x` as varying over manual mesh `axes` inside shard_map —
-    pcast on jax >= 0.9, pvary before (shared by ring_attention/pipeline)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axes), to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, tuple(axes))
-    # check_rep-era jax has no varying-axis system at all — the mark is
-    # meaningless there, and identity is exactly what pvary lowers to
-    return x
+    """Mark `x` as varying over manual mesh `axes` inside shard_map
+    (shared by ring_attention/pipeline)."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def host_local_mesh_info(mesh: Mesh) -> dict:

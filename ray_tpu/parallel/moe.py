@@ -26,6 +26,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -144,18 +145,6 @@ def moe_ffn_ep(params: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
             aux = jax.lax.pmean(aux, token_axes)
         return y.astype(x_local.dtype), aux
 
-    try:
-        from jax import shard_map  # jax >= 0.8 surface (no check_rep kwarg)
-
-        # y/aux are replicated over the ep axis by construction (the reverse
-        # all_to_all returns every token's outputs to its home shard), which
-        # the varying-axis checker cannot infer through the exchange
-        smap_kwargs = {"check_vma": False}
-    except ImportError:  # pre-0.8: the experimental surface, check_rep era
-        from jax.experimental.shard_map import shard_map
-
-        smap_kwargs = {"check_rep": False}
-
     param_specs = {
         "router": P(),            # replicated
         "w_in": P(axis),          # experts sharded over the ep axis
@@ -165,5 +154,8 @@ def moe_ffn_ep(params: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
         local, mesh=mesh,
         in_specs=(param_specs, tokens_spec),
         out_specs=(tokens_spec, P()),
-        **smap_kwargs,
+        # y/aux are replicated over the ep axis by construction (the reverse
+        # all_to_all returns every token's outputs to its home shard), which
+        # the varying-axis checker cannot infer through the exchange
+        check_vma=False,
     )(params, x)

@@ -13,16 +13,7 @@ parallelism — not present in the reference".
 from __future__ import annotations
 
 import jax
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.8 container: the experimental check_rep surface
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = _functools.partial(_shard_map, check_rep=False)
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import BATCH_AXES

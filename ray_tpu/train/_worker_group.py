@@ -198,6 +198,15 @@ class TrainWorker:
         def run():
             ctx_mod.set_context(ctx)
             try:
+                from ray_tpu.tpu import accelerator as tpu_accel
+
+                if self.world_size == 1 and os.environ.get(
+                        tpu_accel.GRANTED_CHIPS_ENV):
+                    # a chip-holding worker checks what JAX shows it against
+                    # its grant before the train fn builds anything (a
+                    # multi-host gang must join jax.distributed first:
+                    # setup_jax_distributed checks there)
+                    tpu_accel.check_granted_devices()
                 if config is not None:
                     train_fn(config)
                 else:

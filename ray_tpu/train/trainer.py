@@ -165,8 +165,18 @@ class JaxTrainer(DataParallelTrainer):
     def __init__(self, train_loop_per_worker: Callable, **kwargs):
         scaling = kwargs.get("scaling_config") or ScalingConfig()
         if scaling.use_tpu and not scaling.resources_per_worker:
-            # one worker process per TPU host, owning all its chips
-            scaling.resources_per_worker = {"TPU": 4.0}
+            # one worker process per TPU host, owning all its chips: take
+            # the count the hosts advertise, never an assumed one
+            import ray_tpu
+
+            per_host = [n["resources"].get("TPU", 0) for n in ray_tpu.nodes()
+                        if n["state"] == "ALIVE"]
+            chips = min((c for c in per_host if c > 0), default=0)
+            if not chips:
+                raise ValueError(
+                    "ScalingConfig(use_tpu=True) but no alive node "
+                    "advertises a TPU resource")
+            scaling.resources_per_worker = {"TPU": float(chips)}
         kwargs["scaling_config"] = scaling
         super().__init__(train_loop_per_worker, **kwargs)
 
@@ -193,3 +203,6 @@ def setup_jax_distributed(local_device_count: Optional[int] = None) -> None:
         coordinator_address=coord, num_processes=world, process_id=rank,
         **kwargs,
     )
+    from ray_tpu.tpu.accelerator import check_granted_devices
+
+    check_granted_devices()

@@ -147,34 +147,53 @@ def test_collective_send_recv(ray_init):
 
 
 def test_tpu_detection_from_env(monkeypatch):
+    """Chips are counted (device files, or the override), never assumed
+    from the generation; the slice shape comes from TPU_ACCELERATOR_TYPE."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-16")
     monkeypatch.setenv("TPU_WORKER_ID", "0")
-    info = TpuAcceleratorManager.detect(allow_jax_probe=False)
+    assert TpuAcceleratorManager.detect() is None  # no chip device files
+    GLOBAL_CONFIG.apply_system_config({"tpu_chips_per_host": 4})
+    info = TpuAcceleratorManager.detect()
     assert info is not None
     assert info.generation == "v5e"
     assert info.pod_type == "v5e-16"
-    assert info.chips_on_host == 8
-    assert info.hosts_in_slice == 2
+    assert info.chips_on_host == 4
+    assert info.hosts_in_slice == 4
     res, labels = TpuAcceleratorManager.node_resources_and_labels(info)
-    assert res["TPU"] == 8.0
-    assert res["TPU-v5e"] == 8.0
+    assert res["TPU"] == 4.0
+    assert res["TPU-v5e"] == 4.0
     assert res["TPU-v5e-16-head"] == 1.0  # worker 0 = slice head
     assert labels["tpu-pod-type"] == "v5e-16"
 
     monkeypatch.setenv("TPU_WORKER_ID", "1")
-    info2 = TpuAcceleratorManager.detect(allow_jax_probe=False)
+    info2 = TpuAcceleratorManager.detect()
     res2, _ = TpuAcceleratorManager.node_resources_and_labels(info2)
     assert "TPU-v5e-16-head" not in res2
+
+    # a host that does not say what it is still advertises its chips
+    monkeypatch.delenv("TPU_ACCELERATOR_TYPE")
+    res3, _ = TpuAcceleratorManager.node_resources_and_labels()
+    assert res3 == {"TPU": 4.0}
 
 
 def test_visible_chips_env():
     env = {}
     TpuAcceleratorManager.set_visible_chips_env(env, [0, 1], chips_per_host=8)
-    assert env["TPU_VISIBLE_CHIPS"] == "0,1"
-    assert env["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,2,1"
+    assert env == {"TPU_VISIBLE_CHIPS": "0,1",
+                   "TPU_CHIPS_PER_HOST_BOUNDS": "1,2,1",
+                   "TPU_HOST_BOUNDS": "1,1,1"}
+    env1 = {}
+    TpuAcceleratorManager.set_visible_chips_env(env1, [3], chips_per_host=4)
+    assert env1 == {"TPU_VISIBLE_CHIPS": "3",
+                    "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+                    "TPU_HOST_BOUNDS": "1,1,1"}
     env2 = {}
     TpuAcceleratorManager.set_visible_chips_env(env2, list(range(8)), 8)
     assert env2 == {}  # full host: leave libtpu defaults
+    with pytest.raises(ValueError, match="sub-host grants"):
+        TpuAcceleratorManager.set_visible_chips_env({}, [0, 1, 2], 4)
 
 
 def test_megascale_env():
@@ -213,9 +232,9 @@ def test_reducescatter_output_never_replicated_and_permute(ray_init):
         def __init__(self, rank, world):
             os.environ["JAX_PLATFORMS"] = "cpu"
             # TWO local CPU devices per process: the mesh must use both.
-            # Old jax only honors the XLA_FLAGS spelling, so rewrite it
-            # BEFORE the first jax import in this fresh worker process
-            # (dropping any inherited device-count flag, e.g. conftest's 8).
+            # Rewrite XLA_FLAGS BEFORE the first jax import in this fresh
+            # worker process, dropping the inherited device-count flag
+            # (conftest's 8).
             flags = [
                 f for f in os.environ.get("XLA_FLAGS", "").split()
                 if "xla_force_host_platform_device_count" not in f
@@ -225,10 +244,7 @@ def test_reducescatter_output_never_replicated_and_permute(ray_init):
             import jax
 
             jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update("jax_num_cpu_devices", 2)
-            except AttributeError:  # pre-config-option jax: XLA_FLAGS rules
-                pass
+            jax.config.update("jax_num_cpu_devices", 2)
             self.rank, self.world = rank, world
 
         def run(self):

@@ -20,21 +20,6 @@ CFG = LlamaConfig(
     dtype=jnp.float32, param_dtype=jnp.float32,
 )
 
-# The pp schedule is written against the modern shard_map surface
-# (partial-manual via axis_names= plus jax.lax.pvary varying marks); old
-# jax has neither, and backporting partial-manual to the check_rep era is
-# a rewrite, not a shim. Tracked in ROADMAP ("pre-existing tier-1 triage").
-_OLD_SMAP = not (
-    hasattr(jax, "shard_map")  # axis_names= partial-manual surface
-    and (hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast"))  # to_varying
-)
-needs_modern_shard_map = pytest.mark.skipif(
-    _OLD_SMAP,
-    reason="pipeline pp schedule needs jax.shard_map axis_names=/pvary "
-           "(partial-manual); this jax predates both",
-)
-
-
 def _tokens(batch=8, seq=32):
     return jax.random.randint(
         jax.random.key(1), (batch, seq), 0, CFG.vocab_size, dtype=jnp.int32)
@@ -63,7 +48,6 @@ def _run_pipelined(tokens, spec: MeshSpec, n_micro, steps=2, lr=1e-2):
     return losses
 
 
-@needs_modern_shard_map
 def test_two_stage_loss_parity_with_single_stage():
     """The VERDICT's done-criterion: a 2-stage split trains with loss parity
     against single-stage (same init, same data, same optimizer)."""
@@ -73,7 +57,6 @@ def test_two_stage_loss_parity_with_single_stage():
     np.testing.assert_allclose(base, pp, rtol=2e-3)
 
 
-@needs_modern_shard_map
 def test_pipeline_composes_with_dp_and_tp():
     tokens = _tokens()
     base = _run_single_stage(tokens)
@@ -81,7 +64,6 @@ def test_pipeline_composes_with_dp_and_tp():
     np.testing.assert_allclose(base, pp, rtol=2e-3)
 
 
-@needs_modern_shard_map
 def test_four_stage_deep_pipeline():
     tokens = _tokens()
     base = _run_single_stage(tokens)
