@@ -17,8 +17,10 @@ Exit 0 only if every phase passed on TPU chips. This process never
 initialises a JAX backend (a parent that holds the chip starves its
 workers): chip count comes from ray_tpu.cluster_resources(), platform and
 device_kind from the processes that hold the chips. Each phase prints one
-JSON line; the last line of stdout is the summary. Timings are smoke timings
-of one run, not metrics. Nothing here claims a gain: "claim" is null.
+JSON line, then a summary line ("claim" is null: nothing here claims a gain),
+and the last line of stdout is the result the chip check reads, with exactly
+these keys: {"ok": true, "device": {"platform", "kind", "count"}}. A failed
+run prints no result line. Timings are smoke timings of one run, not metrics.
 """
 
 from __future__ import annotations
@@ -659,15 +661,19 @@ def main(argv=None) -> int:
             print("chip_smoke: FAILED: the driver process initialised a JAX "
                   "backend", file=sys.stderr)
             return 1
+    print(json.dumps({
+        "summary": "chip_smoke", "phases": {n: "ok" for n in names},
+        "preset": args.preset, "driver_jax_backend_initialised": False,
+        "claim": None,
+    }), flush=True)
+    # the result line the chip check reads: exactly these keys, the device as
+    # JAX reported it to the process(es) that held the chips in the last phase
     last = results[names[-1]]
     print(json.dumps({
         "ok": True,
-        "device": {"platform": last["platform"], "kind": last["device_kind"],
-                   "count": last["device_count"]},
-        "phases": {n: "ok" for n in names},
-        "preset": args.preset,
-        "driver_jax_backend_initialised": False,
-        "claim": None,
+        "device": {"platform": str(last["platform"]),
+                   "kind": str(last["device_kind"]),
+                   "count": int(last["device_count"])},
     }), flush=True)
     return 0
 

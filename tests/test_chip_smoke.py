@@ -41,6 +41,30 @@ def test_failing_phase_fails_the_run(monkeypatch):
     assert not ray_tpu.is_initialized()
 
 
+def test_last_stdout_line_is_the_result_and_nothing_else(monkeypatch, capsys):
+    """The chip check reads the last line of stdout: one JSON object with
+    exactly "ok" and "device" {"platform", "kind", "count"}. Everything else
+    (phases, "claim": null) goes on the summary line before it."""
+    import json
+
+    def passed(chips, preset):
+        return {"phase": "serve", "ok": True, "platform": "cpu",
+                "device_kind": "cpu", "device_count": chips}
+
+    monkeypatch.setitem(chip_smoke.PHASES, "serve", passed)
+    # the test process, unlike a chip_smoke.py driver, may hold a (CPU)
+    # backend from an earlier test; main() would rightly fail on that
+    from jax._src import xla_bridge
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: False)
+    assert chip_smoke.main(["--preset", "tiny", "--phase", "serve"]) == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 2}}
+    summary = json.loads(lines[-2])
+    assert summary["claim"] is None and summary["phases"] == {"serve": "ok"}
+
+
 def test_worker_platform_pinning(monkeypatch):
     """Granted -> tpu, ungranted -> cpu, unless the daemon's environment
     names platforms without TPU (tier-1's cpu), which wins."""
