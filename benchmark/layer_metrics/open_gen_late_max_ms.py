@@ -1,0 +1,4 @@
+"""`gen_late_max_ms` in a cell that is judged on request time."""
+from benchmark.layer_metrics.gen_late_max_ms import LAYER, SOURCE, UNIT, read  # noqa: F401
+
+MOVES = "req_p50_s"

@@ -1,0 +1,4 @@
+"""`prefix_hit_share` in a cell that is judged on request time."""
+from benchmark.layer_metrics.prefix_hit_share import LAYER, SOURCE, UNIT, read  # noqa: F401
+
+MOVES = "req_p50_s"

@@ -1,0 +1,448 @@
+"""Runner `serve_dp`: the served path as a user calls it.
+
+    ray_tpu.init() -> serve.start() -> build_dp_app(dp_size = chips) ->
+    HTTP POSTs through the proxy -> DPEngineGroup -> LLMEngine -> PagedEngine
+
+One engine process per chip. This process generates the load (one thread,
+asyncio) and never initialises a JAX backend. The recipe is chip_smoke.py's;
+the clocks, the traffic and the checks are the benchmark's own.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import math
+import time
+from typing import Any, Dict, List
+
+from benchmark.lib import stats as st
+from benchmark.lib.config import CellFailure, model_overrides, published
+from benchmark.runners import _inside
+
+DEPLOYMENT = "bench"
+REQUEST_TIMEOUT_S = 240.0
+# how long after the close a closed loop waits for the requests that straddle it
+DRAIN_CAP_S = 90.0
+# What makes a run not `correct` besides a wrong or failed answer. These are
+# the yardstick's own and no traffic file can set them:
+#   late_share     the generator's worst lateness (send - due) as a share of
+#                  the median request time: a starved generator must not be
+#                  read as a fast server;
+#   counter_share  how far a closed loop's client-side `out_tokens_per_s` may
+#                  lie from the engine's own count (the change of `tokens_out`
+#                  over the window). The estimate spreads each answer's tokens
+#                  over its lifetime, which holds while nothing waits in front
+#                  of the engine: 27 chip runs without a queue read -2.3% to
+#                  +0.6% of the count (26 of them within 0.9%), 7 runs with
+#                  64 callers on 32 slots +3.7% to +7.0% (PR 22). 3% parts
+#                  the two: beyond it the estimate reads the queue and not
+#                  the engine. It is a third of the metric's bound (10%,
+#                  set by the host's noise and not by the estimate).
+# On the CPU the system under test starves the generator of cores and a window
+# holds a few dozen tokens, so the rehearsal only runs the arithmetic.
+LIMITS = {"late_share": 0.05, "counter_share": 0.03}
+REHEARSAL_LIMITS = {"late_share": 1.0, "counter_share": 0.5}
+# how far under a position's largest reference logit a returned token's logit
+# may lie. System and reference differ in precision only: bf16 weights are
+# exact in float32, so the gap comes from bf16 activations (8 significant
+# bits: a value of magnitude m is known to m * 2^-8) through the layers and
+# from the order of summation. Both the returned token's logit and the
+# largest one carry that error: allowed are 6 bf16 steps of the largest
+# |logit| (measured on v5e, PR 22: the worst of 45 runs was 2.6 steps, a gap
+# of 0.049 at a largest |logit| of 4.8, where the system chose the reference's
+# second candidate). A wrong mask, position
+# or block table moves random-weight logits by whole units (their spread is
+# about 1, the largest of 32768 about 4.5) and fails; so would int8 or fp8
+# activations, which are 16 times coarser than bf16.
+CHECK_TOLERANCE_BF16_STEPS = 6.0
+
+
+# --------------------------------------------------------------------------
+# HTTP client
+# --------------------------------------------------------------------------
+
+
+async def post(session, url: str, req: Dict[str, Any]) -> Dict[str, Any]:
+    """One completion. Returns {"ok", "token_ids", "error"}: anything but the
+    requested number of tokens (or fewer, ended by EOS) is a failure."""
+    import aiohttp
+
+    payload = {"prompt": req["prompt"], "max_tokens": req["max_tokens"],
+               "temperature": 0.0}
+    try:
+        async with session.post(url, json=payload) as resp:
+            status = resp.status
+            body = await resp.json(content_type=None)
+    except (aiohttp.ClientError, asyncio.TimeoutError, ValueError) as e:
+        return {"ok": False, "token_ids": [], "error": repr(e)}
+    result = body.get("result") if isinstance(body, dict) else None
+    if status != 200 or not isinstance(result, dict) or not result.get("choices"):
+        return {"ok": False, "token_ids": [],
+                "error": f"HTTP {status}: {str(body)[:300]}"}
+    choice = result["choices"][0]
+    ids = choice.get("token_ids") or []
+    want = req["max_tokens"]
+    ok = len(ids) == want or (len(ids) < want
+                              and choice.get("finish_reason") == "stop")
+    return {"ok": ok, "token_ids": ids,
+            "error": None if ok else f"{len(ids)} of {want} tokens"}
+
+
+class Load:
+    """The load generator: sends requests on this process's event loop and
+    keeps one record per request, all on `time.monotonic()`."""
+
+    def __init__(self, url: str):
+        self.url = url
+        self.records: List[Dict[str, Any]] = []
+        self.session = None
+
+    async def __aenter__(self):
+        import aiohttp
+
+        self.session = aiohttp.ClientSession(
+            connector=aiohttp.TCPConnector(limit=0),
+            timeout=aiohttp.ClientTimeout(total=REQUEST_TIMEOUT_S))
+        return self
+
+    async def __aexit__(self, *exc):
+        await self.session.close()
+
+    async def send(self, req: Dict[str, Any], due: float) -> Dict[str, Any]:
+        rec = {"tag": req.get("tag", ""), "due": due, "sent": time.monotonic(),
+               "prompt_tokens": req["prompt_tokens"],
+               "max_tokens": req["max_tokens"]}
+        self.records.append(rec)
+        try:
+            out = await post(self.session, self.url, req)
+        except asyncio.CancelledError:
+            rec["cancelled"] = True
+            raise
+        rec.update(done=time.monotonic(), ok=out["ok"], error=out["error"],
+                   tokens=len(out["token_ids"]), token_ids=out["token_ids"])
+        return rec
+
+
+async def sleep_until(t: float) -> None:
+    delay = t - time.monotonic()
+    if delay > 0:
+        await asyncio.sleep(delay)
+
+
+async def open_loop(load: Load, schedule: List[Dict[str, Any]], t_open: float
+                    ) -> None:
+    """Send each request at its due time whatever the state of the others.
+    The tail (tag "t") is sent only while requests of the window are in
+    flight, so that those finish under the load they started under."""
+    tasks, in_window = [], []
+    for req in schedule:
+        due = t_open + req["due"]
+        if req["tag"] == "t" and all(t.done() for t in in_window):
+            break
+        await sleep_until(due)
+        task = asyncio.ensure_future(load.send(req, due))
+        tasks.append(task)
+        if req["tag"] == "w":
+            in_window.append(task)
+    await asyncio.gather(*in_window)
+    for t in tasks:
+        t.cancel()
+    await asyncio.gather(*tasks, return_exceptions=True)
+
+
+async def closed_loop(load: Load, streams: List[Any], t_open: float,
+                      ramp_s: float, window_s: float) -> None:
+    """Each caller sends its next request when the last is answered. Callers
+    start spread over the first half of the ramp and keep going after the
+    close, under the same load, until every request sent before it is
+    answered (at most `DRAIN_CAP_S`): the window's throughput needs the end
+    of each request that straddles its close."""
+    t_close = t_open + window_s
+
+    async def caller(i: int, stream) -> None:
+        due = t_open - ramp_s + 0.5 * ramp_s * i / max(1, len(streams))
+        await sleep_until(due)
+        for req in stream:
+            rec = await load.send(req, due)
+            due = rec["done"]
+
+    tasks = [asyncio.ensure_future(caller(i, s)) for i, s in enumerate(streams)]
+    await sleep_until(t_close)
+    while (time.monotonic() < t_close + DRAIN_CAP_S
+           and any("done" not in r for r in load.records if r["sent"] < t_close)
+           and not any(t.done() for t in tasks)):
+        await asyncio.sleep(0.05)
+    for t in tasks:
+        t.cancel()
+    results = await asyncio.gather(*tasks, return_exceptions=True)
+    for r in results:
+        if isinstance(r, Exception) and not isinstance(
+                r, asyncio.CancelledError):
+            raise r
+
+
+# --------------------------------------------------------------------------
+# set-up: warm-up and the correctness sample
+# --------------------------------------------------------------------------
+
+
+def warm_requests(traffic: Dict[str, Any], seed: int) -> List[Dict[str, Any]]:
+    """One request per shape the cell's traffic can use (the traffic file
+    lists them): each prompt length in `prefill_tokens` lands in its own
+    prefill bucket; each entry of `suffix_tokens` shares the first KV block
+    of the first prompt and so runs that suffix bucket over a cached
+    prefix. Two tokens each, so the decode step runs too."""
+    from benchmark.traffic import _common as c
+
+    warm = traffic["warm"]
+    rng = c.rng_for(seed, 90)
+    out = [c.request(c.prompt_of(rng, n, f"warm{seed:x}.{i:x}"), 2)
+           for i, n in enumerate(warm["prefill_tokens"])]
+    block = int(traffic["kv_block_size"])
+    shared = out[0]["prompt"][: block - 1]
+    for j, n in enumerate(warm["suffix_tokens"]):
+        out.append(c.request(shared + c.text(rng, n, head=f"s{j:x} "), 2))
+    return out
+
+
+def check_requests(generator, traffic: Dict[str, Any], seed: int
+                   ) -> List[Dict[str, Any]]:
+    """The seeded sample that is held against the reference: at the cell's
+    own lengths, through the same HTTP path."""
+    from benchmark.traffic import _common as c
+
+    chk = traffic["check"]
+    if "session_of_client" in chk:
+        stream = generator.stream(traffic, seed, int(chk["session_of_client"]))
+        reqs = [next(stream) for _ in range(int(chk["requests"]))]
+    else:
+        rng = c.rng_for(seed, 91)
+        reqs = [c.request(c.prompt_of(rng, n, f"chk{seed:x}.{i:x}"), 0)
+                for i, n in enumerate(chk["prompt_tokens"])]
+        reqs += [dict(reqs[i]) for i in chk["repeat"]]
+    return [{**r, "max_tokens": int(chk["answer_tokens"]), "tag": "check"}
+            for r in reqs]
+
+
+def judge_check(gaps: List[Dict[str, Any]], tol_steps: float) -> Dict[str, Any]:
+    worst_steps, worst_gap, agree, total = 0.0, 0.0, 0, 0
+    for g in gaps:
+        step = 2.0 ** -8 * max(1.0, g["max_abs_logit"])
+        worst_gap = max(worst_gap, max(g["gaps"]))
+        worst_steps = max(worst_steps, max(g["gaps"]) / step)
+        agree += g["argmax_equal"]
+        total += len(g["gaps"])
+    return {"ok": worst_steps <= tol_steps, "worst_gap": worst_gap,
+            "worst_gap_bf16_steps": worst_steps, "tolerance_steps": tol_steps,
+            "argmax_equal": f"{agree}/{total}"}
+
+
+# --------------------------------------------------------------------------
+# the run
+# --------------------------------------------------------------------------
+
+
+def sum_stats(per_rank: List[Dict[str, Any]]) -> Dict[str, Any]:
+    out: Dict[str, Any] = {k: sum(s[k] for s in per_rank)
+                           for k in ("steps", "tokens_out",
+                                     "mid_decode_admissions", "blocks_in_use")}
+    caches = [s["prefix_cache"] for s in per_rank if s.get("prefix_cache")]
+    out["prefix_cache"] = {k: sum(c[k] for c in caches)
+                           for k in ("hits", "block_hits", "misses",
+                                     "evictions")} if caches else None
+    ttfts = [s["ttft_p50_s"] for s in per_rank if "ttft_p50_s" in s]
+    out["ttft_p50_s"] = st.median(ttfts) if ttfts else None
+    return out
+
+
+def run(ctx) -> Dict[str, Any]:
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.llm import BOS, LLMConfig
+    from ray_tpu.llm.serving_patterns import build_dp_app
+
+    cfg, traffic, cell = ctx.config, ctx.traffic, ctx.cell
+    chips, seed, window_s = int(cell["chips"]), ctx.seed, float(ctx.seconds)
+    generator = ctx.generator
+    engine_cfg = dict(cfg["engine"])
+    traffic = {**traffic, "kv_block_size": engine_cfg["kv_block_size"]}
+
+    base = serve.start(http_port=0)
+    llm = LLMConfig(model_id=cell["config"], model=cfg["program"]["preset"],
+                    model_overrides=model_overrides(cfg), seed=seed,
+                    max_new_tokens=16)
+    handle = build_dp_app(llm, dp_size=chips, deployment_name=DEPLOYMENT,
+                          engine_config=engine_cfg)
+    url = f"{base}/{DEPLOYMENT}"
+    replica = handle._replicas[0]
+    engines = ray_tpu.get(
+        replica.__rt_call__.remote(_inside.replica_engines), timeout=900)
+    if len(engines) != chips:
+        raise CellFailure(f"{len(engines)} engines for {chips} chips")
+
+    def engine_stats() -> Dict[str, Any]:
+        return sum_stats(ray_tpu.get([e.stats.remote() for e in engines],
+                                     timeout=120))
+
+    infos = ray_tpu.get([e.device_info.remote() for e in engines], timeout=900)
+    device = ctx.check_devices(
+        [(i["platform"], i["device_kind"], i["device_count"]) for i in infos])
+
+    async def drive() -> Dict[str, Any]:
+        art: Dict[str, Any] = {}
+        async with Load(url) as load:
+            # 1. every shape the window will use, one request each
+            for req in warm_requests(traffic, seed):
+                rec = await load.send(req, time.monotonic())
+                if not rec["ok"]:
+                    raise CellFailure(f"warm-up request failed: {rec['error']}")
+            # 2. the correctness sample, then the reference where the weights are
+            samples = []
+            for req in check_requests(generator, traffic, seed):
+                rec = await load.send(req, time.monotonic())
+                if not rec["ok"]:
+                    raise CellFailure(f"check request failed: {rec['error']}")
+                samples.append({
+                    "prompt_ids": [BOS] + list(req["prompt"].encode()),
+                    "answer_ids": rec["token_ids"]})
+            gaps = await asyncio.to_thread(
+                lambda: ray_tpu.get(engines[0].__rt_call__.remote(
+                    _inside.engine_reference_check, published(cfg), samples,
+                    int(traffic["check"]["pad_multiple"])),
+                    timeout=900))
+            art["check"] = judge_check(gaps, CHECK_TOLERANCE_BF16_STEPS)
+            ctx.log(f"reference check: {art['check']}")
+            load.records.clear()
+
+            # 3. the ramp (set-up), then the window
+            ramp_s = float(traffic["ramp_s"])
+            t_open = time.monotonic() + ramp_s + 0.05
+            art["t_open"], art["window_s"] = t_open, window_s
+
+            async def at_edges() -> None:
+                await sleep_until(t_open)
+                art["cache_files_open"] = ctx.cache_files()
+                art["stats_open"] = await asyncio.to_thread(engine_stats)
+                if ctx.trace:
+                    trace_s = float(traffic["trace_s"])
+                    await sleep_until(t_open + 0.25 * window_s)
+                    ctx.log("tracing the engine's process")
+                    art["trace_call"] = await asyncio.to_thread(
+                        lambda: ray_tpu.get(engines[0].__rt_call__.remote(
+                            _inside.engine_trace, ctx.trace_dir, trace_s),
+                            timeout=600))
+                    ctx.log("trace written")
+                await sleep_until(t_open + window_s)
+                art["stats_close"] = await asyncio.to_thread(engine_stats)
+                art["cache_files_close"] = ctx.cache_files()
+
+            edges = asyncio.ensure_future(at_edges())
+            if generator.LOOP == "open":
+                await open_loop(load, generator.schedule(traffic, seed, window_s),
+                                t_open)
+            else:
+                streams = [generator.stream(traffic, seed, i)
+                           for i in range(int(traffic["clients"]))]
+                await closed_loop(load, streams, t_open, ramp_s, window_s)
+            await edges
+            art["records"] = load.records
+        return art
+
+    art = asyncio.run(drive())
+    art["engine"] = engine_cfg
+    art["device"] = device
+    memory = ray_tpu.get([e.__rt_call__.remote(_inside.engine_memory)
+                          for e in engines], timeout=120)
+    peaks = [m["memory_peak_bytes"] for m in memory
+             if m["memory_peak_bytes"] is not None]
+    art["memory_peak_bytes"] = max(peaks) if peaks else None
+    if ctx.trace:
+        from ray_tpu.util import tracing
+
+        art["spans"] = tracing.list_spans(limit=500_000)
+    art["engine_init_s"] = [i["init_s"] for i in infos]
+    return finish(ctx, art)
+
+
+def finish(ctx, art: Dict[str, Any]) -> Dict[str, Any]:
+    """From the records to the end-to-end metrics and the verdict."""
+    t_open, window_s = art["t_open"], art["window_s"]
+    t_close = t_open + window_s
+    recs = art["records"]
+    if ctx.generator.LOOP == "open":
+        judged = [r for r in recs if r["tag"] == "w"]
+    else:
+        # the closed loop is judged on every request that overlaps the window
+        judged = [r for r in recs
+                  if r["sent"] < t_close and r.get("done", math.inf) > t_open]
+    failed = [r for r in judged if not r.get("ok")]
+    latency = [r["done"] - r["due"] if r.get("ok") else math.inf for r in judged]
+    late = [r["sent"] - r["due"] for r in recs
+            if t_open <= r["sent"] < t_close]
+    art["gen_late_s"] = late
+    e2e: Dict[str, Any] = {}
+    n = len(judged)
+    if ctx.generator.LOOP == "open":
+        e2e["req_p50_s"] = st.median(latency) if latency else None
+        e2e["req_p90_s"] = (st.percentile(latency, 90.0)
+                            if st.samples_beyond(n, 90.0) >= 10 else None)
+    else:
+        # Tokens generated inside the window, from the client's clock alone:
+        # each answer's tokens weighted by the share of its lifetime (sent ->
+        # answered) that fell inside. Counting only answers completed inside
+        # would drop the work in flight at both edges, about half a request a
+        # slot, whose luck moves the count by several percent run to run.
+        # The weighting holds while a request is generated over its whole
+        # lifetime, so the estimate is held to the engine's count below.
+        e2e["out_tokens_per_s"] = sum(
+            r["tokens"] * (min(r["done"], t_close) - max(r["sent"], t_open))
+            / (r["done"] - r["sent"]) for r in judged if r.get("ok")) / window_s
+        art["closed_req_s"] = [r["done"] - r["sent"] for r in judged
+                               if r.get("ok")]
+        art["counter_tokens_per_s"] = (
+            art["stats_close"]["tokens_out"] - art["stats_open"]["tokens_out"]
+        ) / window_s
+    art["end_to_end"] = e2e
+    limits = REHEARSAL_LIMITS if ctx.rehearsal else LIMITS
+    problems = []
+    if not art["check"]["ok"]:
+        problems.append(f"reference check failed: {art['check']}")
+    if failed:
+        problems.append(f"{len(failed)} requests failed: "
+                        f"{failed[0].get('error', 'unanswered')}")
+    if art["cache_files_close"] != art["cache_files_open"]:
+        problems.append(
+            f"compiled inside the window: {art['cache_files_open']} -> "
+            f"{art['cache_files_close']} files in the compile cache")
+    ok_lat = [x for x in latency if math.isfinite(x)]
+    if late and ok_lat:
+        ref = (st.median(ok_lat) if ctx.generator.LOOP == "open"
+               else st.median(art["closed_req_s"]))
+        if max(late) > limits["late_share"] * ref:
+            problems.append(
+                f"the generator ran late: at worst {max(late) * 1e3:.1f} ms "
+                f"against a median request of {ref:.3f} s")
+    counted = art.get("counter_tokens_per_s")
+    if counted is not None:
+        got = e2e["out_tokens_per_s"]
+        if not counted > 0 or abs(got / counted - 1.0) > limits["counter_share"]:
+            problems.append(
+                f"out_tokens_per_s {got:.2f} from the client's clock against "
+                f"{counted:.2f} from the engine's tokens_out: the estimate "
+                "does not hold for this traffic")
+    art["problems"] = problems
+    art["attempted"], art["failed"] = n, len(failed)
+
+    def in_flight(t: float) -> int:
+        return sum(1 for r in recs if r["sent"] <= t < r.get("done", math.inf))
+
+    half = sorted(judged, key=lambda r: r["due"])
+    art["load"] = {
+        "in_flight_open": in_flight(t_open), "in_flight_close": in_flight(t_close),
+        "latency_p50_first_half": st.median(
+            [r["done"] - r["due"] for r in half[: n // 2] if r.get("ok")] or [0]),
+        "latency_p50_second_half": st.median(
+            [r["done"] - r["due"] for r in half[n // 2:] if r.get("ok")] or [0]),
+        "last_done_after_close_s": max(
+            [r["done"] for r in judged if "done" in r] or [t_close]) - t_close}
+    return art
