@@ -1,0 +1,4 @@
+"""`decode_device_ms_per_step` in a cell that is judged on request time."""
+from benchmark.layer_metrics.decode_device_ms_per_step import LAYER, SOURCE, UNIT, read  # noqa: F401
+
+MOVES = "req_p50_s"

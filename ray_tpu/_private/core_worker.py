@@ -3112,12 +3112,8 @@ class CoreWorker:
         if end:
             segments.append(("hop:reply", end, time.time()))
         for name, start, stop in segments:
-            tracing.record_span({
-                "trace_id": ctx["trace_id"],
-                "span_id": os.urandom(8).hex(),
-                "parent_span_id": ctx.get("parent_span_id", ""),
-                "name": name, "start": start, "end": max(start, stop),
-            }, task_id=spec.task_id.binary())
+            tracing.record_interval(ctx, name, start, stop,
+                                    task_id=spec.task_id.binary())
 
     def _record_task_reply(self, spec: TaskSpec, reply: dict):
         sub = self._submissions.get(spec.task_id.binary())

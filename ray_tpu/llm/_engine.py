@@ -25,15 +25,44 @@ batching, PagedAttention block tables, streaming). Rebuilt for XLA:
 from __future__ import annotations
 
 import asyncio
+import collections
 import math
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from ray_tpu.models.llama import LlamaConfig, rms_norm, rope_tables
+from ray_tpu.util import tracing
 
-__all__ = ["EngineConfig", "PagedEngine"]
+__all__ = ["EngineConfig", "PagedEngine", "PHASES"]
+
+# Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
+# into the profiler's own trace (the device trace's clock) whenever a
+# profiler session is on; an inactive annotation is a flag check. Each is
+# opened and closed on one thread: sweep and emit on the event loop's,
+# the others on the `asyncio.to_thread` worker that runs `_try_admit` or the
+# decode step. The benchmark's readers find them by these names.
+PHASE_SWEEP = "engine:sweep"                    # drain _pending, abort sweep
+PHASE_ADMIT = "engine:admit"                    # one per _try_admit call
+PHASE_PREFIX_MATCH = "engine:prefix_match"      # chain_keys, match, eviction
+PHASE_PREFILL = "engine:prefill"                # the jitted whole-prompt call
+PHASE_SUFFIX_PREFILL = "engine:suffix_prefill"  # ... over a cached prefix
+PHASE_SAMPLE_FIRST = "engine:sample_first"      # waits for the prefill
+PHASE_STEP = "engine:step"                      # one run_step call, around:
+PHASE_UPLOAD = "engine:upload"                  # the step's six host arrays
+PHASE_DISPATCH = "engine:dispatch"              # the decode step's launch
+PHASE_DEVICE_WAIT = "engine:device_wait"        # np.asarray(toks)
+PHASE_EMIT = "engine:emit"                      # the per-slot walk
+PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
+          PHASE_SUFFIX_PREFILL, PHASE_SAMPLE_FIRST, PHASE_STEP, PHASE_UPLOAD,
+          PHASE_DISPATCH, PHASE_DEVICE_WAIT, PHASE_EMIT)
+# the per-request spans on the tracing plane (util/tracing.py), three a
+# request and none a step: the control store keeps 10,000 events
+SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
+SPAN_PREFILL = "engine:prefill"  # admission start -> first token
+SPAN_DECODE = "engine:decode"    # first token -> done
 
 
 @dataclass
@@ -74,7 +103,8 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
     max_blocks = -(-ecfg.max_model_len // bs)
     Lmax = max_blocks * bs
 
-    def step(params, kc, vc, tables, lens, active, last_tok, keys, temps):
+    def paged_decode_step(params, kc, vc, tables, lens, active, last_tok,
+                          keys, temps):
         """kc/vc [L, NB, BS, KV, HD]; tables [B, max_blocks] int32;
         lens/active/last_tok [B]; keys [B,2] uint32; temps [B].
         Returns (next_tok [B], kc, vc)."""
@@ -139,7 +169,7 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         sampled = jax.vmap(sample_one)(keys, logits, temps)
         return sampled, kc, vc
 
-    return jax.jit(step, donate_argnums=(1, 2))
+    return jax.jit(paged_decode_step, donate_argnums=(1, 2))
 
 
 def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
@@ -154,7 +184,7 @@ def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     bs = ecfg.kv_block_size
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-    def prefill(S, params, kc, vc, table, prompt, plen):
+    def paged_prefill(S, params, kc, vc, table, prompt, plen):
         """prompt [S] right-padded; table [max_blocks]; plen scalar."""
         dt = cfg.dtype
         hd = cfg.head_dim
@@ -205,7 +235,7 @@ def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
         logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
         return logits, kc, vc
 
-    return prefill
+    return paged_prefill
 
 
 def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
@@ -252,7 +282,8 @@ def _make_suffix_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     Lmax = max_blocks * bs
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-    def prefill_suffix(S, params, kc, vc, table, suffix, cached_len, slen):
+    def paged_suffix_prefill(S, params, kc, vc, table, suffix, cached_len,
+                             slen):
         """suffix [S] right-padded tokens at absolute positions
         cached_len..cached_len+slen; table [max_blocks] the FULL row
         (cached prefix blocks + this request's fresh blocks)."""
@@ -312,7 +343,7 @@ def _make_suffix_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
         logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
         return logits, kc, vc
 
-    return prefill_suffix
+    return paged_suffix_prefill
 
 
 @dataclass
@@ -330,7 +361,16 @@ class _Request:
     # loop drops it from the waiting queue or releases its slot + blocks
     # at the next step boundary instead of decoding for nobody
     aborted: bool = False
-    t_start: float = 0.0  # monotonic enqueue time (TTFT signal)
+    # monotonic stamps of the request's phases (0.0 = not reached): they
+    # feed stats()' ttft/queue-wait medians and, for a traced request, the
+    # engine:queue / engine:prefill / engine:decode spans
+    t_start: float = 0.0   # enqueued
+    t_admit: float = 0.0   # admission began (slot and blocks in hand)
+    t_first: float = 0.0   # first token emitted
+    t_done: float = 0.0    # finished, failed or dropped
+    # the caller's span (the `completions_stream` execution span) when the
+    # request is traced; the three spans are recorded as its children
+    trace_parent: Optional[dict] = None
     # disaggregated serving: prefill ran on ANOTHER worker; admission
     # injects the transferred KV blocks instead of running _prefill
     # (reference: serving_patterns/prefill_decode — KV transfer between
@@ -386,9 +426,8 @@ class PagedEngine:
         self.steps = 0
         self.tokens_out = 0
         self.mid_decode_admissions = 0
-        import collections
-
         self._ttfts = collections.deque(maxlen=256)
+        self._queue_waits = collections.deque(maxlen=256)
 
     # -- device-state recovery -----------------------------------------
 
@@ -447,6 +486,13 @@ class PagedEngine:
         return len(self.free_blocks) >= want
 
     def _try_admit(self, req: _Request) -> bool:
+        import jax
+
+        with jax.profiler.TraceAnnotation(PHASE_ADMIT):
+            return self._admit(req)
+
+    def _admit(self, req: _Request) -> bool:
+        t_admit = time.monotonic()
         need = self._blocks_needed(req)
         try:
             slot = next(i for i, r in enumerate(self.slot_req) if r is None)
@@ -455,33 +501,37 @@ class PagedEngine:
         if req.prefilled is not None:
             if not self._free_with_eviction(need):
                 return False
+            req.t_admit = t_admit
             return self._admit_prefilled(req, slot, need)
+        import jax
+        import jax.numpy as jnp
+
         cache = self._prefix_cache
         plen = len(req.prompt)
         hits: List[int] = []
         keys: List[bytes] = []
-        if cache is not None:
-            from ray_tpu.llm._prefix_cache import chain_keys
+        with jax.profiler.TraceAnnotation(PHASE_PREFIX_MATCH):
+            if cache is not None:
+                from ray_tpu.llm._prefix_cache import chain_keys
 
-            keys = chain_keys(req.prompt, self.bs)
-            # reuse is capped one token short of the prompt: the LAST
-            # prompt token must run through prefill locally or there are
-            # no logits to sample the first generated token from
-            hits = cache.match(keys[: (plen - 1) // self.bs])
-        need_new = need - len(hits)
-        if not self._free_with_eviction(need_new):
+                keys = chain_keys(req.prompt, self.bs)
+                # reuse is capped one token short of the prompt: the LAST
+                # prompt token must run through prefill locally or there
+                # are no logits to sample the first generated token from
+                hits = cache.match(keys[: (plen - 1) // self.bs])
+            need_new = need - len(hits)
+            fits = self._free_with_eviction(need_new)
+        if not fits:
             if cache is not None:
                 cache.cancel_match(hits)
             return False
+        req.t_admit = t_admit
         blocks = [self.free_blocks.pop() for _ in range(need_new)]
         row_blocks = hits + blocks
         try:
             row = np.zeros((self.max_blocks,), np.int32)
             row[: len(row_blocks)] = row_blocks
             self.tables[slot] = row
-            import jax
-            import jax.numpy as jnp
-
             cached_len = len(hits) * self.bs
             if cached_len:
                 # prefill ONLY the suffix over the cached prefix blocks
@@ -489,17 +539,21 @@ class PagedEngine:
                 S = max(8, 1 << (slen - 1).bit_length())  # pow-2 bucket
                 suffix = np.zeros((S,), np.int32)
                 suffix[:slen] = req.prompt[cached_len:]
-                logits, self.kc, self.vc = self._suffix_prefill(
-                    S, self.params, self.kc, self.vc, jnp.asarray(row),
-                    jnp.asarray(suffix), jnp.int32(cached_len),
-                    jnp.int32(slen))
+                with jax.profiler.TraceAnnotation(
+                        PHASE_SUFFIX_PREFILL, S=S, cached_len=cached_len):
+                    logits, self.kc, self.vc = self._suffix_prefill(
+                        S, self.params, self.kc, self.vc, jnp.asarray(row),
+                        jnp.asarray(suffix), jnp.int32(cached_len),
+                        jnp.int32(slen))
             else:
                 S = max(8, 1 << (plen - 1).bit_length())  # pow-2 bucket
                 prompt = np.zeros((S,), np.int32)
                 prompt[:plen] = req.prompt
-                logits, self.kc, self.vc = self._prefill(
-                    S, self.params, self.kc, self.vc, jnp.asarray(row),
-                    jnp.asarray(prompt), jnp.int32(plen))
+                with jax.profiler.TraceAnnotation(
+                        PHASE_PREFILL, S=S, cached_len=0):
+                    logits, self.kc, self.vc = self._prefill(
+                        S, self.params, self.kc, self.vc, jnp.asarray(row),
+                        jnp.asarray(prompt), jnp.int32(plen))
             tok = self._sample_first(req, slot, logits)
         except BaseException:
             # any failure between the block pop and slot activation (prefill
@@ -531,10 +585,10 @@ class PagedEngine:
     def _emit(self, req: _Request, tok: int):
         req.produced += 1
         self.tokens_out += 1
-        if req.produced == 1 and req.t_start:
-            import time
-
-            self._ttfts.append(time.monotonic() - req.t_start)
+        if req.produced == 1:
+            req.t_first = time.monotonic()
+            self._ttfts.append(req.t_first - req.t_start)
+            self._queue_waits.append(req.t_admit - req.t_start)
         done = (
             (self.eos_id is not None and tok == self.eos_id)
             or req.produced >= req.max_tokens
@@ -564,7 +618,32 @@ class PagedEngine:
         self.active[slot] = False
         self.slot_req[slot] = None
         req.slot = -1
+        self._finish(req)
         self._publish_metrics()
+
+    def _finish(self, req: _Request):
+        """The request left the engine (done, aborted or failed): stamp it
+        and, if its caller was traced, record the phases it reached as
+        child spans of the caller's span."""
+        if req.t_done:
+            return
+        req.t_done = time.monotonic()
+        if req.trace_parent is None:
+            return
+        wall = time.time() - time.monotonic()
+        # each phase ends where the next one reached begins
+        end = req.t_done
+        for name, start in ((SPAN_DECODE, req.t_first),
+                            (SPAN_PREFILL, req.t_admit),
+                            (SPAN_QUEUE, req.t_start)):
+            if start:
+                tracing.record_interval(req.trace_parent, name,
+                                        start + wall, end + wall)
+                end = start
+
+    def _fail(self, req: _Request, error: Exception):
+        req.queue.put_nowait(error)
+        self._finish(req)
 
     def _sample_first(self, req: _Request, slot: int, logits):
         """Sample the first generated token + seed the slot's decode RNG —
@@ -572,14 +651,15 @@ class PagedEngine:
         fold_in MUST match or the two paths diverge)."""
         import jax
 
-        key = jax.random.PRNGKey(req.seed * 1000003 + req.rid)
-        if req.temperature > 0:
-            tok = int(jax.random.categorical(
-                key, logits / max(req.temperature, 1e-6)))
-        else:
-            tok = int(np.argmax(np.asarray(logits)))
-        self._rngs[slot] = np.asarray(
-            jax.random.key_data(jax.random.fold_in(key, 7)), np.uint32)
+        with jax.profiler.TraceAnnotation(PHASE_SAMPLE_FIRST):
+            key = jax.random.PRNGKey(req.seed * 1000003 + req.rid)
+            if req.temperature > 0:
+                tok = int(jax.random.categorical(
+                    key, logits / max(req.temperature, 1e-6)))
+            else:
+                tok = int(np.argmax(np.asarray(logits)))
+            self._rngs[slot] = np.asarray(
+                jax.random.key_data(jax.random.fold_in(key, 7)), np.uint32)
         return tok
 
     def _activate_slot(self, req: _Request, slot: int, tok: int):
@@ -612,7 +692,7 @@ class PagedEngine:
             # malformed transfer: failing the REQUEST (not returning False,
             # which _run_loop reads as "wait for resources") keeps the
             # admission queue moving
-            req.queue.put_nowait(ValueError(
+            self._fail(req, ValueError(
                 f"transferred KV has {nb} blocks; prompt of "
                 f"{len(req.prompt)} tokens needs {expect} "
                 f"(budget {need})"))
@@ -623,11 +703,11 @@ class PagedEngine:
             row[: len(blocks)] = blocks
             self.tables[slot] = row
             if self._inject is None:
-                self._inject = jax.jit(
-                    lambda kc, vc, phys, k, v: (kc.at[:, phys].set(k),
-                                                vc.at[:, phys].set(v)),
-                    donate_argnums=(0, 1),
-                )
+                def paged_kv_inject(kc, vc, phys, k, v):
+                    return kc.at[:, phys].set(k), vc.at[:, phys].set(v)
+
+                self._inject = jax.jit(paged_kv_inject,
+                                       donate_argnums=(0, 1))
             phys = jnp.asarray(np.asarray(blocks[:nb], np.int32))
             self.kc, self.vc = self._inject(
                 self.kc, self.vc, phys,
@@ -651,22 +731,24 @@ class PagedEngine:
                 self._run_loop())
 
     async def _run_loop(self):
-        import collections
-
+        import jax
         import jax.numpy as jnp
 
+        phase = jax.profiler.TraceAnnotation
         waiting: "collections.deque[_Request]" = collections.deque()
         while True:
             mid_decode = bool(self.active.any())
-            while not self._pending.empty():
-                waiting.append(self._pending.get_nowait())
-            # disconnect sweep: a consumer that walked away (client abort,
-            # SSE timeout) releases its slot + KV blocks at this step
-            # boundary — BEFORE admission, so the freed blocks admit the
-            # waiting head this same tick instead of leaking until OOM
-            for r in list(self.slot_req):
-                if r is not None and r.aborted and r.slot >= 0:
-                    self._release(r)
+            with phase(PHASE_SWEEP):
+                while not self._pending.empty():
+                    waiting.append(self._pending.get_nowait())
+                # disconnect sweep: a consumer that walked away (client
+                # abort, SSE timeout) releases its slot + KV blocks at this
+                # step boundary — BEFORE admission, so the freed blocks
+                # admit the waiting head this same tick instead of leaking
+                # until OOM
+                for r in list(self.slot_req):
+                    if r is not None and r.aborted and r.slot >= 0:
+                        self._release(r)
             # admit in arrival order while slots + blocks allow — requests
             # landing here while slots decode are the "admitted mid-decode"
             # continuous-batching case
@@ -674,12 +756,13 @@ class PagedEngine:
                 req = waiting[0]
                 if req.aborted:
                     waiting.popleft()  # consumer gone before admission
+                    self._finish(req)
                     continue
                 if self._blocks_needed(req) > self.ecfg.num_kv_blocks:
                     # can never fit even a drained pool: surface an ERROR,
                     # not a silently empty completion
                     waiting.popleft()
-                    req.queue.put_nowait(ValueError(
+                    self._fail(req, ValueError(
                         f"request needs {self._blocks_needed(req)} KV "
                         f"blocks but the pool has "
                         f"{self.ecfg.num_kv_blocks}"))
@@ -689,13 +772,13 @@ class PagedEngine:
                     ok = await asyncio.to_thread(self._try_admit, req)
                 except Exception as e:  # noqa: BLE001 — prefill failed
                     waiting.popleft()
-                    req.queue.put_nowait(e)
+                    self._fail(req, e)
                     if self._device_state_invalid():
                         # prefill donates kc/vc: a failure after donation
                         # destroyed every in-flight sequence's cache
                         for r in list(self.slot_req):
                             if r is not None:
-                                r.queue.put_nowait(e)
+                                self._fail(r, e)
                         self._reset_device_state()
                     continue
                 if not ok:
@@ -709,12 +792,18 @@ class PagedEngine:
             step = self.steps
 
             def run_step():
-                toks, self.kc, self.vc = self._decode(
-                    self.params, self.kc, self.vc,
-                    jnp.asarray(self.tables), jnp.asarray(self.lens),
-                    jnp.asarray(self.active), jnp.asarray(self.last_tok),
-                    jnp.asarray(self._rngs), jnp.asarray(self.temps))
-                return np.asarray(toks)
+                # the outer annotation names a device gap that straddles
+                # two of the inner ones (else a Python frame and its line)
+                with phase(PHASE_STEP):
+                    with phase(PHASE_UPLOAD):
+                        state = [jnp.asarray(a) for a in (
+                            self.tables, self.lens, self.active,
+                            self.last_tok, self._rngs, self.temps)]
+                    with phase(PHASE_DISPATCH):
+                        toks, self.kc, self.vc = self._decode(
+                            self.params, self.kc, self.vc, *state)
+                    with phase(PHASE_DEVICE_WAIT):
+                        return np.asarray(toks)
 
             try:
                 toks = await asyncio.to_thread(run_step)
@@ -726,23 +815,24 @@ class PagedEngine:
                         req.queue.put_nowait(e)
                         self._release(req)
                 while waiting:
-                    waiting.popleft().queue.put_nowait(e)
+                    self._fail(waiting.popleft(), e)
                 while not self._pending.empty():
-                    self._pending.get_nowait().queue.put_nowait(e)
+                    self._fail(self._pending.get_nowait(), e)
                 if self._device_state_invalid():
                     # rebuild the donated pool so _ensure_loop's restart on
                     # the next generate_stream starts from a clean engine
                     self._reset_device_state()
                 raise
-            self.steps = step + 1
-            self._rngs[:, 1] += 1  # fresh fold per step
-            for slot, req in enumerate(list(self.slot_req)):
-                if req is None or not self.active[slot]:
-                    continue
-                self.lens[slot] += 1
-                tok = int(toks[slot])
-                self.last_tok[slot] = tok
-                self._emit(req, tok)
+            with phase(PHASE_EMIT):
+                self.steps = step + 1
+                self._rngs[:, 1] += 1  # fresh fold per step
+                for slot, req in enumerate(list(self.slot_req)):
+                    if req is None or not self.active[slot]:
+                        continue
+                    self.lens[slot] += 1
+                    tok = int(toks[slot])
+                    self.last_tok[slot] = tok
+                    self._emit(req, tok)
             await asyncio.sleep(0)  # let admissions interleave
 
     # -- public API -----------------------------------------------------
@@ -760,13 +850,12 @@ class PagedEngine:
                 f"prompt of {len(prompt_ids)} tokens exceeds "
                 f"max_model_len={self.ecfg.max_model_len}")
         await self._ensure_loop()
-        import time
-
         self._rid += 1
         req = _Request(self._rid, list(prompt_ids), int(max_tokens),
                        float(temperature), int(seed),
                        queue=asyncio.Queue(), prefilled=prefilled,
-                       t_start=time.monotonic())
+                       t_start=time.monotonic(),
+                       trace_parent=tracing.current_span())
         self._pending.put_nowait(req)
         try:
             while True:
@@ -828,6 +917,7 @@ class PagedEngine:
         cache = self._prefix_cache
         evictable = cache.evictable_blocks() if cache is not None else 0
         ttfts = sorted(self._ttfts)
+        queue_waits = sorted(self._queue_waits)
         out = {
             "steps": self.steps,
             "tokens_out": self.tokens_out,
@@ -842,5 +932,8 @@ class PagedEngine:
             "prefix_cache": cache.stats() if cache is not None else None,
         }
         if ttfts:
+            # time to first token is queue wait + prefill: an operator
+            # needs the split to tell a backlog from a slow prefill
             out["ttft_p50_s"] = ttfts[len(ttfts) // 2]
+            out["queue_wait_p50_s"] = queue_waits[len(queue_waits) // 2]
         return out

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -255,6 +256,10 @@ class DPEngineGroup:
         max_new = int(payload.get("max_tokens", self.config.max_new_tokens))
         i = min(range(len(self.engines)), key=lambda j: self.load[j])
         self.load[i] += 1
+        # this replica's monotonic clock: the call's start, the first
+        # token's arrival and the last, for the answer's usage
+        t_call = time.monotonic()
+        t_first = 0.0
         try:
             toks: List[int] = []
             gen = self.engines[i].completions_stream.options(
@@ -264,8 +269,11 @@ class DPEngineGroup:
                     "temperature", self.config.temperature)))
             async for ref in gen:
                 toks.append(await ref)
+                if not t_first:
+                    t_first = time.monotonic()
         finally:
             self.load[i] = max(0, self.load[i] - 1)
+        t_last = time.monotonic()
         text = self.tokenizer.decode(toks)
         return {
             "object": "text_completion",
@@ -275,7 +283,12 @@ class DPEngineGroup:
             "choices": [{"index": 0, "text": text, "token_ids": toks,
                          "finish_reason": "stop" if len(toks) < max_new
                          else "length"}],
-            "usage": {"completion_tokens": len(toks), "dp_rank": i},
+            # ttft_s: call start -> first token here (queue + prefill + one
+            # hop); total_s: call start -> last token. A stream that gave
+            # no token has no first one: ttft_s is then total_s
+            "usage": {"completion_tokens": len(toks), "dp_rank": i,
+                      "ttft_s": (t_first or t_last) - t_call,
+                      "total_s": t_last - t_call},
         }
 
 

@@ -248,6 +248,7 @@ def _flash_fwd_tpu(q, k, v, causal, block_q, block_k):
 
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=(
             _out((b, h, s, hd), q.dtype, q, k, v),
             _out((b, h, s, 1), jnp.float32, q, k, v),
@@ -439,6 +440,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
                                      v.dtype, rep)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_dq",
         out_shape=_out((b, h, s, hd), q.dtype, q, k, v, g),
         grid=(b, h, s // block_q),
         in_specs=[
@@ -472,6 +474,7 @@ def _flash_bwd_tpu(q, k, v, o, lse, g, causal, block_q, block_k,
                                         q.dtype, g.dtype)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         out_shape=(
             _out((b, h, s, hd), jnp.float32, q, k, v, g),
             _out((b, h, s, hd), jnp.float32, q, k, v, g),
@@ -608,6 +611,7 @@ def _flash_chunk_tpu(q, k, v, o, m, l, causal, block_q, block_k):
                                      v.dtype, rep)
     return pl.pallas_call(
         kernel,
+        name="flash_chunk",
         out_shape=(
             _out((b, h, sq, hd), jnp.float32, q, k, v, o, m, l),
             _out((b, h, sq, 1), jnp.float32, q, k, v, o, m, l),
@@ -761,6 +765,7 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
                                      v.dtype, rep)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_hop_dq",
         out_shape=_out((b, h, sq, hd), jnp.float32, q, k, v, g),
         grid=(b, h, sq // block_q),
         in_specs=[
@@ -788,6 +793,7 @@ def _hop_bwd_tpu(q, k, v, g, lse, delta, causal, block_q, block_k,
                                         q.dtype, g.dtype)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_hop_dkv",
         out_shape=(
             _out((b, h, sk, hd), jnp.float32, q, k, v, g),
             _out((b, h, sk, hd), jnp.float32, q, k, v, g),

@@ -186,8 +186,11 @@ class HttpProxy:
                                                name)
         # ingress span: the root of the request's trace — the handle's
         # pick span and the replica-side admission/batch/execution spans
-        # all chain under it (stitched by trace id in timeline())
-        with tracing.span(f"ingress:{name}"):
+        # all chain under it (stitched by trace id in timeline()). A trace
+        # of its own: the handler inherits the context the server was
+        # started in (the span of the proxy actor's `ready()` call), and
+        # chaining to that would hang every request under one trace
+        with tracing.span(f"ingress:{name}", new_trace=True):
             try:
                 result = await caller.remote(payload)
             except Exception as e:  # noqa: BLE001 — typed mapping below
@@ -228,8 +231,10 @@ class HttpProxy:
 
         # ingress span: created manually (its END rides the stream outcome,
         # not a lexical scope) and installed as the current context for the
-        # whole dispatch so the handle submission chains under it
-        ingress_sp = tracing.start_manual_span(f"ingress:{name}")
+        # whole dispatch so the handle submission chains under it; a trace
+        # of its own, as on the unary path
+        ingress_sp = tracing.start_manual_span(f"ingress:{name}",
+                                               new_trace=True)
         with tracing.installed_span(ingress_sp):
             n_chunks = 0
             # Defer the 200/SSE headers until the FIRST item arrives:

@@ -1,28 +1,21 @@
 """JAX/TPU profiler capture across the cluster.
 
-Reference surface: python/ray/util/tpu.py:1060 init_jax_profiler (starts
-the profiler server inside workers) and the dashboard's JAX capture
-endpoint (dashboard/modules/reporter/jax_profile_manager.py:11). Here
-capture is a plain remote task pinned to the target node, writing an
-XPlane/perfetto trace directory the driver can fetch or inspect.
+Reference surface: the dashboard's JAX capture endpoint
+(dashboard/modules/reporter/jax_profile_manager.py:11). Capture writes an
+XPlane/perfetto trace directory the driver can fetch or inspect, either
+from a fresh task pinned to a node (`capture_on_node`) or from inside a
+live actor's own process (`capture_in_actor`): only the process that
+holds a chip can trace it.
 """
 
 from __future__ import annotations
 
+import asyncio
 import os
 import time
 from typing import List, Optional
 
 import ray_tpu
-
-
-def init_jax_profiler(port: int = 9999) -> int:
-    """Start the in-process profiler server (attachable from TensorBoard /
-    xprof; reference: util/tpu.py init_jax_profiler)."""
-    import jax
-
-    jax.profiler.start_server(port)
-    return port
 
 
 def capture_local(logdir: str, duration_s: float = 2.0,
@@ -43,11 +36,10 @@ def capture_local(logdir: str, duration_s: float = 2.0,
     return logdir
 
 
-@ray_tpu.remote
-def _capture_task(logdir: Optional[str], duration_s: float):
-    """Runs on the target node's worker: captures its JAX runtime trace.
-    logdir=None creates a temp dir ON THE TARGET (a dashboard-side path
-    would be meaningless on another node). Returns (logdir, files)."""
+def _capture_here(logdir: Optional[str], duration_s: float):
+    """Capture this process's JAX runtime trace. logdir=None creates a
+    temp dir ON THE TARGET (a dashboard-side path would be meaningless on
+    another node). Returns (logdir, files)."""
     if logdir is None:
         import tempfile
 
@@ -57,6 +49,17 @@ def _capture_task(logdir: Optional[str], duration_s: float):
     for root, _dirs, files in os.walk(logdir):
         out.extend(os.path.join(root, f) for f in files)
     return logdir, out
+
+
+_capture_task = ray_tpu.remote(_capture_here)
+
+
+async def _capture_in_actor(_instance, logdir: Optional[str],
+                            duration_s: float):
+    """`__rt_call__` body: the capture runs on a thread of the actor's
+    process, so the actor's event loop (an engine's decode loop) keeps
+    running while it is traced and while `stop_trace` writes the file."""
+    return await asyncio.to_thread(_capture_here, logdir, duration_s)
 
 
 def node_capture_task(node_id_hex: str):
@@ -73,11 +76,28 @@ def node_capture_task(node_id_hex: str):
 def capture_on_node(node_id_hex: str, logdir: Optional[str] = None,
                     duration_s: float = 2.0) -> List[str]:
     """Capture a JAX profile on a specific node (reference: the dashboard
-    agent's per-node capture). Returns trace file paths on that node."""
+    agent's per-node capture). Returns trace file paths on that node.
+
+    The capture runs in a NEW worker on that node: it sees that worker's
+    own (idle) JAX runtime, and no chip that another process holds. To
+    trace an engine or a train worker, use `capture_in_actor`."""
     _dir, files = ray_tpu.get(
         node_capture_task(node_id_hex).remote(logdir, duration_s),
         timeout=duration_s + 120)
     return files
 
 
-__all__ = ["capture_local", "capture_on_node", "init_jax_profiler", "node_capture_task"]
+def capture_in_actor(actor, logdir: Optional[str] = None,
+                     duration_s: float = 2.0) -> List[str]:
+    """Capture a JAX profile inside a live actor's own process, the one
+    that holds its chip: device operations, the programs by name and the
+    process's `TraceAnnotation`s (the engine loop's `engine:*` phases)
+    land on one clock. Returns trace file paths on the actor's node."""
+    _dir, files = ray_tpu.get(
+        actor.__rt_call__.remote(_capture_in_actor, logdir, duration_s),
+        timeout=duration_s + 120)
+    return files
+
+
+__all__ = ["capture_in_actor", "capture_local", "capture_on_node",
+           "node_capture_task"]

@@ -40,9 +40,12 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import logging
 import os
 import time
 from typing import Any, Dict, List, Optional
+
+logger = logging.getLogger(__name__)
 
 _ENABLED = os.environ.get("RT_TRACING_ENABLED", "") in ("1", "true")
 _current_span: "contextvars.ContextVar[Optional[dict]]" = (
@@ -183,26 +186,52 @@ def record_span(span: dict, task_id: bytes = b"") -> None:
         pass
 
 
+def _child_span(name: str, parent: Optional[dict], start: float) -> dict:
+    """A span under `parent` — a span ({trace_id, span_id}) or a wire
+    context ({trace_id, parent_span_id}) — or the root of a new trace."""
+    return {
+        "trace_id": parent["trace_id"] if parent else _new_id(16),
+        "span_id": _new_id(8),
+        "parent_span_id": (parent.get("span_id") or
+                           parent.get("parent_span_id", "")) if parent else "",
+        "name": name,
+        "start": start,
+    }
+
+
+def record_interval(parent: dict, name: str, start: float, end: float,
+                    task_id: bytes = b"") -> None:
+    """Record a finished child span of `parent` whose wall-clock start and
+    end were stamped elsewhere: the hops of a traced call (stamps from the
+    reply), the phases of an engine request (stamps on the request)."""
+    sp = _child_span(name, parent, start)
+    sp["end"] = max(start, end)
+    record_span(sp, task_id=task_id)
+
+
+def _open_span(name: str, parent: Optional[dict], new_trace: bool) -> dict:
+    if new_trace:
+        parent = None
+    elif parent is None:
+        parent = _current_span.get()
+    return _child_span(name, parent, time.time())
+
+
 @contextlib.contextmanager
-def span(name: str, parent: Optional[dict] = None, task_id: bytes = b""):
+def span(name: str, parent: Optional[dict] = None, task_id: bytes = b"",
+         new_trace: bool = False):
     """Explicit span in the current process: chains to the current span
     (or an explicit `parent` {trace_id, span_id} captured earlier — batch
     flushes run in timer callbacks outside the request context), installs
     itself as current for the body, and records through the task-event
-    plane on exit. Yields None (and costs one contextvar read) when
-    tracing is off."""
+    plane on exit. `new_trace` starts a trace of its own whatever the
+    current span is: the root of one request in a server whose handlers
+    inherit the context the server was started in. Yields None (and costs
+    one contextvar read) when tracing is off."""
     if not tracing_enabled():
         yield None
         return
-    cur = parent if parent is not None else _current_span.get()
-    sp = {
-        "trace_id": cur["trace_id"] if cur else _new_id(16),
-        "span_id": _new_id(8),
-        "parent_span_id": (cur.get("span_id") or
-                           cur.get("parent_span_id", "")) if cur else "",
-        "name": name,
-        "start": time.time(),
-    }
+    sp = _open_span(name, parent, new_trace)
     token = _current_span.set(sp)
     try:
         yield sp
@@ -212,23 +241,15 @@ def span(name: str, parent: Optional[dict] = None, task_id: bytes = b""):
         record_span(sp, task_id=task_id)
 
 
-def start_manual_span(name: str, parent: Optional[dict] = None
-                      ) -> Optional[dict]:
+def start_manual_span(name: str, parent: Optional[dict] = None,
+                      new_trace: bool = False) -> Optional[dict]:
     """Span helper for code that cannot hold a context manager open across
     its lifetime (async generators driven by a remote consumer: a `with`
     spanning yields would leak the contextvar into the consumer's turns).
-    Finish with end_manual_span()."""
+    Finish with end_manual_span(). `new_trace` as in span()."""
     if not tracing_enabled():
         return None
-    cur = parent if parent is not None else _current_span.get()
-    return {
-        "trace_id": cur["trace_id"] if cur else _new_id(16),
-        "span_id": _new_id(8),
-        "parent_span_id": (cur.get("span_id") or
-                           cur.get("parent_span_id", "")) if cur else "",
-        "name": name,
-        "start": time.time(),
-    }
+    return _open_span(name, parent, new_trace)
 
 
 @contextlib.contextmanager
@@ -298,10 +319,20 @@ def list_spans(limit: int = 1000) -> List[Dict[str, Any]]:
     """Finished spans recorded through the task-event plane (driver-side
     view over the cluster's trace history). Reads RAW task events — the
     per-task latest-state collapse of list_tasks() would drop SPAN records
-    once the task's FINISHED event lands."""
+    once the task's FINISHED event lands.
+
+    The control store keeps the newest `task_event_buffer_max` events of
+    all kinds, and the workers' buffers are capped too: when any were
+    dropped the history has gaps (a trace may miss spans), and a warning
+    says how many."""
     from ray_tpu.util.state import _control_call
 
     reply = _control_call("list_task_events", {"limit": limit * 4})
+    if reply.get("dropped"):
+        logger.warning(
+            "list_spans: %d task events were dropped since the cluster "
+            "started (%d held): spans may be missing from their traces",
+            reply["dropped"], len(reply["events"]))
     out = []
     for ev in reply["events"]:
         if ev.get("event") == "SPAN" and ev.get("trace_id"):
@@ -322,5 +353,5 @@ def list_spans(limit: int = 1000) -> List[Dict[str, Any]]:
 __all__ = ["DERIVE_CTX", "bind_generator", "bind_span", "current_span",
            "derive_trace_id", "enable_tracing", "end_manual_span",
            "execution_span", "inject_context", "installed_span",
-           "list_spans", "record_span", "resolve_context", "span",
-           "start_manual_span", "tracing_enabled"]
+           "list_spans", "record_interval", "record_span", "resolve_context",
+           "span", "start_manual_span", "tracing_enabled"]

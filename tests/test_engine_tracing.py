@@ -1,0 +1,276 @@
+"""Engine-process tracing: the loop's phases as profiler annotations, the
+three per-request spans on the tracing plane, one trace id per HTTP
+request, and the profiler hook inside an actor's own process."""
+
+import asyncio
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import ray_tpu
+from ray_tpu._private.config import GLOBAL_CONFIG
+from ray_tpu.llm import _engine
+from ray_tpu.llm._engine import EngineConfig, PagedEngine
+from ray_tpu.models.llama import LlamaConfig, init_params
+from ray_tpu.util import tracing
+
+CFG = LlamaConfig(
+    vocab_size=512, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+    ffn_dim=128, max_seq_len=128, dtype=jnp.float32, param_dtype=jnp.float32)
+SPANS = (_engine.SPAN_QUEUE, _engine.SPAN_PREFILL, _engine.SPAN_DECODE)
+
+
+def small_engine(**kw):
+    return PagedEngine(CFG, init_params(CFG, jax.random.PRNGKey(0)),
+                       EngineConfig(max_num_seqs=2, kv_block_size=4,
+                                    num_kv_blocks=32, max_model_len=64, **kw))
+
+
+def host_event_names(logdir):
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    assert paths, f"no .xplane.pb under {logdir}"
+    return {e.name for plane in ProfileData.from_file(paths[-1]).planes
+            for line in plane.lines for e in line.events}
+
+
+# ---------------------------------------------------------------------------
+# in-process: annotations, stamps, spans
+# ---------------------------------------------------------------------------
+
+
+def test_profiler_capture_holds_every_engine_phase(tmp_path):
+    """A capture around a few engine steps, read back with ProfileData,
+    holds every phase name of `_engine.PHASES`: a rename fails here and
+    not in a chip run (the benchmark's readers find the phases by name)."""
+    eng = small_engine(prefix_cache=True)
+    shared = [7, 8, 9, 10, 11, 12, 13, 14]   # two full blocks
+
+    async def one(prompt):
+        return [t async for t in eng.generate_stream(prompt, max_tokens=3)]
+
+    async def main():
+        await one(shared + [1])               # warm: compile outside
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            await one(shared + [2, 3])        # suffix prefill over the cache
+            await one([20, 21, 22])           # whole-prompt prefill
+        finally:
+            await asyncio.to_thread(jax.profiler.stop_trace)
+
+    asyncio.run(main())
+    names = host_event_names(str(tmp_path))
+    assert set(_engine.PHASES) <= names, set(_engine.PHASES) - names
+
+
+def test_tracing_off_records_no_engine_span(monkeypatch):
+    """Tracing off: the request carries its stamps, `stats()` splits the
+    queue wait out of the time to first token, and nothing is recorded."""
+    recorded = []
+    monkeypatch.setattr(tracing, "record_span",
+                        lambda span, task_id=b"": recorded.append(span))
+    # off whatever an earlier test file of this process switched on
+    monkeypatch.setattr(tracing, "_ENABLED", False)
+    monkeypatch.delenv("RT_TRACING_ENABLED", raising=False)
+    assert not tracing.tracing_enabled()
+    eng = small_engine()
+
+    async def main():
+        return [t async for t in eng.generate_stream([1, 2, 3], max_tokens=4)]
+
+    assert len(asyncio.run(main())) == 4
+    assert recorded == []
+    stats = eng.stats()
+    assert 0.0 <= stats["queue_wait_p50_s"] <= stats["ttft_p50_s"]
+
+
+def test_traced_request_records_the_phases_it_reached(monkeypatch):
+    """Under a caller's span a finished request leaves queue, prefill and
+    decode as that span's children, end to end without a gap; a request
+    whose consumer walks away before admission leaves only its queue."""
+    recorded = []
+    monkeypatch.setattr(tracing, "record_span",
+                        lambda span, task_id=b"": recorded.append(span))
+    eng = small_engine()
+    parent = {"trace_id": "ab" * 16, "span_id": "cd" * 8}
+
+    async def main():
+        with tracing.installed_span(parent):
+            done = [t async for t in eng.generate_stream(
+                [1, 2, 3], max_tokens=4)]
+            # both slots busy, so the third waits; its consumer leaves
+            held = [eng.generate_stream([4, 5], max_tokens=40)
+                    for _ in range(2)]
+            for g in held:
+                await g.__anext__()
+            gone = eng.generate_stream([6], max_tokens=4)
+            waiter = asyncio.ensure_future(gone.__anext__())
+            await asyncio.sleep(0.05)
+            waiter.cancel()
+            await asyncio.gather(waiter, return_exceptions=True)
+            await gone.aclose()
+            for g in held:
+                await g.aclose()
+            for _ in range(50):          # the loop's sweep drops all three
+                await asyncio.sleep(0.02)
+                if len(recorded) >= 3 + 1 + 2 * 3:
+                    break
+        return done
+
+    assert len(asyncio.run(main())) == 4
+    assert all(s["trace_id"] == parent["trace_id"]
+               and s["parent_span_id"] == parent["span_id"] for s in recorded)
+    first = sorted(recorded[:3], key=lambda s: s["start"])
+    assert [s["name"] for s in first] == list(SPANS)
+    assert first[0]["end"] == first[1]["start"]
+    assert first[1]["end"] == first[2]["start"]
+    assert all(s["end"] >= s["start"] for s in first)
+    # the abandoned request never reached admission
+    by_start = {}
+    for s in recorded[3:]:
+        by_start.setdefault(s["name"], []).append(s)
+    assert len(by_start[_engine.SPAN_QUEUE]) == 3
+    assert len(by_start[_engine.SPAN_PREFILL]) == 2
+    assert len(by_start[_engine.SPAN_DECODE]) == 2
+
+
+# ---------------------------------------------------------------------------
+# cluster: one trace per HTTP request, the profiler hook inside an actor
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def traced_cluster():
+    info = ray_tpu.init(num_cpus=8, system_config={"tracing_enabled": True})
+    yield info
+    ray_tpu.shutdown()
+
+
+@pytest.fixture()
+def traced(traced_cluster):
+    # the conftest config reset runs after every test; the cluster's
+    # workers inherited the flag at spawn
+    GLOBAL_CONFIG.apply_system_config({"tracing_enabled": True})
+    yield
+
+
+@pytest.fixture(scope="module")
+def dp_app(traced_cluster):
+    """One tiny data-parallel engine behind a route, for the tests below:
+    (handle, base URL of the HTTP proxy)."""
+    from ray_tpu import serve
+    from ray_tpu.llm import LLMConfig
+    from ray_tpu.llm.serving_patterns import build_dp_app
+
+    config = LLMConfig(model="tiny", max_new_tokens=4, model_overrides=dict(
+        dtype=jnp.float32, param_dtype=jnp.float32))
+    handle = build_dp_app(
+        config, dp_size=1, deployment_name="traced_dp",
+        engine_config=dict(max_num_seqs=2, kv_block_size=8,
+                           num_kv_blocks=32, max_model_len=64))
+    yield handle, serve.start(http_port=0)
+    serve.delete("traced_dp")
+
+
+def test_dp_answer_carries_first_token_and_total_time(traced, dp_app):
+    """A non-streaming answer says when its first token came and when its
+    last, on the replica's clock, beside the keys it had."""
+    handle, _ = dp_app
+    t0 = time.monotonic()
+    out = handle.remote({"prompt": "time me", "max_tokens": 4}).result(
+        timeout=300)
+    elapsed = time.monotonic() - t0
+    usage = out["usage"]
+    assert set(usage) == {"completion_tokens", "dp_rank", "ttft_s", "total_s"}
+    assert usage["completion_tokens"] == len(
+        out["choices"][0]["token_ids"]) >= 1
+    assert usage["dp_rank"] == 0
+    assert 0.0 < usage["ttft_s"] <= usage["total_s"] <= elapsed
+
+
+def test_each_http_request_is_one_trace_down_to_the_engine(traced, dp_app):
+    import httpx
+
+    _, base = dp_app
+    for prompt in ("first request", "second"):
+        r = httpx.post(f"{base}/traced_dp", json={"prompt": prompt},
+                       timeout=300)
+        assert r.status_code == 200, r.text
+        assert r.json()["result"]["usage"]["completion_tokens"] >= 1
+
+    def request_traces():
+        by_trace = {}
+        for s in tracing.list_spans(limit=4000):
+            by_trace.setdefault(s["trace_id"], []).append(s)
+        return {t: ss for t, ss in by_trace.items()
+                if any(s["name"].startswith("ingress:") for s in ss)}
+
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        traces = request_traces()
+        if len(traces) == 2 and all(
+                {s["name"] for s in ss} >= set(SPANS)
+                for ss in traces.values()):
+            break
+        time.sleep(0.5)
+    assert len(traces) == 2, {t: sorted(s["name"] for s in ss)
+                              for t, ss in traces.items()}
+    for ss in traces.values():
+        by_id = {s["span_id"]: s for s in ss}
+        names = [s["name"] for s in ss]
+        assert sum(n.startswith("ingress:") for n in names) == 1, names
+        engine = {s["name"]: s for s in ss if s["name"] in SPANS}
+        assert set(engine) == set(SPANS), names
+        # the three hang under one completions_stream execution span...
+        parents = {s["parent_span_id"] for s in engine.values()}
+        assert len(parents) == 1
+        up = by_id[parents.pop()]
+        assert "completions_stream" in up["name"]
+        # ...whose parent links lead to this trace's ingress span
+        chain = [up["name"]]
+        while not up["name"].startswith("ingress:"):
+            up = by_id[up["parent_span_id"]]
+            chain.append(up["name"])
+        assert up["parent_span_id"] == "", chain
+        assert any(n.startswith("handle:pick") for n in names), names
+        assert any(n.startswith("replica:admit") for n in names), names
+        q, p, d = (engine[n] for n in SPANS)
+        assert q["ts"] <= p["ts"] <= d["ts"]
+        assert q["ts"] >= up["ts"] - 0.05   # inside the ingress span
+
+
+def test_capture_in_actor_traces_the_actors_own_process(traced_cluster,
+                                                        tmp_path):
+    """The capture runs where the actor's work runs: its annotation is in
+    the trace, and its event loop keeps serving while it is traced."""
+    from ray_tpu.tpu.profiler import capture_in_actor
+
+    @ray_tpu.remote
+    class Spinner:
+        async def spin(self, seconds):
+            import asyncio as aio
+
+            import jax as j
+
+            end, n = time.monotonic() + seconds, 0
+            while time.monotonic() < end:
+                with j.profiler.TraceAnnotation("spinner:turn"):
+                    j.numpy.ones((8, 8)).sum().block_until_ready()
+                n += 1
+                await aio.sleep(0.005)
+            return n
+
+    actor = Spinner.remote()
+    ray_tpu.get(actor.spin.remote(0.05), timeout=120)     # warm
+    turns = actor.spin.remote(2.0)
+    files = capture_in_actor(actor, str(tmp_path / "prof"), duration_s=0.5)
+    assert any(f.endswith(".xplane.pb") for f in files), files
+    assert ray_tpu.get(turns, timeout=120) > 10
+    assert "spinner:turn" in host_event_names(str(tmp_path / "prof"))
+    ray_tpu.kill(actor)

@@ -165,3 +165,70 @@ def test_streaming_generator_body_chains(ray_init):
     for s in inners:
         assert s["trace_id"] == outer[0]["trace_id"]
         assert s["parent_span_id"] == outer[0]["span_id"]
+
+
+@pytest.mark.parametrize("parent,parent_id", [
+    ({"trace_id": "t" * 32, "span_id": "s" * 16, "parent_span_id": "x"},
+     "s" * 16),                                     # a span: its child
+    ({"trace_id": "t" * 32, "parent_span_id": "p" * 16}, "p" * 16),  # wire ctx
+])
+def test_record_interval_is_a_child_with_explicit_ends(monkeypatch, parent,
+                                                       parent_id):
+    """The helper behind the `hop:` segments and the engine's request
+    phases: start and end as stamped, never negative, the parent's trace."""
+    recorded = []
+    monkeypatch.setattr(tracing, "record_span",
+                        lambda span, task_id=b"": recorded.append(
+                            (span, task_id)))
+    tracing.record_interval(parent, "hop:flight", 10.0, 12.5, task_id=b"tid")
+    tracing.record_interval(parent, "hop:reply", 10.0, 9.0)
+    (a, tid), (b, _) = recorded
+    assert (a["trace_id"], a["parent_span_id"]) == ("t" * 32, parent_id)
+    assert (a["name"], a["start"], a["end"], tid) == (
+        "hop:flight", 10.0, 12.5, b"tid")
+    assert (b["start"], b["end"]) == (10.0, 10.0)
+    assert len(a["span_id"]) == 16 and a["span_id"] != b["span_id"]
+
+
+def test_new_trace_span_ignores_the_inherited_context(ray_init):
+    """A server's handlers inherit the context the server was started in:
+    `new_trace` roots a request's span in a trace of its own, and spans
+    opened under it chain to it."""
+    with tracing.span("server-start") as outer:
+        with tracing.span("ingress:x", new_trace=True) as root:
+            with tracing.span("inner") as inner:
+                pass
+        manual = tracing.start_manual_span("ingress:y", new_trace=True)
+        chained = tracing.start_manual_span("handle:pick")
+    assert root["trace_id"] != outer["trace_id"]
+    assert root["parent_span_id"] == ""
+    assert (inner["trace_id"], inner["parent_span_id"]) == (
+        root["trace_id"], root["span_id"])
+    assert manual["trace_id"] not in (outer["trace_id"], root["trace_id"])
+    assert manual["parent_span_id"] == ""
+    assert chained["trace_id"] == outer["trace_id"]
+
+
+@pytest.mark.parametrize("dropped", [0, 7])
+def test_list_spans_says_how_many_events_were_dropped(monkeypatch, caplog,
+                                                      dropped):
+    """The control store's `dropped` count reaches the reader of a trace:
+    a warning with the number when events were lost, none otherwise, and
+    the spans that were kept either way."""
+    from ray_tpu.util import state
+
+    events = [{"event": "SPAN", "task_id": b"\x01", "name": "ingress:x",
+               "trace_id": "t" * 32, "span_id": "s" * 16, "ts": 1.0,
+               "duration_s": 0.5},
+              {"event": "FINISHED", "task_id": b"\x01", "name": "f"}]
+    monkeypatch.setattr(state, "_control_call", lambda method, payload: {
+        "events": events, "dropped": dropped})
+    with caplog.at_level("WARNING", logger=tracing.logger.name):
+        spans = tracing.list_spans()
+    assert [s["name"] for s in spans] == ["ingress:x"]
+    warned = [r.getMessage() for r in caplog.records
+              if r.name == tracing.logger.name]
+    if dropped:
+        assert len(warned) == 1 and "7 task events were dropped" in warned[0]
+    else:
+        assert warned == []

@@ -1,0 +1,126 @@
+"""Compiles for a described (not attached) TPU v5e, so that what only the
+chip's compiler shows is checked in tier-1 at no chip time. Keep every such
+test in this one file: the worker that runs it loads the TPU's library and
+holds it until it exits."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops import flash_attention as fa
+
+PALLAS = 'custom_call_target="tpu_custom_call"'
+B, H, KVH, S, HD = 1, 16, 8, 1024, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def on_v5e(topo, monkeypatch):
+    """Shapes placed on the described chip; the kernels' own path (the
+    module asks the live backend, which is the CPU here); no compile cache
+    (an entry compiled for a described chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(fa, "_kernel_path", lambda *a: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def pallas_calls(hlo: str):
+    """{instruction name: (operand shapes, result shapes)} of the Pallas
+    custom calls in a compiled module's text, operands resolved through
+    the instructions that define them."""
+    shape = re.compile(r"(?:bf16|f32|s32|u32)\[[\d,]*\]")
+    defs = {m.group(1): m.group(2) for m in re.finditer(
+        r"%(\S+) = (.*?) [a-z][a-z\-]*\(", hlo)}
+    out = {}
+    for line in hlo.splitlines():
+        if PALLAS not in line:
+            continue
+        m = re.search(r"%(\S+) = (.*?) custom-call\((.*?)\), custom_call_target",
+                      line)
+        operands = [shape.findall(defs[o]) for o in re.findall(
+            r"%([^\s,)]+)", m.group(3))]
+        out[m.group(1)] = ([s for o in operands for s in o],
+                           shape.findall(m.group(2)))
+    return out
+
+
+def attention_grad(spec):
+    q, k, v = spec((B, H, S, HD)), spec((B, KVH, S, HD)), spec((B, KVH, S, HD))
+
+    def loss(q, k, v):
+        return fa.flash_attention_bhsd(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, k, v)
+
+
+def chunk(spec):
+    f32 = jnp.float32
+    args = (spec((B, H, S, HD)), spec((B, KVH, S, HD)), spec((B, KVH, S, HD)),
+            spec((B, H, S, HD), f32), spec((B, H, S, 1), f32),
+            spec((B, H, S, 1), f32))
+    return (lambda *a: fa.flash_chunk_bhsd(*a, causal=True)), args
+
+
+def hop_backward(spec):
+    f32 = jnp.float32
+    args = (spec((B, H, S, HD)), spec((B, KVH, S, HD)), spec((B, KVH, S, HD)),
+            spec((B, H, S, HD)), spec((B, H, S, 1), f32),
+            spec((B, H, S, 1), f32))
+    return (lambda *a: fa.flash_hop_bwd(*a, True)), args
+
+
+Q, KV = f"bf16[{B},{H},{S},{HD}]", f"bf16[{B},{KVH},{S},{HD}]"
+QF, ROW, COL = (f"f32[{B},{H},{S},{HD}]", f"f32[{B},{H},{S},1]",
+                f"f32[{B},{H},1,{S}]")
+
+
+@pytest.mark.parametrize("build,expected", [
+    # name -> (operands, results): the signatures benchmark/lib/xplane.py's
+    # flash_call_shape tells the kernels apart by (q, k, v first; 3 -> 2
+    # forward, 6 -> 1 dq, 6 -> 2 dkv)
+    (attention_grad, {
+        "flash_fwd": ([Q, KV, KV], [Q, ROW]),
+        "flash_dq": ([Q, KV, KV, Q, ROW, ROW], [Q]),
+        "flash_dkv": ([Q, KV, KV, Q, COL, COL], [QF, QF])}),
+    (chunk, {"flash_chunk": ([Q, KV, KV, QF, ROW, ROW], [QF, ROW, ROW])}),
+    (hop_backward, {
+        "flash_hop_dq": ([Q, KV, KV, Q, ROW, ROW], [QF]),
+        "flash_hop_dkv": ([Q, KV, KV, Q, COL, COL], [QF, QF])}),
+], ids=["attention_grad", "chunk", "hop_backward"])
+def test_flash_kernels_compile_for_v5e_under_their_names(on_v5e, build,
+                                                         expected):
+    """Each `pl.pallas_call` of ops/flash_attention.py is a named Mosaic
+    custom call in the v5e program, with the operands and results it had
+    before it was named: the trace's readers recognise the calls by
+    signature and the breakdown shows them by name."""
+    fn, args = build(on_v5e)
+    hlo = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    calls = pallas_calls(hlo)
+    assert len(calls) == len(expected), sorted(calls)
+    for name, signature in expected.items():
+        found = [sig for instr, sig in calls.items() if name + "_" in instr
+                 or instr.startswith(name + ".") or instr == name]
+        assert found == [signature], (name, calls)
