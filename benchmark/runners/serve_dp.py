@@ -13,7 +13,7 @@ from __future__ import annotations
 import asyncio
 import math
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Tuple
 
 from benchmark.lib import stats as st
 from benchmark.lib.config import CellFailure, model_overrides, published
@@ -24,10 +24,30 @@ REQUEST_TIMEOUT_S = 240.0
 # how long after the close a closed loop waits for the requests that straddle it
 DRAIN_CAP_S = 90.0
 # What makes a run not `correct` besides a wrong or failed answer. These are
-# the yardstick's own and no traffic file can set them:
-#   late_share     the generator's worst lateness (send - due) as a share of
-#                  the median request time: a starved generator must not be
-#                  read as a fast server;
+# the yardstick's own; no traffic file, cell file or environment variable
+# sets them:
+#   late_share     the generator's lateness (send - due) as a share of the
+#                  median request time: a starved generator must not be read
+#                  as the server. In a closed loop lateness is the client's
+#                  pause between an answer and its next send; it lowers the
+#                  offered concurrency by late / (late + request), so the
+#                  request time is its scale and the *worst* send is judged.
+#                  In an open loop a request is timed from its due time, so
+#                  a late send reads as a slower server, never a faster one;
+#                  the share is held by the *90th percentile* of lateness (a
+#                  generator that is late throughout), because one sample of
+#                  92 against a median that a faster server shrinks would
+#                  refuse the server for getting faster (5% of 10 s is
+#                  501 ms, of 1.1 s 55 ms; the driver's host read a worst
+#                  send of 157.7 ms in PR 22's check);
+#   gap_share      open loop only: the worst lateness as a share of the mean
+#                  gap between arrivals, 1 / rate_per_s, which no change to
+#                  the program moves. What one late send can spoil is the
+#                  arrival process: at more than half a gap late it has, on
+#                  average, changed places with its neighbour's; below that
+#                  the offered process is the cell's. Chat-open: 278 ms.
+#                  Machine stalls on record read 1.25 and 2-4 s (refused),
+#                  ordinary hosts 2.5 to 157.7 ms (pass at any server speed);
 #   counter_share  how far a closed loop's client-side `out_tokens_per_s` may
 #                  lie from the engine's own count (the change of `tokens_out`
 #                  over the window). The estimate spreads each answer's tokens
@@ -36,12 +56,31 @@ DRAIN_CAP_S = 90.0
 #                  +0.6% of the count (26 of them within 0.9%), 7 runs with
 #                  64 callers on 32 slots +3.7% to +7.0% (PR 22). 3% parts
 #                  the two: beyond it the estimate reads the queue and not
-#                  the engine. It is a third of the metric's bound (10%,
-#                  set by the host's noise and not by the estimate).
+#                  the engine. It is under the metric's bound (5.5%, set
+#                  by the host's noise and not by the estimate).
 # On the CPU the system under test starves the generator of cores and a window
 # holds a few dozen tokens, so the rehearsal only runs the arithmetic.
-LIMITS = {"late_share": 0.05, "counter_share": 0.03}
-REHEARSAL_LIMITS = {"late_share": 1.0, "counter_share": 0.5}
+LIMITS = {"late_share": 0.05, "gap_share": 0.5, "counter_share": 0.03}
+REHEARSAL_LIMITS = {"late_share": 1.0, "gap_share": math.inf,
+                    "counter_share": 0.5}
+# A window whose only faults are of these kinds says that the host stalled
+# (the generator's process or the client's clock: 4 of some 130 builders'
+# runs of sound trees, PR 22 to 25), not that the system did anything wrong:
+# `judged_windows` measures once more. Wrong or failed answers and a
+# compilation inside the window are never measured again.
+HOST_FAULTS = frozenset({"late", "counter"})
+# the second window draws its traffic from --seed + this: salts and documents
+# unlike the first window's, or the prefix cache would answer them
+SECOND_WINDOW_SEED = 2 ** 40
+# One run has 360 s (the contract's limit for a run whose programs are
+# compiled; the benchmark cannot tell that it was given the longer limit of
+# a first run). A second window is started only if it would end inside it,
+# with this much left for the trace's reduction and the shutdown.
+RUN_LIMIT_S = 360.0
+RUN_END_S = 20.0
+# how long the engine gets to finish the requests a closed window abandoned
+# (the longest answer, 256 tokens, takes 41 s at 160 ms a step)
+SETTLE_CAP_S = 45.0
 # how far under a position's largest reference logit a returned token's logit
 # may lie. System and reference differ in precision only: bf16 weights are
 # exact in float32, so the gap comes from bf16 activations (8 significant
@@ -288,8 +327,57 @@ def run(ctx) -> Dict[str, Any]:
     device = ctx.check_devices(
         [(i["platform"], i["device_kind"], i["device_count"]) for i in infos])
 
+    async def window(load: Load, window_seed: int) -> Dict[str, Any]:
+        """The ramp (set-up), then one window of the cell's traffic drawn
+        from `window_seed`, with everything read at its edges."""
+        load.records = []
+        ramp_s = float(traffic["ramp_s"])
+        t_open = time.monotonic() + ramp_s + 0.05
+        art: Dict[str, Any] = {"t_open": t_open, "window_s": window_s}
+
+        async def at_edges() -> None:
+            await sleep_until(t_open)
+            art["cache_files_open"] = ctx.cache_files()
+            art["stats_open"] = await asyncio.to_thread(engine_stats)
+            if ctx.trace:
+                trace_s = float(traffic["trace_s"])
+                await sleep_until(t_open + 0.25 * window_s)
+                ctx.log("tracing the engine's process")
+                art["trace_call"] = await asyncio.to_thread(
+                    lambda: ray_tpu.get(engines[0].__rt_call__.remote(
+                        _inside.engine_trace, ctx.trace_dir, trace_s),
+                        timeout=600))
+                ctx.log("trace written")
+            await sleep_until(t_open + window_s)
+            art["stats_close"] = await asyncio.to_thread(engine_stats)
+            art["cache_files_close"] = ctx.cache_files()
+
+        edges = asyncio.ensure_future(at_edges())
+        if generator.LOOP == "open":
+            await open_loop(
+                load, generator.schedule(traffic, window_seed, window_s), t_open)
+        else:
+            streams = [generator.stream(traffic, window_seed, i)
+                       for i in range(int(traffic["clients"]))]
+            await closed_loop(load, streams, t_open, ramp_s, window_s)
+        await edges
+        art["records"] = load.records
+        return art
+
+    async def settle() -> None:
+        """Until the engine has finished what the closed window abandoned
+        (its counter of generated tokens stands still for a second), so that
+        the next ramp starts from an idle engine as the first did."""
+        t0, last = time.monotonic(), None
+        while time.monotonic() < t0 + SETTLE_CAP_S:
+            now = (await asyncio.to_thread(engine_stats))["tokens_out"]
+            if now == last:
+                break
+            last = now
+            await asyncio.sleep(1.0)
+        ctx.log(f"the engine settled in {time.monotonic() - t0:.1f} s")
+
     async def drive() -> Dict[str, Any]:
-        art: Dict[str, Any] = {}
         async with Load(url) as load:
             # 1. every shape the window will use, one request each
             for req in warm_requests(traffic, seed):
@@ -310,43 +398,11 @@ def run(ctx) -> Dict[str, Any]:
                     _inside.engine_reference_check, published(cfg), samples,
                     int(traffic["check"]["pad_multiple"])),
                     timeout=900))
-            art["check"] = judge_check(gaps, CHECK_TOLERANCE_BF16_STEPS)
-            ctx.log(f"reference check: {art['check']}")
-            load.records.clear()
-
-            # 3. the ramp (set-up), then the window
-            ramp_s = float(traffic["ramp_s"])
-            t_open = time.monotonic() + ramp_s + 0.05
-            art["t_open"], art["window_s"] = t_open, window_s
-
-            async def at_edges() -> None:
-                await sleep_until(t_open)
-                art["cache_files_open"] = ctx.cache_files()
-                art["stats_open"] = await asyncio.to_thread(engine_stats)
-                if ctx.trace:
-                    trace_s = float(traffic["trace_s"])
-                    await sleep_until(t_open + 0.25 * window_s)
-                    ctx.log("tracing the engine's process")
-                    art["trace_call"] = await asyncio.to_thread(
-                        lambda: ray_tpu.get(engines[0].__rt_call__.remote(
-                            _inside.engine_trace, ctx.trace_dir, trace_s),
-                            timeout=600))
-                    ctx.log("trace written")
-                await sleep_until(t_open + window_s)
-                art["stats_close"] = await asyncio.to_thread(engine_stats)
-                art["cache_files_close"] = ctx.cache_files()
-
-            edges = asyncio.ensure_future(at_edges())
-            if generator.LOOP == "open":
-                await open_loop(load, generator.schedule(traffic, seed, window_s),
-                                t_open)
-            else:
-                streams = [generator.stream(traffic, seed, i)
-                           for i in range(int(traffic["clients"]))]
-                await closed_loop(load, streams, t_open, ramp_s, window_s)
-            await edges
-            art["records"] = load.records
-        return art
+            check = judge_check(gaps, CHECK_TOLERANCE_BF16_STEPS)
+            ctx.log(f"reference check: {check}")
+            # 3. the window, and one more if only the host was at fault
+            return await judged_windows(
+                ctx, check, lambda s: window(load, s), settle)
 
     art = asyncio.run(drive())
     art["engine"] = engine_cfg
@@ -361,7 +417,34 @@ def run(ctx) -> Dict[str, Any]:
 
         art["spans"] = tracing.list_spans(limit=500_000)
     art["engine_init_s"] = [i["init_s"] for i in infos]
-    return finish(ctx, art)
+    return art
+
+
+async def judged_windows(ctx, check: Dict[str, Any], window, settle
+                         ) -> Dict[str, Any]:
+    """The run's verdict: one window (`await window(seed)`, judged whole by
+    `finish`), and exactly one more, on the engine that is up, when every
+    fault of the first is the host's (`HOST_FAULTS`). The second window is
+    judged by the same rules and stands, faults and all. `setup_s` stays
+    with the first opening (`first_t_open`), so a second window cannot
+    move it; `retried` says why there was one."""
+    t0 = time.monotonic()
+    first = finish(ctx, {**await window(ctx.seed), "check": check})
+    took = time.monotonic() - t0
+    verdict, why = first, None
+    if first["faults"] and first["faults"] <= HOST_FAULTS:
+        await settle()
+        ends = ctx.elapsed() + took + RUN_END_S
+        if ends > RUN_LIMIT_S:
+            ctx.log(f"no second window: it would end {ends:.0f} s after the "
+                    f"start, a run has {RUN_LIMIT_S:.0f} s")
+        else:
+            why = "; ".join(first["problems"])
+            ctx.log(f"the host's fault alone, so one more window: {why}")
+            verdict = finish(ctx, {
+                **await window(ctx.seed + SECOND_WINDOW_SEED), "check": check})
+    verdict["first_t_open"], verdict["retried"] = first["t_open"], why
+    return verdict
 
 
 def finish(ctx, art: Dict[str, Any]) -> Dict[str, Any]:
@@ -404,33 +487,62 @@ def finish(ctx, art: Dict[str, Any]) -> Dict[str, Any]:
         ) / window_s
     art["end_to_end"] = e2e
     limits = REHEARSAL_LIMITS if ctx.rehearsal else LIMITS
-    problems = []
+    faults: List[Tuple[str, str]] = []     # (kind, what the log says)
     if not art["check"]["ok"]:
-        problems.append(f"reference check failed: {art['check']}")
+        faults.append(("reference", f"reference check failed: {art['check']}"))
     if failed:
-        problems.append(f"{len(failed)} requests failed: "
-                        f"{failed[0].get('error', 'unanswered')}")
+        faults.append(("failed", f"{len(failed)} requests failed: "
+                       f"{failed[0].get('error', 'unanswered')}"))
     if art["cache_files_close"] != art["cache_files_open"]:
-        problems.append(
-            f"compiled inside the window: {art['cache_files_open']} -> "
-            f"{art['cache_files_close']} files in the compile cache")
+        faults.append(("compiled",
+                       f"compiled inside the window: {art['cache_files_open']} "
+                       f"-> {art['cache_files_close']} files in the compile "
+                       "cache"))
     ok_lat = [x for x in latency if math.isfinite(x)]
-    if late and ok_lat:
-        ref = (st.median(ok_lat) if ctx.generator.LOOP == "open"
-               else st.median(art["closed_req_s"]))
+    held: List[str] = []       # each number compared beside its limit
+    if late and ok_lat and ctx.generator.LOOP == "open":
+        gap = 1.0 / float(ctx.traffic["rate_per_s"])
+        ref, p90 = st.median(ok_lat), st.percentile(late, 90.0)
+        held += [f"worst lateness {max(late) * 1e3:.1f} ms (limit "
+                 f"{limits['gap_share'] * gap * 1e3:.1f})",
+                 f"lateness p90 {p90 * 1e3:.1f} ms (limit "
+                 f"{limits['late_share'] * ref * 1e3:.1f})"]
+        if max(late) > limits["gap_share"] * gap:
+            faults.append(("late",
+                           f"the generator ran late: at worst "
+                           f"{max(late) * 1e3:.1f} ms against a mean gap "
+                           f"between arrivals of {gap * 1e3:.1f} ms"))
+        if p90 > limits["late_share"] * ref:
+            faults.append(("late",
+                           f"the generator ran late: {p90 * 1e3:.1f} ms at the "
+                           f"90th percentile of {len(late)} sends against a "
+                           f"median request of {ref:.3f} s"))
+    elif late and ok_lat:
+        ref = st.median(art["closed_req_s"])
+        held.append(f"worst lateness {max(late) * 1e3:.1f} ms (limit "
+                    f"{limits['late_share'] * ref * 1e3:.1f})")
         if max(late) > limits["late_share"] * ref:
-            problems.append(
-                f"the generator ran late: at worst {max(late) * 1e3:.1f} ms "
-                f"against a median request of {ref:.3f} s")
+            faults.append(("late",
+                           f"the generator ran late: at worst "
+                           f"{max(late) * 1e3:.1f} ms against a median request "
+                           f"of {ref:.3f} s"))
     counted = art.get("counter_tokens_per_s")
     if counted is not None:
         got = e2e["out_tokens_per_s"]
+        if counted > 0:
+            held.append(f"client's rate / engine's count - 1 = "
+                        f"{got / counted - 1.0:+.4f} (limit +-"
+                        f"{limits['counter_share']})")
         if not counted > 0 or abs(got / counted - 1.0) > limits["counter_share"]:
-            problems.append(
-                f"out_tokens_per_s {got:.2f} from the client's clock against "
-                f"{counted:.2f} from the engine's tokens_out: the estimate "
-                "does not hold for this traffic")
-    art["problems"] = problems
+            faults.append(("counter",
+                           f"out_tokens_per_s {got:.2f} from the client's clock "
+                           f"against {counted:.2f} from the engine's "
+                           "tokens_out: the estimate does not hold for this "
+                           "traffic"))
+    ctx.log("held to: " + "; ".join(
+        [f"{len(failed)} of {n} requests failed (limit 0)"] + held))
+    art["faults"] = {kind for kind, _ in faults}
+    art["problems"] = [text for _, text in faults]
     art["attempted"], art["failed"] = n, len(failed)
 
     def in_flight(t: float) -> int:

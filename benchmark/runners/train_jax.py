@@ -72,7 +72,11 @@ def train_loop(config: Dict[str, Any]) -> None:
     from benchmark.lib import reference, stats
 
     cfg_file, traffic = config["config"], config["traffic"]
-    seed, seconds, n = config["seed"], config["seconds"], config["chips"]
+    # `--seed` may be a little over 2**31 and reaches the jitted `make_batch`
+    # as an int32 (OverflowError): folded into 31 bits here, once, for the
+    # weights' key too; a seed under 2**31 is itself
+    seed = int(config["seed"]) % 2 ** 31
+    seconds, n = config["seconds"], config["chips"]
     generator = importlib.import_module(
         f"benchmark.traffic.{traffic['generator']}")
     devices = jax.devices()[:n]

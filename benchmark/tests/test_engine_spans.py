@@ -1,16 +1,11 @@
 """The readers of the engine's own spans and of the device's programs
 (lib/host_spans.py and the five per-layer metrics on it): on a trace written
 by hand, on a small trace recorded on the chip, on a program that writes
-none of it, and end to end on the CPU through run.py.
-
-No cell file lists the five metrics yet: a cell's metrics come from its file
-under workloads/, and this PR (tracing) may only add files. `with_engine_metrics`
-makes the edit a `benchmark` PR would make, on a copy, and the rehearsal runs
-there."""
+none of it, and end to end on the CPU through run.py. Every serve cell lists
+the five (PR 26), the cells judged on request time as `open_<name>`."""
 
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -26,23 +21,6 @@ NAMES = ("engine_queue_wait_p50_ms", "engine_prefill_stall_share",
          "engine_host_ms_per_step", "decode_device_ms_per_step",
          "prefill_device_share")
 MS = 1e6   # nanoseconds
-
-
-def with_engine_metrics(bench_dir: str) -> None:
-    """Append the five names to every serve cell's `per_layer` list under
-    `bench_dir`/workloads (`open_` twins where the cell is judged on request
-    time): the whole of what reporting them in a cell takes."""
-    folder = os.path.join(bench_dir, "workloads")
-    for fname in sorted(os.listdir(folder)):
-        path = os.path.join(folder, fname)
-        with open(path) as f:
-            cell = json.load(f)
-        if "out_tokens_per_s" in cell["end_to_end"]:
-            cell["per_layer"] += list(NAMES)
-        elif "req_p50_s" in cell["end_to_end"]:
-            cell["per_layer"] += ["open_" + n for n in NAMES]
-        with open(path, "w") as f:
-            json.dump(cell, f, indent=1)
 
 
 def read(name, art):
@@ -257,22 +235,19 @@ def test_each_reader_has_its_open_twin():
 
 @pytest.mark.parametrize("cell,prefix", [("tiny-docqa-closed", ""),
                                          ("tiny-chat-open", "open_")])
-def test_tiny_cell_reads_the_engine_metrics_on_the_cpu(tmp_path, cell, prefix):
-    """run.py on a copy of the benchmark whose cells list the five metrics:
-    the run stays `correct`, and the three that need no device (the spans,
-    and the loop's phases from the CPU trace) are read; a CPU run reports
-    them to stderr only."""
-    bench = tmp_path / "benchmark"
-    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    with_engine_metrics(str(bench))
+def test_tiny_cell_reads_the_engine_metrics_on_the_cpu(cell, prefix):
+    """run.py on a tiny cell: the run stays `correct`, and the three that
+    need no device (the spans, and the loop's phases from the CPU trace) are
+    read; a CPU run reports them to stderr only."""
+    with open(os.path.join(BENCH, "workloads", f"{cell}.json")) as f:
+        assert set(prefix + n for n in NAMES) <= set(json.load(f)["per_layer"])
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}
-    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, str(bench / "run.py"), "--workload", cell, "--seed",
-         "7", "--seconds", "5", "--trace", "1"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "7", "--seconds", "5", "--trace", "1"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is True and result["metrics"] == {}

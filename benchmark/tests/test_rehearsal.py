@@ -26,21 +26,24 @@ def load(*parts):
         return json.load(f)
 
 
-def run_cell(cell, trace, seconds=5, seed=5):
+def run_cell(cell, trace, seconds=5, seed=5, script="run.py"):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_COMPILATION_CACHE_DIR",)}
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+        [sys.executable, os.path.join(BENCH, script), "--workload", cell,
          "--seed", str(seed), "--seconds", str(seconds), "--trace", str(trace)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     return proc
 
 
-@pytest.mark.parametrize("cell,trace", [
-    ("tiny-chat-open", 0), ("tiny-docqa-closed", 1), ("tiny-pretrain-fsdp4", 0)])
-def test_tiny_cell_runs_end_to_end_on_the_cpu(cell, trace):
-    proc = run_cell(cell, trace)
+@pytest.mark.parametrize("cell,trace,seed", [
+    ("tiny-chat-open", 0, 5), ("tiny-docqa-closed", 1, 5),
+    ("tiny-pretrain-fsdp4", 0, 5),
+    # the driver's seeds go a little over 2**31, more than an int32 holds
+    ("tiny-pretrain-fsdp4", 0, 2303000001), ("tiny-chat-open", 0, 2 ** 31 + 11)])
+def test_tiny_cell_runs_end_to_end_on_the_cpu(cell, trace, seed):
+    proc = run_cell(cell, trace, seed=seed)
     assert proc.returncode == 0, proc.stderr[-3000:]
     last = proc.stdout.strip().splitlines()[-1]
     result = json.loads(last)
@@ -55,6 +58,32 @@ def test_tiny_cell_runs_end_to_end_on_the_cpu(cell, trace):
     # utilization under a device metric's name
     assert result["metrics"] == {}
     assert "reference check" in proc.stderr
+
+
+@pytest.mark.parametrize("cell,trace", [
+    ("tiny-chat-open", 1), ("tiny-docqa-closed", 0)])
+def test_a_host_fault_in_the_first_window_is_followed_by_one_more(cell, trace):
+    """tests/forced_retry.py marks the first window as a stalled host would:
+    the run ramps again on the engine that is up, measures and judges a
+    second window, and reports it with the first opening as its set-up."""
+    proc = run_cell(cell, trace, script=os.path.join("tests", "forced_retry.py"))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert set(result) == RESULT_KEYS
+    assert result["correct"] is True, proc.stderr[-3000:]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    log = [ln for ln in proc.stderr.splitlines() if ln.startswith("[bench")]
+    again = [ln for ln in log if "one more window" in ln]
+    assert len(again) == 1 and any("the engine settled" in ln for ln in log)
+    assert sum("reference check" in ln for ln in log) == 1
+    summary = json.loads(next(
+        ln for ln in log if "summary: " in ln).split("summary: ", 1)[1])
+    assert "forced by tests/forced_retry.py" in summary["retried"]
+    # set-up ended before the first window, a whole window before the second
+    second_ramp_at = float(again[0][len("[bench"):].split("s]")[0])
+    assert summary["end_to_end"]["setup_s"] < second_ramp_at - 5.0
+    if trace:
+        assert sum("tracing the engine's process" in ln for ln in log) == 2
 
 
 def test_a_real_cell_refuses_to_run_without_a_chip():

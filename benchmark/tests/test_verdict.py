@@ -1,40 +1,65 @@
 """What makes a serve run not `correct` besides a wrong answer: a late
 generator and a client-side token rate that leaves the engine's own count.
-Both limits are the runner's; no traffic file can loosen them."""
+The limits are the runner's; no traffic file can loosen them. A window whose
+only faults are those two is the host's and is measured once more."""
 
+import asyncio
+import json
+import os
 from types import SimpleNamespace
 
 import pytest
 
+from benchmark.lib import stats as st
 from benchmark.runners import serve_dp
 
-WINDOW = 50.0
+HERE = os.path.dirname(os.path.abspath(__file__))
+WINDOW = 51.0
+COUNT = 408           # tokens closed_records() generates inside the window
+with open(os.path.join(HERE, "..", "traffic", "chat-open.json")) as f:
+    CHAT_OPEN = json.load(f)
+GAP = 1.0 / CHAT_OPEN["rate_per_s"]          # 556 ms between arrivals
 
 
-def ctx_of(loop, rehearsal=False, traffic=None):
+def ctx_of(loop, rehearsal=False, traffic=None, elapsed=150.0):
+    logged = []
     return SimpleNamespace(generator=SimpleNamespace(LOOP=loop),
-                           rehearsal=rehearsal, traffic=traffic or {})
+                           rehearsal=rehearsal, seed=7, logged=logged,
+                           traffic=CHAT_OPEN if traffic is None else traffic,
+                           log=logged.append, elapsed=lambda: elapsed)
 
 
-def art_of(records, tokens_out=0):
-    return {"t_open": 100.0, "window_s": WINDOW, "records": records,
-            "check": {"ok": True}, "cache_files_open": 3, "cache_files_close": 3,
+def art_of(records, tokens_out=0, t_open=100.0, check_ok=True, cache_close=3):
+    return {"t_open": t_open, "window_s": WINDOW, "records": records,
+            "check": {"ok": check_ok}, "cache_files_open": 3,
+            "cache_files_close": cache_close,
             "stats_open": {"tokens_out": 1000},
             "stats_close": {"tokens_out": 1000 + tokens_out}}
 
 
-def closed_records(callers=4, every=5.0, tokens=10, wait=0.0):
+def closed_records(callers=4, every=5.0, tokens=10, wait=0.0, t_open=100.0):
     """Callers answering back to back from before the window to after it: a
     request takes `every` seconds, of which the first `wait` are spent
     queueing, and returns `tokens`."""
     recs = []
     for c in range(callers):
-        t = 80.0 + c * every / callers
-        while t < 100.0 + WINDOW + every:
+        t = t_open - 20.0 + c * every / callers
+        while t < t_open + WINDOW + every:
             recs.append({"tag": "w", "due": t, "sent": t, "done": t + every,
                          "ok": True, "error": None, "tokens": tokens})
             t += every
     return recs
+
+
+def open_records(request_s, late_s=0.001, t_open=100.0):
+    """The chat-open cell's arrivals, evenly spaced at its rate over the
+    window, every send `late_s` late and every answer `request_s` after its
+    due time."""
+    n = round(CHAT_OPEN["rate_per_s"] * WINDOW)
+    return [{"tag": "w", "due": t_open + i * GAP,
+             "sent": t_open + i * GAP + late_s,
+             "done": t_open + i * GAP + request_s, "ok": True, "error": None,
+             "tokens": 64} for i in range(n)]
 
 
 def test_closed_loop_estimate_agrees_with_the_count_and_is_held_to_it():
@@ -47,6 +72,7 @@ def test_closed_loop_estimate_agrees_with_the_count_and_is_held_to_it():
     art = serve_dp.finish(ctx_of("closed"),
                           art_of(recs, tokens_out=round(truth / 1.05)))
     assert any("tokens_out" in p for p in art["problems"])
+    assert art["faults"] == {"counter"}
     # a counter that did not move cannot confirm anything
     art = serve_dp.finish(ctx_of("closed"), art_of(recs, tokens_out=0))
     assert any("tokens_out" in p for p in art["problems"])
@@ -55,27 +81,227 @@ def test_closed_loop_estimate_agrees_with_the_count_and_is_held_to_it():
 def test_a_traffic_file_cannot_loosen_the_limits():
     """Whatever keys a traffic file carries, the limits are the runner's;
     only a rehearsal cell (never in BENCHMARK.json) gets the looser ones."""
+    loose = {"late_limit_share": 1.0, "drain_cap_s": 1.0, "counter_share": 1.0,
+             "late_share": 1.0, "gap_share": 100.0,
+             "limits": {"late_share": 1.0, "gap_share": 100.0},
+             "rate_per_s": CHAT_OPEN["rate_per_s"]}
+    assert serve_dp.LIMITS == {"late_share": 0.05, "gap_share": 0.5,
+                               "counter_share": 0.03}
+    assert serve_dp.CHECK_TOLERANCE_BF16_STEPS == 6.0
     recs = closed_records()
     inside = next(r for r in recs if 110.0 < r["sent"] < 120.0)
     inside["sent"] += 0.3                    # one send 0.3 s late, request 5 s
-    loose = {"late_limit_share": 1.0, "drain_cap_s": 1.0, "counter_share": 1.0}
     art = serve_dp.finish(ctx_of("closed", traffic=loose),
-                          art_of(recs, tokens_out=round(400 / 1.05)))
+                          art_of(recs, tokens_out=round(COUNT / 1.05)))
     assert any("ran late" in p for p in art["problems"])
     assert any("tokens_out" in p for p in art["problems"])
     art = serve_dp.finish(ctx_of("closed", rehearsal=True, traffic=loose),
-                          art_of(recs, tokens_out=round(400 / 1.05)))
+                          art_of(recs, tokens_out=round(COUNT / 1.05)))
     assert art["problems"] == []
+    # the open loop's two clauses: one send 0.6 of a gap late, and every
+    # send 80 ms late at a 1 s median
+    for recs in (open_records(10.0), open_records(1.0, late_s=0.080)):
+        recs[40]["sent"] += 0.6 * GAP
+        art = serve_dp.finish(ctx_of("open", traffic=loose), art_of(recs))
+        assert art["faults"] == {"late"}
+        art = serve_dp.finish(ctx_of("open", rehearsal=True, traffic=loose),
+                              art_of(recs))
+        assert art["problems"] == []
 
 
-def test_open_loop_is_judged_on_the_worst_lateness():
-    recs = [{"tag": "w", "due": 100.0 + i * 0.5, "sent": 100.0 + i * 0.5 + 0.001,
-             "done": 100.0 + i * 0.5 + 10.0, "ok": True, "error": None,
-             "tokens": 64} for i in range(100)]
-    art = serve_dp.finish(ctx_of("open"), art_of(recs))
-    assert art["problems"] == [] and art["attempted"] == 100
-    assert art["end_to_end"]["req_p50_s"] == pytest.approx(10.0)
+def old_rule_refuses(recs, art):
+    """The rule before PR 26: the worst lateness against 5% of the median
+    request time of the system under test."""
+    late = [r["sent"] - r["due"] for r in recs]
+    return max(late) > 0.05 * art["end_to_end"]["req_p50_s"]
+
+
+@pytest.mark.parametrize("request_s,every_late_s,one_late_s,said,old_rule", [
+    # a quiet host, the standing tree's median and a nine times faster one
+    (10.0, 0.001, None, None, False),
+    (1.1, 0.001, None, None, False),
+    # one send 150 ms late (the driver's host read 157.7 ms in PR 22's
+    # check): the arrival process is the cell's at any server speed, where
+    # the old rule refused the faster server (55 ms)
+    (10.0, 0.001, 0.150, None, False),
+    (1.1, 0.001, 0.150, None, True),
+    (1.1, 0.001, 0.1577, None, True),
+    # one send 0.6 of a gap late (333 ms) has changed places with its
+    # neighbour's: not the cell's traffic, whatever the server's speed. The
+    # old rule let it pass on the standing tree (501 ms)
+    (10.0, 0.001, 0.6 * GAP, "mean gap between arrivals of 555.6 ms", False),
+    (1.0, 0.001, 0.6 * GAP, "mean gap between arrivals of 555.6 ms", True),
+    # the machine stalls on record (PR 24's seed 2401000013, PR 22's seed
+    # 110) stay refused
+    (11.2, 0.003, 1.2542, "at worst 1257.2 ms against a mean gap", True),
+    (10.0, 0.003, 3.0, "against a mean gap", True),
+    # a generator that is late throughout is read as the server: every send
+    # 80 ms late is 8% of a 1 s request, and nothing of a 10 s one
+    (1.0, 0.080, None, "80.0 ms at the 90th percentile of 92 sends against "
+                       "a median request of 1.000 s", True),
+    (10.0, 0.080, None, None, False),
+])
+def test_open_loop_lateness_is_held_to_the_traffics_own_clock(
+        request_s, every_late_s, one_late_s, said, old_rule):
+    recs = open_records(request_s, late_s=every_late_s)
+    if one_late_s is not None:
+        recs[40]["sent"] += one_late_s
+    ctx = ctx_of("open")
+    art = serve_dp.finish(ctx, art_of(recs))
+    assert art["attempted"] == len(recs) == 92
+    # every run's log has each number compared beside its limit
+    assert any("worst lateness" in m and "(limit 277.8)" in m
+               and f"(limit {50 * request_s:.1f})" in m for m in ctx.logged)
+    assert art["end_to_end"]["req_p50_s"] == pytest.approx(request_s)
     assert "counter_tokens_per_s" not in art      # open loop: nothing to hold
-    recs[40]["sent"] += 0.6                        # 6% of the median request
+    assert old_rule_refuses(recs, art) is old_rule
+    if said is None:
+        assert art["problems"] == [] and art["faults"] == set()
+    else:
+        assert art["faults"] == {"late"} and len(art["problems"]) == 1
+        assert "ran late" in art["problems"][0] and said in art["problems"][0]
+    # whatever the verdict, the worst send is what the per-layer metric shows
+    assert max(art["gen_late_s"]) == pytest.approx(
+        every_late_s + (one_late_s or 0.0))
+
+
+def test_the_open_loops_limit_is_the_cells_and_not_looser_than_it_was():
+    """On the standing tree (median request 10.03 s; ledger, PR 24) the old
+    limit was 501 ms; the new one is half a gap between arrivals, and no
+    quantity the system under test sets is in it."""
+    new_limit = serve_dp.LIMITS["gap_share"] * GAP
+    assert new_limit == pytest.approx(0.2778, abs=1e-4)
+    assert new_limit <= 0.05 * 10.03
+    for request_s in (0.2, 1.1, 10.0, 40.0):
+        for late_s, refused in ((new_limit - 0.005, False),
+                                (new_limit + 0.005, True)):
+            recs = open_records(request_s)
+            recs[40]["sent"] += late_s - 0.001
+            art = serve_dp.finish(ctx_of("open"), art_of(recs))
+            assert bool(art["problems"]) is refused, (request_s, late_s)
+
+
+def test_end_to_end_values_are_what_they_were():
+    """`finish` computes the end-to-end metrics as before PR 26: the median
+    and the 90th percentile of done - due, and the lifetime-weighted token
+    rate, written out here from the records."""
+    recs = open_records(10.0)
+    for i, r in enumerate(recs):
+        r["done"] += 0.05 * (i % 17)
     art = serve_dp.finish(ctx_of("open"), art_of(recs))
-    assert any("ran late" in p and "601.0 ms" in p for p in art["problems"])
+    latency = [r["done"] - r["due"] for r in recs]
+    assert art["end_to_end"] == {"req_p50_s": st.median(latency),
+                                 "req_p90_s": st.percentile(latency, 90.0)}
+    recs = closed_records()
+    art = serve_dp.finish(ctx_of("closed"), art_of(recs, tokens_out=COUNT))
+    lo, hi = 100.0, 100.0 + WINDOW
+    want = sum(r["tokens"] * (min(r["done"], hi) - max(r["sent"], lo))
+               / (r["done"] - r["sent"]) for r in recs
+               if r["sent"] < hi and r["done"] > lo) / WINDOW
+    assert art["end_to_end"] == {"out_tokens_per_s": want}
+
+
+# --------------------------------------------------------------------------
+# one second window, for a run whose only fault is the host's
+# --------------------------------------------------------------------------
+
+
+def stalled(recs, late_s=1.2):
+    recs[40]["sent"] += late_s
+    return recs
+
+
+def failed(recs):
+    recs[10].update(ok=False, error="HTTP 500")
+    return recs
+
+
+def run_windows(ctx, arts, check_ok=True):
+    """`judged_windows` over canned windows: the verdict, the seeds the
+    windows were drawn from and how often the engine was left to settle."""
+    seeds, settled = [], []
+
+    async def window(seed):
+        seeds.append(seed)
+        return {k: v for k, v in arts[len(seeds) - 1].items() if k != "check"}
+
+    async def settle():
+        settled.append(len(seeds))
+
+    art = asyncio.run(serve_dp.judged_windows(
+        ctx, {"ok": check_ok}, window, settle))
+    return art, seeds, settled
+
+
+SECOND = 400.0      # the second window opens 300 s after the first
+
+
+@pytest.mark.parametrize("loop,first,second,windows,correct", [
+    # nothing wrong: one window
+    ("open", lambda: art_of(open_records(10.0)), None, 1, True),
+    # the generator stalled once: exactly one more window, which stands
+    ("open", lambda: art_of(stalled(open_records(10.0))),
+     lambda: art_of(open_records(10.0, t_open=SECOND), t_open=SECOND), 2, True),
+    # the client's clock left the engine's count: the same
+    ("closed", lambda: art_of(closed_records(), tokens_out=round(COUNT / 1.06)),
+     lambda: art_of(closed_records(t_open=SECOND), tokens_out=COUNT,
+                    t_open=SECOND), 2, True),
+    # both at once (a stall of the whole machine)
+    ("closed", lambda: art_of(stalled(closed_records(), 0.3),
+                              tokens_out=round(COUNT / 1.06)),
+     lambda: art_of(closed_records(t_open=SECOND), tokens_out=COUNT,
+                    t_open=SECOND), 2, True),
+    # a second fault is final: no third window
+    ("open", lambda: art_of(stalled(open_records(10.0))),
+     lambda: art_of(stalled(open_records(10.0, t_open=SECOND)), t_open=SECOND),
+     2, False),
+    # the second window is judged by every rule, not only the first's
+    ("open", lambda: art_of(stalled(open_records(10.0))),
+     lambda: art_of(failed(open_records(10.0, t_open=SECOND)), t_open=SECOND),
+     2, False),
+    # a failed request, a compilation in the window: the system's, never
+    # measured again, alone or beside a late generator
+    ("open", lambda: art_of(failed(open_records(10.0))), None, 1, False),
+    ("open", lambda: art_of(failed(stalled(open_records(10.0)))), None, 1, False),
+    ("open", lambda: art_of(stalled(open_records(10.0)), cache_close=4),
+     None, 1, False),
+    ("closed", lambda: art_of(failed(closed_records()),
+                              tokens_out=round(COUNT / 1.06)), None, 1, False),
+])
+def test_one_second_window_for_the_hosts_faults_alone(
+        loop, first, second, windows, correct):
+    ctx = ctx_of(loop)
+    arts = [first()] + ([second()] if second else [])
+    art, seeds, settled = run_windows(ctx, arts)
+    assert len(seeds) == windows
+    assert (art["problems"] == []) is correct
+    # set-up ends where the first window opened, whichever window is judged
+    assert art["first_t_open"] == 100.0
+    if windows == 1:
+        assert seeds == [7] and settled == [] and art["retried"] is None
+        assert art["t_open"] == 100.0
+    else:
+        # traffic unlike the first window's, after the engine settled
+        assert seeds == [7, 7 + serve_dp.SECOND_WINDOW_SEED] and settled == [1]
+        assert art["t_open"] == SECOND and art["records"] is arts[1]["records"]
+        assert "ran late" in art["retried"] or "tokens_out" in art["retried"]
+        assert any("one more window" in m for m in ctx.logged)
+
+
+def test_a_failed_reference_check_is_never_measured_again():
+    ctx = ctx_of("open")
+    art, seeds, _ = run_windows(
+        ctx, [art_of(stalled(open_records(10.0)))], check_ok=False)
+    assert seeds == [7] and art["faults"] == {"reference", "late"}
+    assert art["retried"] is None
+
+
+def test_no_second_window_that_would_outlast_the_runs_limit():
+    """After a cold first set-up (compilation, 140-200 s) the second window
+    would end past the 360 s one run has: the first verdict stands, and the
+    log says why."""
+    ctx = ctx_of("open", elapsed=serve_dp.RUN_LIMIT_S - serve_dp.RUN_END_S + 1.0)
+    art, seeds, settled = run_windows(ctx, [art_of(stalled(open_records(10.0)))])
+    assert seeds == [7] and settled == [1]
+    assert art["faults"] == {"late"} and art["retried"] is None
+    assert any("no second window" in m for m in ctx.logged)
