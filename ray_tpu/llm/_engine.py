@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import logging
 import math
 import time
 from dataclasses import dataclass
@@ -95,13 +96,19 @@ def _apply_rope_q(x, cos, sin):
 
 
 def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
-    """Build the jitted whole-batch single-token decode step."""
+    """Build the jitted whole-batch single-token decode step. Returns
+    (step, path, note): which attention the step was built with
+    (`paged_attention.KERNEL` or `XLA`, decided here from the backend and
+    the shapes) and, where a TPU was refused the kernel, why."""
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.ops import paged_attention
+
     bs = ecfg.kv_block_size
     max_blocks = -(-ecfg.max_model_len // bs)
-    Lmax = max_blocks * bs
+    path, note = paged_attention.decode_path(
+        cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, bs, cfg.dtype)
 
     def paged_decode_step(params, kc, vc, tables, lens, active, last_tok,
                           keys, temps):
@@ -119,43 +126,36 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         phys = jnp.where(
             active, tables[jnp.arange(B), blk], 0).astype(jnp.int32)
         off = (lens % bs).astype(jnp.int32)
-
-        idx = jnp.arange(Lmax)
-        valid = (idx[None, :] <= lens[:, None]) & active[:, None]  # [B,Lmax]
+        # the live context, read after the scatter: positions 0..lens
+        # (the current token's included); an inactive slot attends nothing
+        live = jnp.where(active, lens + 1, 0).astype(jnp.int32)
 
         def layer(carry, xs):
-            h = carry
-            p, kcl, vcl = xs
+            # the pool rides in the carry and is written in place: as the
+            # scan's xs/ys every layer's slice is copied out and back, and
+            # the whole pool once more at the end
+            h, kc, vc = carry
+            p, l = xs
             x = rms_norm(h, p["ln1"], cfg.norm_eps)
             q = (x @ p["wq"].astype(dt)).reshape(B, 1, cfg.n_heads, hd)
             k = (x @ p["wk"].astype(dt)).reshape(B, 1, cfg.n_kv_heads, hd)
             v = (x @ p["wv"].astype(dt)).reshape(B, 1, cfg.n_kv_heads, hd)
             q = _apply_rope_q(q, cos, sin).astype(dt)
             k = _apply_rope_q(k, cos, sin).astype(dt)
-            kcl = kcl.at[phys, off].set(k[:, 0])
-            vcl = vcl.at[phys, off].set(v[:, 0])
-            # paged gather: [B, max_blocks, BS, KV, HD] → [B, Lmax, KV, HD]
-            k_all = kcl[tables].reshape(B, Lmax, cfg.n_kv_heads, hd)
-            v_all = vcl[tables].reshape(B, Lmax, cfg.n_kv_heads, hd)
-            if cfg.n_kv_heads != cfg.n_heads:
-                rep = cfg.n_heads // cfg.n_kv_heads
-                k_all = jnp.repeat(k_all, rep, axis=2)
-                v_all = jnp.repeat(v_all, rep, axis=2)
-            scale = 1.0 / math.sqrt(hd)
-            logits = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k_all,
-                preferred_element_type=jnp.float32) * scale
-            logits = jnp.where(valid[:, None, None, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-            o = jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
+            kc = kc.at[l, phys, off].set(k[:, 0])
+            vc = vc.at[l, phys, off].set(v[:, 0])
+            o = paged_attention.decode_attention(
+                path, q[:, 0], kc, vc, l, tables, live)         # [B,H,HD]
             h = h + o.reshape(B, 1, -1) @ p["wo"].astype(dt)
             x2 = rms_norm(h, p["ln2"], cfg.norm_eps)
             gate = jax.nn.silu(x2 @ p["w1"].astype(dt))
             up = x2 @ p["w3"].astype(dt)
             h = h + (gate * up) @ p["w2"].astype(dt)
-            return h, (kcl, vcl)
+            return (h, kc, vc), None
 
-        h, (kc, vc) = jax.lax.scan(layer, h, (params["layers"], kc, vc))
+        (h, kc, vc), _ = jax.lax.scan(
+            layer, (h, kc, vc),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         h = rms_norm(h, params["norm"], cfg.norm_eps)
         logits = (h[:, 0] @ params["lm_head"].astype(dt)).astype(jnp.float32)
 
@@ -169,7 +169,7 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         sampled = jax.vmap(sample_one)(keys, logits, temps)
         return sampled, kc, vc
 
-    return jax.jit(paged_decode_step, donate_argnums=(1, 2))
+    return jax.jit(paged_decode_step, donate_argnums=(1, 2)), path, note
 
 
 def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
@@ -415,7 +415,11 @@ class PagedEngine:
             self._prefix_cache = PrefixCache(
                 self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
         self._alloc_device_state()
-        self._decode = _make_decode_step(cfg, e)
+        # "paged_kernel" | "xla", fixed for the engine's life (stats())
+        self._decode, self.decode_attention, self._decode_note = (
+            _make_decode_step(cfg, e))
+        if self._decode_note:
+            logging.getLogger(__name__).warning(self._decode_note)
         self._prefill = _make_prefill(cfg, e)
         self._suffix_prefill = _make_suffix_prefill(cfg, e)
         self._pending: "asyncio.Queue[_Request]" = None  # type: ignore
@@ -426,6 +430,11 @@ class PagedEngine:
         self.steps = 0
         self.tokens_out = 0
         self.mid_decode_admissions = 0
+        # positions decode attention had to read (each active slot's context,
+        # the current token's included) and what scoring max_model_len
+        # positions of every slot reads: their ratio is the live share
+        self.attn_positions_live = 0
+        self.attn_positions_dense = 0
         self._ttfts = collections.deque(maxlen=256)
         self._queue_waits = collections.deque(maxlen=256)
 
@@ -825,6 +834,10 @@ class PagedEngine:
                 raise
             with phase(PHASE_EMIT):
                 self.steps = step + 1
+                self.attn_positions_live += int(
+                    self.lens[self.active].sum() + self.active.sum())
+                self.attn_positions_dense += (
+                    self.ecfg.max_num_seqs * self.ecfg.max_model_len)
                 self._rngs[:, 1] += 1  # fresh fold per step
                 for slot, req in enumerate(list(self.slot_req)):
                     if req is None or not self.active[slot]:
@@ -930,7 +943,12 @@ class PagedEngine:
             "active_slots": int(self.active.sum()),
             "mid_decode_admissions": self.mid_decode_admissions,
             "prefix_cache": cache.stats() if cache is not None else None,
+            "attn_positions_live": self.attn_positions_live,
+            "attn_positions_dense": self.attn_positions_dense,
+            "decode_attention": self.decode_attention,
         }
+        if self._decode_note:
+            out["decode_attention_note"] = self._decode_note
         if ttfts:
             # time to first token is queue wait + prefill: an operator
             # needs the split to tell a backlog from a slow prefill

@@ -1,0 +1,297 @@
+"""Decode attention over a paged KV cache, in Pallas for TPU.
+
+One query token a slot against that slot's *live* context, read in place
+from the block pool: the kernel walks only ``ceil(length / BS)`` entries of
+the slot's block table, so a step costs what the live keys and values cost
+and not ``max_model_len`` positions of every slot.
+
+* The pool stays in HBM in the engine's layout ``[L, NB, BS, KV, HD]``, seen
+  as ``[L * NB, BS * KV, HD]`` (the same bytes): a page is one contiguous
+  ``[BS * KV, HD]`` tile that holds all KV heads of its ``BS`` tokens, and
+  layer ``l``'s pages start at ``l * NB``.
+* Pages move by DMA in groups of ``G`` into double-buffered VMEM; the next
+  group (of this slot, or the first of the next slot) is in flight while the
+  current one is scored. A page past the live length is never fetched.
+* All KV heads of a group are scored in one matmul: ``q [H, HD]`` against the
+  group's rows ``[G * BS * KV, HD]`` gives ``[H, rows]``, of which a query
+  head keeps the columns of its own KV head (a mask, no ``repeat``, no copy
+  of the cache). K and V pass through the MXU as stored, in the cache's
+  dtype; scores, softmax and the accumulator are float32 (online softmax).
+
+Off-TPU (CPU tests) `decode_attention` runs `xla_decode_attention`, the
+gather / repeat / dense-scores formulation the kernel replaces and is tested
+against. Which one a decode step uses is decided when the step is built
+(`decode_path`), from the backend and the shapes alone.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+_INTERPRET = False  # test-only: run the kernel in the Pallas interpreter
+
+NEG_INF = -1e30
+KERNEL, XLA = "paged_kernel", "xla"
+# rows of K (or V) scored at once: 8 pages of 16 tokens x 8 KV heads
+_GROUP_ROWS = 1024
+_MAX_GROUP_PAGES = 16
+
+
+# ---------------------------------------------------------------------------
+# XLA reference (the only path off-TPU)
+# ---------------------------------------------------------------------------
+
+
+def xla_decode_attention(q, kc, vc, layer, tables, lengths):
+    """q [B, H, HD]; kc/vc [L, NB, BS, KV, HD], of which layer `layer`
+    (a scalar) is read; tables [B, max_blocks]; lengths [B] (0 = inactive
+    slot) → o [B, H, HD]. Gathers every block of every table row and scores
+    all ``max_blocks * BS`` positions."""
+    B, H, hd = q.shape
+    kcl, vcl = kc[layer], vc[layer]
+    _, bs, kvh, _ = kcl.shape
+    Lmax = tables.shape[1] * bs
+    valid = jnp.arange(Lmax)[None, :] < lengths[:, None]         # [B,Lmax]
+    # paged gather: [B, max_blocks, BS, KV, HD] → [B, Lmax, KV, HD]
+    k_all = kcl[tables].reshape(B, Lmax, kvh, hd)
+    v_all = vcl[tables].reshape(B, Lmax, kvh, hd)
+    if kvh != H:
+        k_all = jnp.repeat(k_all, H // kvh, axis=2)
+        v_all = jnp.repeat(v_all, H // kvh, axis=2)
+    scale = 1.0 / math.sqrt(hd)
+    logits = jnp.einsum(
+        "bqhd,bkhd->bhqk", q[:, None], k_all,
+        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# the kernel
+# ---------------------------------------------------------------------------
+
+
+def _decode_kernel(tables_ref, lengths_ref, first_ref, q_ref, token_ref,
+                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref, *,
+                   scale, group_pages, page_rows, block_size, max_blocks):
+    """One grid step = one slot. tables_ref [B * max_blocks], lengths_ref
+    [B], first_ref [1] (the layer's first page in the pool) in SMEM;
+    q_ref/o_ref [1, Hp, HD]; token_ref [Hp, rows] (`_column_tokens`);
+    k_hbm/v_hbm [L * NB, page_rows, HD] in HBM; k_buf/v_buf [2, rows, HD]
+    with rows = group_pages * page_rows; sems [2, 2] (K/V x buffer);
+    parity_ref [1]: the buffer the next group lands in, carried from slot
+    to slot."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, bs = group_pages, block_size
+    b, B = pl.program_id(0), pl.num_programs(0)
+
+    def pages_of(slot):
+        return lax.div(lengths_ref[slot] + (bs - 1), bs)
+
+    def each_page(slot, g, buf, act):
+        """`act` on the K and the V copy of every live page of group g of
+        `slot`: nothing is done, and the table is not read, from the first
+        page past the live length on."""
+        left = pages_of(slot) - g * G
+        for i in range(G):
+            @pl.when(i < left)
+            def _(i=i):
+                page = first_ref[0] + tables_ref[
+                    slot * max_blocks + g * G + i]
+                dst = pl.ds(i * page_rows, page_rows)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]))
+
+    def start(slot, g, buf):
+        each_page(slot, g, buf, lambda copy: copy.start())
+
+    def wait(slot, g, buf):
+        each_page(slot, g, buf, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _():
+        # rows that no DMA fills are masked out of the scores, and their
+        # probability 0 must meet a finite V: no stale NaN in the buffers
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        parity_ref[0] = 0
+        start(0, 0, 0)
+
+    length = lengths_ref[b]
+    # an inactive slot still takes one (empty) turn, so that the chain of
+    # prefetches from slot to slot is never broken
+    n_groups = jnp.maximum(lax.div(pages_of(b) + (G - 1), G), 1)
+    q = q_ref[0]                                             # [Hp, HD]
+    token = token_ref[...]                                   # [Hp, rows]
+
+    def group(g, carry):
+        m, l, acc = carry
+        buf = parity_ref[0]
+        nxt = 1 - buf
+        more = g + 1 < n_groups
+
+        @pl.when(more)
+        def _():
+            start(b, g + 1, nxt)
+
+        @pl.when(jnp.logical_not(more) & (b + 1 < B))
+        def _():
+            start(b + 1, 0, nxt)
+
+        wait(b, g, buf)
+        k = k_buf[buf]                                       # [rows, HD]
+        v = v_buf[buf]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        valid = token < length - g * (G * bs)
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        parity_ref[0] = nxt
+        return m_new, l, acc
+
+    Hp, hd = q_ref.shape[1:]
+    m0 = jnp.full((Hp, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((Hp, 1), jnp.float32)
+    acc0 = jnp.zeros((Hp, hd), jnp.float32)
+    _, l, acc = lax.fori_loop(0, n_groups, group, (m0, l0, acc0))
+    # an inactive slot (and a padded query head) has l == 0 and acc == 0
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _sublane_tile(dtype) -> int:
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _column_tokens(Hp: int, rows: int, n_heads: int, kv_heads: int):
+    """[Hp, rows] int32: row r of a group of pages is token r // KV of the
+    group, KV head r % KV. For query head h the entry is that token's number
+    where the row is of h's own KV head, and a number no length reaches
+    elsewhere (and in every column of a padded head): one comparison with
+    the length left then masks the foreign heads and the dead positions."""
+    import numpy as np
+
+    col = np.arange(rows)[None, :]
+    head = np.arange(Hp)[:, None]
+    own = (col % kv_heads == head // (n_heads // kv_heads)) & (head < n_heads)
+    return np.where(own, col // kv_heads, 2 ** 30).astype(np.int32)
+
+
+def _group_pages(page_rows: int, max_blocks: int) -> int:
+    return max(1, min(_GROUP_ROWS // page_rows, _MAX_GROUP_PAGES, max_blocks))
+
+
+def paged_decode_attention(q, kc, vc, layer, tables, lengths):
+    """The kernel: same arguments and result as `xla_decode_attention`.
+    It is handed the whole pool and the layer's number, not the layer's
+    slice: a slice of the pool as an operand is a copy of it (84 MB a layer
+    for K and again for V at the benchmark's shapes)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, hd = q.shape
+    L, NB, bs, kvh, _ = kc.shape
+    max_blocks = tables.shape[1]
+    page_rows = bs * kvh
+    G = _group_pages(page_rows, max_blocks)
+    rows = G * page_rows
+    # query heads fill whole sublane tiles of the MXU's left operand; a
+    # padded head belongs to no KV head and comes out 0
+    tile = _sublane_tile(q.dtype)
+    Hp = -(-H // tile) * tile
+    qp = q if Hp == H else jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
+    lengths = jnp.clip(lengths, 0, max_blocks * bs).astype(jnp.int32)
+    # for XLA's scheduler only: the live tokens are data, a quarter of the
+    # tables' reach is a guess
+    live = B * max_blocks * bs // 4
+
+    kernel = functools.partial(
+        _decode_kernel, scale=1.0 / math.sqrt(hd), group_pages=G,
+        page_rows=page_rows, block_size=bs, max_blocks=max_blocks)
+    o = pl.pallas_call(
+        kernel,
+        name="paged_decode_attention",
+        out_shape=jax.ShapeDtypeStruct((B, Hp, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, Hp, hd), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((Hp, rows), lambda b, *_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, Hp, hd), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, hd), kc.dtype),
+                pltpu.VMEM((2, rows, hd), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        # slots run in order: the buffers, their parity and the prefetch of
+        # the next slot's first group are carried from one to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * 2 * Hp * kvh * live * hd),
+            bytes_accessed=int(2 * live * kvh * hd * kc.dtype.itemsize
+                               + 2 * q.size * q.dtype.itemsize),
+            transcendentals=int(Hp * kvh * live),
+        ),
+        interpret=_INTERPRET,
+    )(tables.reshape(-1).astype(jnp.int32), lengths,
+      (jnp.asarray(layer, jnp.int32) * NB).reshape(1), qp,
+      _column_tokens(Hp, rows, H, kvh),
+      kc.reshape(L * NB, page_rows, hd), vc.reshape(L * NB, page_rows, hd))
+    return o if Hp == H else o[:, :H]
+
+
+# ---------------------------------------------------------------------------
+# which path a decode step takes
+# ---------------------------------------------------------------------------
+
+
+def decode_path(n_heads: int, n_kv_heads: int, head_dim: int,
+                block_size: int, dtype):
+    """(path, note) for a decode step of these shapes, decided once when
+    the step is built: `KERNEL` on a TPU backend (or under the test
+    interpreter) when the kernel takes the shape; otherwise `XLA`. `note`
+    is None unless a TPU backend was refused the kernel: then it names
+    every constraint the shape breaks, for the log and for `stats()`."""
+    if jax.default_backend() != "tpu" and not _INTERPRET:
+        return XLA, None
+    tile = _sublane_tile(dtype)
+    need = []
+    if head_dim % 128:
+        need.append(f"head_dim % 128 == 0 (head_dim={head_dim})")
+    if block_size % tile:
+        need.append(f"kv_block_size % {tile} == 0, the sublane tile of "
+                    f"{jnp.dtype(dtype).name} (kv_block_size={block_size})")
+    if n_heads % n_kv_heads:
+        need.append(f"heads % kv_heads == 0 ({n_heads} % {n_kv_heads})")
+    if need:
+        return XLA, ("no paged-attention kernel for this shape, decode "
+                     "attention gathers max_model_len positions a slot; the "
+                     "kernel needs " + "; ".join(need))
+    return KERNEL, None
+
+
+def decode_attention(path: str, q, kc, vc, layer, tables, lengths):
+    fn = paged_decode_attention if path == KERNEL else xla_decode_attention
+    return fn(q, kc, vc, layer, tables, lengths)

@@ -90,6 +90,27 @@ def test_tracing_off_records_no_engine_span(monkeypatch):
     assert 0.0 <= stats["queue_wait_p50_s"] <= stats["ttft_p50_s"]
 
 
+def test_stats_carry_the_decode_attention_and_its_live_share():
+    """`stats()` names the attention the decode step was built with and
+    counts, step by step, the positions it had to read beside the positions
+    a dense pass over max_model_len reads: two concurrent requests."""
+    eng = small_engine()
+
+    async def one(prompt, n):
+        return [t async for t in eng.generate_stream(prompt, max_tokens=n)]
+
+    async def main():
+        return await asyncio.gather(one([1, 2, 3], 6), one([4, 5], 4))
+
+    assert [len(o) for o in asyncio.run(main())] == [6, 4]
+    stats = eng.stats()
+    assert stats["decode_attention"] == "xla"
+    assert stats["attn_positions_dense"] == stats["steps"] * 2 * 64
+    # each decoded token read its own context: 3..8 and 2..4 cached + 1
+    assert stats["attn_positions_live"] == sum(range(4, 9)) + sum(range(3, 6))
+    assert 0 < stats["attn_positions_live"] < stats["attn_positions_dense"]
+
+
 def test_traced_request_records_the_phases_it_reached(monkeypatch):
     """Under a caller's span a finished request leaves queue, prefill and
     decode as that span's children, end to end without a gap; a request

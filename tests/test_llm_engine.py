@@ -40,6 +40,34 @@ def test_paged_matches_dense_decode():
     assert eng.stats()["free_blocks"] == 32
 
 
+@pytest.mark.parametrize("requests,steps,live", [
+    # a request of P prompt tokens and N answer tokens takes N - 1 decode
+    # steps (the first token comes from the prefill), and step j reads the
+    # P + j cached positions and the current token's
+    ([(3, 5)], 4, 4 + 5 + 6 + 7),
+    ([(3, 5), (5, 3)], 6, (4 + 5 + 6 + 7) + (6 + 7)),
+], ids=["one_request", "two_in_turn"])
+def test_stats_count_live_and_dense_attention_positions(requests, steps, live):
+    params = init_params(CFG, jax.random.PRNGKey(0))
+    eng = PagedEngine(CFG, params, EngineConfig(
+        max_num_seqs=2, kv_block_size=4, num_kv_blocks=32, max_model_len=64))
+
+    async def main():
+        for plen, n in requests:          # one after the other: exact counts
+            out = [t async for t in eng.generate_stream(
+                list(range(1, plen + 1)), max_tokens=n, temperature=0.0)]
+            assert len(out) == n
+
+    asyncio.run(main())
+    stats = eng.stats()
+    assert stats["decode_attention"] == "xla"      # the CPU: no kernel
+    assert "decode_attention_note" not in stats
+    assert stats["steps"] == steps
+    assert stats["attn_positions_live"] == live
+    # what scoring max_model_len positions of every slot reads
+    assert stats["attn_positions_dense"] == steps * 2 * 64
+
+
 def test_block_reuse_across_waves():
     """More sequences over time than the pool could ever hold at once."""
     params = init_params(CFG, jax.random.PRNGKey(0))

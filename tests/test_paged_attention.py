@@ -1,0 +1,193 @@
+"""ops/paged_attention.py: the decode kernel (in the Pallas interpreter)
+against the XLA formulation it replaces, and the choice between them."""
+
+import asyncio
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+
+HD, BS = 128, 16
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """The kernel in the interpreter, two pages a group: a slot of eight
+    blocks then takes up to four turns of the double buffer."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    monkeypatch.setattr(pa, "_group_pages", lambda rows, max_blocks: 2)
+
+
+def pool_and_tables(rng, lens, *, kv, max_blocks, layers=2, dtype=jnp.bfloat16,
+                    order="shuffled"):
+    """A pool whose layer 1 holds the slots' blocks (owned blocks drawn
+    without order from 1..NB-1, block 0 the trash block) and whose every
+    other block, and all of layer 0, is NaN: nothing but the live pages of
+    the asked layer may be read."""
+    need = [-(-n // BS) for n in lens]
+    nb = sum(need) + 6
+    ids = np.arange(1, nb)
+    ids = rng.permutation(ids) if order == "shuffled" else ids[::-1]
+    tables = np.zeros((len(lens), max_blocks), np.int32)
+    used = 0
+    for b, n in enumerate(need):
+        tables[b, :n] = ids[used:used + n]
+        used += n
+    owned = np.zeros(nb, bool)
+    owned[tables[tables > 0]] = True
+    shape = (layers, nb, BS, kv, HD)
+    k, v = (rng.standard_normal(shape).astype(np.float32) for _ in range(2))
+    clean = [jnp.array(x, dtype) for x in (k, v)]   # a copy: x goes on
+    for x in (k, v):
+        x[:-1] = np.nan
+        x[-1, ~owned] = np.nan
+    return clean, [jnp.asarray(x, dtype) for x in (k, v)], jnp.asarray(tables)
+
+
+def check(lens, *, heads, kv, max_blocks=8, dtype=jnp.bfloat16,
+          order="shuffled", seed=0):
+    rng = np.random.default_rng(seed)
+    (k, v), (k_nan, v_nan), tables = pool_and_tables(
+        rng, lens, kv=kv, max_blocks=max_blocks, dtype=dtype, order=order)
+    q = jnp.asarray(rng.standard_normal((len(lens), heads, HD)), dtype)
+    lengths = jnp.asarray(lens, jnp.int32)
+    layer = k.shape[0] - 1
+    want = np.asarray(pa.xla_decode_attention(
+        q, k, v, layer, tables, lengths), np.float32)
+    got = np.asarray(jax.jit(pa.paged_decode_attention)(
+        q, k_nan, v_nan, layer, tables, lengths), np.float32)
+    assert np.isfinite(got).all()
+    live = np.asarray(lens) > 0
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+    # an inactive slot attends nothing: zeros, not the NaN of an empty softmax
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("lens", [
+    [40, 0, 17, 100],            # ragged, with an inactive slot (trash row)
+    [1, BS - 1, BS, BS + 1],     # round a block's edge
+    [8 * BS, 8 * BS - 1, 2 * BS, 2 * BS + 1],   # max_model_len, group edges
+    [0, 0, 0, 0],                # nothing active at all
+], ids=["ragged_inactive", "block_edge", "max_model_len", "all_inactive"])
+def test_kernel_matches_xla_over_lengths(interpreted, lens):
+    check(lens, heads=8, kv=2)
+
+
+@pytest.mark.parametrize("order", ["shuffled", "descending"])
+def test_block_tables_neither_contiguous_nor_ordered(interpreted, order):
+    check([70, 33, 0, 128], heads=8, kv=2, order=order, seed=3)
+
+
+@pytest.mark.parametrize("heads,kv", [(32, 8), (2, 1), (4, 4)],
+                         ids=["gqa_4to1_8kv", "gqa_2to1_1kv", "mha"])
+def test_grouped_query_heads(interpreted, heads, kv):
+    """32/8 is the benchmark's cell; 2/1 pads the query heads to a sublane
+    tile, and the padded heads belong to no KV head."""
+    check([50, 5, 0, 97], heads=heads, kv=kv, seed=heads)
+
+
+def test_float32_cache(interpreted):
+    check([50, 5, 0, 97], heads=4, kv=2, dtype=jnp.float32)
+
+
+def test_default_group_size_and_one_program_shape(monkeypatch):
+    """The group size the chip runs (8 pages of 16 x 8 rows), and lengths
+    as data: every mix of lengths runs the one compiled program."""
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    assert pa._group_pages(16 * 8, 128) == 8
+    traces = []
+    fn = jax.jit(lambda *a: traces.append(1) or pa.paged_decode_attention(*a))
+    rng = np.random.default_rng(1)
+    for lens in ([3, 200], [129, 0], [256, 256]):
+        (k, v), _, tables = pool_and_tables(
+            rng, [256, 256], kv=8, max_blocks=16, layers=1)
+        q = jnp.asarray(rng.standard_normal((2, 32, HD)), jnp.bfloat16)
+        lengths = jnp.asarray(lens, jnp.int32)
+        got = np.asarray(fn(q, k, v, 0, tables, lengths), np.float32)
+        want = np.asarray(pa.xla_decode_attention(
+            q, k, v, 0, tables, lengths), np.float32)
+        live = np.asarray(lens) > 0
+        np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+    assert len(traces) == 1
+
+
+def test_column_tokens_marks_own_head_and_padding():
+    t = pa._column_tokens(16, 32, n_heads=4, kv_heads=2)   # rep 2
+    assert t.shape == (16, 32)
+    # head 0 and 1 read KV head 0 (even rows), head 2 and 3 KV head 1
+    assert (t[0, 0::2] == np.arange(16)).all() and (t[0, 1::2] > 2 ** 20).all()
+    assert (t[3, 1::2] == np.arange(16)).all() and (t[3, 0::2] > 2 ** 20).all()
+    assert (t[4:] > 2 ** 20).all()                          # padded heads
+
+
+def test_decode_path_follows_backend_and_shape(monkeypatch):
+    bf16 = jnp.bfloat16
+    assert pa.decode_path(32, 8, 128, 16, bf16) == (pa.XLA, None)   # the CPU
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    assert pa.decode_path(32, 8, 128, 16, bf16) == (pa.KERNEL, None)
+    monkeypatch.setattr(pa, "_INTERPRET", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.decode_path(32, 8, 128, 16, bf16) == (pa.KERNEL, None)
+    assert pa.decode_path(2, 1, 128, 8, jnp.float32) == (pa.KERNEL, None)
+    for shape, word in (((32, 8, 64, 16, bf16), "head_dim"),
+                        ((32, 8, 128, 8, bf16), "kv_block_size"),
+                        ((32, 5, 128, 16, bf16), "kv_heads")):
+        path, note = pa.decode_path(*shape)
+        assert path == pa.XLA and word in note
+
+
+def test_engine_on_a_tpu_says_when_it_is_refused_the_kernel(monkeypatch,
+                                                            caplog):
+    """A TPU backend with a shape the kernel does not take falls back to
+    the XLA lines, and says so: once in the log and in stats()."""
+    from ray_tpu.llm._engine import EngineConfig, PagedEngine
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    cfg = LlamaConfig(vocab_size=64, dim=32, n_layers=1, n_heads=2,
+                      n_kv_heads=1, ffn_dim=64, max_seq_len=32,
+                      dtype=jnp.float32, param_dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with caplog.at_level("WARNING", logger="ray_tpu.llm._engine"):
+        eng = PagedEngine(cfg, params, EngineConfig(
+            max_num_seqs=2, kv_block_size=8, num_kv_blocks=8, max_model_len=32))
+    stats = eng.stats()
+    assert stats["decode_attention"] == "xla"
+    assert "head_dim" in stats["decode_attention_note"]
+    assert [r for r in caplog.records if "head_dim" in r.getMessage()]
+
+
+def test_engine_with_the_kernel_matches_dense_decode(monkeypatch):
+    """PagedEngine built with the kernel (interpreter, head_dim 128) gives
+    the tokens of the dense decoder, as test_paged_matches_dense_decode
+    shows for the XLA path."""
+    from ray_tpu.llm._engine import EngineConfig, PagedEngine
+    from ray_tpu.llm._generate import generate
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    monkeypatch.setattr(pa, "_INTERPRET", True)
+    cfg = LlamaConfig(vocab_size=256, dim=256, n_layers=2, n_heads=2,
+                      n_kv_heads=1, ffn_dim=256, max_seq_len=64,
+                      dtype=jnp.float32, param_dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [[1, 5, 9], [3, 3, 3, 7, 2, 8, 1, 4, 4, 6], [42]]
+    dense = generate(cfg, params, prompts, max_new_tokens=8, temperature=0.0)
+    eng = PagedEngine(cfg, params, EngineConfig(
+        max_num_seqs=4, kv_block_size=8, num_kv_blocks=16, max_model_len=32))
+    assert eng.stats()["decode_attention"] == "paged_kernel"
+
+    async def run_one(p):
+        return [t async for t in eng.generate_stream(
+            p, max_tokens=8, temperature=0.0)]
+
+    async def main():
+        return await asyncio.gather(*[run_one(p) for p in prompts])
+
+    assert asyncio.run(main()) == dense
+    stats = eng.stats()
+    assert stats["free_blocks"] == 16
+    assert 0 < stats["attn_positions_live"] < stats["attn_positions_dense"]

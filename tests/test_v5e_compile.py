@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops import paged_attention as pa
 
 PALLAS = 'custom_call_target="tpu_custom_call"'
 B, H, KVH, S, HD = 1, 16, 8, 1024, 128
@@ -124,3 +125,48 @@ def test_flash_kernels_compile_for_v5e_under_their_names(on_v5e, build,
         found = [sig for instr, sig in calls.items() if name + "_" in instr
                  or instr.startswith(name + ".") or instr == name]
         assert found == [signature], (name, calls)
+
+
+V5E_HBM_BYTES = 15.75e9    # what the runtime leaves a program of 16 GiB
+
+
+def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
+                                                            monkeypatch):
+    """`jit_paged_decode_step` at the serve cells' shapes (Mistral-7B widths,
+    16 layers, 32 slots, 2,560 blocks of 16, tables of 128): the attention is
+    the named Pallas call, no value over all 2,048 positions of every slot is
+    left, the pool is not copied, and the program fits the chip."""
+    from ray_tpu.llm._engine import EngineConfig, _make_decode_step
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    cfg = LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, rope_theta=1e6, max_seq_len=2048, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    slots, blocks = 32, 2560
+    step, path, note = _make_decode_step(cfg, EngineConfig(
+        max_num_seqs=slots, kv_block_size=16, num_kv_blocks=blocks,
+        max_model_len=2048))
+    assert (path, note) == (pa.KERNEL, None)
+    params = jax.tree.map(
+        lambda x: on_v5e(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = on_v5e((16, blocks + 1, 16, 8, 128))
+    compiled = step.trace(
+        params, pool, pool, on_v5e((slots, 128), jnp.int32),
+        on_v5e((slots,), jnp.int32), on_v5e((slots,), jnp.bool_),
+        on_v5e((slots,), jnp.int32), on_v5e((slots, 2), jnp.uint32),
+        on_v5e((slots,), jnp.float32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines() if PALLAS in line]
+    assert len(kernels) == 1 and "%paged_decode_attention" in kernels[0]
+    assert f"bf16[{16 * (blocks + 1)},128,128]" in kernels[0]   # pool in place
+    assert not re.findall(r"(?:f32|bf16)\[32,2048,[\d,]*\]", hlo)
+    m = compiled.memory_analysis()
+    # the pool (2.7 GB) rides in the scan's carry and is donated: no copy
+    assert m.alias_size_in_bytes > 2.6e9 and m.temp_size_in_bytes < 0.2e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
