@@ -4,9 +4,12 @@ Reference surface: python/ray/llm/_internal/serve/ (LLMServer
 core/server/llm_server.py:127, OpenAI-compatible ingress
 core/ingress/builder.py:213 build_openai_app) and batch processors
 (llm/_internal/batch/processor/). Where the reference wraps vLLM's CUDA
-engine, the engine HERE is the in-framework JAX Llama model with a
-KV-cache decode loop (_generate.py) — serving replicas are ordinary serve
-deployments, so routing/autoscaling/gang placement come from ray_tpu.serve.
+engine, the engine HERE is in-framework JAX: `LLMServer` runs the Llama
+model with a KV-cache decode loop (_generate.py); `LLMEngine` wraps the
+continuous-batching `PagedEngine` (_engine.py), which serves every family
+of `MODEL_FAMILIES` — `LLMConfig.model` names a family and a preset.
+Serving replicas are ordinary serve deployments, so routing/autoscaling/
+gang placement come from ray_tpu.serve.
 """
 
 from __future__ import annotations
@@ -18,6 +21,12 @@ from typing import Any, Dict, List, Optional
 from ray_tpu.llm._generate import generate, init_cache
 
 BOS, EOS = 256, 257
+# family -> (module, its config class, its `f(cfg, key)` that makes the
+# seeded weights a server without a checkpoint serves): the engine builds its
+# steps and caches from the config's type
+MODEL_FAMILIES = {
+    "llama": ("ray_tpu.models.llama", "LlamaConfig", "init_params"),
+    "ling": ("ray_tpu.models.ling", "LingConfig", "seeded_params")}
 
 
 class ByteTokenizer:
@@ -39,7 +48,10 @@ class LLMConfig:
     """Reference: llm LLMConfig (model_loading_config + engine_kwargs)."""
 
     model_id: str = "llama-tiny-random"
-    model: str = "tiny"            # LlamaConfig preset name
+    # "<family>:<preset>": a model family of `MODEL_FAMILIES` and a preset
+    # (a classmethod of its config class); a bare preset is the Llama
+    # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny"
+    model: str = "tiny"
     model_overrides: Dict[str, Any] = field(default_factory=dict)
     checkpoint_path: Optional[str] = None  # pickled params pytree
     max_new_tokens: int = 32
@@ -48,16 +60,24 @@ class LLMConfig:
     seed: int = 0
 
     def build_model(self):
+        """(the family's config, its parameters): what `PagedEngine` takes.
+        `LLMServer`'s one-request-at-a-time `generate` path runs the Llama
+        family only."""
+        import importlib
+
         import jax
 
-        from ray_tpu.models.llama import LlamaConfig, init_params
         from ray_tpu.tpu.accelerator import check_granted_devices
 
         # a worker holding a chip grant must see exactly those chips on the
         # TPU backend before anything is built on it
         check_granted_devices()
-        preset = getattr(LlamaConfig, self.model)
-        cfg = preset(**self.model_overrides)
+        family, _, preset = self.model.rpartition(":")
+        module, config_cls, seeded = MODEL_FAMILIES[family or "llama"]
+        model = importlib.import_module(module)
+        init_params = getattr(model, seeded)
+        cfg = getattr(getattr(model, config_cls), preset)(
+            **self.model_overrides)
         assert cfg.vocab_size >= ByteTokenizer.vocab_size, (
             "model vocab must cover the byte tokenizer's 258 ids")
         if self.checkpoint_path:

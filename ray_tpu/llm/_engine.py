@@ -30,10 +30,12 @@ import logging
 import math
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Union
 
 import numpy as np
 
+from ray_tpu.llm import _ling_steps
+from ray_tpu.models.ling import LingConfig
 from ray_tpu.models.llama import LlamaConfig, rms_norm, rope_tables
 from ray_tpu.util import tracing
 
@@ -64,19 +66,34 @@ PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
 SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
 SPAN_PREFILL = "engine:prefill"  # admission start -> first token
 SPAN_DECODE = "engine:decode"    # first token -> done
+# a turn of the loop is admissions (tens of ms each) and one decode step:
+# one that takes longer than this is counted as a stall (stats())
+STALL_TURN_S = 1.0
 
 
 @dataclass
 class EngineConfig:
     """Sizing knobs (reference: vLLM engine_kwargs max_num_seqs /
-    block_size / gpu_memory_utilization → num blocks)."""
+    block_size / gpu_memory_utilization → num blocks).
+
+    What the blocks hold depends on the model's family. Llama: keys and
+    values of every layer. Ling (recurrent layers beside latent attention):
+    the pool holds one latent vector a token for each latent-attention layer
+    ([layers, blocks, block size, rank + rope], same table and allocator),
+    and beside it every slot owns one float32 recurrent state per
+    linear-attention layer and its short convolution's tail; those are sized
+    by `max_num_seqs` alone, are reset by the slot's next prefill and are
+    never paged (`ray_tpu/llm/_ling_steps.py`)."""
 
     max_num_seqs: int = 4          # decode batch slots
     kv_block_size: int = 16        # tokens per KV block
     num_kv_blocks: int = 64        # pool size (excl. the trash block)
     max_model_len: int = 256       # prompt + generation cap per sequence
     # None = follow the llm_prefix_cache_enabled config flag (the bench
-    # A/B lever passes an explicit bool)
+    # A/B lever passes an explicit bool). A model with recurrent layers has
+    # no prefix cache (a cached block of latents is no use without the
+    # recurrent state at its boundary, which nothing snapshots): None turns
+    # it off there, True is refused
     prefix_cache: Optional[bool] = None
 
 
@@ -159,17 +176,26 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         h = rms_norm(h, params["norm"], cfg.norm_eps)
         logits = (h[:, 0] @ params["lm_head"].astype(dt)).astype(jnp.float32)
 
-        def sample_one(key_data, lg, t):
-            key = jax.random.wrap_key_data(key_data.astype(jnp.uint32))
-            greedy = jnp.argmax(lg).astype(jnp.int32)
-            samp = jax.random.categorical(
-                key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
-            return jnp.where(t > 0, samp, greedy)
-
-        sampled = jax.vmap(sample_one)(keys, logits, temps)
-        return sampled, kc, vc
+        return sample_tokens(keys, logits, temps), kc, vc
 
     return jax.jit(paged_decode_step, donate_argnums=(1, 2)), path, note
+
+
+def sample_tokens(keys, logits, temps):
+    """Inside a decode step: one token a slot from logits [B, V], greedy
+    where the slot's temperature is 0, else drawn with the slot's key (raw
+    key data [B, 2])."""
+    import jax
+    import jax.numpy as jnp
+
+    def sample_one(key_data, lg, t):
+        key = jax.random.wrap_key_data(key_data.astype(jnp.uint32))
+        greedy = jnp.argmax(lg).astype(jnp.int32)
+        samp = jax.random.categorical(
+            key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
+        return jnp.where(t > 0, samp, greedy)
+
+    return jax.vmap(sample_one)(keys, logits, temps)
 
 
 def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
@@ -376,6 +402,11 @@ class _Request:
     # (reference: serving_patterns/prefill_decode — KV transfer between
     # prefill and decode engines)
     prefilled: Optional[tuple] = None  # (k [L,nb,bs,kvh,hd], v, last_logits)
+    # check_routing's request: receives under "routing" the expert layers'
+    # choices at every position computed ([moe_layers, n, top_k + 1] a piece)
+    # and, where it has the key "steps", what each decode step computed the
+    # slot's router and recurrence from, with the state before and after
+    probe: Optional[Dict[str, Any]] = None
 
 
 class PagedEngine:
@@ -387,10 +418,16 @@ class PagedEngine:
     `generate_stream` concurrently — requests arriving mid-decode are
     admitted at the next step boundary."""
 
-    def __init__(self, cfg: LlamaConfig, params, ecfg: Optional[EngineConfig] = None,
+    def __init__(self, cfg: Union[LlamaConfig, LingConfig], params,
+                 ecfg: Optional[EngineConfig] = None,
                  eos_id: Optional[int] = None):
         self.cfg = cfg
         self.ecfg = ecfg or EngineConfig()
+        # a model with recurrent layers: per-slot state beside the pool
+        self._recurrent = isinstance(cfg, LingConfig)
+        # the device arrays every step is given, donates and returns
+        self._cache_names = (_ling_steps.CACHE_NAMES if self._recurrent
+                             else ("kc", "vc"))
         self.params = params
         self.eos_id = eos_id
         e = self.ecfg
@@ -406,6 +443,13 @@ class PagedEngine:
         from ray_tpu._private.config import GLOBAL_CONFIG
 
         enabled = e.prefix_cache
+        if self._recurrent:
+            if enabled:
+                raise ValueError(
+                    "prefix_cache=True with recurrent layers: a shared block "
+                    "of latents would need the recurrent state at its "
+                    "boundary, and nothing snapshots that state")
+            enabled = False
         if enabled is None:
             enabled = GLOBAL_CONFIG.get("llm_prefix_cache_enabled")
         self._prefix_cache = None
@@ -416,12 +460,17 @@ class PagedEngine:
                 self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
         self._alloc_device_state()
         # "paged_kernel" | "xla", fixed for the engine's life (stats())
+        make_decode, make_prefill = (
+            (_ling_steps.make_decode_step, _ling_steps.make_prefill)
+            if self._recurrent else (_make_decode_step, _make_prefill))
         self._decode, self.decode_attention, self._decode_note = (
-            _make_decode_step(cfg, e))
+            make_decode(cfg, e))
         if self._decode_note:
             logging.getLogger(__name__).warning(self._decode_note)
-        self._prefill = _make_prefill(cfg, e)
-        self._suffix_prefill = _make_suffix_prefill(cfg, e)
+        self._prefill = make_prefill(cfg, e)
+        # no prefix cache with recurrent layers, so no prefill over one
+        self._suffix_prefill = (None if self._recurrent
+                                else _make_suffix_prefill(cfg, e))
         self._pending: "asyncio.Queue[_Request]" = None  # type: ignore
         self._inject = None  # lazy jitted donated KV scatter (P/D admission)
         self._loop_task = None
@@ -435,14 +484,34 @@ class PagedEngine:
         # positions of every slot reads: their ratio is the live share
         self.attn_positions_live = 0
         self.attn_positions_dense = 0
+        # what the decode step counts on the device and returns behind its
+        # tokens (none for Llama), summed over the steps
+        self._step_counters = {name: 0 for name in (
+            _ling_steps.COUNTERS if self._recurrent else ())}
+        # the slot whose router and recurrence inputs the decode step hands
+        # out (check_routing; one request at a time), and its device copy
+        self._probe_slot = None
+        # turns of the loop (from one step's tokens to the next's: sweep,
+        # admissions, one decode step) that took over STALL_TURN_S: how
+        # many, their seconds, of those the seconds before the step, and
+        # when the last one ended (unix time)
+        self._stalls = {"loop_stalls": 0, "loop_stall_s": 0.0,
+                        "loop_stall_admit_s": 0.0, "loop_stall_last_at": 0.0}
         self._ttfts = collections.deque(maxlen=256)
         self._queue_waits = collections.deque(maxlen=256)
 
     # -- device-state recovery -----------------------------------------
 
+    def _cache(self) -> tuple:
+        return tuple(getattr(self, n) for n in self._cache_names)
+
+    def _set_cache(self, arrays) -> None:
+        for name, a in zip(self._cache_names, arrays):
+            setattr(self, name, a)
+
     def _device_state_invalid(self) -> bool:
         try:
-            return bool(self.kc.is_deleted() or self.vc.is_deleted())
+            return any(a.is_deleted() for a in self._cache())
         except AttributeError:
             return False
 
@@ -454,10 +523,14 @@ class PagedEngine:
 
         cfg, e = self.cfg, self.ecfg
         NB = e.num_kv_blocks + 1
-        self.kc = jnp.zeros(
-            (cfg.n_layers, NB, self.bs, cfg.n_kv_heads, cfg.head_dim),
-            cfg.dtype)
-        self.vc = jnp.zeros_like(self.kc)
+        if self._recurrent:
+            self._set_cache(_ling_steps.alloc_cache(cfg, e))
+            self._probe_arg = jnp.int32(0)
+        else:
+            self.kc = jnp.zeros(
+                (cfg.n_layers, NB, self.bs, cfg.n_kv_heads, cfg.head_dim),
+                cfg.dtype)
+            self.vc = jnp.zeros_like(self.kc)
         self.free_blocks = list(range(1, NB))
 
     def _reset_device_state(self):
@@ -497,7 +570,10 @@ class PagedEngine:
     def _try_admit(self, req: _Request) -> bool:
         import jax
 
-        with jax.profiler.TraceAnnotation(PHASE_ADMIT):
+        # state_reset: the admission hands a slot's recurrent state to a new
+        # request (its prefill starts from zeros and overwrites it)
+        with jax.profiler.TraceAnnotation(
+                PHASE_ADMIT, state_reset=int(self._recurrent)):
             return self._admit(req)
 
     def _admit(self, req: _Request) -> bool:
@@ -508,6 +584,11 @@ class PagedEngine:
         except StopIteration:
             return False
         if req.prefilled is not None:
+            if self._recurrent:
+                self._fail(req, ValueError(
+                    "transferred KV cannot seed a model with recurrent "
+                    "layers: its state is not in the blocks"))
+                return True
             if not self._free_with_eviction(need):
                 return False
             req.t_admit = t_admit
@@ -560,9 +641,21 @@ class PagedEngine:
                 prompt[:plen] = req.prompt
                 with jax.profiler.TraceAnnotation(
                         PHASE_PREFILL, S=S, cached_len=0):
-                    logits, self.kc, self.vc = self._prefill(
-                        S, self.params, self.kc, self.vc, jnp.asarray(row),
-                        jnp.asarray(prompt), jnp.int32(plen))
+                    if self._recurrent:
+                        logits, routing, *cache = self._prefill(
+                            S, self.params, *self._cache(), jnp.asarray(row),
+                            jnp.asarray(prompt), jnp.int32(plen),
+                            jnp.int32(slot))
+                        self._set_cache(cache)
+                        if req.probe is not None:
+                            req.probe["routing"].append(
+                                np.asarray(routing)[:, :plen])
+                            self._probe_admitted(req, slot)
+                    else:
+                        logits, self.kc, self.vc = self._prefill(
+                            S, self.params, self.kc, self.vc,
+                            jnp.asarray(row), jnp.asarray(prompt),
+                            jnp.int32(plen))
             tok = self._sample_first(req, slot, logits)
         except BaseException:
             # any failure between the block pop and slot activation (prefill
@@ -612,8 +705,24 @@ class PagedEngine:
         if done and req.slot >= 0:
             self._release(req)
 
+    def _probe_admitted(self, req: _Request, slot: int):
+        """A request that asked for the decode step's mechanisms owns the
+        step's probe from here to its release; its recurrent state as the
+        prefill left it is the replay's starting point."""
+        import jax.numpy as jnp
+
+        if "steps" not in req.probe:
+            return
+        assert self._probe_slot is None, "one probed request at a time"
+        self._probe_slot, self._probe_arg = slot, jnp.int32(slot)
+        req.probe["state0"] = np.asarray(self.state[:, slot])
+
     def _release(self, req: _Request):
         slot = req.slot
+        if slot == self._probe_slot:
+            self._probe_slot = None
+            if not self._device_state_invalid():
+                req.probe["state"] = np.asarray(self.state[:, slot])
         need = self._blocks_needed(req)
         cache = self._prefix_cache
         for b in self.tables[slot][:need]:
@@ -745,7 +854,10 @@ class PagedEngine:
 
         phase = jax.profiler.TraceAnnotation
         waiting: "collections.deque[_Request]" = collections.deque()
+        t_turn = None      # when the last turn ended; None after an idle wait
         while True:
+            if t_turn is None:
+                t_turn = time.monotonic()
             mid_decode = bool(self.active.any())
             with phase(PHASE_SWEEP):
                 while not self._pending.empty():
@@ -796,9 +908,13 @@ class PagedEngine:
             if not self.active.any():
                 # idle: block until a request arrives
                 waiting.append(await self._pending.get())
+                t_turn = None
                 continue
+            t_step = time.monotonic()
             # one decode step for every active slot
             step = self.steps
+            probing = any(r is not None and r.probe is not None
+                          for r in self.slot_req)
 
             def run_step():
                 # the outer annotation names a device gap that straddles
@@ -808,14 +924,21 @@ class PagedEngine:
                         state = [jnp.asarray(a) for a in (
                             self.tables, self.lens, self.active,
                             self.last_tok, self._rngs, self.temps)]
+                    if self._recurrent:
+                        state.append(self._probe_arg)
                     with phase(PHASE_DISPATCH):
-                        toks, self.kc, self.vc = self._decode(
-                            self.params, self.kc, self.vc, *state)
+                        toks, *rest = self._decode(
+                            self.params, *self._cache(), *state)
+                        n = len(self._cache_names)
+                        self._set_cache(rest[:n])
                     with phase(PHASE_DEVICE_WAIT):
-                        return np.asarray(toks)
+                        # past the caches: what a check reads, fetched only
+                        # while a request asks
+                        return np.asarray(toks), (
+                            jax.device_get(rest[n]) if probing else None)
 
             try:
-                toks = await asyncio.to_thread(run_step)
+                toks, probe = await asyncio.to_thread(run_step)
             except Exception as e:  # noqa: BLE001 — decode step failed
                 # the device state is suspect: fail every in-flight and
                 # queued request (callers must never hang on a dead loop)
@@ -839,13 +962,31 @@ class PagedEngine:
                 self.attn_positions_dense += (
                     self.ecfg.max_num_seqs * self.ecfg.max_model_len)
                 self._rngs[:, 1] += 1  # fresh fold per step
+                # behind the tokens: the step's counters
+                B = len(self.slot_req)
+                for name, n in zip(self._step_counters, toks[B:]):
+                    self._step_counters[name] += int(n)
                 for slot, req in enumerate(list(self.slot_req)):
                     if req is None or not self.active[slot]:
                         continue
+                    if req.probe is not None:
+                        req.probe["routing"].append(
+                            probe["routing"][:, slot:slot + 1])
+                        if slot == self._probe_slot:
+                            req.probe["steps"].append(
+                                {k: v for k, v in probe.items()
+                                 if k != "routing"})
                     self.lens[slot] += 1
                     tok = int(toks[slot])
                     self.last_tok[slot] = tok
                     self._emit(req, tok)
+            now = time.monotonic()
+            if now - t_turn > STALL_TURN_S:
+                self._stalls["loop_stalls"] += 1
+                self._stalls["loop_stall_s"] += now - t_turn
+                self._stalls["loop_stall_admit_s"] += t_step - t_turn
+                self._stalls["loop_stall_last_at"] = time.time()
+            t_turn = now
             await asyncio.sleep(0)  # let admissions interleave
 
     # -- public API -----------------------------------------------------
@@ -853,11 +994,13 @@ class PagedEngine:
     async def generate_stream(self, prompt_ids: List[int], *,
                               max_tokens: int = 32,
                               temperature: float = 0.0, seed: int = 0,
-                              prefilled: Optional[tuple] = None):
+                              prefilled: Optional[tuple] = None,
+                              probe: Optional[Dict[str, Any]] = None):
         """Async generator of token ids. Engine-side failures raise into the
         consumer (queue items: int token | None end | Exception).
         `prefilled=(k, v, last_logits)` admits with KV transferred from a
-        remote prefill worker instead of running prefill here."""
+        remote prefill worker instead of running prefill here. `probe`
+        (see `check_routing`) receives what a check holds to a reference."""
         if len(prompt_ids) + 1 > self.ecfg.max_model_len:
             raise ValueError(
                 f"prompt of {len(prompt_ids)} tokens exceeds "
@@ -867,7 +1010,7 @@ class PagedEngine:
         req = _Request(self._rid, list(prompt_ids), int(max_tokens),
                        float(temperature), int(seed),
                        queue=asyncio.Queue(), prefilled=prefilled,
-                       t_start=time.monotonic(),
+                       probe=probe, t_start=time.monotonic(),
                        trace_parent=tracing.current_span())
         self._pending.put_nowait(req)
         try:
@@ -895,12 +1038,16 @@ class PagedEngine:
 
         import jax
 
-        from ray_tpu.models.llama import forward
+        if self._recurrent:
+            got, ref = _ling_steps.check_prefill(
+                self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
+        else:
+            from ray_tpu.models.llama import forward
 
-        got, _, _, _ = prefill_fresh_pool(
-            self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
-        ref = jax.jit(functools.partial(forward, self.cfg))(
-            self.params, np.asarray([list(prompt_ids)], np.int32))[0, -1]
+            got, _, _, _ = prefill_fresh_pool(
+                self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
+            ref = jax.jit(functools.partial(forward, self.cfg))(
+                self.params, np.asarray([list(prompt_ids)], np.int32))[0, -1]
         got, ref = np.asarray(got), np.asarray(ref)
         return {
             "prompt_tokens": len(prompt_ids),
@@ -909,6 +1056,72 @@ class PagedEngine:
             "max_abs_ref": float(np.abs(ref).max()),
             "argmax_equal": bool(got.argmax() == ref.argmax()),
         }
+
+    async def check_routing(self, prompt_ids: List[int], max_tokens: int,
+                            mechanisms: bool = False) -> Dict[str, Any]:
+        """One greedy request through the engine's loop, the timed path's
+        own programs, with every expert layer's routing recorded: the tokens
+        and, for each position computed (the prompt's, then one a decode
+        step: prompt + max_tokens - 1 in all), the chosen experts and the
+        kept-groups mask, "routing" [moe_layers, positions, top_k + 1].
+
+        With `mechanisms` (one such request at a time) also what the router
+        and the recurrence computed from at every decode step, stacked over
+        the max_tokens - 1 steps under the keys of `_ling_steps.PROBE`, and
+        the slot's recurrent state as the prefill left it ("state0") and
+        after the last step ("state"), [kda_layers, H, dk, dv]: a reference
+        given the same inputs must arrive at the same scores and state.
+
+        A debug path beside `check_prefill`: a decode step computes these
+        anyway and the loop fetches them only while such a request is in a
+        slot."""
+        probe: Dict[str, Any] = {"routing": []}
+        if mechanisms:
+            probe["steps"] = []
+        toks = [t async for t in self.generate_stream(
+            prompt_ids, max_tokens=max_tokens, probe=probe)]
+        out = {"token_ids": toks,
+               "routing": np.concatenate(probe["routing"], axis=1)}
+        if mechanisms:
+            steps = probe.pop("steps")
+            out.update({k: np.stack([st[k] for st in steps])
+                        for k in (steps[0] if steps else ())})
+            out["state0"], out["state"] = probe["state0"], probe["state"]
+        return out
+
+    def step_hlo(self, prefill_lengths: List[int]) -> Dict[str, List[str]]:
+        """The optimized HLO text of the compiled steps, by the program names
+        the device trace shows: the decode step and the prefill at each
+        prompt length's bucket. Every instruction carries its `op_name`, the
+        `jax.named_scope`s it was traced under included, which the trace's
+        events do not; a reader joins the two by instruction name. Compiles
+        from shapes alone (a hit in the compile cache for a step that has
+        run), so it may run beside the loop."""
+        import jax
+        import jax.numpy as jnp
+
+        def shape(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        params = jax.tree.map(shape, self.params)
+        cache = [shape(a) for a in self._cache()]
+        state = [shape(a) for a in (self.tables, self.lens, self.active,
+                                    self.last_tok, self._rngs, self.temps)]
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        if self._recurrent:
+            state.append(i32)          # the probed slot
+        out = {"jit_paged_decode_step": [self._decode.lower(
+            params, *cache, *state).compile().as_text()]}
+        out["jit_paged_prefill"] = []
+        for n in prefill_lengths:
+            S = max(8, 1 << (n - 1).bit_length())
+            args = [shape(self.tables[0]),
+                    jax.ShapeDtypeStruct((S,), jnp.int32), i32]
+            if self._recurrent:
+                args.append(i32)       # the slot whose state is written
+            out["jit_paged_prefill"].append(self._prefill.lower(
+                S, params, *cache, *args).compile().as_text())
+        return out
 
     def _publish_metrics(self):
         """Engine telemetry on the metrics plane (constructors are
@@ -949,6 +1162,13 @@ class PagedEngine:
         }
         if self._decode_note:
             out["decode_attention_note"] = self._decode_note
+        if self._recurrent:
+            out.update(self._step_counters)
+            out.update(self._stalls)
+            out["state_bytes"] = int(self.state.nbytes + self.tails.nbytes)
+            # the latents a decode step's attention had to read, summed
+            out["latent_positions_live"] = (
+                self.attn_positions_live * self.cfg.mla_layers)
         if ttfts:
             # time to first token is queue wait + prefill: an operator
             # needs the split to tell a backlog from a slow prefill

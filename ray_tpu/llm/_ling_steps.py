@@ -1,0 +1,223 @@
+"""The engine's jitted steps for the Ling family (`models/ling.py`): what
+`_engine._make_decode_step` / `_make_prefill` are to the Llama block, with
+three kinds of per-sequence state in place of one KV pool:
+
+    latents  [mla_layers, NB, BS, 1, W]   paged under the engine's block
+             table and allocator: the KV pool's layout with one "head" a
+             token, whose key is the latent (rank + rope = 576 values, W =
+             640 with the lanes' padding, `LingConfig.latent_width`) and
+             whose value is its first `rank`
+    state    [kda_layers, slots, H, dk, dv] float32, one recurrent state a
+             slot: written whole by the slot's prefill (which starts from
+             zeros, so a reused slot carries nothing over), read and written
+             in place by every decode step, never paged
+    tails    [kda_layers, slots, K-1, 3*H*dk]: the short convolution's last
+             inputs, kept with the state
+
+All three are donated to each step and returned by it. The steps keep the
+Llama steps' names (`paged_decode_step`, `paged_prefill`), so the device
+trace's `jit_paged_*` programs mean the same for every family.
+
+The decode step's first result is one int32 vector, fetched once a step: the
+sampled tokens [B], then `COUNTERS` summed over the expert layers. Its last
+is what a check reads and the loop leaves on the device unless a request
+asked (`PagedEngine.check_routing`): every expert layer's routing, and for
+the one slot `probe_slot` names the router's input and scores and the
+recurrence's inputs, so that a reference can hold the router and the state
+to the same inputs (`PROBE`).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+from ray_tpu.models import ling
+from ray_tpu.models.llama import rms_norm
+
+# what a decode step counts on the device, in the order it returns them
+COUNTERS = ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
+            "moe_load_max")
+CACHE_NAMES = ("latents", "state", "tails")
+# the decode step's last result, by key: "routing" [moe_layers, B, top_k + 1]
+# (chosen experts and kept-groups mask, every slot) and, of slot `probe_slot`
+# alone, "router_x" [moe_layers, D] (the router's input, dtype), "router_s"
+# [moe_layers, n_experts] (its scores, float32) and the recurrence's inputs
+# "q", "k", "v", "g" [kda_layers, H, dk] and "beta" [kda_layers, H] (float32)
+PROBE = ("routing", "router_x", "router_s", "q", "k", "v", "g", "beta")
+
+
+def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:
+    import jax.numpy as jnp
+
+    B, H, dk = ecfg.max_num_seqs, cfg.n_heads, cfg.head_dim
+    return (
+        jnp.zeros((cfg.mla_layers, ecfg.num_kv_blocks + 1,
+                   ecfg.kv_block_size, 1, cfg.latent_width), cfg.dtype),
+        jnp.zeros((cfg.kda_layers, B, H, dk, dk), jnp.float32),
+        jnp.zeros((cfg.kda_layers, B, cfg.conv_kernel - 1, cfg.conv_channels),
+                  cfg.dtype))
+
+
+def make_decode_step(cfg: ling.LingConfig, ecfg):
+    """The jitted whole-batch single-token step. Returns (step, path, note)
+    like `_engine._make_decode_step`: on a TPU the latent attention is
+    `ops/paged_attention`'s kernel over the block table, the latents as one
+    KV head that is its own value (the kernel scales by its head width, so
+    the query is pre-scaled to the model's 1/sqrt(nope + rope)); elsewhere
+    an XLA gather of the table's blocks."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.llm._engine import sample_tokens
+    from ray_tpu.ops import paged_attention
+
+    bs = ecfg.kv_block_size
+    max_blocks = -(-ecfg.max_model_len // bs)
+    kinds = cfg.kinds()
+    W = cfg.latent_width
+    path, note = paged_attention.decode_path(cfg.n_heads, 1, W, bs, cfg.dtype)
+
+    def attention(latents, layer, tables, live):
+        if path != paged_attention.KERNEL:
+            context = latents[layer][tables].reshape(
+                tables.shape[0], max_blocks * bs, W)
+            return ling.attend_latents(cfg, context, live)
+
+        def attend(q, scale):
+            q = jnp.pad(q * jnp.asarray(scale * math.sqrt(W), q.dtype),
+                        ((0, 0), (0, 0), (0, W - q.shape[-1])))
+            return paged_attention.paged_decode_attention(
+                q, latents, latents, layer, tables, live
+            )[..., : cfg.kv_lora_rank]
+
+        return attend
+
+    def paged_decode_step(params, latents, state, tails, tables, lens, active,
+                          last_tok, keys, temps, probe_slot):
+        dt = cfg.dtype
+        B = last_tok.shape[0]
+        h = params["tok_emb"].astype(dt)[last_tok]               # [B, D]
+        blk = jnp.clip(lens // bs, 0, max_blocks - 1)
+        # inactive slots write into the reserved trash block 0
+        phys = jnp.where(active, tables[jnp.arange(B), blk], 0).astype(jnp.int32)
+        off = (lens % bs).astype(jnp.int32)
+        live = jnp.where(active, lens + 1, 0).astype(jnp.int32)
+        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+        probe = {name: [] for name in PROBE}
+        i_kda = i_mla = 0
+        for (attn, _), p in zip(kinds, params["layers"]):
+            x = rms_norm(h, p["ln1"], cfg.norm_eps)
+            if attn == "kda":
+                y, new, tail, inputs = ling.kda_decode(
+                    cfg, p, x, state[i_kda], tails[i_kda])
+                # an empty slot keeps what it had: its next prefill overwrites it
+                keep = active[:, None, None, None]
+                state = state.at[i_kda].set(jnp.where(keep, new, state[i_kda]))
+                tails = tails.at[i_kda].set(tail)
+                for name, a in zip(("q", "k", "v", "g", "beta"), inputs):
+                    probe[name].append(a[probe_slot])
+                i_kda += 1
+            else:
+                with jax.named_scope("mla"):
+                    lat = ling.mla_latents(cfg, p, x, lens)
+                    latents = latents.at[i_mla, phys, off, 0].set(
+                        jnp.pad(lat, ((0, 0), (0, W - lat.shape[1]))))
+                    attend = attention(latents, i_mla, tables, live)
+                y = ling.mla_decode(cfg, p, x, lens, attend)
+                i_mla += 1
+            h = h + y
+            x = rms_norm(h, p["ln2"], cfg.norm_eps)
+            y, route, counts, scores = ling.ffn(cfg, p, x, active)
+            h = h + y
+            if route is not None:
+                counters = counters + counts
+                probe["routing"].append(route)
+                probe["router_x"].append(x[probe_slot])
+                probe["router_s"].append(scores[probe_slot])
+        h = rms_norm(h, params["norm"], cfg.norm_eps)
+        logits = (h @ params["lm_head"].astype(dt)).astype(jnp.float32)
+        out = jnp.concatenate([sample_tokens(keys, logits, temps), counters])
+        return (out, latents, state, tails,
+                {name: jnp.stack(a) for name, a in probe.items() if a})
+
+    return jax.jit(paged_decode_step, donate_argnums=(1, 2, 3)), path, note
+
+
+def make_prefill(cfg: ling.LingConfig, ecfg):
+    """Jitted single-request prefill at a static padded length S: the KDA
+    layers as a chunked scan from a zero state, the MLA layers expanded;
+    writes the slot's state and tails and the latents of its blocks.
+    Returns (last logits, routing [moe_layers, S, top_k + 1], caches)."""
+    import jax
+    import jax.numpy as jnp
+
+    bs = ecfg.kv_block_size
+    kinds = cfg.kinds()
+
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3, 4))
+    def paged_prefill(S, params, latents, state, tails, table, prompt, plen,
+                      slot):
+        dt = cfg.dtype
+        idx = jnp.arange(S)
+        valid = idx < plen
+        phys = jnp.where(valid, table[jnp.clip(idx // bs, 0,
+                                               table.shape[0] - 1)], 0)
+        off = (idx % bs).astype(jnp.int32)
+        h = params["tok_emb"].astype(dt)[prompt]                 # [S, D]
+        routing = []
+        i_kda = i_mla = 0
+        for (attn, _), p in zip(kinds, params["layers"]):
+            x = rms_norm(h, p["ln1"], cfg.norm_eps)
+            if attn == "kda":
+                y, final, tail = ling.kda_prefill(cfg, p, x, valid)
+                state = state.at[i_kda, slot].set(final)
+                tails = tails.at[i_kda, slot].set(tail)
+                i_kda += 1
+            else:
+                y, lat = ling.mla_prefill(cfg, p, x, valid)
+                with jax.named_scope("mla"):
+                    latents = latents.at[i_mla, phys, off, 0].set(jnp.pad(
+                        lat, ((0, 0), (0, cfg.latent_width - lat.shape[1]))))
+                i_mla += 1
+            h = h + y
+            y, route, _, _ = ling.ffn(
+                cfg, p, rms_norm(h, p["ln2"], cfg.norm_eps), valid)
+            h = h + y
+            if route is not None:
+                routing.append(route)
+        h = rms_norm(h, params["norm"], cfg.norm_eps)
+        last = h[jnp.clip(plen - 1, 0, S - 1)]
+        logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
+        routing = (jnp.stack(routing) if routing
+                   else jnp.zeros((0, S, cfg.top_k + 1), jnp.int32))
+        return logits, routing, latents, state, tails
+
+    return paged_prefill
+
+
+def check_prefill(cfg: ling.LingConfig, ecfg, prefill, params, prompt_ids):
+    """The jitted `prefill` on caches of its own (one slot, the prompt's
+    blocks) against `ling.forward` on the same prompt: (last logits of the
+    step, of the forward pass)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    p = list(prompt_ids) or [0]
+    plen = len(p)
+    nb = -(-plen // ecfg.kv_block_size)
+    S = max(8, 1 << (plen - 1).bit_length())
+    caches = alloc_cache(cfg, dataclasses.replace(
+        ecfg, max_num_seqs=1, num_kv_blocks=nb))
+    prompt = np.zeros((S,), np.int32)
+    prompt[:plen] = p
+    got = prefill(S, params, *caches, jnp.arange(1, nb + 1, dtype=jnp.int32),
+                  jnp.asarray(prompt), jnp.int32(plen), jnp.int32(0))[0]
+    ref = jax.jit(functools.partial(ling.forward, cfg))(
+        params, jnp.asarray(prompt), jnp.int32(plen))[plen - 1]
+    return got, ref

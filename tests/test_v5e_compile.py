@@ -170,3 +170,54 @@ def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
     assert m.alias_size_in_bytes > 2.6e9 and m.temp_size_in_bytes < 0.2e9
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
+        on_v5e, monkeypatch):
+    """`jit_paged_decode_step` of the Ling family at the cell's shapes
+    (published widths, 7 layers, 128 held experts, 64 slots, 16,384 blocks):
+    the latent attention is the paged kernel over the pool in place (a latent
+    640 wide in memory: at 576 the compiler laid the pool out blocks-innermost
+    and copied it there and back every step, PR 29), the recurrent state and
+    the pool are donated and not copied, the experts are grouped matmuls, and
+    the program fits the chip beside 10.35 GB of weights."""
+    from ray_tpu.llm import _ling_steps
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import ling
+
+    monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    cfg = ling.LingConfig(
+        vocab_size=39296, n_layers=7, layer_ids=(1, 6, 7, 8, 9, 10, 11),
+        first_k_dense=1, n_held=128, max_seq_len=4096)
+    ecfg = EngineConfig(max_num_seqs=64, kv_block_size=16,
+                        num_kv_blocks=16384, max_model_len=4096)
+    step, path, note = _ling_steps.make_decode_step(cfg, ecfg)
+    assert (path, note) == (pa.KERNEL, None)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: ling.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _ling_steps.alloc_cache(cfg, ecfg))]
+    compiled = step.trace(
+        params, *caches, on_v5e((64, 256), jnp.int32),
+        on_v5e((64,), jnp.int32), on_v5e((64,), jnp.bool_),
+        on_v5e((64,), jnp.int32), on_v5e((64, 2), jnp.uint32),
+        on_v5e((64,), jnp.float32), on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines()
+               if PALLAS in line and "%paged_decode_attention" in line]
+    assert len(kernels) == 1 and "bf16[16385,16,640]" in kernels[0]
+    assert hlo.count("%ragged-dot-none") > 0
+    # neither the pool nor the state is copied, whole or by layer
+    assert not re.findall(r" copy\([^)]*(?:16385|64,32,128,128)", hlo)
+    m = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.2e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
