@@ -234,6 +234,8 @@ class LLMEngine:
         cfg, params = config.build_model()
         self.engine = PagedEngine(
             cfg, params, engine_config or EngineConfig(), eos_id=EOS)
+        # every program the loop can dispatch, before the first request
+        self.engine.warm_up()
         self._t0 = None
         self._init_s = time.monotonic() - t0
 

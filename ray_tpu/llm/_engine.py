@@ -11,12 +11,15 @@ batching, PagedAttention block tables, streaming). Rebuilt for XLA:
   the pool and a new request reuses them immediately.
 - **Static shapes for XLA**: the decode step is ONE jitted function over the
   fixed slot count — inactive slots write to a reserved trash block and are
-  masked out — so admission/turnover never recompiles. Prefill jits per
-  pow-2 length bucket.
-- **Continuous batching**: an admission queue merges new requests into the
-  RUNNING decode batch between steps (prefill writes the prompt's KV into
-  freshly allocated blocks, then the slot joins the next decode step) —
-  no stop-the-world batch boundaries.
+  masked out — so admission/turnover never recompiles.
+- **Continuous batching, prompts in the decode step**: an admission is
+  bookkeeping (slot, blocks, prefix-cache match); the prompt then runs as
+  chunks of a few static widths (`chunk_ladder`), one chunk of one request
+  a step, in the same program and the same weight matmuls as the running
+  slots' tokens, so nobody waits for a prefill. The request's first token
+  is sampled in the step that holds its prompt's last token and the slot
+  decodes from the next step on. (A family whose steps take no chunk, Ling,
+  still runs each prompt whole, awaited in the loop.)
 - **Streaming**: tokens flow to callers through per-request async queues;
   the engine runs as an async actor and `generate_stream` is an async
   generator riding the framework's streaming-generator plane.
@@ -30,7 +33,7 @@ import logging
 import math
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -50,23 +53,27 @@ __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 PHASE_SWEEP = "engine:sweep"                    # drain _pending, abort sweep
 PHASE_ADMIT = "engine:admit"                    # one per _try_admit call
 PHASE_PREFIX_MATCH = "engine:prefix_match"      # chain_keys, match, eviction
+# a family whose prompts run whole, awaited in the loop (Ling), and P/D
+# admission's first token:
 PHASE_PREFILL = "engine:prefill"                # the jitted whole-prompt call
-PHASE_SUFFIX_PREFILL = "engine:suffix_prefill"  # ... over a cached prefix
 PHASE_SAMPLE_FIRST = "engine:sample_first"      # waits for the prefill
-PHASE_STEP = "engine:step"                      # one run_step call, around:
-PHASE_UPLOAD = "engine:upload"                  # the step's six host arrays
+# one run_step call (argument `chunk`: the width of the prompt chunk the step
+# carries, 0 for none), around the three below
+PHASE_STEP = "engine:step"
+PHASE_UPLOAD = "engine:upload"                  # the step's host arrays
 PHASE_DISPATCH = "engine:dispatch"              # the decode step's launch
 PHASE_DEVICE_WAIT = "engine:device_wait"        # np.asarray(toks)
 PHASE_EMIT = "engine:emit"                      # the per-slot walk
 PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
-          PHASE_SUFFIX_PREFILL, PHASE_SAMPLE_FIRST, PHASE_STEP, PHASE_UPLOAD,
-          PHASE_DISPATCH, PHASE_DEVICE_WAIT, PHASE_EMIT)
+          PHASE_SAMPLE_FIRST, PHASE_STEP, PHASE_UPLOAD, PHASE_DISPATCH,
+          PHASE_DEVICE_WAIT, PHASE_EMIT)
 # the per-request spans on the tracing plane (util/tracing.py), three a
 # request and none a step: the control store keeps 10,000 events
 SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
-SPAN_PREFILL = "engine:prefill"  # admission start -> first token
+SPAN_PREFILL = "engine:prefill"  # admission start -> first token (its chunks)
 SPAN_DECODE = "engine:decode"    # first token -> done
-# a turn of the loop is admissions (tens of ms each) and one decode step:
+# a turn of the loop is admissions (bookkeeping, or for Ling a whole prompt
+# of tens of ms) and one decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
 
@@ -112,11 +119,32 @@ def _apply_rope_q(x, cos, sin):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
+def chunk_ladder(ecfg: EngineConfig) -> Tuple[int, ...]:
+    """The static widths C > 0 of the prompt chunk a decode step may carry
+    (`_make_decode_step`), ascending: a chunk of n prompt tokens runs at the
+    narrowest width that holds it, a longer prompt in pieces of the widest.
+    128 and 256 at max_model_len 2048, measured on a v5e at Mistral-7B
+    widths, 16 layers, 32 slots (PERF.md section 6, PR 30): up to ~256 rows
+    a chunk rides under the step's read of the weights at 12-17 us a row (a
+    step of 13.9 ms takes 14.7 / 15.5 / 18.3 ms with 64 / 128 / 256 rows);
+    past that the step is compute-bound at 44 us a row (29.3 ms with 512),
+    every decoding slot waits that long for its token, and both closed-loop
+    cells completed 5% fewer tokens a second with 512 as the widest. Each
+    width is one more program to compile and to load before traffic (~1.7 s
+    of every start from a warm compile cache): a third of 64 rows would
+    save 0.8 ms on a quarter of chat's admissions, under 0.5% of a step."""
+    widest = min(256, max(8, 1 << ((ecfg.max_model_len // 4).bit_length() - 1)))
+    return (widest // 2, widest)
+
+
 def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
-    """Build the jitted whole-batch single-token decode step. Returns
-    (step, path, note): which attention the step was built with
+    """Build the jitted whole-batch single-token decode step, which may also
+    carry one chunk of one admitting request's prompt. Returns (step, path,
+    note): which attention the decode rows were built with
     (`paged_attention.KERNEL` or `XLA`, decided here from the backend and
     the shapes) and, where a TPU was refused the kernel, why."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
@@ -127,17 +155,34 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
     path, note = paged_attention.decode_path(
         cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, bs, cfg.dtype)
 
-    def paged_decode_step(params, kc, vc, tables, lens, active, last_tok,
-                          keys, temps):
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
+    def paged_decode_step(C, params, kc, vc, tables, lens, active, last_tok,
+                          keys, temps, chunk_ids=None, chunk_at=None):
         """kc/vc [L, NB, BS, KV, HD]; tables [B, max_blocks] int32;
         lens/active/last_tok [B]; keys [B,2] uint32; temps [B].
-        Returns (next_tok [B], kc, vc)."""
+        Returns (next_tok [B], kc, vc).
+
+        With a static chunk width C > 0 also chunk_ids [C], the next prompt
+        tokens of the request in slot chunk_at[0] (an inactive row of the
+        batch), at absolute positions chunk_at[1].. of which the first
+        chunk_at[2] are real. The C rows go through every weight matmul
+        with the B decode rows as one [B + C, D] batch (one read of the
+        weights); their K and V are scattered into the slot's blocks
+        (padding into the trash block) and they attend the slot's own
+        table up to their positions: over a cached prefix, after earlier
+        chunks or from position 0 alike. Behind the B tokens come the
+        token drawn from the chunk's last real row with the slot's key
+        (the request's first, where the chunk ends its prompt) and that
+        key folded with 7, the slot's decode stream, as two int32."""
         dt = cfg.dtype
         B = last_tok.shape[0]
+        R = B + C
         hd = cfg.head_dim
-        h = params["tok_emb"].astype(dt)[last_tok][:, None]     # [B,1,D]
-        pos = lens[:, None]                                      # [B,1]
-        cos, sin = rope_tables(cfg, pos)
+        ids = last_tok
+        # one position a decode row, then the chunk's: two calls, so that a
+        # decode step's positions stay one [B, 1] call
+        cos, sin = (t.reshape(1, B, -1) for t in
+                    rope_tables(cfg, lens[:, None]))
         # inactive slots write into the reserved trash block 0
         blk = jnp.clip(lens // bs, 0, max_blocks - 1)
         phys = jnp.where(
@@ -146,6 +191,19 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         # the live context, read after the scatter: positions 0..lens
         # (the current token's included); an inactive slot attends nothing
         live = jnp.where(active, lens + 1, 0).astype(jnp.int32)
+        if C:
+            slot, start, n = chunk_at[0], chunk_at[1], chunk_at[2]
+            row = tables[slot]
+            qpos = start + jnp.arange(C, dtype=jnp.int32)
+            ids = jnp.concatenate([ids, chunk_ids])
+            ccos, csin = rope_tables(cfg, qpos[None])
+            cos = jnp.concatenate([cos, ccos], axis=1)
+            sin = jnp.concatenate([sin, csin], axis=1)
+            phys = jnp.concatenate([phys, jnp.where(
+                qpos < start + n,
+                row[jnp.clip(qpos // bs, 0, max_blocks - 1)], 0)])
+            off = jnp.concatenate([off, qpos % bs])
+        h = params["tok_emb"].astype(dt)[ids][None]              # [1,R,D]
 
         def layer(carry, xs):
             # the pool rides in the carry and is written in place: as the
@@ -154,16 +212,19 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
             h, kc, vc = carry
             p, l = xs
             x = rms_norm(h, p["ln1"], cfg.norm_eps)
-            q = (x @ p["wq"].astype(dt)).reshape(B, 1, cfg.n_heads, hd)
-            k = (x @ p["wk"].astype(dt)).reshape(B, 1, cfg.n_kv_heads, hd)
-            v = (x @ p["wv"].astype(dt)).reshape(B, 1, cfg.n_kv_heads, hd)
-            q = _apply_rope_q(q, cos, sin).astype(dt)
-            k = _apply_rope_q(k, cos, sin).astype(dt)
-            kc = kc.at[l, phys, off].set(k[:, 0])
-            vc = vc.at[l, phys, off].set(v[:, 0])
+            q = (x @ p["wq"].astype(dt)).reshape(1, R, cfg.n_heads, hd)
+            k = (x @ p["wk"].astype(dt)).reshape(1, R, cfg.n_kv_heads, hd)
+            v = (x @ p["wv"].astype(dt)).reshape(1, R, cfg.n_kv_heads, hd)
+            q = _apply_rope_q(q, cos, sin).astype(dt)[0]
+            k = _apply_rope_q(k, cos, sin).astype(dt)[0]
+            kc = kc.at[l, phys, off].set(k)
+            vc = vc.at[l, phys, off].set(v[0])
             o = paged_attention.decode_attention(
-                path, q[:, 0], kc, vc, l, tables, live)         # [B,H,HD]
-            h = h + o.reshape(B, 1, -1) @ p["wo"].astype(dt)
+                path, q[:B], kc, vc, l, tables, live)            # [B,H,HD]
+            if C:
+                o = jnp.concatenate([o, paged_attention.chunk_attention(
+                    q[B:], kc, vc, l, row, qpos, start + n)])
+            h = h + o.reshape(1, R, -1) @ p["wo"].astype(dt)
             x2 = rms_norm(h, p["ln2"], cfg.norm_eps)
             gate = jax.nn.silu(x2 @ p["w1"].astype(dt))
             up = x2 @ p["w3"].astype(dt)
@@ -173,12 +234,25 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         (h, kc, vc), _ = jax.lax.scan(
             layer, (h, kc, vc),
             (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        h = h[0]
+        if C:
+            # the rows whose logits are read: B decode rows and the chunk's
+            # last real one, sampled with the slot's own key and temperature
+            h = jnp.concatenate(
+                [h[:B], h[B + jnp.clip(n - 1, 0, C - 1)][None]])
+            keys = jnp.concatenate([keys, keys[slot][None]])
+            temps = jnp.concatenate([temps, temps[slot][None]])
         h = rms_norm(h, params["norm"], cfg.norm_eps)
-        logits = (h[:, 0] @ params["lm_head"].astype(dt)).astype(jnp.float32)
+        logits = (h @ params["lm_head"].astype(dt)).astype(jnp.float32)
+        toks = sample_tokens(keys, logits, temps)
+        if C:
+            stream = jax.random.key_data(jax.random.fold_in(
+                jax.random.wrap_key_data(keys[B]), 7))
+            toks = jnp.concatenate(
+                [toks, jax.lax.bitcast_convert_type(stream, jnp.int32)])
+        return toks, kc, vc
 
-        return sample_tokens(keys, logits, temps), kc, vc
-
-    return jax.jit(paged_decode_step, donate_argnums=(1, 2)), path, note
+    return paged_decode_step, path, note
 
 
 def sample_tokens(keys, logits, temps):
@@ -289,89 +363,6 @@ def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
     return logits, kc, vc, nb
 
 
-def _make_suffix_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
-    """Jitted prefill of a prompt SUFFIX over a cached prefix: the first
-    ``cached_len`` tokens' KV already sit in the request's table blocks
-    (spliced in from the prefix cache), so only the suffix runs through
-    the model. Suffix K/V scatter at their absolute positions into the
-    request's fresh blocks; attention gathers the WHOLE table (decode's
-    paged-gather pattern) so suffix queries see the cached prefix keys.
-    Jits per pow-2 SUFFIX-length bucket — a long shared system prompt
-    costs one short-bucket compile, not a long-bucket one."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    bs = ecfg.kv_block_size
-    max_blocks = -(-ecfg.max_model_len // bs)
-    Lmax = max_blocks * bs
-
-    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-    def paged_suffix_prefill(S, params, kc, vc, table, suffix, cached_len,
-                             slen):
-        """suffix [S] right-padded tokens at absolute positions
-        cached_len..cached_len+slen; table [max_blocks] the FULL row
-        (cached prefix blocks + this request's fresh blocks)."""
-        dt = cfg.dtype
-        hd = cfg.head_dim
-        h = params["tok_emb"].astype(dt)[suffix][None]   # [1,S,D]
-        qidx = jnp.arange(S, dtype=jnp.int32)
-        qpos = cached_len + qidx                          # absolute
-        cos, sin = rope_tables(cfg, qpos[None])
-        in_range = qidx < slen
-        # padded suffix positions scatter into the trash block 0
-        phys = jnp.where(in_range, table[jnp.clip(qpos // bs, 0,
-                                                  max_blocks - 1)], 0)
-        off = (qpos % bs).astype(jnp.int32)
-        kidx = jnp.arange(Lmax)
-        # query x key validity: causal over ABSOLUTE positions — cached
-        # prefix keys (kidx < cached_len) are visible to every live query;
-        # anything past the prompt (stale pool contents) is masked out
-        valid = (kidx[None, None, :] <= qpos[None, :, None]) \
-            & in_range[None, :, None]                     # [1,S,Lmax]
-
-        def layer(carry, xs):
-            h = carry
-            p, kcl, vcl = xs
-            x = rms_norm(h, p["ln1"], cfg.norm_eps)
-            q = (x @ p["wq"].astype(dt)).reshape(1, S, cfg.n_heads, hd)
-            k = (x @ p["wk"].astype(dt)).reshape(1, S, cfg.n_kv_heads, hd)
-            v = (x @ p["wv"].astype(dt)).reshape(1, S, cfg.n_kv_heads, hd)
-            q = _apply_rope_q(q, cos, sin).astype(dt)
-            k = _apply_rope_q(k, cos, sin).astype(dt)
-            kcl = kcl.at[phys, off].set(k[0])
-            vcl = vcl.at[phys, off].set(v[0])
-            # paged gather AFTER the scatter: suffix keys join the cached
-            # prefix keys already resident in the table's blocks
-            k_all = kcl[table].reshape(Lmax, cfg.n_kv_heads, hd)[None]
-            v_all = vcl[table].reshape(Lmax, cfg.n_kv_heads, hd)[None]
-            if cfg.n_kv_heads != cfg.n_heads:
-                rep = cfg.n_heads // cfg.n_kv_heads
-                k_all = jnp.repeat(k_all, rep, axis=2)
-                v_all = jnp.repeat(v_all, rep, axis=2)
-            scale = 1.0 / math.sqrt(hd)
-            lg = jnp.einsum("bqhd,bkhd->bhqk", q, k_all,
-                            preferred_element_type=jnp.float32) * scale
-            lg = jnp.where(valid[:, None], lg, -1e30)
-            probs = jax.nn.softmax(lg, axis=-1).astype(dt)
-            o = jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
-            h = h + o.reshape(1, S, -1) @ p["wo"].astype(dt)
-            x2 = rms_norm(h, p["ln2"], cfg.norm_eps)
-            gate = jax.nn.silu(x2 @ p["w1"].astype(dt))
-            up = x2 @ p["w3"].astype(dt)
-            h = h + (gate * up) @ p["w2"].astype(dt)
-            return h, (kcl, vcl)
-
-        h, (kc, vc) = jax.lax.scan(layer, h, (params["layers"], kc, vc))
-        h = rms_norm(h, params["norm"], cfg.norm_eps)
-        last = h[0, jnp.clip(slen - 1, 0, S - 1)]
-        logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
-        return logits, kc, vc
-
-    return paged_suffix_prefill
-
-
 @dataclass
 class _Request:
     rid: int
@@ -394,6 +385,11 @@ class _Request:
     t_admit: float = 0.0   # admission began (slot and blocks in hand)
     t_first: float = 0.0   # first token emitted
     t_done: float = 0.0    # finished, failed or dropped
+    # admission in chunks: the pool holds the prompt's positions [0, cursor)
+    # (blocks hit in the prefix cache, then the chunks run so far); the
+    # prompt's block keys, for the cache
+    cursor: int = 0
+    block_keys: tuple = ()
     # the caller's span (the `completions_stream` execution span) when the
     # request is traced; the three spans are recorded as its children
     trace_parent: Optional[dict] = None
@@ -407,6 +403,14 @@ class _Request:
     # and, where it has the key "steps", what each decode step computed the
     # slot's router and recurrence from, with the state before and after
     probe: Optional[Dict[str, Any]] = None
+
+
+def _request_key(req: _Request) -> Tuple[int, int]:
+    """The raw data of `jax.random.PRNGKey(seed * 1000003 + rid)`, the key
+    `_sample_first` draws a request's first token with, without a dispatch
+    to the device: a 32-bit seed's key is (0, seed), and a wider one is cut
+    to its low 32 bits (no 64-bit types here; tests hold the two equal)."""
+    return 0, (req.seed * 1000003 + req.rid) & 0xFFFFFFFF
 
 
 class PagedEngine:
@@ -460,17 +464,25 @@ class PagedEngine:
                 self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
         self._alloc_device_state()
         # "paged_kernel" | "xla", fixed for the engine's life (stats())
-        make_decode, make_prefill = (
-            (_ling_steps.make_decode_step, _ling_steps.make_prefill)
-            if self._recurrent else (_make_decode_step, _make_prefill))
+        make_decode, make_prefill, ladder = (
+            (_ling_steps.make_decode_step, _ling_steps.make_prefill,
+             getattr(_ling_steps, "chunk_ladder", None))
+            if self._recurrent
+            else (_make_decode_step, _make_prefill, chunk_ladder))
         self._decode, self.decode_attention, self._decode_note = (
             make_decode(cfg, e))
         if self._decode_note:
             logging.getLogger(__name__).warning(self._decode_note)
+        # whole prompts: a family without a ladder in the loop; check_prefill
         self._prefill = make_prefill(cfg, e)
-        # no prefix cache with recurrent layers, so no prefill over one
-        self._suffix_prefill = (None if self._recurrent
-                                else _make_suffix_prefill(cfg, e))
+        # the chunk widths the family's decode step takes. With a ladder a
+        # prompt is admitted in chunks that ride in the decode steps; with
+        # none (its step module offers no such step) whole, awaited in the
+        # loop
+        self._ladder: Tuple[int, ...] = ladder(e) if ladder else ()
+        # admitted requests whose prompts are not all in the pool yet, in
+        # arrival order: the head's next chunk rides in the next step
+        self._prefilling: "collections.deque[_Request]" = collections.deque()
         self._pending: "asyncio.Queue[_Request]" = None  # type: ignore
         self._inject = None  # lazy jitted donated KV scatter (P/D admission)
         self._loop_task = None
@@ -479,6 +491,11 @@ class PagedEngine:
         self.steps = 0
         self.tokens_out = 0
         self.mid_decode_admissions = 0
+        # chunks run (one a step that carries any), the prompt tokens in
+        # them and the rows of padding beside those
+        self.prefill_chunks = 0
+        self.prefill_chunk_tokens = 0
+        self.prefill_chunk_pad_tokens = 0
         # positions decode attention had to read (each active slot's context,
         # the current token's included) and what scoring max_model_len
         # positions of every slot reads: their ratio is the live share
@@ -508,6 +525,11 @@ class PagedEngine:
     def _set_cache(self, arrays) -> None:
         for name, a in zip(self._cache_names, arrays):
             setattr(self, name, a)
+
+    def _host_state(self) -> tuple:
+        """The host arrays every step uploads, in the step's order."""
+        return (self.tables, self.lens, self.active, self.last_tok,
+                self._rngs, self.temps)
 
     def _device_state_invalid(self) -> bool:
         try:
@@ -547,6 +569,7 @@ class PagedEngine:
         self.last_tok[:] = 0
         self.temps[:] = 0.0
         self.slot_req = [None] * self.ecfg.max_num_seqs
+        self._prefilling.clear()
         if self._prefix_cache is not None:
             # cached blocks pointed into the old (destroyed) pool
             self._prefix_cache.clear()
@@ -617,45 +640,37 @@ class PagedEngine:
             return False
         req.t_admit = t_admit
         blocks = [self.free_blocks.pop() for _ in range(need_new)]
-        row_blocks = hits + blocks
+        row = np.zeros((self.max_blocks,), np.int32)
+        row[: need] = hits + blocks
+        self.tables[slot] = row
+        if self._ladder:
+            # bookkeeping only: the prompt past the cached blocks runs as
+            # chunks of the coming steps (`_next_chunk`). The slot is the
+            # request's from here, so the abort sweep finds it
+            req.slot, self.slot_req[slot] = slot, req
+            req.cursor, req.block_keys = len(hits) * self.bs, tuple(keys)
+            self._prefilling.append(req)
+            if hits:
+                from ray_tpu.util.metrics import Counter
+
+                Counter("rt_llm_prefix_hits_total",
+                        "KV blocks reused from the prompt-prefix cache "
+                        "instead of re-prefilled.").inc(len(hits))
+            return True
         try:
-            row = np.zeros((self.max_blocks,), np.int32)
-            row[: len(row_blocks)] = row_blocks
-            self.tables[slot] = row
-            cached_len = len(hits) * self.bs
-            if cached_len:
-                # prefill ONLY the suffix over the cached prefix blocks
-                slen = plen - cached_len
-                S = max(8, 1 << (slen - 1).bit_length())  # pow-2 bucket
-                suffix = np.zeros((S,), np.int32)
-                suffix[:slen] = req.prompt[cached_len:]
-                with jax.profiler.TraceAnnotation(
-                        PHASE_SUFFIX_PREFILL, S=S, cached_len=cached_len):
-                    logits, self.kc, self.vc = self._suffix_prefill(
-                        S, self.params, self.kc, self.vc, jnp.asarray(row),
-                        jnp.asarray(suffix), jnp.int32(cached_len),
-                        jnp.int32(slen))
-            else:
-                S = max(8, 1 << (plen - 1).bit_length())  # pow-2 bucket
-                prompt = np.zeros((S,), np.int32)
-                prompt[:plen] = req.prompt
-                with jax.profiler.TraceAnnotation(
-                        PHASE_PREFILL, S=S, cached_len=0):
-                    if self._recurrent:
-                        logits, routing, *cache = self._prefill(
-                            S, self.params, *self._cache(), jnp.asarray(row),
-                            jnp.asarray(prompt), jnp.int32(plen),
-                            jnp.int32(slot))
-                        self._set_cache(cache)
-                        if req.probe is not None:
-                            req.probe["routing"].append(
-                                np.asarray(routing)[:, :plen])
-                            self._probe_admitted(req, slot)
-                    else:
-                        logits, self.kc, self.vc = self._prefill(
-                            S, self.params, self.kc, self.vc,
-                            jnp.asarray(row), jnp.asarray(prompt),
-                            jnp.int32(plen))
+            S = max(8, 1 << (plen - 1).bit_length())  # pow-2 bucket
+            prompt = np.zeros((S,), np.int32)
+            prompt[:plen] = req.prompt
+            with jax.profiler.TraceAnnotation(
+                    PHASE_PREFILL, S=S, cached_len=0):
+                logits, routing, *caches = self._prefill(
+                    S, self.params, *self._cache(), jnp.asarray(row),
+                    jnp.asarray(prompt), jnp.int32(plen), jnp.int32(slot))
+                self._set_cache(caches)
+                if req.probe is not None:
+                    req.probe["routing"].append(
+                        np.asarray(routing)[:, :plen])
+                    self._probe_admitted(req, slot)
             tok = self._sample_first(req, slot, logits)
         except BaseException:
             # any failure between the block pop and slot activation (prefill
@@ -664,25 +679,49 @@ class PagedEngine:
             # deadlocks; the donated-invalid case is rebuilt by the caller
             # via _reset_device_state, which recreates free_blocks anyway
             self.free_blocks.extend(blocks)
-            if cache is not None:
-                cache.cancel_match(hits)
             self.tables[slot] = 0
             raise
-        if cache is not None and keys:
-            # every FULL prompt block (matched prefix + freshly prefilled)
-            # is now cacheable; this request holds one ref on each until
-            # release. Cap-evicted zero-ref blocks return to the pool.
-            full = plen // self.bs
-            self.free_blocks.extend(
-                cache.register(keys[:full], row_blocks[:full]))
-            if hits:
-                from ray_tpu.util.metrics import Counter
-
-                Counter("rt_llm_prefix_hits_total",
-                        "KV blocks reused from the prompt-prefix cache "
-                        "instead of re-prefilled.").inc(len(hits))
         self._activate_slot(req, slot, tok)
         return True
+
+    def _next_chunk(self):
+        """The oldest admitted request whose prompt is not all in the pool,
+        and what of it the next step runs: (request, tokens, width), the
+        width the narrowest of the ladder that holds the tokens; None when
+        no prompt is waiting."""
+        while self._prefilling and self._prefilling[0].slot < 0:
+            self._prefilling.popleft()      # released by the abort sweep
+        if not self._prefilling:
+            return None
+        req = self._prefilling[0]
+        n = min(len(req.prompt) - req.cursor, self._ladder[-1])
+        return req, n, next(c for c in self._ladder if c >= n)
+
+    def _chunk_done(self, req: _Request, n: int, width: int, tail):
+        """A step that carried `n` tokens of `req`'s prompt has run: move
+        the cursor, offer the blocks it completed to the prefix cache (the
+        step that wrote them has run, so every later step sees them; a
+        request admitted while the prompt is still in chunks can hit only
+        these) and, if the prompt is through, start the slot's decoding
+        with the first token and the stream key the step returned."""
+        slot = req.slot
+        req.cursor += n
+        self.prefill_chunks += 1
+        self.prefill_chunk_tokens += n
+        self.prefill_chunk_pad_tokens += width - n
+        if self._prefix_cache is not None:
+            # every FULL prompt block in the pool (matched, then written by
+            # the chunks so far) is cacheable; this request holds one ref on
+            # each until release. Cap-evicted zero-ref blocks return to the
+            # pool.
+            full = req.cursor // self.bs
+            self.free_blocks.extend(self._prefix_cache.register(
+                req.block_keys[:full], self.tables[slot][:full]))
+        if req.cursor < len(req.prompt):
+            return
+        self._prefilling.popleft()
+        self._rngs[slot] = np.asarray(tail[1:3], np.int32).view(np.uint32)
+        self._activate_slot(req, slot, int(tail[0]))
 
     def _emit(self, req: _Request, tok: int):
         req.produced += 1
@@ -872,7 +911,9 @@ class PagedEngine:
                         self._release(r)
             # admit in arrival order while slots + blocks allow — requests
             # landing here while slots decode are the "admitted mid-decode"
-            # continuous-batching case
+            # continuous-batching case. With a chunk ladder an admission is
+            # bookkeeping and the prompt rides in the steps below; without
+            # one the prompt's whole prefill is awaited here
             while waiting:
                 req = waiting[0]
                 if req.aborted:
@@ -891,11 +932,11 @@ class PagedEngine:
                 req.admitted_mid_decode = mid_decode
                 try:
                     ok = await asyncio.to_thread(self._try_admit, req)
-                except Exception as e:  # noqa: BLE001 — prefill failed
+                except Exception as e:  # noqa: BLE001 — admission failed
                     waiting.popleft()
                     self._fail(req, e)
                     if self._device_state_invalid():
-                        # prefill donates kc/vc: a failure after donation
+                        # prefill donates the caches: a failure after donation
                         # destroyed every in-flight sequence's cache
                         for r in list(self.slot_req):
                             if r is not None:
@@ -905,30 +946,46 @@ class PagedEngine:
                 if not ok:
                     break  # head waits for blocks/slots to free
                 waiting.popleft()
-            if not self.active.any():
+            chunk = self._next_chunk() if self._ladder else None
+            if chunk is None and not self.active.any():
                 # idle: block until a request arrives
                 waiting.append(await self._pending.get())
                 t_turn = None
                 continue
             t_step = time.monotonic()
-            # one decode step for every active slot
+            # one step: a token for every active slot and, riding along, the
+            # next chunk of the oldest admitted prompt (alone, with every
+            # slot inactive, when nothing decodes yet)
             step = self.steps
+            width, chunk_args = 0, ()
+            if chunk is not None:
+                admitting, n, width = chunk
+                at = admitting.cursor
+                ids = np.zeros((width,), np.int32)
+                ids[:n] = admitting.prompt[at:at + n]
+                chunk_args = (ids, np.asarray(
+                    [admitting.slot, at, n], np.int32))
+                # the slot's row of keys and temperatures is idle until it
+                # decodes: the step draws the request's first token with them
+                self._rngs[admitting.slot] = _request_key(admitting)
+                self.temps[admitting.slot] = admitting.temperature
+            # the step's static chunk width, where the family's step has one
+            lead = (width,) if self._ladder else ()
             probing = any(r is not None and r.probe is not None
                           for r in self.slot_req)
 
             def run_step():
                 # the outer annotation names a device gap that straddles
                 # two of the inner ones (else a Python frame and its line)
-                with phase(PHASE_STEP):
+                with phase(PHASE_STEP, chunk=width):
                     with phase(PHASE_UPLOAD):
                         state = [jnp.asarray(a) for a in (
-                            self.tables, self.lens, self.active,
-                            self.last_tok, self._rngs, self.temps)]
+                            *self._host_state(), *chunk_args)]
                     if self._recurrent:
                         state.append(self._probe_arg)
                     with phase(PHASE_DISPATCH):
                         toks, *rest = self._decode(
-                            self.params, *self._cache(), *state)
+                            *lead, self.params, *self._cache(), *state)
                         n = len(self._cache_names)
                         self._set_cache(rest[:n])
                     with phase(PHASE_DEVICE_WAIT):
@@ -980,6 +1037,8 @@ class PagedEngine:
                     tok = int(toks[slot])
                     self.last_tok[slot] = tok
                     self._emit(req, tok)
+                if chunk is not None:
+                    self._chunk_done(*chunk, toks[B:])
             now = time.monotonic()
             if now - t_turn > STALL_TURN_S:
                 self._stalls["loop_stalls"] += 1
@@ -991,6 +1050,26 @@ class PagedEngine:
 
     # -- public API -----------------------------------------------------
 
+    def warm_up(self) -> None:
+        """Compile and run once every program the loop can dispatch: the
+        decode step at each chunk width and without one, on an idle batch
+        whose rows all land in the trash block. After it no request, of
+        whatever length, compiles anything. Before the loop starts (the
+        caches are donated to each call). A family admitted by whole
+        prompts has a program a prompt-length bucket and is not warmed."""
+        import jax.numpy as jnp
+
+        if not self._ladder:
+            return
+        state = [jnp.asarray(a) for a in self._host_state()]
+        for width in (0, *self._ladder):
+            chunk = (jnp.zeros((width,), jnp.int32),
+                     jnp.zeros((3,), jnp.int32)) if width else ()
+            toks, *caches = self._decode(
+                width, self.params, *self._cache(), *state, *chunk)
+            self._set_cache(caches)
+            toks.block_until_ready()
+
     async def generate_stream(self, prompt_ids: List[int], *,
                               max_tokens: int = 32,
                               temperature: float = 0.0, seed: int = 0,
@@ -1001,13 +1080,14 @@ class PagedEngine:
         `prefilled=(k, v, last_logits)` admits with KV transferred from a
         remote prefill worker instead of running prefill here. `probe`
         (see `check_routing`) receives what a check holds to a reference."""
+        prompt_ids = list(prompt_ids) or [0]
         if len(prompt_ids) + 1 > self.ecfg.max_model_len:
             raise ValueError(
                 f"prompt of {len(prompt_ids)} tokens exceeds "
                 f"max_model_len={self.ecfg.max_model_len}")
         await self._ensure_loop()
         self._rid += 1
-        req = _Request(self._rid, list(prompt_ids), int(max_tokens),
+        req = _Request(self._rid, prompt_ids, int(max_tokens),
                        float(temperature), int(seed),
                        queue=asyncio.Queue(), prefilled=prefilled,
                        probe=probe, t_start=time.monotonic(),
@@ -1105,13 +1185,13 @@ class PagedEngine:
 
         params = jax.tree.map(shape, self.params)
         cache = [shape(a) for a in self._cache()]
-        state = [shape(a) for a in (self.tables, self.lens, self.active,
-                                    self.last_tok, self._rngs, self.temps)]
+        state = [shape(a) for a in self._host_state()]
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         if self._recurrent:
             state.append(i32)          # the probed slot
+        lead = (0,) if self._ladder else ()
         out = {"jit_paged_decode_step": [self._decode.lower(
-            params, *cache, *state).compile().as_text()]}
+            *lead, params, *cache, *state).compile().as_text()]}
         out["jit_paged_prefill"] = []
         for n in prefill_lengths:
             S = max(8, 1 << (n - 1).bit_length())
@@ -1155,6 +1235,11 @@ class PagedEngine:
                               - len(self.free_blocks) - evictable),
             "active_slots": int(self.active.sum()),
             "mid_decode_admissions": self.mid_decode_admissions,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_tokens": self.prefill_chunk_tokens,
+            "prefill_chunk_pad_tokens": self.prefill_chunk_pad_tokens,
+            # one chunk a step: the same count until a step carries several
+            "steps_with_chunk": self.prefill_chunks,
             "prefix_cache": cache.stats() if cache is not None else None,
             "attn_positions_live": self.attn_positions_live,
             "attn_positions_dense": self.attn_positions_dense,

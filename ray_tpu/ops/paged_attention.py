@@ -22,6 +22,10 @@ Off-TPU (CPU tests) `decode_attention` runs `xla_decode_attention`, the
 gather / repeat / dense-scores formulation the kernel replaces and is tested
 against. Which one a decode step uses is decided when the step is built
 (`decode_path`), from the backend and the shapes alone.
+
+`chunk_attention` is the other reader of the pool: the rows of one prompt
+chunk that rides in a decode step, over their own sequence's table, in XLA
+on every backend.
 """
 
 from __future__ import annotations
@@ -43,7 +47,7 @@ _MAX_GROUP_PAGES = 16
 
 
 # ---------------------------------------------------------------------------
-# XLA reference (the only path off-TPU)
+# XLA: the decode reference (the only path off-TPU) and the chunk's attention
 # ---------------------------------------------------------------------------
 
 
@@ -70,6 +74,54 @@ def xla_decode_attention(q, kc, vc, layer, tables, lengths):
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)[:, 0]
+
+
+def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
+    """Causal attention of one sequence's prompt chunk over its own block
+    table: q [C, H, HD] at absolute positions qpos [C]; kc/vc [L, NB, BS, KV,
+    HD], of which layer `layer` (a scalar) is read after the chunk's own keys
+    were scattered into it; row [max_blocks] the sequence's table row. Row i
+    sees the keys at positions <= qpos[i]: cached prefix, earlier chunks and
+    its own chunk alike. Keys are read `tile` positions at a time up to `end`
+    (the chunk's last position + 1) with an online softmax, so the scores
+    cost what the live context costs and not max_model_len. -> [C, H, HD]."""
+    C, H, hd = q.shape
+    L, NB, bs, kvh, _ = kc.shape
+    rep = H // kvh
+    blocks = max(1, min(tile, row.shape[0] * bs) // bs)   # blocks a tile
+    tile = blocks * bs
+    row = jnp.pad(row, (0, -row.shape[0] % blocks)) + layer * NB
+    k_pages = kc.reshape(L * NB, bs, kvh, hd)             # the same bytes
+    v_pages = vc.reshape(L * NB, bs, kvh, hd)
+    # a query head next to the others of its KV head: no repeat of K or V
+    qg = q.reshape(C, kvh, rep, hd)
+    scale = 1.0 / math.sqrt(hd)
+
+    def one_tile(t, carry):
+        m, l, acc = carry
+        at = lax.dynamic_slice(row, (t * blocks,), (blocks,))
+        k = k_pages[at].reshape(tile, kvh, hd)
+        v = v_pages[at].reshape(tile, kvh, hd)
+        s = jnp.einsum("ckrd,wkd->krcw", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        seen = (t * tile + jnp.arange(tile))[None, :] <= qpos[:, None]
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum(
+            "krcw,wkd->krcd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((kvh, rep, C, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((kvh, rep, C, 1), jnp.float32)
+    acc0 = jnp.zeros((kvh, rep, C, hd), jnp.float32)
+    _, l, acc = lax.fori_loop(0, (end + tile - 1) // tile, one_tile,
+                              (m0, l0, acc0))
+    o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)     # [KV, rep, C, HD]
+    return o.transpose(2, 0, 1, 3).reshape(C, H, hd)
 
 
 # ---------------------------------------------------------------------------

@@ -30,43 +30,110 @@ def small_engine(**kw):
                                     num_kv_blocks=32, max_model_len=64, **kw))
 
 
-def host_event_names(logdir):
+def host_events(logdir):
     from jax.profiler import ProfileData
 
     paths = glob.glob(os.path.join(
         logdir, "plugins", "profile", "*", "*.xplane.pb"))
     assert paths, f"no .xplane.pb under {logdir}"
-    return {e.name for plane in ProfileData.from_file(paths[-1]).planes
-            for line in plane.lines for e in line.events}
+    return [e for plane in ProfileData.from_file(paths[-1]).planes
+            for line in plane.lines for e in line.events]
+
+
+def host_event_names(logdir):
+    return {e.name for e in host_events(logdir)}
+
+
+def captured(tmp_path, eng, prompts):
+    """The engine's events, [(name, {argument: value})], while it serves
+    `prompts` one after the other, after one request outside the capture
+    (it compiles)."""
+    async def one(prompt):
+        return [t async for t in eng.generate_stream(prompt, max_tokens=3)]
+
+    async def main():
+        await one(prompts[0][:-1] + [1])
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for prompt in prompts:
+                await one(prompt)
+        finally:
+            await asyncio.to_thread(jax.profiler.stop_trace)
+
+    asyncio.run(main())
+    return [(e.name, dict(e.stats)) for e in host_events(str(tmp_path))
+            if e.name.startswith("engine:")]
 
 
 # ---------------------------------------------------------------------------
 # in-process: annotations, stamps, spans
 # ---------------------------------------------------------------------------
 
+WHOLE_PROMPT_ONLY = {_engine.PHASE_PREFILL, _engine.PHASE_SAMPLE_FIRST}
 
-def test_profiler_capture_holds_every_engine_phase(tmp_path):
-    """A capture around a few engine steps, read back with ProfileData,
-    holds every phase name of `_engine.PHASES`: a rename fails here and
-    not in a chip run (the benchmark's readers find the phases by name)."""
+
+def test_chunked_admission_writes_its_phases_and_each_steps_chunk_width(
+        tmp_path):
+    """A capture around a few engine steps, read back with ProfileData: an
+    engine that admits in chunks writes every phase of `_engine.PHASES` but
+    the whole-prompt prefill's two and no other (a rename fails here and not
+    in a chip run: the benchmark's readers find the phases by name), and
+    every `engine:step` says the width of the chunk it carried: 0 or one of
+    the ladder's."""
     eng = small_engine(prefix_cache=True)
     shared = [7, 8, 9, 10, 11, 12, 13, 14]   # two full blocks
+    events = captured(tmp_path, eng, [
+        shared + [2, 3],                      # a chunk behind cached blocks
+        [20, 21, 22],                         # a whole prompt in one chunk
+        list(range(30, 30 + 40))])            # three chunks
+    assert {n for n, _ in events} == set(_engine.PHASES) - WHOLE_PROMPT_ONLY
+    widths = [args["chunk"] for n, args in events if n == _engine.PHASE_STEP]
+    ladder = _engine.chunk_ladder(eng.ecfg)
+    assert set(widths) <= {0, *ladder} and {0, ladder[0], ladder[-1]} <= set(
+        widths)
+    # three requests of 3 tokens: 5 chunks and 6 steps without one
+    assert sorted(widths) == [0] * 6 + sorted([8, 8, 16, 16, 8])
 
-    async def one(prompt):
-        return [t async for t in eng.generate_stream(prompt, max_tokens=3)]
+
+def test_whole_prompt_admission_writes_every_phase(tmp_path):
+    """The family whose steps take no chunk (Ling) is admitted by whole
+    prompts awaited in the loop: with the chunked path's phases above,
+    `PHASES` names exactly what the two still write."""
+    from ray_tpu.models import ling
+
+    cfg = ling.LingConfig.tiny()
+    eng = PagedEngine(cfg, ling.init_params(cfg, jax.random.PRNGKey(0)),
+                      EngineConfig(max_num_seqs=2, kv_block_size=16,
+                                   num_kv_blocks=16, max_model_len=64))
+    events = captured(tmp_path, eng, [[20, 21, 22]])
+    assert {n for n, _ in events} == set(_engine.PHASES)
+    assert {args["chunk"] for n, args in events
+            if n == _engine.PHASE_STEP} == {0}
+    stats = eng.stats()
+    assert stats["prefill_chunks"] == stats["steps_with_chunk"] == 0
+
+
+def test_chunk_counters_account_for_every_prompt_token():
+    """`prefill_chunk_tokens` is the prompt tokens sent less the blocks the
+    prefix cache handed out, exactly; a chunk a step that carries one."""
+    eng = small_engine(prefix_cache=True)
+    shared = list(range(100, 100 + 22))       # five full blocks of 4
+    prompts = [shared + [1, 2, 3], shared + [4], [9] * 37, shared[:9]]
 
     async def main():
-        await one(shared + [1])               # warm: compile outside
-        jax.profiler.start_trace(str(tmp_path))
-        try:
-            await one(shared + [2, 3])        # suffix prefill over the cache
-            await one([20, 21, 22])           # whole-prompt prefill
-        finally:
-            await asyncio.to_thread(jax.profiler.stop_trace)
+        for prompt in prompts:
+            assert len([t async for t in eng.generate_stream(
+                prompt, max_tokens=4)]) == 4
 
     asyncio.run(main())
-    names = host_event_names(str(tmp_path))
-    assert set(_engine.PHASES) <= names, set(_engine.PHASES) - names
+    stats = eng.stats()
+    hits = stats["prefix_cache"]["block_hits"]
+    assert hits == 5 + 2
+    assert stats["prefill_chunk_tokens"] == sum(map(len, prompts)) - 4 * hits
+    assert stats["prefill_chunks"] == stats["steps_with_chunk"] == 2 + 1 + 3 + 1
+    assert stats["steps_with_chunk"] <= stats["steps"] == 7 + 3 * len(prompts)
+    # 25 = 16 + 9 -> 16; 3 -> 8; 37 = 16 + 16 + 5 -> 8; 1 -> 8
+    assert stats["prefill_chunk_pad_tokens"] == 7 + 5 + 3 + 7
 
 
 def test_tracing_off_records_no_engine_span(monkeypatch):

@@ -1,11 +1,13 @@
 """Continuous batching + paged KV engine (reference: vllm_engine.py:283):
 concurrent streaming completions with mid-decode admission, block reuse,
-and parity with the dense decoder."""
+parity with the dense decoder, and prompts admitted as chunks that ride in
+the decode steps."""
 
 import asyncio
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import ray_tpu
@@ -41,11 +43,13 @@ def test_paged_matches_dense_decode():
 
 
 @pytest.mark.parametrize("requests,steps,live", [
-    # a request of P prompt tokens and N answer tokens takes N - 1 decode
-    # steps (the first token comes from the prefill), and step j reads the
-    # P + j cached positions and the current token's
-    ([(3, 5)], 4, 4 + 5 + 6 + 7),
-    ([(3, 5), (5, 3)], 6, (4 + 5 + 6 + 7) + (6 + 7)),
+    # a request of P prompt tokens and N answer tokens takes N steps: one
+    # that carries its prompt as a chunk (every slot inactive: it reads no
+    # cached position) and hands out the first token, then N - 1 decode
+    # steps, of which step j reads the P + j cached positions and the
+    # current token's
+    ([(3, 5)], 5, 4 + 5 + 6 + 7),
+    ([(3, 5), (5, 3)], 8, (4 + 5 + 6 + 7) + (6 + 7)),
 ], ids=["one_request", "two_in_turn"])
 def test_stats_count_live_and_dense_attention_positions(requests, steps, live):
     params = init_params(CFG, jax.random.PRNGKey(0))
@@ -66,6 +70,191 @@ def test_stats_count_live_and_dense_attention_positions(requests, steps, live):
     assert stats["attn_positions_live"] == live
     # what scoring max_model_len positions of every slot reads
     assert stats["attn_positions_dense"] == steps * 2 * 64
+
+
+# ---------------------------------------------------------------------------
+# prompts as chunks of the decode steps
+# ---------------------------------------------------------------------------
+
+CHUNK_ECFG = EngineConfig(max_num_seqs=3, kv_block_size=4, num_kv_blocks=64,
+                          max_model_len=64, prefix_cache=False)
+LADDER = (8, 16)
+# on and around every chunk width and block edge, several chunks of the
+# widest, and the longest prompt the engine takes
+LENGTHS = (1, 3, 4, 5, 7, 8, 9, 15, 16, 17, 2 * 16 + 3, 64 - 1)
+ANSWER = 6
+TEMPERATURE, SEED = 0.8, 11
+
+
+def a_prompt(n, salt=0):
+    return [int(t) for t in
+            np.random.default_rng(1000 * salt + n).integers(1, 500, n)]
+
+
+def test_the_ladder_of_chunk_widths():
+    from ray_tpu.llm._engine import chunk_ladder
+
+    assert chunk_ladder(CHUNK_ECFG) == LADDER
+    # the serve cells' engine: two programs beside the plain decode step
+    assert chunk_ladder(EngineConfig(max_model_len=2048)) == (128, 256)
+
+
+@pytest.mark.parametrize("seed,rid", [(0, 1), (7, 3), (2 ** 31 - 1, 9),
+                                      (2903000608, 41), (2 ** 40 + 5, 1000)])
+def test_request_key_is_prngkey_of_the_seed_formula(seed, rid):
+    """The step draws a request's first token with the key `_sample_first`
+    made by `jax.random.PRNGKey`; the loop writes that key's data itself."""
+    from ray_tpu.llm._engine import _Request, _request_key
+
+    got = _request_key(_Request(rid, [1], 1, 0.0, seed))
+    want = jax.random.key_data(jax.random.PRNGKey(seed * 1000003 + rid))
+    assert list(np.asarray(want)) == list(got)
+
+
+@pytest.fixture(scope="module")
+def chunk_params():
+    return init_params(CFG, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def served(chunk_params):
+    """Every prompt length through one engine, greedy and seeded, admitted
+    at an idle engine and while another slot decodes: {(mode, plen,
+    temperature): (rid, tokens)}, the background requests' tokens, and the
+    engine's `stats()` and count of compiled step programs at the end."""
+    eng = PagedEngine(CFG, chunk_params, CHUNK_ECFG)
+    out, background = {}, []
+
+    async def one(prompt, temperature, n=ANSWER):
+        rid = eng._rid + 1
+        toks = [t async for t in eng.generate_stream(
+            prompt, max_tokens=n, temperature=temperature, seed=SEED)]
+        return rid, toks
+
+    async def main():
+        for plen in LENGTHS:
+            for temperature in (0.0, TEMPERATURE):
+                out["idle", plen, temperature] = await one(
+                    a_prompt(plen), temperature)
+        for plen in LENGTHS:
+            # a slot that decodes all through the admissions of this length
+            long = a_prompt(6, salt=plen)
+            gen = eng.generate_stream(long, max_tokens=40)
+            head = [await gen.__anext__() for _ in range(2)]
+            for temperature in (0.0, TEMPERATURE):
+                out["busy", plen, temperature] = await one(
+                    a_prompt(plen), temperature)
+            background.append((long, head + [t async for t in gen]))
+
+    asyncio.run(main())
+    return out, background, eng.stats(), eng._decode._cache_size()
+
+
+def greedy_reference(params, prompts, n):
+    from ray_tpu.llm._generate import generate
+
+    return generate(CFG, params, prompts, max_new_tokens=n, temperature=0.0)
+
+
+@pytest.mark.parametrize("mode", ["idle", "busy"])
+@pytest.mark.parametrize("plen", LENGTHS)
+def test_chunked_admission_equals_generate_greedy(served, chunk_params, mode,
+                                                  plen):
+    _, toks = served[0][mode, plen, 0.0]
+    # the longest prompt leaves room for one token under max_model_len
+    want = greedy_reference(chunk_params, [a_prompt(plen)], ANSWER)[0]
+    assert toks == want[:min(ANSWER, 64 - plen)]
+
+
+@pytest.mark.parametrize("mode", ["idle", "busy"])
+@pytest.mark.parametrize("plen", LENGTHS)
+def test_chunked_admission_draws_the_seeded_tokens(served, chunk_params, mode,
+                                                   plen):
+    """Temperature > 0: token j is the draw from the dense forward pass's
+    logits with the request's own keys: `PRNGKey(seed * 1000003 + rid)` for
+    the first, then that key folded with 7 and counted up a step."""
+    from ray_tpu.models.llama import forward
+
+    rid, toks = served[0][mode, plen, TEMPERATURE]
+    assert len(toks) == min(ANSWER, 64 - plen)
+    seq = np.zeros((1, 64 + ANSWER), np.int32)       # one compiled shape
+    seq[0, :plen + len(toks)] = a_prompt(plen) + toks
+    logits = jax.jit(lambda p, x: forward(CFG, p, x))(chunk_params, seq)[0]
+    key = jax.random.PRNGKey(SEED * 1000003 + rid)
+    stream = np.asarray(jax.random.key_data(jax.random.fold_in(key, 7)))
+    for j, tok in enumerate(toks):
+        if j:
+            key = jax.random.wrap_key_data(
+                stream + np.asarray([0, j - 1], np.uint32))
+        want = jax.random.categorical(
+            key, logits[plen - 1 + j].astype(jnp.float32) / TEMPERATURE)
+        assert int(want) == tok, (j, toks)
+
+
+def test_slots_decoding_beside_the_admissions_are_undisturbed(served,
+                                                              chunk_params):
+    _, background, _, _ = served
+    prompts = [p for p, _ in background]
+    assert [t for _, t in background] == greedy_reference(
+        chunk_params, prompts, 40)
+
+
+def test_mixed_traffic_compiles_the_ladder_and_no_more(served):
+    """Whatever the prompts' lengths and the batch's state, the loop has
+    dispatched the decode step at the ladder's widths and without a chunk:
+    three programs."""
+    _, _, stats, programs = served
+    assert programs == len(LADDER) + 1
+    sent = 2 * 2 * sum(LENGTHS) + 6 * len(LENGTHS)
+    assert stats["prefill_chunk_tokens"] == sent
+    assert stats["prefill_chunks"] == stats["steps_with_chunk"] < stats["steps"]
+    assert stats["free_blocks"] == 64
+
+
+def test_warm_up_compiles_every_program_before_the_first_request(
+        chunk_params):
+    eng = PagedEngine(CFG, chunk_params, CHUNK_ECFG)
+    eng.warm_up()
+    assert eng._decode._cache_size() == len(LADDER) + 1
+    assert eng.stats()["steps"] == 0
+
+    async def main():
+        return [t async for t in eng.generate_stream(
+            a_prompt(2 * 16 + 3), max_tokens=ANSWER)]
+
+    assert [asyncio.run(main())] == greedy_reference(
+        chunk_params, [a_prompt(2 * 16 + 3)], ANSWER)
+    assert eng._decode._cache_size() == len(LADDER) + 1
+
+
+def test_every_active_slot_gets_a_token_in_each_step_of_a_long_admission(
+        chunk_params):
+    """A prompt of four chunks admitted beside a decoding slot: the chunks
+    ride in that slot's steps. The slot's 20 tokens take 20 steps (its own
+    prompt's chunk, then 19 decode steps) whether or not the long prompt is
+    admitted meanwhile, so it got a token in each of the long prompt's four
+    steps."""
+    eng = PagedEngine(CFG, chunk_params, CHUNK_ECFG)
+    long = a_prompt(3 * 16 + 5)
+
+    async def main():
+        gen = eng.generate_stream(a_prompt(5), max_tokens=20)
+        head = [await gen.__anext__() for _ in range(3)]
+        first = [t async for t in eng.generate_stream(long, max_tokens=1)]
+        at_first = eng.stats()
+        return head + [t async for t in gen], first, at_first
+
+    toks, first, at_first = asyncio.run(main())
+    assert len(toks) == 20
+    assert [first] == greedy_reference(chunk_params, [long], 1)
+    # the long prompt's first token came in its fourth step, by when the
+    # decoding slot had its own chunk's token and one from every step since
+    assert at_first["steps_with_chunk"] == 1 + 4
+    assert at_first["tokens_out"] == at_first["steps"] + 1
+    stats = eng.stats()
+    assert stats["steps"] == 20 and stats["tokens_out"] == 21
+    assert stats["prefill_chunk_tokens"] == 5 + len(long)
+    assert stats["prefill_chunk_pad_tokens"] == (8 - 5) + (8 - 5)
 
 
 def test_block_reuse_across_waves():

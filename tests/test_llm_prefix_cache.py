@@ -1,6 +1,7 @@
 """Prefix-cache correctness + KV-block lifecycle (reference: vLLM automatic
 prefix caching tests): pure PrefixCache units, warm-vs-cold generation
-equality through the paged engine's suffix-prefill path, and the
+equality through the paged engine's chunks that start behind the cached
+blocks, blocks shared while their prompt is still in chunks, and the
 client-disconnect block-leak regression."""
 
 import asyncio
@@ -177,6 +178,102 @@ def test_eviction_under_pool_pressure_preserves_output():
     # warm rerun of the LAST prompt (its blocks are still resident)
     p = list(np.random.RandomState(3).randint(1, 500, size=64))
     assert _gen(eng, p, max_tokens=4) == outs[3]
+
+
+# -- chunks over cached blocks ------------------------------------------------
+# max_model_len 256: the ladder of chunk widths is (32, 64)
+
+
+def _dense(prompt, max_tokens=8):
+    from ray_tpu.llm._generate import generate
+
+    return generate(CFG, init_params(CFG, jax.random.PRNGKey(0)), [prompt],
+                    max_new_tokens=max_tokens, temperature=0.0)[0]
+
+
+@pytest.mark.parametrize("tail", [1, 15, 16, 17, 64, 65, 2 * 64 + 3])
+def test_chunks_start_at_the_cached_length(tail):
+    """A prompt whose first 80 tokens (5 blocks) are cached runs only its
+    tail, as chunks whose first starts at position 80: on and around the
+    chunk widths and over several chunks. The answer is the dense
+    decoder's, and the chunks held exactly the tokens the cache did not."""
+    eng = _engine()
+    prefix = [int(t) for t in np.random.RandomState(5).randint(1, 500, 80)]
+    _gen(eng, prefix + [3], max_tokens=2)
+    before = eng.stats()
+    prompt = prefix + [int(t) for t in
+                       np.random.RandomState(tail).randint(1, 500, tail)]
+    assert _gen(eng, prompt) == _dense(prompt)
+    after = eng.stats()
+    assert (after["prefix_cache"]["block_hits"]
+            - before["prefix_cache"]["block_hits"]) == 5
+    assert (after["prefill_chunk_tokens"]
+            - before["prefill_chunk_tokens"]) == tail
+    assert (after["prefill_chunks"] - before["prefill_chunks"]
+            == -(-tail // 64))
+
+
+def test_a_prompt_still_in_chunks_shares_only_the_blocks_written():
+    """The second request shares 230 tokens with the first and arrives when
+    the first has run one chunk of its four: it is matched to blocks a
+    finished step wrote (64 tokens a chunk, 4 blocks) and to none of the
+    14 that share its keys but hold nothing yet; both answers are the
+    dense decoder's."""
+    eng = _engine(max_num_seqs=3)
+    shared = [int(t) for t in np.random.RandomState(9).randint(1, 500, 230)]
+    first, second = shared + [7, 8, 9], shared + [11, 12]
+
+    async def one(prompt):
+        return [t async for t in eng.generate_stream(
+            prompt, max_tokens=8, temperature=0.0)]
+
+    async def main():
+        a = asyncio.ensure_future(one(first))
+        while eng.stats()["prefill_chunks"] < 1:
+            await asyncio.sleep(0)
+        hits0 = eng.stats()["prefix_cache"]["block_hits"]
+        b = asyncio.ensure_future(one(second))
+        while eng.stats()["prefix_cache"]["block_hits"] == hits0:
+            await asyncio.sleep(0)
+        hits = eng.stats()["prefix_cache"]["block_hits"] - hits0
+        return await a, await b, hits
+
+    a, b, hits = asyncio.run(main())
+    assert hits % 4 == 0 and 4 <= hits < 230 // 16, hits
+    assert a == _dense(first) and b == _dense(second)
+    st = eng.stats()
+    # each ran what it was not handed: the second from its hits on
+    assert st["prefill_chunk_tokens"] == len(first) + len(second) - 16 * hits
+    assert st["free_blocks"] == 32 and st["blocks_in_use"] == 0
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_an_abort_between_two_chunks_hands_every_block_back(prefix_cache):
+    """The consumer of a four-chunk prompt leaves after its first chunk: the
+    next sweep frees the slot and every block, the blocks the chunk had
+    completed stay cached (evictable), and the next request is served."""
+    eng = _engine(prefix_cache=prefix_cache)
+    prompt = [int(t) for t in np.random.RandomState(4).randint(1, 500, 200)]
+
+    async def main():
+        gen = eng.generate_stream(prompt, max_tokens=8)
+        waiter = asyncio.ensure_future(gen.__anext__())
+        while eng.stats()["prefill_chunks"] < 1:
+            await asyncio.sleep(0)
+        waiter.cancel()
+        await asyncio.gather(waiter, return_exceptions=True)
+        await gen.aclose()
+        for _ in range(100):
+            await asyncio.sleep(0.02)
+            if eng.stats()["blocks_in_use"] == 0:
+                break
+        return eng.stats()
+
+    st = asyncio.run(main())
+    assert st["prefill_chunks"] < 4 and st["tokens_out"] == 0, st
+    assert st["blocks_in_use"] == 0 and st["free_blocks"] == 32
+    assert st["active_slots"] == 0
+    assert _gen(eng, prompt[:90]) == _dense(prompt[:90])
 
 
 # -- client-disconnect leak regression --------------------------------------

@@ -40,7 +40,10 @@ def pool_and_tables(rng, lens, *, kv, max_blocks, layers=2, dtype=jnp.bfloat16,
     owned[tables[tables > 0]] = True
     shape = (layers, nb, BS, kv, HD)
     k, v = (rng.standard_normal(shape).astype(np.float32) for _ in range(2))
-    clean = [jnp.array(x, dtype) for x in (k, v)]   # a copy: x goes on
+    # a copy made here: x goes on, and on the CPU a float32 array may share
+    # its buffer with the device's (the NaN then showed in `clean` now and
+    # then: test_float32_cache, PR 29's run)
+    clean = [jnp.asarray(x.copy(), dtype) for x in (k, v)]
     for x in (k, v):
         x[:-1] = np.nan
         x[-1, ~owned] = np.nan
@@ -191,3 +194,48 @@ def test_engine_with_the_kernel_matches_dense_decode(monkeypatch):
     stats = eng.stats()
     assert stats["free_blocks"] == 16
     assert 0 < stats["attn_positions_live"] < stats["attn_positions_dense"]
+
+
+# ---------------------------------------------------------------------------
+# the attention of a prompt chunk that rides in a decode step
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("start,n", [(0, 24), (0, 5), (16, 24), (37, 24),
+                                     (100, 24), (104, 3)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_chunk_attention_equals_dense_causal_attention(start, n, dtype):
+    """24 chunk rows of one sequence at positions start.. over that
+    sequence's table (tiles of two blocks, so the context takes several):
+    each of the first n rows is dense causal attention over the keys at
+    positions <= its own, whatever lies behind them in the table's blocks
+    (NaN here: a tile past the chunk's end is never read, a key past a
+    row's position never counted); the padding rows come out finite."""
+    rng = np.random.default_rng(start + n)
+    heads, kv, C = 4, 2, 24
+    end = start + n
+    (k, v), _, tables = pool_and_tables(rng, [8 * BS], kv=kv, max_blocks=8,
+                                        dtype=jnp.float32)
+    layer, row = k.shape[0] - 1, tables[0]
+    live = -(-end // (2 * BS)) * 2 * BS            # whole tiles up to end
+    pos = np.arange(8 * BS)
+    flat = np.asarray(row)[pos // BS], pos % BS
+    dead = jnp.asarray(pos >= live)[:, None, None]
+    k_nan, v_nan = (
+        x.at[layer, flat[0], flat[1]].set(
+            jnp.where(dead, jnp.nan, x[layer, flat[0], flat[1]])).astype(dtype)
+        for x in (k, v))
+    q = jnp.asarray(rng.standard_normal((C, heads, HD)), dtype)
+    qpos = start + jnp.arange(C, dtype=jnp.int32)
+    got = np.asarray(jax.jit(pa.chunk_attention, static_argnames="tile")(
+        q, k_nan, v_nan, layer, row, qpos, end, tile=2 * BS), np.float32)
+    assert np.isfinite(got).all()
+    keys, values = (np.repeat(np.asarray(x.astype(dtype), np.float32)[
+        layer, flat[0], flat[1]], heads // kv, axis=1) for x in (k, v))
+    s = np.einsum("chd,whd->hcw", np.asarray(q, np.float32), keys) / HD ** 0.5
+    s = np.where(pos[None, None, :] <= np.asarray(qpos)[None, :, None],
+                 s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("hcw,whd->chd", p / p.sum(-1, keepdims=True), values)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got[:n], want[:n], atol=tol, rtol=tol)

@@ -130,12 +130,11 @@ def test_flash_kernels_compile_for_v5e_under_their_names(on_v5e, build,
 V5E_HBM_BYTES = 15.75e9    # what the runtime leaves a program of 16 GiB
 
 
-def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
-                                                            monkeypatch):
-    """`jit_paged_decode_step` at the serve cells' shapes (Mistral-7B widths,
-    16 layers, 32 slots, 2,560 blocks of 16, tables of 128): the attention is
-    the named Pallas call, no value over all 2,048 positions of every slot is
-    left, the pool is not copied, and the program fits the chip."""
+def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
+    """`jit_paged_decode_step` compiled for the described v5e at the serve
+    cells' shapes (Mistral-7B widths, 16 layers, 32 slots, blocks of 16,
+    tables of 128) with a pool of `blocks` and a prompt chunk of `width`
+    rows (0: none)."""
     from ray_tpu.llm._engine import EngineConfig, _make_decode_step
     from ray_tpu.models.llama import LlamaConfig, init_params
 
@@ -144,7 +143,7 @@ def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
         vocab_size=32768, dim=4096, n_layers=16, n_heads=32, n_kv_heads=8,
         ffn_dim=14336, rope_theta=1e6, max_seq_len=2048, dtype=jnp.bfloat16,
         param_dtype=jnp.bfloat16)
-    slots, blocks = 32, 2560
+    slots = 32
     step, path, note = _make_decode_step(cfg, EngineConfig(
         max_num_seqs=slots, kv_block_size=16, num_kv_blocks=blocks,
         max_model_len=2048))
@@ -153,12 +152,22 @@ def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
         lambda x: on_v5e(x.shape, x.dtype),
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
     pool = on_v5e((16, blocks + 1, 16, 8, 128))
-    compiled = step.trace(
-        params, pool, pool, on_v5e((slots, 128), jnp.int32),
+    chunk = (on_v5e((width,), jnp.int32), on_v5e((3,), jnp.int32))
+    return step.trace(
+        width, params, pool, pool, on_v5e((slots, 128), jnp.int32),
         on_v5e((slots,), jnp.int32), on_v5e((slots,), jnp.bool_),
         on_v5e((slots,), jnp.int32), on_v5e((slots, 2), jnp.uint32),
-        on_v5e((slots,), jnp.float32),
+        on_v5e((slots,), jnp.float32), *(chunk if width else ()),
     ).lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
+                                                            monkeypatch):
+    """The step without a chunk, 2,560 blocks: the attention is the named
+    Pallas call, no value over all 2,048 positions of every slot is left,
+    the pool is not copied, and the program fits the chip."""
+    blocks = 2560
+    compiled = mistral_decode_step(on_v5e, monkeypatch, blocks, 0)
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_decode_step")
     kernels = [line for line in hlo.splitlines() if PALLAS in line]
@@ -168,6 +177,35 @@ def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
     m = compiled.memory_analysis()
     # the pool (2.7 GB) rides in the scan's carry and is donated: no copy
     assert m.alias_size_in_bytes > 2.6e9 and m.temp_size_in_bytes < 0.2e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("blocks", [2560, 5120])
+def test_decode_step_with_the_widest_chunk_compiles_for_v5e_pool_in_place(
+        on_v5e, monkeypatch, blocks):
+    """The step that carries a prompt chunk of the ladder's widest width, at
+    the cells' 2,560 blocks and at 5,120 (which the whole-prompt prefills,
+    with their second copy of the pool, did not fit: 18.4 GB): the chunk's
+    keys and values are scattered into the donated pool and read from it in
+    place (no second buffer of the pool's shape, temporaries under 1 GB),
+    the decode rows keep the paged kernel, and the program fits the chip."""
+    from ray_tpu.llm._engine import EngineConfig, chunk_ladder
+
+    width = chunk_ladder(EngineConfig(max_model_len=2048))[-1]
+    assert width == 256
+    compiled = mistral_decode_step(on_v5e, monkeypatch, blocks, width)
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines() if PALLAS in line]
+    assert len(kernels) == 1 and "%paged_decode_attention" in kernels[0]
+    pool = f"bf16[16,{blocks + 1},16,8,128]"
+    assert not re.findall(
+        r" (?:copy|dynamic-slice)\([^)]*" + re.escape(pool), hlo)
+    m = compiled.memory_analysis()
+    pool_bytes = 2 * 16 * (blocks + 1) * 16 * 8 * 128 * 2
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < 1e9
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
 
