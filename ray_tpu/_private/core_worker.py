@@ -229,14 +229,35 @@ class ReferenceCounter:
 
     def remove_local(self, ref: ObjectRef):
         ref._released = True
+        # never blocks: `ObjectRef.__del__` comes here from the cyclic GC,
+        # which can run on a thread that is inside one of this class's own
+        # critical sections (`add_local` at a bytecode boundary), where a
+        # blocking acquire of the plain lock never returns. A try that fails
+        # hands the decrement to the loop.
+        if not self._lock.acquire(blocking=False):
+            self.cw.schedule(self._remove_local_on_loop(ref))
+            return
+        try:
+            last = self._drop_local(ref.binary())
+        finally:
+            self._lock.release()
+        if last:
+            self.cw.schedule(self._on_zero_local(ref))
+
+    def _drop_local(self, key: bytes) -> bool:
+        """Under the lock: one local reference less; True if it was the last."""
+        n = self.local_counts.get(key, 0) - 1
+        if n > 0:
+            self.local_counts[key] = n
+            return False
+        self.local_counts.pop(key, None)
+        return True
+
+    async def _remove_local_on_loop(self, ref: ObjectRef):
         with self._lock:
-            key = ref.binary()
-            n = self.local_counts.get(key, 0) - 1
-            if n > 0:
-                self.local_counts[key] = n
-                return
-            self.local_counts.pop(key, None)
-        self.cw.schedule(self._on_zero_local(ref))
+            last = self._drop_local(ref.binary())
+        if last:
+            await self._on_zero_local(ref)
 
     async def _on_zero_local(self, ref: ObjectRef):
         key = ref.binary()

@@ -21,12 +21,33 @@ from typing import Any, Dict, List, Optional
 from ray_tpu.llm._generate import generate, init_cache
 
 BOS, EOS = 256, 257
-# family -> (module, its config class, its `f(cfg, key)` that makes the
-# seeded weights a server without a checkpoint serves): the engine builds its
-# steps and caches from the config's type
+# family -> (model module, its config class, its `f(cfg, key)` that makes the
+# seeded weights a server without a checkpoint serves, its step set: all
+# `PagedEngine` knows of the family, "module" or "module:attribute", the
+# interface in `llm/_engine.py`'s docstring). Every name is resolved when a
+# model of the family is built or served, not before
 MODEL_FAMILIES = {
-    "llama": ("ray_tpu.models.llama", "LlamaConfig", "init_params"),
-    "ling": ("ray_tpu.models.ling", "LingConfig", "seeded_params")}
+    "llama": ("ray_tpu.models.llama", "LlamaConfig", "init_params",
+              "ray_tpu.llm._engine:LLAMA_STEPS"),
+    "ling": ("ray_tpu.models.ling", "LingConfig", "seeded_params",
+             "ray_tpu.llm._ling_steps")}
+
+
+def step_set(cfg):
+    """The step set of the family whose config class `cfg` is an instance
+    of. The class is matched by its module and name, so that looking one up
+    imports that family's steps and no other family's anything."""
+    import importlib
+
+    classes = {(c.__module__, c.__name__) for c in type(cfg).__mro__}
+    for module, config_cls, _, steps in MODEL_FAMILIES.values():
+        if (module, config_cls) in classes:
+            path, _, attribute = steps.partition(":")
+            found = importlib.import_module(path)
+            return getattr(found, attribute) if attribute else found
+    raise TypeError(
+        f"{type(cfg).__name__} is the config class of no family in "
+        f"MODEL_FAMILIES ({sorted(MODEL_FAMILIES)})")
 
 
 class ByteTokenizer:
@@ -73,7 +94,7 @@ class LLMConfig:
         # TPU backend before anything is built on it
         check_granted_devices()
         family, _, preset = self.model.rpartition(":")
-        module, config_cls, seeded = MODEL_FAMILIES[family or "llama"]
+        module, config_cls, seeded, _ = MODEL_FAMILIES[family or "llama"]
         model = importlib.import_module(module)
         init_params = getattr(model, seeded)
         cfg = getattr(getattr(model, config_cls), preset)(
@@ -329,11 +350,6 @@ class LLMEngine:
         return {k: s[k] for k in ("ttft_p50_s", "tokens_per_s") if k in s}
 
 
-def engine_actor_class():
-    """Back-compat accessor; the class is a plain module attribute now."""
-    return LLMEngine
-
-
 def build_openai_app(config: LLMConfig, *, deployment_name: str = "v1"):
     """Deploy the completions endpoint; returns the serve handle
     (reference: build_openai_app core/ingress/builder.py:213 — the HTTP
@@ -392,7 +408,6 @@ __all__ = [
     "LLMServer",
     "batch_completions",
     "build_openai_app",
-    "engine_actor_class",
 ]
 
 from ray_tpu._private.usage import record_library_usage as _rlu

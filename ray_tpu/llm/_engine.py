@@ -1,28 +1,82 @@
-"""Continuous-batching LLM engine with a paged KV cache — TPU-native.
+"""Continuous-batching LLM engine over a paged block pool — TPU-native.
 
 Reference capability: the vLLM engine the reference wraps
 (python/ray/llm/_internal/serve/engines/vllm/vllm_engine.py:283 — continuous
-batching, PagedAttention block tables, streaming). Rebuilt for XLA:
+batching, PagedAttention block tables, streaming). Rebuilt for XLA; this
+module is the scheduler, and serves any model family of
+`ray_tpu.llm.MODEL_FAMILIES` through that family's *step set* (below):
 
-- **Paged KV cache**: one shared pool of fixed-size KV blocks
-  ([layers, num_blocks, block_size, kv_heads, head_dim]); each decode slot
-  owns a block table (physical block ids). No per-sequence max-length
-  allocation, no fragmentation: finished sequences return their blocks to
-  the pool and a new request reuses them immediately.
+- **Paged pool**: one shared pool of fixed-size blocks; each decode slot owns
+  a block table (physical block ids). No per-sequence max-length allocation,
+  no fragmentation: finished sequences return their blocks to the pool and a
+  new request reuses them immediately. What a block holds, and what else a
+  slot carries, is the step set's (`alloc_cache`); the scheduler owns the
+  tables, the free list and the prefix cache over them.
 - **Static shapes for XLA**: the decode step is ONE jitted function over the
   fixed slot count — inactive slots write to a reserved trash block and are
   masked out — so admission/turnover never recompiles.
-- **Continuous batching, prompts in the decode step**: an admission is
-  bookkeeping (slot, blocks, prefix-cache match); the prompt then runs as
-  chunks of a few static widths (`chunk_ladder`), one chunk of one request
-  a step, in the same program and the same weight matmuls as the running
-  slots' tokens, so nobody waits for a prefill. The request's first token
-  is sampled in the step that holds its prompt's last token and the slot
-  decodes from the next step on. (A family whose steps take no chunk, Ling,
-  still runs each prompt whole, awaited in the loop.)
+- **Continuous batching, prompts in the decode step**: where the step set
+  has a `chunk_ladder`, an admission is bookkeeping (slot, blocks,
+  prefix-cache match); the prompt then runs as chunks of a few static
+  widths, one chunk of one request a step, in the same program and the same
+  weight matmuls as the running slots' tokens, so nobody waits for a
+  prefill. The request's first token is sampled in the step that holds its
+  prompt's last token and the slot decodes from the next step on. Where the
+  ladder is empty each prompt runs whole through the step set's prefill,
+  awaited in the loop. The ladder is the one thing the scheduler's path
+  forks on.
 - **Streaming**: tokens flow to callers through per-request async queues;
   the engine runs as an async actor and `generate_stream` is an async
   generator riding the framework's streaming-generator plane.
+
+**The step set** is all `PagedEngine` knows of a model family: a namespace (a
+module, or an object such as `LLAMA_STEPS` below) with exactly the names of
+`STEP_SET`, found from the config's class by `ray_tpu.llm.step_set`. "cache"
+is the tuple of device arrays in `CACHE_NAMES` order; B is `max_num_seqs`.
+
+CACHE_NAMES  the device arrays every step takes after the params, donates
+    and returns, each held as the engine's attribute of that name: under the
+    block table [layers, num_kv_blocks + 1, kv_block_size, ...] (block 0 is
+    the trash block), per slot [layers, B, ...].
+alloc_cache(cfg, ecfg) -> cache, zeroed.
+make_decode_step(cfg, ecfg) -> (step, path, note). `path` names the
+    attention it was built with (`stats()["decode_attention"]`); `note` says
+    why a TPU was refused the kernel, or is None. The jitted step:
+        paged_decode_step([C,] params, *cache, tables [B, max_blocks],
+            lens [B], active [B], last_tok [B], keys [B, 2] uint32,
+            temps [B] [, chunk_ids [C], chunk_at [3]] [, probe_slot])
+            -> (toks, *cache [, probe])
+    The static chunk width C leads iff the ladder is not empty, and the
+    chunk (`chunk_at`: slot, start position, real tokens) follows iff C > 0;
+    `probe_slot` (a device scalar) and `probe` are there iff `PROBE` is not
+    empty. `toks` is one int32 vector, fetched once a step: a token a slot,
+    then `COUNTERS`, then for a chunk the token drawn from its last real row
+    and the slot's decode stream key (two int32).
+chunk_ladder(ecfg) -> the widths C > 0 the step takes, ascending; () for a
+    step that takes no chunk.
+make_prefill(cfg, ecfg) -> the jitted whole-prompt `paged_prefill`: with an
+    empty ladder the loop's (`_admit_whole` states its signature), else the
+    set's own business (`check_prefill`, P/D's `PrefillWorker`).
+check_prefill(cfg, ecfg, prefill, params, prompt_ids) -> (got, ref): that
+    prefill's last logits on caches of its own, and the family's reference
+    forward pass's on the same prompt.
+COUNTERS  names of what the step counts on the device; `stats()` sums them.
+PROBE  keys of the dict the step returns last, fetched only while a checked
+    request is in a slot (`check_routing`): "routing" [layers, B, ...] of
+    every slot (the loop's prefill returns its twin, [layers, S, ...],
+    second), the others of slot `probe_slot` alone.
+SLOT_STATE  by name, the cache array [layers, B, ...] a slot carries beside
+    its blocks (an admission hands it to the new request; a probed request
+    reads its slot's), or None where the blocks are all a sequence has.
+NO_PREFIX_CACHE  None where a block alone resumes a sequence, so the prefix
+    cache may share it; else why not: what `prefix_cache=True` is refused
+    with.
+make_kv_inject(cfg, ecfg) -> the jitted, donating `paged_kv_inject(*cache,
+    phys [nb], *blocks) -> cache` that seeds blocks `phys` from
+    `generate_stream(prefilled=(*blocks, last_logits))`, one [layers, nb,
+    ...] a cache array; raises ValueError saying why where none can.
+extra_stats(cfg, cache, attn_positions_live) -> the family's own entries of
+    `stats()`.
 """
 
 from __future__ import annotations
@@ -32,17 +86,21 @@ import collections
 import logging
 import math
 import time
-from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple, Union
+import types
+from dataclasses import dataclass, replace
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ray_tpu.llm import _ling_steps
-from ray_tpu.models.ling import LingConfig
 from ray_tpu.models.llama import LlamaConfig, rms_norm, rope_tables
 from ray_tpu.util import tracing
 
 __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
+
+# the names a step set has, all of them and no other (module docstring)
+STEP_SET = ("CACHE_NAMES", "alloc_cache", "make_decode_step", "chunk_ladder",
+            "make_prefill", "check_prefill", "COUNTERS", "PROBE",
+            "SLOT_STATE", "NO_PREFIX_CACHE", "make_kv_inject", "extra_stats")
 
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
@@ -53,8 +111,8 @@ __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 PHASE_SWEEP = "engine:sweep"                    # drain _pending, abort sweep
 PHASE_ADMIT = "engine:admit"                    # one per _try_admit call
 PHASE_PREFIX_MATCH = "engine:prefix_match"      # chain_keys, match, eviction
-# a family whose prompts run whole, awaited in the loop (Ling), and P/D
-# admission's first token:
+# a step set without a chunk ladder (its prompts run whole, awaited in the
+# loop), and P/D admission's first token:
 PHASE_PREFILL = "engine:prefill"                # the jitted whole-prompt call
 PHASE_SAMPLE_FIRST = "engine:sample_first"      # waits for the prefill
 # one run_step call (argument `chunk`: the width of the prompt chunk the step
@@ -72,8 +130,8 @@ PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
 SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
 SPAN_PREFILL = "engine:prefill"  # admission start -> first token (its chunks)
 SPAN_DECODE = "engine:decode"    # first token -> done
-# a turn of the loop is admissions (bookkeeping, or for Ling a whole prompt
-# of tens of ms) and one decode step:
+# a turn of the loop is admissions (bookkeeping, or without a chunk ladder a
+# whole prompt of tens of ms) and one decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
 
@@ -83,29 +141,43 @@ class EngineConfig:
     """Sizing knobs (reference: vLLM engine_kwargs max_num_seqs /
     block_size / gpu_memory_utilization → num blocks).
 
-    What the blocks hold depends on the model's family. Llama: keys and
-    values of every layer. Ling (recurrent layers beside latent attention):
-    the pool holds one latent vector a token for each latent-attention layer
-    ([layers, blocks, block size, rank + rope], same table and allocator),
-    and beside it every slot owns one float32 recurrent state per
-    linear-attention layer and its short convolution's tail; those are sized
-    by `max_num_seqs` alone, are reset by the slot's next prefill and are
-    never paged (`ray_tpu/llm/_ling_steps.py`)."""
+    They size the scheduler's side: slots, block tables, the pool's block
+    count. What a block holds, and what a slot carries beside its blocks
+    (sized by `max_num_seqs` alone, never paged), is the family's step set's
+    `alloc_cache` (the module docstring; `LLAMA_STEPS` below,
+    `llm/_ling_steps.py`)."""
 
     max_num_seqs: int = 4          # decode batch slots
     kv_block_size: int = 16        # tokens per KV block
     num_kv_blocks: int = 64        # pool size (excl. the trash block)
     max_model_len: int = 256       # prompt + generation cap per sequence
     # None = follow the llm_prefix_cache_enabled config flag (the bench
-    # A/B lever passes an explicit bool). A model with recurrent layers has
-    # no prefix cache (a cached block of latents is no use without the
-    # recurrent state at its boundary, which nothing snapshots): None turns
-    # it off there, True is refused
+    # A/B lever passes an explicit bool). Where a block alone does not
+    # resume a sequence (the step set's `NO_PREFIX_CACHE`) None turns it
+    # off and True is refused
     prefix_cache: Optional[bool] = None
 
 
+def sample_tokens(keys, logits, temps):
+    """Inside a decode step: one token a slot from logits [B, V], greedy
+    where the slot's temperature is 0, else drawn with the slot's key (raw
+    key data [B, 2])."""
+    import jax
+    import jax.numpy as jnp
+
+    def sample_one(key_data, lg, t):
+        key = jax.random.wrap_key_data(key_data.astype(jnp.uint32))
+        greedy = jnp.argmax(lg).astype(jnp.int32)
+        samp = jax.random.categorical(
+            key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
+        return jnp.where(t > 0, samp, greedy)
+
+    return jax.vmap(sample_one)(keys, logits, temps)
+
+
 # ---------------------------------------------------------------------------
-# jitted model steps (paged attention)
+# the Llama family's step set (`LLAMA_STEPS` at the section's end): keys and
+# values of every layer in the pool, nothing beside it
 # ---------------------------------------------------------------------------
 
 
@@ -255,23 +327,6 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
     return paged_decode_step, path, note
 
 
-def sample_tokens(keys, logits, temps):
-    """Inside a decode step: one token a slot from logits [B, V], greedy
-    where the slot's temperature is 0, else drawn with the slot's key (raw
-    key data [B, 2])."""
-    import jax
-    import jax.numpy as jnp
-
-    def sample_one(key_data, lg, t):
-        key = jax.random.wrap_key_data(key_data.astype(jnp.uint32))
-        greedy = jnp.argmax(lg).astype(jnp.int32)
-        samp = jax.random.categorical(
-            key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
-        return jnp.where(t > 0, samp, greedy)
-
-    return jax.vmap(sample_one)(keys, logits, temps)
-
-
 def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     """Jitted single-request prefill at a static padded length S: plain
     causal attention over the prompt, KV scattered into the request's
@@ -338,6 +393,16 @@ def _make_prefill(cfg: LlamaConfig, ecfg: EngineConfig):
     return paged_prefill
 
 
+def _alloc_cache(cfg: LlamaConfig, ecfg: EngineConfig):
+    """kc, vc [layers, blocks + the trash block, block size, KV heads, head
+    dim]: every layer's keys and values under the engine's block table."""
+    import jax.numpy as jnp
+
+    kc = jnp.zeros((cfg.n_layers, ecfg.num_kv_blocks + 1, ecfg.kv_block_size,
+                    cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    return kc, jnp.zeros_like(kc)
+
+
 def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
                        prompt_ids: List[int]):
     """Run the jitted `prefill` over one prompt into a pool sized to exactly
@@ -347,12 +412,9 @@ def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
 
     p = list(prompt_ids) or [0]
     plen = len(p)
-    bs = ecfg.kv_block_size
-    nb = -(-plen // bs)
+    nb = -(-plen // ecfg.kv_block_size)
     S = max(8, 1 << (plen - 1).bit_length())
-    kc = jnp.zeros((cfg.n_layers, nb + 1, bs, cfg.n_kv_heads, cfg.head_dim),
-                   cfg.dtype)
-    vc = jnp.zeros_like(kc)
+    kc, vc = _alloc_cache(cfg, replace(ecfg, num_kv_blocks=nb))
     table = np.zeros((max(nb, 1),), np.int32)
     table[:nb] = np.arange(1, nb + 1)
     prompt = np.zeros((S,), np.int32)
@@ -361,6 +423,48 @@ def prefill_fresh_pool(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
         S, params, kc, vc, jnp.asarray(table), jnp.asarray(prompt),
         jnp.int32(plen))
     return logits, kc, vc, nb
+
+
+def _check_prefill(cfg: LlamaConfig, ecfg: EngineConfig, prefill, params,
+                   prompt_ids: List[int]):
+    """The jitted `prefill` on a pool of its own against
+    `models.llama.forward` on the same prompt: two writings of one model."""
+    import functools
+
+    import jax
+
+    from ray_tpu.models.llama import forward
+
+    got = prefill_fresh_pool(cfg, ecfg, prefill, params, prompt_ids)[0]
+    ref = jax.jit(functools.partial(forward, cfg))(
+        params, np.asarray([list(prompt_ids)], np.int32))[0, -1]
+    return got, ref
+
+
+def _make_kv_inject(cfg: LlamaConfig, ecfg: EngineConfig):
+    """The decode side of prefill/decode disaggregation: blocks `phys` of
+    the pool take the keys and values another worker's prefill computed
+    (`prefill_fresh_pool`'s blocks 1..nb)."""
+    import jax
+
+    def paged_kv_inject(kc, vc, phys, k, v):
+        return kc.at[:, phys].set(k), vc.at[:, phys].set(v)
+
+    return jax.jit(paged_kv_inject, donate_argnums=(0, 1))
+
+
+LLAMA_STEPS = types.SimpleNamespace(
+    CACHE_NAMES=("kc", "vc"), alloc_cache=_alloc_cache,
+    make_decode_step=_make_decode_step, chunk_ladder=chunk_ladder,
+    make_prefill=_make_prefill, check_prefill=_check_prefill,
+    COUNTERS=(), PROBE=(), SLOT_STATE=None, NO_PREFIX_CACHE=None,
+    make_kv_inject=_make_kv_inject,
+    extra_stats=lambda cfg, cache, attn_positions_live: {})
+
+
+# ---------------------------------------------------------------------------
+# the scheduler
+# ---------------------------------------------------------------------------
 
 
 @dataclass
@@ -394,10 +498,11 @@ class _Request:
     # request is traced; the three spans are recorded as its children
     trace_parent: Optional[dict] = None
     # disaggregated serving: prefill ran on ANOTHER worker; admission
-    # injects the transferred KV blocks instead of running _prefill
+    # injects the transferred blocks instead of running the prompt
     # (reference: serving_patterns/prefill_decode — KV transfer between
-    # prefill and decode engines)
-    prefilled: Optional[tuple] = None  # (k [L,nb,bs,kvh,hd], v, last_logits)
+    # prefill and decode engines): (*blocks, last_logits), one
+    # [layers, nb, ...] a cache array (Llama: k, v [L, nb, bs, kvh, hd])
+    prefilled: Optional[tuple] = None
     # check_routing's request: receives under "routing" the expert layers'
     # choices at every position computed ([moe_layers, n, top_k + 1] a piece)
     # and, where it has the key "steps", what each decode step computed the
@@ -414,24 +519,26 @@ def _request_key(req: _Request) -> Tuple[int, int]:
 
 
 class PagedEngine:
-    """The continuous-batching scheduler around the jitted steps.
+    """The continuous-batching scheduler around a family's jitted steps.
 
     Host-side state (block free list, slot table, request queues) is plain
     Python owned by ONE engine loop task; device state (block pool, tables)
     crosses in as arrays each step. Run it inside an async actor and call
     `generate_stream` concurrently — requests arriving mid-decode are
-    admitted at the next step boundary."""
+    admitted at the next step boundary.
 
-    def __init__(self, cfg: Union[LlamaConfig, LingConfig], params,
-                 ecfg: Optional[EngineConfig] = None,
+    `cfg` is the config of a family in `ray_tpu.llm.MODEL_FAMILIES`; all
+    the engine knows of the family is its step set (the module docstring)."""
+
+    def __init__(self, cfg, params, ecfg: Optional[EngineConfig] = None,
                  eos_id: Optional[int] = None):
+        from ray_tpu.llm import step_set
+
         self.cfg = cfg
         self.ecfg = ecfg or EngineConfig()
-        # a model with recurrent layers: per-slot state beside the pool
-        self._recurrent = isinstance(cfg, LingConfig)
+        self._steps = steps = step_set(cfg)
         # the device arrays every step is given, donates and returns
-        self._cache_names = (_ling_steps.CACHE_NAMES if self._recurrent
-                             else ("kc", "vc"))
+        self._cache_names = steps.CACHE_NAMES
         self.params = params
         self.eos_id = eos_id
         e = self.ecfg
@@ -447,12 +554,9 @@ class PagedEngine:
         from ray_tpu._private.config import GLOBAL_CONFIG
 
         enabled = e.prefix_cache
-        if self._recurrent:
+        if steps.NO_PREFIX_CACHE:
             if enabled:
-                raise ValueError(
-                    "prefix_cache=True with recurrent layers: a shared block "
-                    "of latents would need the recurrent state at its "
-                    "boundary, and nothing snapshots that state")
+                raise ValueError(steps.NO_PREFIX_CACHE)
             enabled = False
         if enabled is None:
             enabled = GLOBAL_CONFIG.get("llm_prefix_cache_enabled")
@@ -464,27 +568,21 @@ class PagedEngine:
                 self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
         self._alloc_device_state()
         # "paged_kernel" | "xla", fixed for the engine's life (stats())
-        make_decode, make_prefill, ladder = (
-            (_ling_steps.make_decode_step, _ling_steps.make_prefill,
-             getattr(_ling_steps, "chunk_ladder", None))
-            if self._recurrent
-            else (_make_decode_step, _make_prefill, chunk_ladder))
         self._decode, self.decode_attention, self._decode_note = (
-            make_decode(cfg, e))
+            steps.make_decode_step(cfg, e))
         if self._decode_note:
             logging.getLogger(__name__).warning(self._decode_note)
-        # whole prompts: a family without a ladder in the loop; check_prefill
-        self._prefill = make_prefill(cfg, e)
-        # the chunk widths the family's decode step takes. With a ladder a
-        # prompt is admitted in chunks that ride in the decode steps; with
-        # none (its step module offers no such step) whole, awaited in the
-        # loop
-        self._ladder: Tuple[int, ...] = ladder(e) if ladder else ()
+        # whole prompts: without a ladder in the loop; check_prefill
+        self._prefill = steps.make_prefill(cfg, e)
+        # the chunk widths the decode step takes. With a ladder a prompt is
+        # admitted in chunks that ride in the decode steps (`_admit_chunks`);
+        # with none whole, awaited in the loop (`_admit_whole`)
+        self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
         # admitted requests whose prompts are not all in the pool yet, in
         # arrival order: the head's next chunk rides in the next step
         self._prefilling: "collections.deque[_Request]" = collections.deque()
         self._pending: "asyncio.Queue[_Request]" = None  # type: ignore
-        self._inject = None  # lazy jitted donated KV scatter (P/D admission)
+        self._inject = None  # the step set's KV scatter, at first use (P/D)
         self._loop_task = None
         self._rid = 0
         self._rngs = np.zeros((B, 2), np.uint32)
@@ -502,9 +600,8 @@ class PagedEngine:
         self.attn_positions_live = 0
         self.attn_positions_dense = 0
         # what the decode step counts on the device and returns behind its
-        # tokens (none for Llama), summed over the steps
-        self._step_counters = {name: 0 for name in (
-            _ling_steps.COUNTERS if self._recurrent else ())}
+        # tokens, summed over the steps
+        self._step_counters = {name: 0 for name in steps.COUNTERS}
         # the slot whose router and recurrence inputs the decode step hands
         # out (check_routing; one request at a time), and its device copy
         self._probe_slot = None
@@ -538,28 +635,21 @@ class PagedEngine:
             return False
 
     def _alloc_device_state(self):
-        """Allocate the KV pool + free-block list (block 0 is the trash
+        """Allocate the caches + free-block list (block 0 is the trash
         block). Shared by __init__ and post-failure reset so the pool
         layout can never diverge between the two."""
         import jax.numpy as jnp
 
-        cfg, e = self.cfg, self.ecfg
-        NB = e.num_kv_blocks + 1
-        if self._recurrent:
-            self._set_cache(_ling_steps.alloc_cache(cfg, e))
+        self._set_cache(self._steps.alloc_cache(self.cfg, self.ecfg))
+        if self._steps.PROBE:
             self._probe_arg = jnp.int32(0)
-        else:
-            self.kc = jnp.zeros(
-                (cfg.n_layers, NB, self.bs, cfg.n_kv_heads, cfg.head_dim),
-                cfg.dtype)
-            self.vc = jnp.zeros_like(self.kc)
-        self.free_blocks = list(range(1, NB))
+        self.free_blocks = list(range(1, self.ecfg.num_kv_blocks + 1))
 
     def _reset_device_state(self):
-        """Reallocate the KV pool and clear host bookkeeping. Needed when a
-        jitted step fails AFTER its donated kc/vc inputs were invalidated:
-        every in-flight sequence lost its cache, so the engine must start
-        from an empty pool rather than leave self.kc pointing at deleted
+        """Reallocate the caches and clear host bookkeeping. Needed when a
+        jitted step fails AFTER its donated inputs were invalidated: every
+        in-flight sequence lost its cache, so the engine must start from an
+        empty pool rather than leave the attributes pointing at deleted
         buffers (every later request would die with a confusing
         'buffer donated/deleted' error; advisor r3)."""
         self._alloc_device_state()
@@ -593,13 +683,18 @@ class PagedEngine:
     def _try_admit(self, req: _Request) -> bool:
         import jax
 
-        # state_reset: the admission hands a slot's recurrent state to a new
-        # request (its prefill starts from zeros and overwrites it)
+        # state_reset: the admission hands what the slot carries beside its
+        # blocks to a new request (whose prefill overwrites it)
         with jax.profiler.TraceAnnotation(
-                PHASE_ADMIT, state_reset=int(self._recurrent)):
+                PHASE_ADMIT,
+                state_reset=int(self._steps.SLOT_STATE is not None)):
             return self._admit(req)
 
     def _admit(self, req: _Request) -> bool:
+        """A slot, the blocks (those the prefix cache already holds, then
+        new ones) and the slot's table row; then the prompt, by the one
+        fork the step set's ladder decides. False: the head waits for a slot
+        or for blocks."""
         t_admit = time.monotonic()
         need = self._blocks_needed(req)
         try:
@@ -607,17 +702,8 @@ class PagedEngine:
         except StopIteration:
             return False
         if req.prefilled is not None:
-            if self._recurrent:
-                self._fail(req, ValueError(
-                    "transferred KV cannot seed a model with recurrent "
-                    "layers: its state is not in the blocks"))
-                return True
-            if not self._free_with_eviction(need):
-                return False
-            req.t_admit = t_admit
-            return self._admit_prefilled(req, slot, need)
+            return self._admit_prefilled(req, slot, need, t_admit)
         import jax
-        import jax.numpy as jnp
 
         cache = self._prefix_cache
         plen = len(req.prompt)
@@ -644,19 +730,43 @@ class PagedEngine:
         row[: need] = hits + blocks
         self.tables[slot] = row
         if self._ladder:
-            # bookkeeping only: the prompt past the cached blocks runs as
-            # chunks of the coming steps (`_next_chunk`). The slot is the
-            # request's from here, so the abort sweep finds it
-            req.slot, self.slot_req[slot] = slot, req
-            req.cursor, req.block_keys = len(hits) * self.bs, tuple(keys)
-            self._prefilling.append(req)
-            if hits:
-                from ray_tpu.util.metrics import Counter
+            self._admit_chunks(req, slot, hits, keys)
+        else:
+            self._admit_whole(req, slot, row, blocks)
+        return True
 
-                Counter("rt_llm_prefix_hits_total",
-                        "KV blocks reused from the prompt-prefix cache "
-                        "instead of re-prefilled.").inc(len(hits))
-            return True
+    def _admit_chunks(self, req: _Request, slot: int, hits: List[int],
+                      keys: List[bytes]):
+        """With a chunk ladder an admission is bookkeeping only: the prompt
+        past the cached blocks runs as chunks of the coming steps
+        (`_next_chunk`). The slot is the request's from here, so the abort
+        sweep finds it."""
+        req.slot, self.slot_req[slot] = slot, req
+        req.cursor, req.block_keys = len(hits) * self.bs, tuple(keys)
+        self._prefilling.append(req)
+        if hits:
+            from ray_tpu.util.metrics import Counter
+
+            Counter("rt_llm_prefix_hits_total",
+                    "KV blocks reused from the prompt-prefix cache "
+                    "instead of re-prefilled.").inc(len(hits))
+
+    def _admit_whole(self, req: _Request, slot: int, row, blocks: List[int]):
+        """Without a chunk ladder the whole prompt runs here, awaited in the
+        loop, through the step set's prefill at the prompt's power-of-two
+        bucket S:
+
+            paged_prefill(S, params, *cache, table [max_blocks], prompt [S]
+                          right-padded, plen, slot)
+                -> (last logits [V], routing, *cache)
+
+        It writes the prompt's blocks and the whole of what slot `slot`
+        carries beside them; `routing` ([layers, S, ...]) is read only for a
+        probed request. The first token is sampled on the host."""
+        import jax
+        import jax.numpy as jnp
+
+        plen = len(req.prompt)
         try:
             S = max(8, 1 << (plen - 1).bit_length())  # pow-2 bucket
             prompt = np.zeros((S,), np.int32)
@@ -682,7 +792,6 @@ class PagedEngine:
             self.tables[slot] = 0
             raise
         self._activate_slot(req, slot, tok)
-        return True
 
     def _next_chunk(self):
         """The oldest admitted request whose prompt is not all in the pool,
@@ -746,22 +855,26 @@ class PagedEngine:
 
     def _probe_admitted(self, req: _Request, slot: int):
         """A request that asked for the decode step's mechanisms owns the
-        step's probe from here to its release; its recurrent state as the
-        prefill left it is the replay's starting point."""
+        step's probe from here to its release; what its slot carries beside
+        the blocks, as the prefill left it, is the replay's starting
+        point."""
         import jax.numpy as jnp
 
         if "steps" not in req.probe:
             return
         assert self._probe_slot is None, "one probed request at a time"
         self._probe_slot, self._probe_arg = slot, jnp.int32(slot)
-        req.probe["state0"] = np.asarray(self.state[:, slot])
+        req.probe["state0"] = self._slot_state(slot)
+
+    def _slot_state(self, slot: int):
+        return np.asarray(getattr(self, self._steps.SLOT_STATE)[:, slot])
 
     def _release(self, req: _Request):
         slot = req.slot
         if slot == self._probe_slot:
             self._probe_slot = None
             if not self._device_state_invalid():
-                req.probe["state"] = np.asarray(self.state[:, slot])
+                req.probe["state"] = self._slot_state(slot)
         need = self._blocks_needed(req)
         cache = self._prefix_cache
         for b in self.tables[slot][:need]:
@@ -832,18 +945,30 @@ class PagedEngine:
         self._publish_metrics()
         self._emit(req, tok)
 
-    def _admit_prefilled(self, req: _Request, slot: int, need: int) -> bool:
+    def _admit_prefilled(self, req: _Request, slot: int, need: int,
+                         t_admit: float) -> bool:
         """Admit a request whose prefill ran on ANOTHER worker: scatter the
-        transferred KV block contents into this engine's pool and seed the
-        first token from the transferred last-position logits — the decode
-        side of prefill/decode disaggregation (reference:
+        transferred block contents into this engine's pool (the step set's
+        `make_kv_inject`) and seed the first token from the transferred
+        last-position logits — the decode side of prefill/decode
+        disaggregation (reference:
         serving_patterns/prefill_decode/builder.py:236-238 + the vLLM KV
         transfer connectors)."""
-        import jax
         import jax.numpy as jnp
 
-        k_in, v_in, last_logits = req.prefilled
-        nb = k_in.shape[1]
+        if self._inject is None:
+            try:
+                self._inject = self._steps.make_kv_inject(self.cfg, self.ecfg)
+            except ValueError as refusal:
+                # this family takes no transferred blocks: the request
+                # fails, the queue moves on
+                self._fail(req, refusal)
+                return True
+        if not self._free_with_eviction(need):
+            return False
+        req.t_admit = t_admit
+        *blocks_in, last_logits = req.prefilled
+        nb = blocks_in[0].shape[1]
         expect = -(-len(req.prompt) // self.bs)
         if nb != expect or nb > need:
             # malformed transfer: failing the REQUEST (not returning False,
@@ -859,17 +984,11 @@ class PagedEngine:
             row = np.zeros((self.max_blocks,), np.int32)
             row[: len(blocks)] = blocks
             self.tables[slot] = row
-            if self._inject is None:
-                def paged_kv_inject(kc, vc, phys, k, v):
-                    return kc.at[:, phys].set(k), vc.at[:, phys].set(v)
-
-                self._inject = jax.jit(paged_kv_inject,
-                                       donate_argnums=(0, 1))
             phys = jnp.asarray(np.asarray(blocks[:nb], np.int32))
-            self.kc, self.vc = self._inject(
-                self.kc, self.vc, phys,
-                jnp.asarray(k_in, self.kc.dtype),
-                jnp.asarray(v_in, self.vc.dtype))
+            cache = self._cache()
+            self._set_cache(self._inject(
+                *cache, phys, *(jnp.asarray(b, c.dtype)
+                                for b, c in zip(blocks_in, cache))))
             tok = self._sample_first(req, slot, jnp.asarray(last_logits))
         except BaseException:
             self.free_blocks.extend(blocks)
@@ -969,7 +1088,7 @@ class PagedEngine:
                 # decodes: the step draws the request's first token with them
                 self._rngs[admitting.slot] = _request_key(admitting)
                 self.temps[admitting.slot] = admitting.temperature
-            # the step's static chunk width, where the family's step has one
+            # the step's static chunk width, where the step takes one
             lead = (width,) if self._ladder else ()
             probing = any(r is not None and r.probe is not None
                           for r in self.slot_req)
@@ -979,13 +1098,13 @@ class PagedEngine:
                 # two of the inner ones (else a Python frame and its line)
                 with phase(PHASE_STEP, chunk=width):
                     with phase(PHASE_UPLOAD):
-                        state = [jnp.asarray(a) for a in (
+                        host = [jnp.asarray(a) for a in (
                             *self._host_state(), *chunk_args)]
-                    if self._recurrent:
-                        state.append(self._probe_arg)
+                    if self._steps.PROBE:
+                        host.append(self._probe_arg)
                     with phase(PHASE_DISPATCH):
                         toks, *rest = self._decode(
-                            *lead, self.params, *self._cache(), *state)
+                            *lead, self.params, *self._cache(), *host)
                         n = len(self._cache_names)
                         self._set_cache(rest[:n])
                     with phase(PHASE_DEVICE_WAIT):
@@ -1019,9 +1138,10 @@ class PagedEngine:
                 self.attn_positions_dense += (
                     self.ecfg.max_num_seqs * self.ecfg.max_model_len)
                 self._rngs[:, 1] += 1  # fresh fold per step
-                # behind the tokens: the step's counters
+                # behind the tokens: the step's counters, then the chunk's
                 B = len(self.slot_req)
-                for name, n in zip(self._step_counters, toks[B:]):
+                tail = toks[B:]
+                for name, n in zip(self._step_counters, tail):
                     self._step_counters[name] += int(n)
                 for slot, req in enumerate(list(self.slot_req)):
                     if req is None or not self.active[slot]:
@@ -1038,7 +1158,7 @@ class PagedEngine:
                     self.last_tok[slot] = tok
                     self._emit(req, tok)
                 if chunk is not None:
-                    self._chunk_done(*chunk, toks[B:])
+                    self._chunk_done(*chunk, tail[len(self._step_counters):])
             now = time.monotonic()
             if now - t_turn > STALL_TURN_S:
                 self._stalls["loop_stalls"] += 1
@@ -1055,18 +1175,18 @@ class PagedEngine:
         decode step at each chunk width and without one, on an idle batch
         whose rows all land in the trash block. After it no request, of
         whatever length, compiles anything. Before the loop starts (the
-        caches are donated to each call). A family admitted by whole
-        prompts has a program a prompt-length bucket and is not warmed."""
+        caches are donated to each call). Without a chunk ladder the loop
+        has a program a prompt-length bucket, and none is warmed."""
         import jax.numpy as jnp
 
         if not self._ladder:
             return
-        state = [jnp.asarray(a) for a in self._host_state()]
+        host = [jnp.asarray(a) for a in self._host_state()]
         for width in (0, *self._ladder):
             chunk = (jnp.zeros((width,), jnp.int32),
                      jnp.zeros((3,), jnp.int32)) if width else ()
             toks, *caches = self._decode(
-                width, self.params, *self._cache(), *state, *chunk)
+                width, self.params, *self._cache(), *host, *chunk)
             self._set_cache(caches)
             toks.block_until_ready()
 
@@ -1077,8 +1197,9 @@ class PagedEngine:
                               probe: Optional[Dict[str, Any]] = None):
         """Async generator of token ids. Engine-side failures raise into the
         consumer (queue items: int token | None end | Exception).
-        `prefilled=(k, v, last_logits)` admits with KV transferred from a
-        remote prefill worker instead of running prefill here. `probe`
+        `prefilled=(*blocks, last_logits)` (Llama: k, v) admits with KV
+        transferred from a remote prefill worker instead of running the
+        prompt here. `probe`
         (see `check_routing`) receives what a check holds to a reference."""
         prompt_ids = list(prompt_ids) or [0]
         if len(prompt_ids) + 1 > self.ecfg.max_model_len:
@@ -1110,25 +1231,12 @@ class PagedEngine:
             req.aborted = True
 
     def check_prefill(self, prompt_ids: List[int]) -> Dict[str, Any]:
-        """Prefill's last-position logits against `models.llama.forward` on
-        the same prompt: the paged prefill step and the reference forward
-        are two writings of one model and must agree. Runs on a pool of its
-        own, so the live cache is untouched."""
-        import functools
-
-        import jax
-
-        if self._recurrent:
-            got, ref = _ling_steps.check_prefill(
-                self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
-        else:
-            from ray_tpu.models.llama import forward
-
-            got, _, _, _ = prefill_fresh_pool(
-                self.cfg, self.ecfg, self._prefill, self.params, prompt_ids)
-            ref = jax.jit(functools.partial(forward, self.cfg))(
-                self.params, np.asarray([list(prompt_ids)], np.int32))[0, -1]
-        got, ref = np.asarray(got), np.asarray(ref)
+        """Prefill's last-position logits against the family's reference
+        forward pass on the same prompt: the paged prefill step and the
+        forward pass are two writings of one model and must agree. Runs on
+        caches of its own, so the live ones are untouched."""
+        got, ref = map(np.asarray, self._steps.check_prefill(
+            self.cfg, self.ecfg, self._prefill, self.params, prompt_ids))
         return {
             "prompt_tokens": len(prompt_ids),
             "finite": bool(np.isfinite(got).all()),
@@ -1147,10 +1255,11 @@ class PagedEngine:
 
         With `mechanisms` (one such request at a time) also what the router
         and the recurrence computed from at every decode step, stacked over
-        the max_tokens - 1 steps under the keys of `_ling_steps.PROBE`, and
-        the slot's recurrent state as the prefill left it ("state0") and
-        after the last step ("state"), [kda_layers, H, dk, dv]: a reference
-        given the same inputs must arrive at the same scores and state.
+        the max_tokens - 1 steps under the keys of the step set's `PROBE`,
+        and what the slot carries beside its blocks (`SLOT_STATE`) as the
+        prefill left it ("state0") and after the last step ("state"),
+        [layers, ...]: a reference given the same inputs must arrive at the
+        same scores and state.
 
         A debug path beside `check_prefill`: a decode step computes these
         anyway and the loop fetches them only while such a request is in a
@@ -1185,20 +1294,20 @@ class PagedEngine:
 
         params = jax.tree.map(shape, self.params)
         cache = [shape(a) for a in self._cache()]
-        state = [shape(a) for a in self._host_state()]
+        host = [shape(a) for a in self._host_state()]
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
-        if self._recurrent:
-            state.append(i32)          # the probed slot
+        if self._steps.PROBE:
+            host.append(i32)          # the probed slot
         lead = (0,) if self._ladder else ()
         out = {"jit_paged_decode_step": [self._decode.lower(
-            *lead, params, *cache, *state).compile().as_text()]}
+            *lead, params, *cache, *host).compile().as_text()]}
         out["jit_paged_prefill"] = []
         for n in prefill_lengths:
             S = max(8, 1 << (n - 1).bit_length())
             args = [shape(self.tables[0]),
                     jax.ShapeDtypeStruct((S,), jnp.int32), i32]
-            if self._recurrent:
-                args.append(i32)       # the slot whose state is written
+            if not self._ladder:
+                args.append(i32)       # `_admit_whole`: the slot it writes
             out["jit_paged_prefill"].append(self._prefill.lower(
                 S, params, *cache, *args).compile().as_text())
         return out
@@ -1247,13 +1356,13 @@ class PagedEngine:
         }
         if self._decode_note:
             out["decode_attention_note"] = self._decode_note
-        if self._recurrent:
-            out.update(self._step_counters)
+        out.update(self._step_counters)
+        if not self._ladder:
+            # reported where a prompt, or its bucket's compile, is awaited
+            # inside a turn of the loop
             out.update(self._stalls)
-            out["state_bytes"] = int(self.state.nbytes + self.tails.nbytes)
-            # the latents a decode step's attention had to read, summed
-            out["latent_positions_live"] = (
-                self.attn_positions_live * self.cfg.mla_layers)
+        out.update(self._steps.extra_stats(
+            self.cfg, self._cache(), self.attn_positions_live))
         if ttfts:
             # time to first token is queue wait + prefill: an operator
             # needs the split to tell a backlog from a slow prefill

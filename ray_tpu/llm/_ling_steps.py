@@ -1,6 +1,7 @@
-"""The engine's jitted steps for the Ling family (`models/ling.py`): what
-`_engine._make_decode_step` / `_make_prefill` are to the Llama block, with
-three kinds of per-sequence state in place of one KV pool:
+"""The Ling family's step set (`models/ling.py`): the jitted steps, caches,
+counters and capabilities `PagedEngine` serves it by, under the names of
+`llm/_engine.STEP_SET` (that module's docstring is the interface). Three
+kinds of per-sequence state:
 
     latents  [mla_layers, NB, BS, 1, W]   paged under the engine's block
              table and allocator: the KV pool's layout with one "head" a
@@ -14,9 +15,11 @@ three kinds of per-sequence state in place of one KV pool:
     tails    [kda_layers, slots, K-1, 3*H*dk]: the short convolution's last
              inputs, kept with the state
 
-All three are donated to each step and returned by it. The steps keep the
-Llama steps' names (`paged_decode_step`, `paged_prefill`), so the device
-trace's `jit_paged_*` programs mean the same for every family.
+All three are donated to each step and returned by it. The steps are named
+`paged_decode_step` and `paged_prefill` as every family's are, so the device
+trace's `jit_paged_*` programs mean the same whatever is served. The decode
+step takes no chunk (a chunk would have to hand the KDA state across steps):
+prompts run whole through `make_prefill`, awaited in the engine's loop.
 
 The decode step's first result is one int32 vector, fetched once a step: the
 sampled tokens [B], then `COUNTERS` summed over the expert layers. Its last
@@ -45,6 +48,14 @@ CACHE_NAMES = ("latents", "state", "tails")
 # [moe_layers, n_experts] (its scores, float32) and the recurrence's inputs
 # "q", "k", "v", "g" [kda_layers, H, dk] and "beta" [kda_layers, H] (float32)
 PROBE = ("routing", "router_x", "router_s", "q", "k", "v", "g", "beta")
+# a slot's recurrent state is not in its blocks: a new request's prefill
+# starts it from zeros, and neither a shared block nor a transferred one can
+# resume a sequence
+SLOT_STATE = "state"
+NO_PREFIX_CACHE = (
+    "prefix_cache=True with recurrent layers: a shared block of latents "
+    "would need the recurrent state at its boundary, and nothing snapshots "
+    "that state")
 
 
 def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:
@@ -59,9 +70,27 @@ def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:
                   cfg.dtype))
 
 
+def chunk_ladder(ecfg) -> Tuple[int, ...]:
+    return ()
+
+
+def make_kv_inject(cfg: ling.LingConfig, ecfg):
+    raise ValueError(
+        "transferred KV cannot seed a model with recurrent layers: its "
+        "state is not in the blocks")
+
+
+def extra_stats(cfg: ling.LingConfig, cache, attn_positions_live: int):
+    _, state, tails = cache
+    return {"state_bytes": int(state.nbytes + tails.nbytes),
+            # the latents a decode step's attention had to read, summed
+            "latent_positions_live": attn_positions_live * cfg.mla_layers}
+
+
 def make_decode_step(cfg: ling.LingConfig, ecfg):
-    """The jitted whole-batch single-token step. Returns (step, path, note)
-    like `_engine._make_decode_step`: on a TPU the latent attention is
+    """The jitted whole-batch single-token step. Returns (step, path, note):
+    which latent attention it was built with and, where a TPU was refused
+    the kernel, why. On a TPU the latent attention is
     `ops/paged_attention`'s kernel over the block table, the latents as one
     KV head that is its own value (the kernel scales by its head width, so
     the query is pre-scaled to the model's 1/sqrt(nope + rope)); elsewhere

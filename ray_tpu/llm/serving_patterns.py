@@ -32,10 +32,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 import ray_tpu
-from ray_tpu.llm import EOS, ByteTokenizer, LLMConfig
-from ray_tpu.llm._engine import (
-    EngineConfig, _make_prefill, prefill_fresh_pool,
-)
+from ray_tpu.llm import EOS, ByteTokenizer, LLMConfig, step_set
+from ray_tpu.llm._engine import EngineConfig, prefill_fresh_pool
 from ray_tpu.tpu.accelerator import chip_options
 
 
@@ -49,7 +47,7 @@ class PrefillWorker:
         self.config = config
         self.ecfg = EngineConfig(**(engine_config or {}))
         self.cfg, self.params = config.build_model()
-        self._prefill = _make_prefill(self.cfg, self.ecfg)
+        self._prefill = step_set(self.cfg).make_prefill(self.cfg, self.ecfg)
         self._served = 0
 
     def prefill(self, prompt_ids: List[int]) -> Dict[str, Any]:

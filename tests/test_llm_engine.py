@@ -3,7 +3,16 @@ concurrent streaming completions with mid-decode admission, block reuse,
 parity with the dense decoder, and prompts admitted as chunks that ride in
 the decode steps."""
 
+import ast
 import asyncio
+import dataclasses
+import functools
+import importlib
+import inspect
+import re
+import textwrap
+import types
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +20,9 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from ray_tpu.llm import EOS, LLMConfig, engine_actor_class
+from ray_tpu.llm import (
+    EOS, MODEL_FAMILIES, LLMConfig, LLMEngine, _engine, step_set,
+)
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
 from ray_tpu.models.llama import LlamaConfig, init_params
 
@@ -285,7 +296,6 @@ def ray_init():
 def test_concurrent_streaming_mid_decode_admission(ray_init):
     """The VERDICT done-criterion: N concurrent streaming completions with
     at least one admitted mid-decode, tokens/s reported."""
-    LLMEngine = engine_actor_class()
     config = LLMConfig(model="tiny", model_overrides=dict(
         dtype=jnp.float32, param_dtype=jnp.float32))
     eng = LLMEngine.remote(config, EngineConfig(
@@ -379,3 +389,219 @@ def test_kv_aware_router_prefix_affinity():
     r.done(a2)
     r.done(b1)
     assert all(v == 0 for v in r.load)
+
+
+# ---------------------------------------------------------------------------
+# the step set: what a model family gives the engine (llm/_engine.py's
+# docstring), held by every registered family and by one defined here
+# ---------------------------------------------------------------------------
+
+
+def _own_public_names(steps):
+    """The names a step set defines itself: a module's imports and private
+    helpers are not its interface."""
+    home = getattr(steps, "__name__", None)
+    return {n for n, v in vars(steps).items()
+            if not n.startswith("_") and not inspect.ismodule(v)
+            and (home is None or getattr(v, "__module__", home) == home)}
+
+
+def _engine_code():
+    """The nodes of `PagedEngine`'s code, its docstrings aside."""
+    tree = ast.parse(textwrap.dedent(inspect.getsource(PagedEngine)))
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef)):
+            if ast.get_docstring(node) is not None:
+                node.body = node.body[1:]
+    return list(ast.walk(tree))
+
+
+@pytest.mark.parametrize("family", sorted(MODEL_FAMILIES))
+def test_every_family_keeps_the_step_set_and_the_engine_names_none(family):
+    module, config_cls, _, where = MODEL_FAMILIES[family]
+    cfg_cls = getattr(importlib.import_module(module), config_cls)
+    steps = step_set(cfg_cls.tiny())
+    assert _own_public_names(steps) == set(_engine.STEP_SET)
+    for name in ("CACHE_NAMES", "COUNTERS", "PROBE"):
+        assert all(isinstance(n, str) for n in getattr(steps, name))
+        assert isinstance(getattr(steps, name), tuple)
+    assert steps.SLOT_STATE is None or steps.SLOT_STATE in steps.CACHE_NAMES
+    assert isinstance(steps.NO_PREFIX_CACHE, (str, type(None)))
+    ladder = steps.chunk_ladder(EngineConfig())
+    assert isinstance(ladder, tuple) and list(ladder) == sorted(set(ladder))
+    for name in ("alloc_cache", "make_decode_step", "chunk_ladder",
+                 "make_prefill", "check_prefill", "make_kv_inject",
+                 "extra_stats"):
+        assert callable(getattr(steps, name))
+    # the engine's code: no family, no config class, no step set by name, no
+    # cache array as an attribute, no test of a config's class
+    family_words = {family, config_cls, where.rpartition(".")[2],
+                    where.rpartition(":")[2], "_recurrent"}
+    nodes = _engine_code()
+    names = {n.id for n in nodes if isinstance(n, ast.Name)} | {
+        n.attr for n in nodes if isinstance(n, ast.Attribute)} | {
+        a.name for n in nodes if isinstance(n, (ast.Import, ast.ImportFrom))
+        for a in n.names}
+    assert not {n for n in names for w in family_words
+                if re.search(rf"(^|_){re.escape(w)}(_|$)", n, re.I)}
+    assert not names & set(steps.CACHE_NAMES)
+    assert {n.args[1].id for n in nodes if isinstance(n, ast.Call)
+            and getattr(n.func, "id", "") == "isinstance"} <= {"Exception"}
+
+
+@dataclasses.dataclass(frozen=True)
+class ToyConfig:
+    """A third family, served by the engine as it stands: a bag of tokens.
+    A sequence's state is the sum of its tokens' embeddings (a per-slot
+    array beside the pool, whose blocks hold nothing); its next token's
+    logits are that sum through the head. Integer-valued float32 weights:
+    every sum is exact, so the engine's tokens equal the plain reference's
+    whatever the order of summation."""
+
+    vocab_size: int = 320
+    dim: int = 16
+    dtype: Any = jnp.float32
+
+    @classmethod
+    def tiny(cls, **overrides):
+        return cls(**overrides)
+
+
+def toy_params(cfg, key):
+    def draw(k, shape):
+        return jax.random.randint(k, shape, -4, 5).astype(cfg.dtype)
+
+    emb, head = jax.random.split(key)
+    return {"emb": draw(emb, (cfg.vocab_size, cfg.dim)),
+            "head": draw(head, (cfg.dim, cfg.vocab_size))}
+
+
+def _toy_decode_step(cfg, ecfg):
+    def paged_decode_step(params, bag, tables, lens, active, last_tok, keys,
+                          temps):
+        add = jnp.where(active[:, None], params["emb"][last_tok], 0)
+        bag = bag.at[0].add(add)
+        toks = _engine.sample_tokens(keys, bag[0] @ params["head"], temps)
+        rows = jnp.sum(active).astype(jnp.int32)[None]
+        return jnp.concatenate([toks, rows]), bag
+
+    return jax.jit(paged_decode_step, donate_argnums=(1,)), "none", None
+
+
+def _toy_prefill(cfg, ecfg):
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+    def paged_prefill(S, params, bag, table, prompt, plen, slot):
+        real = (jnp.arange(S) < plen)[:, None]
+        total = jnp.sum(jnp.where(real, params["emb"][prompt], 0), axis=0)
+        bag = bag.at[0, slot].set(total)
+        return (total @ params["head"], jnp.zeros((0, S, 1), jnp.int32), bag)
+
+    return paged_prefill
+
+
+def _toy_alloc(cfg, ecfg):
+    return (jnp.zeros((1, ecfg.max_num_seqs, cfg.dim), cfg.dtype),)
+
+
+def toy_reference(params, prompt, n):
+    emb, head = np.asarray(params["emb"]), np.asarray(params["head"])
+    bag, out = emb[prompt].sum(0), []
+    for _ in range(n):
+        out.append(int(np.argmax(bag @ head)))
+        bag = bag + emb[out[-1]]
+    return out
+
+
+def _toy_check_prefill(cfg, ecfg, prefill, params, prompt_ids):
+    S = max(8, 1 << (len(prompt_ids) - 1).bit_length())
+    prompt = np.zeros((S,), np.int32)
+    prompt[:len(prompt_ids)] = prompt_ids
+    got = prefill(S, params, *_toy_alloc(cfg, dataclasses.replace(
+        ecfg, max_num_seqs=1)), jnp.zeros((1,), jnp.int32),
+        jnp.asarray(prompt), jnp.int32(len(prompt_ids)), jnp.int32(0))[0]
+    return got, np.asarray(params["emb"])[prompt_ids].sum(0) @ np.asarray(
+        params["head"])
+
+
+def _toy_no_inject(cfg, ecfg):
+    raise ValueError("a bag of tokens is not in the blocks: none to transfer")
+
+
+TOY_STEPS = types.SimpleNamespace(
+    CACHE_NAMES=("bag",), alloc_cache=_toy_alloc,
+    make_decode_step=_toy_decode_step, chunk_ladder=lambda ecfg: (),
+    make_prefill=_toy_prefill, check_prefill=_toy_check_prefill,
+    COUNTERS=("toy_rows",), PROBE=(), SLOT_STATE="bag",
+    NO_PREFIX_CACHE="a bag of tokens is not in the blocks: none to share",
+    make_kv_inject=_toy_no_inject,
+    extra_stats=lambda cfg, cache, live: {"bag_bytes": cache[0].nbytes})
+TOY_ECFG = EngineConfig(max_num_seqs=2, kv_block_size=4, num_kv_blocks=16,
+                        max_model_len=32)
+
+
+@pytest.fixture
+def toy(monkeypatch):
+    """The toy family in the table, and nowhere else, for one test: its
+    config and weights come out of `LLMConfig.build_model` as any family's
+    do."""
+    monkeypatch.setitem(MODEL_FAMILIES, "toy", (
+        __name__, "ToyConfig", "toy_params", f"{__name__}:TOY_STEPS"))
+    return LLMConfig(model="toy:tiny").build_model()
+
+
+def _toy_serve(eng, prompts, n, **kw):
+    async def one(p):
+        return [t async for t in eng.generate_stream(p, max_tokens=n, **kw)]
+
+    async def main():
+        return await asyncio.gather(*[one(p) for p in prompts],
+                                    return_exceptions=True)
+
+    return asyncio.run(main())
+
+
+def test_a_third_family_is_served_by_the_engine_as_it_stands(toy):
+    cfg, params = toy
+    assert type(cfg) is ToyConfig and step_set(cfg) is TOY_STEPS
+    eng = PagedEngine(cfg, params, TOY_ECFG)
+    # five callers on two slots: every slot is handed on, and a new
+    # request's prefill overwrites what the last one left in it
+    prompts = [a_prompt(n, salt=7)[:n] for n in (3, 9, 1, 17, 6)]
+    prompts = [[t % cfg.vocab_size for t in p] for p in prompts]
+    assert _toy_serve(eng, prompts, 7) == [
+        toy_reference(params, p, 7) for p in prompts]
+    stats = eng.stats()
+    assert stats["free_blocks"] == 16 and stats["prefix_cache"] is None
+    assert stats["tokens_out"] == 35 and stats["prefill_chunks"] == 0
+    # its counter behind the tokens, its own entry, the whole-prompt loop's
+    assert 0 < stats["toy_rows"] <= 2 * stats["steps"]
+    assert stats["bag_bytes"] == eng.bag.nbytes == 2 * 16 * 4
+    assert stats["decode_attention"] == "none" and "loop_stalls" in stats
+    out = eng.check_prefill(prompts[3])
+    assert out["argmax_equal"] and out["max_abs_diff"] == 0.0
+    assert set(eng.step_hlo([5])) == {"jit_paged_decode_step",
+                                      "jit_paged_prefill"}
+    # a fault that took the donated cache: the next request starts clean
+    eng.bag.delete()
+    assert eng._device_state_invalid()
+    eng._reset_device_state()
+    assert not np.asarray(eng.bag).any()
+    assert _toy_serve(eng, prompts[:1], 4) == [
+        toy_reference(params, prompts[0], 4)]
+
+
+def test_a_step_set_refuses_in_its_own_words(toy):
+    cfg, params = toy
+    with pytest.raises(ValueError, match="none to share"):
+        PagedEngine(cfg, params, dataclasses.replace(
+            TOY_ECFG, prefix_cache=True))
+    eng = PagedEngine(cfg, params, TOY_ECFG)
+    kv = (np.zeros((1, 1, 4, cfg.dim), np.float32), np.zeros((320,)))
+    refused, = _toy_serve(eng, [[5, 6, 7]], 3, prefilled=kv)
+    assert isinstance(refused, ValueError)
+    assert "none to transfer" in str(refused)
+    # the request failed, not the engine
+    assert _toy_serve(eng, [[5, 6, 7]], 3) == [
+        toy_reference(params, [5, 6, 7], 3)]
+    assert eng.stats()["free_blocks"] == 16

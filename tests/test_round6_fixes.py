@@ -161,6 +161,42 @@ def test_recorded_death_drops_borrows():
     assert h.dropped, "authoritatively dead borrower was never reaped"
 
 
+def test_a_release_inside_the_counters_own_lock_is_handed_to_the_loop():
+    """ROADMAP C10: the cyclic GC can run an `ObjectRef.__del__` on a thread
+    that is inside one of `ReferenceCounter`'s own critical sections; with a
+    blocking acquire of the plain lock that thread never came back (tier-1
+    hung in `add_local` -> `__del__` -> `remove_local` now and then)."""
+    from ray_tpu._private.core_worker import ObjectRef, ReferenceCounter
+    from ray_tpu._private.ids import ObjectID
+
+    class _Worker:
+        later, freed = [], []
+
+        def schedule(self, coro):
+            self.later.append(coro)
+
+        def owns(self, ref):
+            return True
+
+        async def free_owned_object(self, oid):
+            self.freed.append(oid)
+
+    cw = _Worker()
+    refs = ReferenceCounter(cw)
+    ref = ObjectRef(ObjectID.from_random(), "owner:1", b"w", _register=False)
+    refs.add_local(ref)
+    refs.add_local(ref)
+    with refs._lock:                # as the GC finds it inside add_local
+        refs.remove_local(ref)      # must come back
+    assert refs.local_counts[ref.binary()] == 2 and len(cw.later) == 1
+    asyncio.run(cw.later.pop())     # the loop's turn
+    assert refs.local_counts[ref.binary()] == 1 and not cw.freed
+    refs.remove_local(ref)          # the lock is free: decremented in place
+    assert ref.binary() not in refs.local_counts
+    asyncio.run(cw.later.pop())
+    assert cw.freed == [ref.object_id()]
+
+
 def test_control_store_worker_liveness_records():
     from ray_tpu._private.control_store import ControlStore
     from ray_tpu._private import protocol as pb
