@@ -25,6 +25,13 @@ module is the scheduler, and serves any model family of
   ladder is empty each prompt runs whole through the step set's prefill,
   awaited in the loop. The ladder is the one thing the scheduler's path
   forks on.
+- **One step ahead**: a turn of the loop dispatches step n+1, then fetches
+  step n's tokens, emits them, sweeps and admits, so all of the host's turn
+  runs while the device computes. Step n+1 takes what step n sampled from
+  the device (`feed_back`); lengths, the keys' fold and a stop by
+  `max_tokens` or `max_model_len` the host applies at the dispatch; a stop
+  by the end token or an abort it sees a step late, and the row that step
+  computed for the ended sequence is dropped (`stats()["rows_dropped"]`).
 - **Streaming**: tokens flow to callers through per-request async queues;
   the engine runs as an async actor and `generate_stream` is an async
   generator riding the framework's streaming-generator plane.
@@ -44,14 +51,21 @@ make_decode_step(cfg, ecfg) -> (step, path, note). `path` names the
     why a TPU was refused the kernel, or is None. The jitted step:
         paged_decode_step([C,] params, *cache, tables [B, max_blocks],
             lens [B], active [B], last_tok [B], keys [B, 2] uint32,
-            temps [B] [, chunk_ids [C], chunk_at [3]] [, probe_slot])
-            -> (toks, *cache [, probe])
+            temps [B], prev [T], fed [B] [, chunk_ids [C], chunk_at [3]]
+            [, probe_slot]) -> (toks [T], *cache [, probe])
     The static chunk width C leads iff the ladder is not empty, and the
     chunk (`chunk_at`: slot, start position, real tokens) follows iff C > 0;
     `probe_slot` (a device scalar) and `probe` are there iff `PROBE` is not
-    empty. `toks` is one int32 vector, fetched once a step: a token a slot,
-    then `COUNTERS`, then for a chunk the token drawn from its last real row
-    and the slot's decode stream key (two int32).
+    empty. `toks` is one int32 vector of one length T whatever C, fetched
+    once a step: a token a slot, then `COUNTERS`, then iff the ladder is
+    not empty three more: for a chunk the token drawn from its last real
+    row and the slot's decode stream key (two int32), else zeros. `prev` is
+    the `toks` of the step dispatched before, still on the device, and
+    `fed` says where each slot's token comes from (`FED_HOST`: `last_tok`
+    and `keys` as uploaded; `FED_STEP`: `prev[slot]`; `FED_CHUNK`: the
+    first token and the key behind `prev`'s tokens): the step opens with
+    `feed_back`, which is what lets the loop dispatch it before the host
+    has seen `prev`.
 chunk_ladder(ecfg) -> the widths C > 0 the step takes, ascending; () for a
     step that takes no chunk.
 make_prefill(cfg, ecfg) -> the jitted whole-prompt `paged_prefill`: with an
@@ -106,8 +120,12 @@ STEP_SET = ("CACHE_NAMES", "alloc_cache", "make_decode_step", "chunk_ladder",
 # into the profiler's own trace (the device trace's clock) whenever a
 # profiler session is on; an inactive annotation is a flag check. Each is
 # opened and closed on one thread: sweep and emit on the event loop's,
-# the others on the `asyncio.to_thread` worker that runs `_try_admit` or the
-# decode step. The benchmark's readers find them by these names.
+# the others on the `asyncio.to_thread` worker that runs `_try_admit` or
+# `_run_step`. A turn is sweep, admit*, step (upload and dispatch of the
+# next step, then the wait for the one before: the device runs all through
+# the turn, so the phases' times overlap its, and only the wait's end is on
+# the critical path), emit. The benchmark's readers find them by these
+# names.
 PHASE_SWEEP = "engine:sweep"                    # drain _pending, abort sweep
 PHASE_ADMIT = "engine:admit"                    # one per _try_admit call
 PHASE_PREFIX_MATCH = "engine:prefix_match"      # chain_keys, match, eviction
@@ -115,12 +133,14 @@ PHASE_PREFIX_MATCH = "engine:prefix_match"      # chain_keys, match, eviction
 # loop), and P/D admission's first token:
 PHASE_PREFILL = "engine:prefill"                # the jitted whole-prompt call
 PHASE_SAMPLE_FIRST = "engine:sample_first"      # waits for the prefill
-# one run_step call (argument `chunk`: the width of the prompt chunk the step
-# carries, 0 for none), around the three below
+# one dispatching `_run_step` call (argument `chunk`: the width of the prompt
+# chunk the step carries, 0 for none), around the three below
 PHASE_STEP = "engine:step"
 PHASE_UPLOAD = "engine:upload"                  # the step's host arrays
 PHASE_DISPATCH = "engine:dispatch"              # the decode step's launch
-PHASE_DEVICE_WAIT = "engine:device_wait"        # np.asarray(toks)
+# np.asarray(toks) of the step dispatched a turn earlier: one a fetched
+# step, alone (outside a step) when the loop drains with nothing to dispatch
+PHASE_DEVICE_WAIT = "engine:device_wait"
 PHASE_EMIT = "engine:emit"                      # the per-slot walk
 PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
           PHASE_SAMPLE_FIRST, PHASE_STEP, PHASE_UPLOAD, PHASE_DISPATCH,
@@ -130,8 +150,9 @@ PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
 SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
 SPAN_PREFILL = "engine:prefill"  # admission start -> first token (its chunks)
 SPAN_DECODE = "engine:decode"    # first token -> done
-# a turn of the loop is admissions (bookkeeping, or without a chunk ladder a
-# whole prompt of tens of ms) and one decode step:
+# a turn of the loop, from one fetch of a step's tokens to the next, is
+# emit, sweep, admissions (bookkeeping, or without a chunk ladder a whole
+# prompt of tens of ms), a dispatch and the rest of a decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
 
@@ -173,6 +194,30 @@ def sample_tokens(keys, logits, temps):
         return jnp.where(t > 0, samp, greedy)
 
     return jax.vmap(sample_one)(keys, logits, temps)
+
+
+# where a slot's token comes from (a decode step's `fed`)
+FED_HOST, FED_STEP, FED_CHUNK = 0, 1, 2
+
+
+def feed_back(prev, fed, last_tok, keys, chunked: bool):
+    """Inside a decode step, first: every slot's token and key, each from
+    where `fed` [B] says. `FED_HOST`: as the host uploaded them (`last_tok`,
+    `keys`). `FED_STEP`: the token the step before drew for the slot,
+    `prev[slot]`, which the host has not seen. `FED_CHUNK` (only where the
+    step takes chunks, `chunked`): the slot's prompt ended in the step
+    before, whose last three values are the request's first token and its
+    decode stream key."""
+    import jax
+    import jax.numpy as jnp
+
+    tok = jnp.where(fed == FED_STEP, prev[: last_tok.shape[0]], last_tok)
+    if chunked:
+        ended = fed == FED_CHUNK
+        tok = jnp.where(ended, prev[-3], tok)
+        stream = jax.lax.bitcast_convert_type(prev[-2:], jnp.uint32)
+        keys = jnp.where(ended[:, None], stream[None], keys)
+    return tok, keys
 
 
 # ---------------------------------------------------------------------------
@@ -229,10 +274,12 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
     def paged_decode_step(C, params, kc, vc, tables, lens, active, last_tok,
-                          keys, temps, chunk_ids=None, chunk_at=None):
+                          keys, temps, prev, fed, chunk_ids=None,
+                          chunk_at=None):
         """kc/vc [L, NB, BS, KV, HD]; tables [B, max_blocks] int32;
-        lens/active/last_tok [B]; keys [B,2] uint32; temps [B].
-        Returns (next_tok [B], kc, vc).
+        lens/active/last_tok/fed [B]; keys [B,2] uint32; temps [B]; prev
+        [B + 3], the step before's result (`feed_back`).
+        Returns (next_tok [B] and three more, kc, vc).
 
         With a static chunk width C > 0 also chunk_ids [C], the next prompt
         tokens of the request in slot chunk_at[0] (an inactive row of the
@@ -245,9 +292,11 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         chunks or from position 0 alike. Behind the B tokens come the
         token drawn from the chunk's last real row with the slot's key
         (the request's first, where the chunk ends its prompt) and that
-        key folded with 7, the slot's decode stream, as two int32."""
+        key folded with 7, the slot's decode stream, as two int32; zeros
+        without a chunk."""
         dt = cfg.dtype
         B = last_tok.shape[0]
+        last_tok, keys = feed_back(prev, fed, last_tok, keys, chunked=True)
         R = B + C
         hd = cfg.head_dim
         ids = last_tok
@@ -322,6 +371,8 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
                 jax.random.wrap_key_data(keys[B]), 7))
             toks = jnp.concatenate(
                 [toks, jax.lax.bitcast_convert_type(stream, jnp.int32)])
+        else:
+            toks = jnp.concatenate([toks, jnp.zeros((3,), jnp.int32)])
         return toks, kc, vc
 
     return paged_decode_step, path, note
@@ -477,6 +528,10 @@ class _Request:
     queue: asyncio.Queue = None  # type: ignore[assignment]
     slot: int = -1
     produced: int = 0
+    # tokens of the steps dispatched for it, fetched or not: a stop the host
+    # can count is applied when this reaches it, `produced` follows at the
+    # fetches
+    scheduled: int = 0
     admitted_mid_decode: bool = False
     # consumer walked away (client disconnect / stream cancel): the engine
     # loop drops it from the waiting queue or releases its slot + blocks
@@ -518,6 +573,30 @@ def _request_key(req: _Request) -> Tuple[int, int]:
     return 0, (req.seed * 1000003 + req.rid) & 0xFFFFFFFF
 
 
+def _mechanisms(probe: Dict[str, Any]) -> Dict[str, Any]:
+    """Of a step's fetched probe, what it computed the probed slot's router
+    and recurrence from."""
+    return {k: v for k, v in probe.items() if k != "routing"}
+
+
+@dataclass
+class _Step:
+    """A dispatched decode step, kept until its tokens are fetched: what
+    the loop needs to hand them out as the slots stood at the dispatch,
+    whatever was released or admitted since."""
+    toks: Any                       # the step's int32 vector, on the device
+    probe: Any                      # its last result; None where none asked
+    # the slots that decoded in it, each with the request it held then
+    rows: List[Tuple[int, _Request]]
+    # (request, real tokens, width) of the prompt chunk it carried, and
+    # whether that chunk ended the prompt
+    chunk: Optional[tuple]
+    chunk_ends: bool
+    probe_slot: Optional[int]       # whose mechanisms `probe` holds
+    ahead: bool                     # dispatched with a step still unfetched
+    live: int                       # positions its decode rows attended
+
+
 class PagedEngine:
     """The continuous-batching scheduler around a family's jitted steps.
 
@@ -526,6 +605,10 @@ class PagedEngine:
     crosses in as arrays each step. Run it inside an async actor and call
     `generate_stream` concurrently — requests arriving mid-decode are
     admitted at the next step boundary.
+
+    The loop runs one step ahead of its results (`_run_loop`): the slot
+    arrays below describe the step to dispatch next, and the tokens of the
+    one before are still on the device when it goes.
 
     `cfg` is the config of a family in `ray_tpu.llm.MODEL_FAMILIES`; all
     the engine knows of the family is its step set (the module docstring)."""
@@ -548,7 +631,10 @@ class PagedEngine:
         self.tables = np.zeros((B, self.max_blocks), np.int32)
         self.lens = np.zeros((B,), np.int32)
         self.active = np.zeros((B,), bool)
+        # the token of a slot the host activated itself; a slot that
+        # decodes takes its token from the step before, on the device (`fed`)
         self.last_tok = np.zeros((B,), np.int32)
+        self.fed = np.full((B,), FED_HOST, np.int32)
         self.temps = np.zeros((B,), np.float32)
         self.slot_req: List[Optional[_Request]] = [None] * B
         from ray_tpu._private.config import GLOBAL_CONFIG
@@ -566,6 +652,10 @@ class PagedEngine:
 
             self._prefix_cache = PrefixCache(
                 self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
+        # the chunk widths the decode step takes. With a ladder a prompt is
+        # admitted in chunks that ride in the decode steps (`_admit_chunks`);
+        # with none whole, awaited in the loop (`_admit_whole`)
+        self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
         self._alloc_device_state()
         # "paged_kernel" | "xla", fixed for the engine's life (stats())
         self._decode, self.decode_attention, self._decode_note = (
@@ -574,10 +664,6 @@ class PagedEngine:
             logging.getLogger(__name__).warning(self._decode_note)
         # whole prompts: without a ladder in the loop; check_prefill
         self._prefill = steps.make_prefill(cfg, e)
-        # the chunk widths the decode step takes. With a ladder a prompt is
-        # admitted in chunks that ride in the decode steps (`_admit_chunks`);
-        # with none whole, awaited in the loop (`_admit_whole`)
-        self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
         # admitted requests whose prompts are not all in the pool yet, in
         # arrival order: the head's next chunk rides in the next step
         self._prefilling: "collections.deque[_Request]" = collections.deque()
@@ -588,6 +674,11 @@ class PagedEngine:
         self._rngs = np.zeros((B, 2), np.uint32)
         self.steps = 0
         self.tokens_out = 0
+        # steps dispatched while the one before was unfetched, and rows
+        # computed for a sequence that had ended (a token or an abort the
+        # loop saw a step late)
+        self.steps_ahead = 0
+        self.rows_dropped = 0
         self.mid_decode_admissions = 0
         # chunks run (one a step that carries any), the prompt tokens in
         # them and the rows of padding beside those
@@ -605,10 +696,10 @@ class PagedEngine:
         # the slot whose router and recurrence inputs the decode step hands
         # out (check_routing; one request at a time), and its device copy
         self._probe_slot = None
-        # turns of the loop (from one step's tokens to the next's: sweep,
-        # admissions, one decode step) that took over STALL_TURN_S: how
-        # many, their seconds, of those the seconds before the step, and
-        # when the last one ended (unix time)
+        # turns of the loop (from one fetch of a step's tokens to the
+        # next: emit, sweep, admissions, a dispatch, the wait) that took
+        # over STALL_TURN_S: how many, their seconds, of those the seconds
+        # before the dispatch, and when the last one ended (unix time)
         self._stalls = {"loop_stalls": 0, "loop_stall_s": 0.0,
                         "loop_stall_admit_s": 0.0, "loop_stall_last_at": 0.0}
         self._ttfts = collections.deque(maxlen=256)
@@ -623,10 +714,13 @@ class PagedEngine:
         for name, a in zip(self._cache_names, arrays):
             setattr(self, name, a)
 
-    def _host_state(self) -> tuple:
-        """The host arrays every step uploads, in the step's order."""
-        return (self.tables, self.lens, self.active, self.last_tok,
-                self._rngs, self.temps)
+    def _step_inputs(self, put, prev) -> list:
+        """What every step takes after the caches, in the step's order: the
+        slot arrays through `put`, and `prev` for the result of the step
+        before."""
+        slots = [put(a) for a in (self.tables, self.lens, self.active,
+                                  self.last_tok, self._rngs, self.temps)]
+        return [*slots, prev, put(self.fed)]
 
     def _device_state_invalid(self) -> bool:
         try:
@@ -641,6 +735,12 @@ class PagedEngine:
         import jax.numpy as jnp
 
         self._set_cache(self._steps.alloc_cache(self.cfg, self.ecfg))
+        # the last dispatched step's result and, until its tokens are
+        # fetched, its record
+        self._toks = jnp.zeros((
+            self.ecfg.max_num_seqs + len(self._steps.COUNTERS)
+            + (3 if self._ladder else 0),), jnp.int32)
+        self._flight: Optional[_Step] = None
         if self._steps.PROBE:
             self._probe_arg = jnp.int32(0)
         self.free_blocks = list(range(1, self.ecfg.num_kv_blocks + 1))
@@ -657,6 +757,7 @@ class PagedEngine:
         self.lens[:] = 0
         self.active[:] = False
         self.last_tok[:] = 0
+        self.fed[:] = FED_HOST
         self.temps[:] = 0.0
         self.slot_req = [None] * self.ecfg.max_num_seqs
         self._prefilling.clear()
@@ -806,18 +907,16 @@ class PagedEngine:
         n = min(len(req.prompt) - req.cursor, self._ladder[-1])
         return req, n, next(c for c in self._ladder if c >= n)
 
-    def _chunk_done(self, req: _Request, n: int, width: int, tail):
-        """A step that carried `n` tokens of `req`'s prompt has run: move
-        the cursor, offer the blocks it completed to the prefix cache (the
-        step that wrote them has run, so every later step sees them; a
-        request admitted while the prompt is still in chunks can hit only
-        these) and, if the prompt is through, start the slot's decoding
-        with the first token and the stream key the step returned."""
+    def _chunk_dispatched(self, req: _Request, n: int):
+        """A step that carries `n` tokens of `req`'s prompt is on its way:
+        move the cursor, offer the blocks it completes to the prefix cache
+        (every step dispatched from here on runs after the one that writes
+        them; a request admitted while the prompt is still in chunks can
+        hit only these) and, if the prompt is through, have the slot decode
+        from the next step on, with the first token and the stream key this
+        step leaves on the device (`FED_CHUNK`)."""
         slot = req.slot
         req.cursor += n
-        self.prefill_chunks += 1
-        self.prefill_chunk_tokens += n
-        self.prefill_chunk_pad_tokens += width - n
         if self._prefix_cache is not None:
             # every FULL prompt block in the pool (matched, then written by
             # the chunks so far) is cacheable; this request holds one ref on
@@ -829,8 +928,23 @@ class PagedEngine:
         if req.cursor < len(req.prompt):
             return
         self._prefilling.popleft()
-        self._rngs[slot] = np.asarray(tail[1:3], np.int32).view(np.uint32)
-        self._activate_slot(req, slot, int(tail[0]))
+        if req.admitted_mid_decode:
+            self.mid_decode_admissions += 1
+        self.lens[slot] = len(req.prompt)
+        self.active[slot] = True
+        self.fed[slot] = FED_CHUNK
+        self._publish_metrics()
+        self._row_dispatched(req)
+
+    def _row_dispatched(self, req: _Request):
+        """One more token of `req` is on its way. A stop the host can count
+        is applied here: the slot sits out the next step and its blocks are
+        free for the admission of this turn, while the request's last
+        tokens are still to be fetched."""
+        req.scheduled += 1
+        if (req.scheduled >= req.max_tokens or len(req.prompt)
+                + req.scheduled >= self.ecfg.max_model_len):
+            self._free_slot(req)
 
     def _emit(self, req: _Request, tok: int):
         req.produced += 1
@@ -839,18 +953,12 @@ class PagedEngine:
             req.t_first = time.monotonic()
             self._ttfts.append(req.t_first - req.t_start)
             self._queue_waits.append(req.t_admit - req.t_start)
-        done = (
-            (self.eos_id is not None and tok == self.eos_id)
-            or req.produced >= req.max_tokens
-            or len(req.prompt) + req.produced >= self.ecfg.max_model_len
-        )
-        if self.eos_id is not None and tok == self.eos_id:
-            req.queue.put_nowait(None)
-        else:
+        eos = self.eos_id is not None and tok == self.eos_id
+        if not eos:
             req.queue.put_nowait(tok)
-            if done:
-                req.queue.put_nowait(None)
-        if done and req.slot >= 0:
+        if (eos or req.produced >= req.max_tokens or len(req.prompt)
+                + req.produced >= self.ecfg.max_model_len):
+            req.queue.put_nowait(None)
             self._release(req)
 
     def _probe_admitted(self, req: _Request, slot: int):
@@ -870,6 +978,26 @@ class PagedEngine:
         return np.asarray(getattr(self, self._steps.SLOT_STATE)[:, slot])
 
     def _release(self, req: _Request):
+        """The request is over (its last token emitted, aborted or failed):
+        its slot and blocks return, unless a counted stop returned them at
+        the dispatch of its last step. A row that a step in flight computes
+        for it is dropped at the fetch."""
+        ahead = self._flight
+        if (ahead is not None and ahead.probe_slot is not None
+                and any(s == ahead.probe_slot and r is req
+                        for s, r in ahead.rows)
+                and not self._device_state_invalid()):
+            # a checked sequence ended by a token the loop saw a step late:
+            # the row in flight moved the slot's state once more, and what
+            # it computed from belongs to the record the state is held to
+            import jax
+
+            req.probe["steps"].append(_mechanisms(jax.device_get(ahead.probe)))
+        if req.slot >= 0:
+            self._free_slot(req)
+        self._finish(req)
+
+    def _free_slot(self, req: _Request):
         slot = req.slot
         if slot == self._probe_slot:
             self._probe_slot = None
@@ -886,9 +1014,9 @@ class PagedEngine:
             self.free_blocks.append(b)
         self.tables[slot] = 0
         self.active[slot] = False
+        self.fed[slot] = FED_HOST
         self.slot_req[slot] = None
         req.slot = -1
-        self._finish(req)
         self._publish_metrics()
 
     def _finish(self, req: _Request):
@@ -933,14 +1061,18 @@ class PagedEngine:
         return tok
 
     def _activate_slot(self, req: _Request, slot: int, tok: int):
-        """Final admission bookkeeping shared by both admission paths."""
+        """Final bookkeeping of the admissions that hand the host the first
+        token (a whole prompt, transferred blocks): the slot decodes from
+        the next step on, from the host's token."""
         self.slot_req[slot] = req
         if req.admitted_mid_decode:
             self.mid_decode_admissions += 1
         req.slot = slot
+        req.scheduled = 1
         self.lens[slot] = len(req.prompt)
         self.active[slot] = True
         self.last_tok[slot] = tok
+        self.fed[slot] = FED_HOST
         self.temps[slot] = req.temperature
         self._publish_metrics()
         self._emit(req, tok)
@@ -1007,12 +1139,20 @@ class PagedEngine:
                 self._run_loop())
 
     async def _run_loop(self):
+        """One step ahead of its results. A turn dispatches step n+1 and
+        only then fetches step n's tokens (the wait ends when step n ends
+        on the device, with step n+1 queued behind it), emits them, sweeps
+        and admits: all of the host's turn runs while the device computes.
+        Step n+1 takes what step n sampled from the device (`feed_back`);
+        what the host can count without the tokens (lengths, the keys'
+        fold, a stop by `max_tokens` or `max_model_len`) it applies at the
+        dispatch; a stop by a token or an abort it sees a step late, and
+        drops the row that step computed (`rows_dropped`)."""
         import jax
-        import jax.numpy as jnp
 
         phase = jax.profiler.TraceAnnotation
         waiting: "collections.deque[_Request]" = collections.deque()
-        t_turn = None      # when the last turn ended; None after an idle wait
+        t_turn = None      # the last fetch's end; None after an idle wait
         while True:
             if t_turn is None:
                 t_turn = time.monotonic()
@@ -1032,7 +1172,8 @@ class PagedEngine:
             # landing here while slots decode are the "admitted mid-decode"
             # continuous-batching case. With a chunk ladder an admission is
             # bookkeeping and the prompt rides in the steps below; without
-            # one the prompt's whole prefill is awaited here
+            # one the prompt's whole prefill is awaited here, dispatched
+            # behind the step in flight
             while waiting:
                 req = waiting[0]
                 if req.aborted:
@@ -1057,116 +1198,174 @@ class PagedEngine:
                     if self._device_state_invalid():
                         # prefill donates the caches: a failure after donation
                         # destroyed every in-flight sequence's cache
-                        for r in list(self.slot_req):
-                            if r is not None:
-                                self._fail(r, e)
-                        self._reset_device_state()
+                        self._fail_in_flight(e)
                     continue
                 if not ok:
                     break  # head waits for blocks/slots to free
                 waiting.popleft()
             chunk = self._next_chunk() if self._ladder else None
-            if chunk is None and not self.active.any():
-                # idle: block until a request arrives
+            flight = self._flight
+            dispatch = chunk is not None or bool(self.active.any())
+            if not dispatch and flight is None:
+                # idle, nothing in flight: block until a request arrives
                 waiting.append(await self._pending.get())
                 t_turn = None
                 continue
             t_step = time.monotonic()
-            # one step: a token for every active slot and, riding along, the
-            # next chunk of the oldest admitted prompt (alone, with every
-            # slot inactive, when nothing decodes yet)
-            step = self.steps
-            width, chunk_args = 0, ()
-            if chunk is not None:
-                admitting, n, width = chunk
-                at = admitting.cursor
-                ids = np.zeros((width,), np.int32)
-                ids[:n] = admitting.prompt[at:at + n]
-                chunk_args = (ids, np.asarray(
-                    [admitting.slot, at, n], np.int32))
-                # the slot's row of keys and temperatures is idle until it
-                # decodes: the step draws the request's first token with them
-                self._rngs[admitting.slot] = _request_key(admitting)
-                self.temps[admitting.slot] = admitting.temperature
-            # the step's static chunk width, where the step takes one
-            lead = (width,) if self._ladder else ()
-            probing = any(r is not None and r.probe is not None
-                          for r in self.slot_req)
-
-            def run_step():
-                # the outer annotation names a device gap that straddles
-                # two of the inner ones (else a Python frame and its line)
-                with phase(PHASE_STEP, chunk=width):
-                    with phase(PHASE_UPLOAD):
-                        host = [jnp.asarray(a) for a in (
-                            *self._host_state(), *chunk_args)]
-                    if self._steps.PROBE:
-                        host.append(self._probe_arg)
-                    with phase(PHASE_DISPATCH):
-                        toks, *rest = self._decode(
-                            *lead, self.params, *self._cache(), *host)
-                        n = len(self._cache_names)
-                        self._set_cache(rest[:n])
-                    with phase(PHASE_DEVICE_WAIT):
-                        # past the caches: what a check reads, fetched only
-                        # while a request asks
-                        return np.asarray(toks), (
-                            jax.device_get(rest[n]) if probing else None)
-
             try:
-                toks, probe = await asyncio.to_thread(run_step)
+                # one hop to a thread: the next step out (a token for every
+                # active slot and, riding along, the next chunk of the
+                # oldest admitted prompt), then the wait for the one before
+                fetched = await asyncio.to_thread(
+                    self._run_step, dispatch, chunk, flight)
             except Exception as e:  # noqa: BLE001 — decode step failed
-                # the device state is suspect: fail every in-flight and
-                # queued request (callers must never hang on a dead loop)
-                for slot, req in enumerate(list(self.slot_req)):
-                    if req is not None:
-                        req.queue.put_nowait(e)
-                        self._release(req)
+                # the device state is suspect, and so are both dispatched
+                # steps: fail every in-flight and queued request (callers
+                # must never hang on a dead loop)
+                self._fail_in_flight(e, flight)
                 while waiting:
                     self._fail(waiting.popleft(), e)
                 while not self._pending.empty():
                     self._fail(self._pending.get_nowait(), e)
-                if self._device_state_invalid():
-                    # rebuild the donated pool so _ensure_loop's restart on
-                    # the next generate_stream starts from a clean engine
-                    self._reset_device_state()
                 raise
-            with phase(PHASE_EMIT):
-                self.steps = step + 1
-                self.attn_positions_live += int(
-                    self.lens[self.active].sum() + self.active.sum())
-                self.attn_positions_dense += (
-                    self.ecfg.max_num_seqs * self.ecfg.max_model_len)
-                self._rngs[:, 1] += 1  # fresh fold per step
-                # behind the tokens: the step's counters, then the chunk's
-                B = len(self.slot_req)
-                tail = toks[B:]
-                for name, n in zip(self._step_counters, tail):
-                    self._step_counters[name] += int(n)
-                for slot, req in enumerate(list(self.slot_req)):
-                    if req is None or not self.active[slot]:
-                        continue
-                    if req.probe is not None:
-                        req.probe["routing"].append(
-                            probe["routing"][:, slot:slot + 1])
-                        if slot == self._probe_slot:
-                            req.probe["steps"].append(
-                                {k: v for k, v in probe.items()
-                                 if k != "routing"})
-                    self.lens[slot] += 1
-                    tok = int(toks[slot])
-                    self.last_tok[slot] = tok
-                    self._emit(req, tok)
-                if chunk is not None:
-                    self._chunk_done(*chunk, tail[len(self._step_counters):])
-            now = time.monotonic()
-            if now - t_turn > STALL_TURN_S:
-                self._stalls["loop_stalls"] += 1
-                self._stalls["loop_stall_s"] += now - t_turn
-                self._stalls["loop_stall_admit_s"] += t_step - t_turn
-                self._stalls["loop_stall_last_at"] = time.time()
-            t_turn = now
+            if flight is not None:
+                with phase(PHASE_EMIT):
+                    self._emit_step(flight, *fetched)
+                now = time.monotonic()
+                if now - t_turn > STALL_TURN_S:
+                    self._stalls["loop_stalls"] += 1
+                    self._stalls["loop_stall_s"] += now - t_turn
+                    self._stalls["loop_stall_admit_s"] += t_step - t_turn
+                    self._stalls["loop_stall_last_at"] = time.time()
+                t_turn = now
             await asyncio.sleep(0)  # let admissions interleave
+
+    def _run_step(self, dispatch: bool, chunk, flight: Optional[_Step]):
+        """On the loop's thread hop: dispatch the next step (`dispatch`;
+        `chunk` is `_next_chunk`'s) and apply what the host knows without
+        its tokens, then fetch the tokens of `flight`, the step dispatched
+        a turn earlier: (tokens, probe or None), or None without one."""
+        import jax
+        import jax.numpy as jnp
+
+        phase = jax.profiler.TraceAnnotation
+        self._flight = None
+        if not dispatch:
+            return self._fetch(flight)
+        width, chunk_args, ends = 0, (), False
+        if chunk is not None:
+            admitting, n, width = chunk
+            at = admitting.cursor
+            ends = at + n == len(admitting.prompt)
+            ids = np.zeros((width,), np.int32)
+            ids[:n] = admitting.prompt[at:at + n]
+            chunk_args = (ids, np.asarray([admitting.slot, at, n], np.int32))
+            # the slot's row of keys and temperatures is idle until it
+            # decodes: the step draws the request's first token with them
+            self._rngs[admitting.slot] = _request_key(admitting)
+            self.temps[admitting.slot] = admitting.temperature
+        # the step's static chunk width, where the step takes one
+        lead = (width,) if self._ladder else ()
+        rows = [(int(slot), self.slot_req[slot])
+                for slot in np.flatnonzero(self.active)]
+        probing = any(req.probe is not None for _, req in rows)
+        # the outer annotation names a device gap that straddles two of the
+        # inner ones (else a Python frame and its line)
+        with phase(PHASE_STEP, chunk=width):
+            with phase(PHASE_UPLOAD):
+                # copies: the slot arrays move on below, while the transfer
+                # (on the CPU the step itself) may still read what it is given
+                host = self._step_inputs(
+                    lambda a: jnp.asarray(a.copy()), self._toks)
+                host.extend(jnp.asarray(a) for a in chunk_args)
+            if self._steps.PROBE:
+                host.append(self._probe_arg)
+            with phase(PHASE_DISPATCH):
+                toks, *rest = self._decode(
+                    *lead, self.params, *self._cache(), *host)
+                n_cache = len(self._cache_names)
+                self._set_cache(rest[:n_cache])
+            # past the caches: what a check reads, kept only while a request
+            # asks
+            self._toks = toks
+            self._flight = _Step(
+                toks, rest[n_cache] if probing else None, rows, chunk, ends,
+                self._probe_slot, flight is not None,
+                int(self.lens[self.active].sum() + self.active.sum()))
+            # what the host knows at dispatch it applies at dispatch
+            self._rngs[:, 1] += 1  # fresh fold per step
+            self.lens[self.active] += 1
+            self.fed[self.active] = FED_STEP
+            for _, req in rows:
+                self._row_dispatched(req)
+            if chunk is not None:
+                self._chunk_dispatched(admitting, n)
+            return self._fetch(flight)
+
+    def _fetch(self, step: Optional[_Step]):
+        import jax
+
+        if step is None:
+            return None
+        with jax.profiler.TraceAnnotation(PHASE_DEVICE_WAIT):
+            return np.asarray(step.toks), (
+                None if step.probe is None else jax.device_get(step.probe))
+
+    def _emit_step(self, step: _Step, toks, probe):
+        """Hand out a fetched step's tokens as the slots stood when it was
+        dispatched: a row whose request has ended since (by the token of
+        the step before, or an abort) is dropped, whoever holds the slot
+        now."""
+        self.steps += 1
+        self.steps_ahead += step.ahead
+        self.attn_positions_live += step.live
+        self.attn_positions_dense += (
+            self.ecfg.max_num_seqs * self.ecfg.max_model_len)
+        # behind the tokens: the step's counters, then the chunk's
+        tail = toks[len(self.slot_req):]
+        for name, n in zip(self._step_counters, tail):
+            self._step_counters[name] += int(n)
+        for slot, req in step.rows:
+            if req.t_done:
+                self.rows_dropped += 1
+                continue
+            if req.probe is not None:
+                req.probe["routing"].append(
+                    probe["routing"][:, slot:slot + 1])
+                if slot == step.probe_slot:
+                    req.probe["steps"].append(_mechanisms(probe))
+            self._emit(req, int(toks[slot]))
+        if step.chunk is None:
+            return
+        req, n, width = step.chunk
+        self.prefill_chunks += 1
+        self.prefill_chunk_tokens += n
+        self.prefill_chunk_pad_tokens += width - n
+        if step.chunk_ends and not req.t_done:
+            first, *stream = tail[len(self._step_counters):]
+            if req.slot >= 0:
+                # the slot decodes on: the key of its next row is the
+                # stream's, counted up once a row dispatched since
+                self._rngs[req.slot] = np.asarray(
+                    stream, np.int32).view(np.uint32)
+                self._rngs[req.slot, 1] += np.uint32(req.scheduled - 1)
+            self._emit(req, int(first))
+
+    def _fail_in_flight(self, error: Exception, fetching=None):
+        """A failed step or admission took the device state with it: every
+        request in a slot or in a dispatched step gets the error, and the
+        donated pool is rebuilt so that the next request starts from a
+        clean engine."""
+        steps = [s for s in (fetching, self._flight) if s is not None]
+        self._flight = None
+        held = [r for s in steps for _, r in s.rows]
+        held += [s.chunk[0] for s in steps if s.chunk is not None]
+        for req in held + list(self.slot_req):
+            if req is not None and not req.t_done:
+                req.queue.put_nowait(error)
+                self._release(req)
+        if self._device_state_invalid():
+            self._reset_device_state()
 
     # -- public API -----------------------------------------------------
 
@@ -1181,7 +1380,7 @@ class PagedEngine:
 
         if not self._ladder:
             return
-        host = [jnp.asarray(a) for a in self._host_state()]
+        host = self._step_inputs(jnp.asarray, self._toks)
         for width in (0, *self._ladder):
             chunk = (jnp.zeros((width,), jnp.int32),
                      jnp.zeros((3,), jnp.int32)) if width else ()
@@ -1294,7 +1493,7 @@ class PagedEngine:
 
         params = jax.tree.map(shape, self.params)
         cache = [shape(a) for a in self._cache()]
-        host = [shape(a) for a in self._host_state()]
+        host = self._step_inputs(shape, shape(self._toks))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         if self._steps.PROBE:
             host.append(i32)          # the probed slot
@@ -1344,6 +1543,11 @@ class PagedEngine:
                               - len(self.free_blocks) - evictable),
             "active_slots": int(self.active.sum()),
             "mid_decode_admissions": self.mid_decode_admissions,
+            # of `steps`, those dispatched before the step ahead of them was
+            # fetched (all but an idle engine's first), and the rows they
+            # computed for sequences that had ended a step earlier
+            "steps_ahead": self.steps_ahead,
+            "rows_dropped": self.rows_dropped,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "prefill_chunk_pad_tokens": self.prefill_chunk_pad_tokens,

@@ -100,7 +100,7 @@ def make_decode_step(cfg: ling.LingConfig, ecfg):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.llm._engine import sample_tokens
+    from ray_tpu.llm._engine import feed_back, sample_tokens
     from ray_tpu.ops import paged_attention
 
     bs = ecfg.kv_block_size
@@ -125,9 +125,10 @@ def make_decode_step(cfg: ling.LingConfig, ecfg):
         return attend
 
     def paged_decode_step(params, latents, state, tails, tables, lens, active,
-                          last_tok, keys, temps, probe_slot):
+                          last_tok, keys, temps, prev, fed, probe_slot):
         dt = cfg.dtype
         B = last_tok.shape[0]
+        last_tok, keys = feed_back(prev, fed, last_tok, keys, chunked=False)
         h = params["tok_emb"].astype(dt)[last_tok]               # [B, D]
         blk = jnp.clip(lens // bs, 0, max_blocks - 1)
         # inactive slots write into the reserved trash block 0

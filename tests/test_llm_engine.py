@@ -167,6 +167,28 @@ def greedy_reference(params, prompts, n):
     return generate(CFG, params, prompts, max_new_tokens=n, temperature=0.0)
 
 
+def seeded_reference(params, prompt, rid, n):
+    """The `n` tokens request `rid` draws at TEMPERATURE with SEED, each from
+    the dense forward pass's logits over the prompt and the draws before
+    it."""
+    from ray_tpu.models.llama import forward
+
+    fwd = jax.jit(lambda p, x: forward(CFG, p, x))
+    key = jax.random.PRNGKey(SEED * 1000003 + rid)
+    stream = np.asarray(jax.random.key_data(jax.random.fold_in(key, 7)))
+    toks = []
+    for j in range(n):
+        if j:
+            key = jax.random.wrap_key_data(
+                stream + np.asarray([0, j - 1], np.uint32))
+        seq = np.zeros((1, 64 + ANSWER), np.int32)   # one compiled shape
+        seq[0, :len(prompt) + j] = prompt + toks
+        logits = fwd(params, seq)[0, len(prompt) - 1 + j]
+        toks.append(int(jax.random.categorical(
+            key, logits.astype(jnp.float32) / TEMPERATURE)))
+    return toks
+
+
 @pytest.mark.parametrize("mode", ["idle", "busy"])
 @pytest.mark.parametrize("plen", LENGTHS)
 def test_chunked_admission_equals_generate_greedy(served, chunk_params, mode,
@@ -184,22 +206,10 @@ def test_chunked_admission_draws_the_seeded_tokens(served, chunk_params, mode,
     """Temperature > 0: token j is the draw from the dense forward pass's
     logits with the request's own keys: `PRNGKey(seed * 1000003 + rid)` for
     the first, then that key folded with 7 and counted up a step."""
-    from ray_tpu.models.llama import forward
-
     rid, toks = served[0][mode, plen, TEMPERATURE]
     assert len(toks) == min(ANSWER, 64 - plen)
-    seq = np.zeros((1, 64 + ANSWER), np.int32)       # one compiled shape
-    seq[0, :plen + len(toks)] = a_prompt(plen) + toks
-    logits = jax.jit(lambda p, x: forward(CFG, p, x))(chunk_params, seq)[0]
-    key = jax.random.PRNGKey(SEED * 1000003 + rid)
-    stream = np.asarray(jax.random.key_data(jax.random.fold_in(key, 7)))
-    for j, tok in enumerate(toks):
-        if j:
-            key = jax.random.wrap_key_data(
-                stream + np.asarray([0, j - 1], np.uint32))
-        want = jax.random.categorical(
-            key, logits[plen - 1 + j].astype(jnp.float32) / TEMPERATURE)
-        assert int(want) == tok, (j, toks)
+    assert toks == seeded_reference(chunk_params, a_prompt(plen), rid,
+                                    len(toks))
 
 
 def test_slots_decoding_beside_the_admissions_are_undisturbed(served,
@@ -479,7 +489,9 @@ def toy_params(cfg, key):
 
 def _toy_decode_step(cfg, ecfg):
     def paged_decode_step(params, bag, tables, lens, active, last_tok, keys,
-                          temps):
+                          temps, prev, fed):
+        last_tok, keys = _engine.feed_back(prev, fed, last_tok, keys,
+                                           chunked=False)
         add = jnp.where(active[:, None], params["emb"][last_tok], 0)
         bag = bag.at[0].add(add)
         toks = _engine.sample_tokens(keys, bag[0] @ params["head"], temps)
@@ -550,12 +562,17 @@ def toy(monkeypatch):
     return LLMConfig(model="toy:tiny").build_model()
 
 
-def _toy_serve(eng, prompts, n, **kw):
-    async def one(p):
+def serve_all(eng, prompts, n, **kw):
+    """Every prompt in flight at once (arrival order = list order), each for
+    `n` tokens (one number, or one a prompt): the streams, or the exception
+    a stream ended with."""
+    async def one(p, n):
         return [t async for t in eng.generate_stream(p, max_tokens=n, **kw)]
 
     async def main():
-        return await asyncio.gather(*[one(p) for p in prompts],
+        eng._pending = eng._loop_task = None   # a loop task an event loop
+        ns = n if isinstance(n, list) else [n] * len(prompts)
+        return await asyncio.gather(*map(one, prompts, ns),
                                     return_exceptions=True)
 
     return asyncio.run(main())
@@ -569,7 +586,7 @@ def test_a_third_family_is_served_by_the_engine_as_it_stands(toy):
     # request's prefill overwrites what the last one left in it
     prompts = [a_prompt(n, salt=7)[:n] for n in (3, 9, 1, 17, 6)]
     prompts = [[t % cfg.vocab_size for t in p] for p in prompts]
-    assert _toy_serve(eng, prompts, 7) == [
+    assert serve_all(eng, prompts, 7) == [
         toy_reference(params, p, 7) for p in prompts]
     stats = eng.stats()
     assert stats["free_blocks"] == 16 and stats["prefix_cache"] is None
@@ -587,7 +604,7 @@ def test_a_third_family_is_served_by_the_engine_as_it_stands(toy):
     assert eng._device_state_invalid()
     eng._reset_device_state()
     assert not np.asarray(eng.bag).any()
-    assert _toy_serve(eng, prompts[:1], 4) == [
+    assert serve_all(eng, prompts[:1], 4) == [
         toy_reference(params, prompts[0], 4)]
 
 
@@ -598,10 +615,297 @@ def test_a_step_set_refuses_in_its_own_words(toy):
             TOY_ECFG, prefix_cache=True))
     eng = PagedEngine(cfg, params, TOY_ECFG)
     kv = (np.zeros((1, 1, 4, cfg.dim), np.float32), np.zeros((320,)))
-    refused, = _toy_serve(eng, [[5, 6, 7]], 3, prefilled=kv)
+    refused, = serve_all(eng, [[5, 6, 7]], 3, prefilled=kv)
     assert isinstance(refused, ValueError)
     assert "none to transfer" in str(refused)
     # the request failed, not the engine
-    assert _toy_serve(eng, [[5, 6, 7]], 3) == [
+    assert serve_all(eng, [[5, 6, 7]], 3) == [
         toy_reference(params, [5, 6, 7], 3)]
     assert eng.stats()["free_blocks"] == 16
+
+
+# ---------------------------------------------------------------------------
+# the loop runs one step ahead of its results (`PagedEngine._run_loop`): step
+# n+1 is dispatched before step n's tokens are fetched and takes them from
+# the device; an end token or an abort is seen a step late
+# ---------------------------------------------------------------------------
+
+LING_ECFG = EngineConfig(max_num_seqs=1, kv_block_size=16, num_kv_blocks=8,
+                         max_model_len=64)
+
+
+@pytest.fixture(scope="module")
+def ling_model():
+    from ray_tpu.models import ling
+
+    cfg = ling.LingConfig.tiny()
+    return cfg, ling.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(params=["llama", "ling", "toy"])
+def one_slot(request, monkeypatch, chunk_params, ling_model):
+    """A family's engine with ONE slot, so that the next request takes the
+    slot the last one left: `make(eos_id)` builds it, `first` is 1 where the
+    request's first token takes a decode step (its prompt's chunk) and 0
+    where the awaited prefill hands it out."""
+    if request.param == "llama":
+        model = (CFG, chunk_params)
+        ecfg = dataclasses.replace(CHUNK_ECFG, max_num_seqs=1)
+    elif request.param == "ling":
+        model, ecfg = ling_model, LING_ECFG
+    else:
+        monkeypatch.setitem(MODEL_FAMILIES, "toy", (
+            __name__, "ToyConfig", "toy_params", f"{__name__}:TOY_STEPS"))
+        model = LLMConfig(model="toy:tiny").build_model()
+        ecfg = dataclasses.replace(TOY_ECFG, max_num_seqs=1)
+
+    def make(eos_id=None):
+        return PagedEngine(*model, ecfg, eos_id=eos_id)
+
+    return types.SimpleNamespace(
+        make=make, first=int(bool(step_set(model[0]).chunk_ladder(ecfg))),
+        prompts=[[t % 300 for t in a_prompt(n, salt=3)] for n in (5, 9, 3)])
+
+
+def until(stream, end):
+    return stream[:stream.index(end)] if end in stream else stream
+
+
+def test_a_sampled_end_token_ends_the_stream_and_frees_the_slot_a_step_late(
+        one_slot):
+    a, b, _ = one_slot.prompts
+    plain_a, plain_b = serve_all(one_slot.make(), [a, b], 8)
+    # an end token that request `a` first samples as its k-th + 1
+    k = next(i for i in range(2, 8) if plain_a[i] not in plain_a[:i])
+    end = plain_a[k]
+    eng = one_slot.make(eos_id=end)
+    got_a, got_b = serve_all(eng, [a, b], 8)
+    # exactly the tokens before it; the request behind it, admitted into the
+    # slot it left, gets none of its tokens
+    assert got_a == plain_a[:k] and got_b == until(plain_b, end)
+    stats = eng.stats()
+    # the step dispatched before the end token was fetched carried one more
+    # row of the ended sequence: dropped, and the slot returned a step late
+    ended_b = len(got_b) < len(plain_b)
+    assert stats["rows_dropped"] == 1 + ended_b
+    first = one_slot.first
+    assert stats["steps"] == (first + k) + 1 + (
+        first + len(got_b) + ended_b - 1) + ended_b
+    assert stats["tokens_out"] == k + 1 + len(got_b) + ended_b
+    assert stats["free_blocks"] == eng.ecfg.num_kv_blocks
+    assert not eng.active.any() and eng._flight is None
+
+
+def test_a_checked_request_that_samples_the_end_token_keeps_state_and_steps(
+        ling_model):
+    """`check_routing(mechanisms=True)` of a sequence that ends by a token:
+    the row dispatched before the token was seen moved the slot's state once
+    more, so the steps handed out include it and the reference's scan over
+    them arrives at the state read."""
+    from benchmark.lib import reference_ling as ref
+    from benchmark.runners._inside_ling import ProgramWeightsLing
+
+    p = [t % 300 for t in a_prompt(9, salt=3)]
+    plain, = serve_all(PagedEngine(*ling_model, LING_ECFG), [p], 12)
+    k = next(i for i in range(3, 12) if plain[i] not in plain[:i])
+    eng = PagedEngine(*ling_model, LING_ECFG, eos_id=plain[k])
+    out = asyncio.run(eng.check_routing(p, 12, mechanisms=True))
+    assert out["token_ids"] == plain[:k]
+    got = ref.mechanism_readings(
+        out, ProgramWeightsLing(ling_model[1]).routers())
+    # k decode steps drew tokens 2..k+1 (the prefill drew the first), the
+    # last of them the end token, and one more ran before it was seen
+    assert got["state_steps"] == k + 1 and eng._probe_slot is None
+    assert got["state_error"] < 1e-5 and got["router_f32_steps"] < 32.0
+
+
+def test_a_stop_the_host_can_count_drops_no_row_and_frees_the_slot_at_once(
+        one_slot):
+    """`max_tokens` (and `max_model_len`) end a sequence at the dispatch of
+    its last step: the request behind it is admitted in the very next turn,
+    as when the loop waited for every step's tokens."""
+    a, b, c = one_slot.prompts
+    eng = one_slot.make()
+    outs = serve_all(eng, [a, b, c], 4)
+    assert outs == [serve_all(one_slot.make(), [p], 4)[0] for p in (a, b, c)]
+    stats = eng.stats()
+    assert stats["rows_dropped"] == 0
+    assert stats["steps"] == 3 * (one_slot.first + 4 - 1)
+    # all but the first were dispatched with the step before unfetched
+    assert stats["steps_ahead"] == stats["steps"] - 1
+    # the prompt + answer that reaches max_model_len stops the same way
+    long = a_prompt(eng.ecfg.max_model_len - 3, salt=4)
+    long = [t % 300 for t in long]
+    out, = serve_all(eng, [long], 8)
+    assert len(out) == 3 and eng.stats()["rows_dropped"] == 0
+    assert eng.stats()["free_blocks"] == eng.ecfg.num_kv_blocks
+
+
+def test_an_abort_between_dispatch_and_fetch_drops_the_row_in_flight(
+        one_slot):
+    """A consumer that walks away mid-decode: the sweep releases the slot
+    while a step that carries a row of the sequence is in flight; that row
+    is dropped at the fetch and the request admitted into the slot meanwhile
+    gets its own tokens."""
+    a, b, _ = one_slot.prompts
+    plain_b, = serve_all(one_slot.make(), [b], 6)
+    eng = one_slot.make()
+
+    async def main():
+        gen = eng.generate_stream(a, max_tokens=30)
+        head = [await gen.__anext__() for _ in range(3)]
+        later = asyncio.ensure_future(serve_one(eng, b, 6))
+        await gen.aclose()
+        return head, await later
+
+    async def serve_one(eng, p, n):
+        return [t async for t in eng.generate_stream(p, max_tokens=n)]
+
+    head, got_b = asyncio.run(main())
+    assert len(head) == 3 and got_b == plain_b
+    stats = eng.stats()
+    assert stats["rows_dropped"] >= 1
+    assert stats["tokens_out"] + stats["rows_dropped"] == (
+        stats["steps"] + (0 if one_slot.first else 2))
+    assert stats["free_blocks"] == eng.ecfg.num_kv_blocks
+
+
+@pytest.mark.parametrize("donated", [False, True],
+                         ids=["before_the_call", "after_the_donation"])
+def test_a_step_that_raises_with_two_outstanding_fails_every_request(
+        one_slot, donated):
+    """The third dispatch raises while the second step's tokens are still
+    on the device: both are suspect. The request in the slot, the one whose
+    last step was in flight and the one queued all get the error; the next
+    request is served from a clean engine."""
+    a, b, c = one_slot.prompts
+    eng = one_slot.make()
+    step, calls = eng._decode, []
+
+    def failing(*args):
+        calls.append(len(calls))
+        if len(calls) == 3:
+            if donated:
+                step(*args)
+            raise RuntimeError("the chip fell over")
+        return step(*args)
+
+    eng._decode = failing
+    # `a` ends at the dispatch of its second step (in flight at the failure),
+    # `b` is in the slot, `c` waits
+    outs = serve_all(eng, [a, b, c], [3 - one_slot.first, 8, 8])
+    assert all(isinstance(o, RuntimeError) for o in outs), outs
+    assert eng._flight is None and not eng.active.any()
+    assert eng.stats()["free_blocks"] == eng.ecfg.num_kv_blocks
+    eng._decode = step
+    assert serve_all(eng, [c], 5) == serve_all(one_slot.make(), [c], 5)
+
+
+class _RecordedToks:
+    """A step's result that says when the host fetches it."""
+
+    def __init__(self, n, value, log):
+        self.n, self.value, self.log = n, value, log
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.n))
+        return np.asarray(self.value)
+
+
+def test_a_recording_step_set_sees_each_dispatch_before_the_fetch_ahead_of_it(
+        toy):
+    """The toy family with a decode step that records when it is dispatched
+    and when its tokens are fetched: dispatch n+1 comes before fetch n, every
+    step under load was dispatched ahead, and the loop goes idle with
+    nothing in flight."""
+    cfg, params = toy
+    log = []
+    inner, path, note = _toy_decode_step(cfg, TOY_ECFG)
+
+    def recording(params, bag, *slots):
+        *slots, prev, fed = slots
+        log.append(("dispatch", sum(k == "dispatch" for k, _ in log) + 1))
+        toks, bag = inner(params, bag, *slots, getattr(prev, "value", prev),
+                          fed)
+        return _RecordedToks(log[-1][1], toks, log), bag
+
+    eng = PagedEngine(cfg, params, TOY_ECFG)
+    eng._decode = recording
+    at = {}
+
+    async def main():
+        gen = eng.generate_stream([5, 6, 7], max_tokens=12)
+        head = [await gen.__anext__() for _ in range(3)]
+        at["early"] = eng.stats()
+        short = [t async for t in eng.generate_stream([9, 9], max_tokens=4)]
+        at["late"] = eng.stats()
+        return head + [t async for t in gen], short
+
+    long, short = asyncio.run(main())
+    assert long == toy_reference(params, [5, 6, 7], 12)
+    assert short == toy_reference(params, [9, 9], 4)
+    n = eng.stats()["steps"]
+    assert n == 11 and eng.stats()["steps_ahead"] == n - 1
+    # dispatch 1, then dispatch n+1 before fetch n, and at the end the last
+    # fetch alone
+    want = [("dispatch", 1)]
+    for k in range(1, n):
+        want += [("dispatch", k + 1), ("fetch", k)]
+    assert log == want + [("fetch", n)]
+    # under load every step is dispatched ahead
+    steps = at["late"]["steps"] - at["early"]["steps"]
+    assert steps > 0
+    assert at["late"]["steps_ahead"] - at["early"]["steps_ahead"] == steps
+    assert eng._flight is None and eng.stats()["rows_dropped"] == 0
+
+
+def test_a_prompts_last_chunk_and_first_decode_row_ride_consecutive_steps(
+        chunk_params):
+    """Mixed admissions beside a decoding slot, greedy and seeded: the step
+    after the one that carried a prompt's last chunk already decodes the
+    slot, from the first token and the stream key that step left on the
+    device (no request loses a step to the lookahead), and the streams are
+    the reference's token for token."""
+    eng = PagedEngine(CFG, chunk_params, CHUNK_ECFG)
+    step, seen = eng._decode, []
+
+    def recording(width, params, kc, vc, tables, lens, active, last_tok,
+                  keys, temps, prev, fed, *chunk):
+        seen.append((width, np.asarray(active), np.asarray(fed),
+                     np.asarray(chunk[1]) if chunk else None))
+        return step(width, params, kc, vc, tables, lens, active, last_tok,
+                    keys, temps, prev, fed, *chunk)
+
+    eng._decode = recording
+    prompts = [a_prompt(n, salt=9) for n in (2 * 16 + 3, 7, 19)]
+
+    async def one(p, temperature):
+        rid = eng._rid + 1
+        return rid, [t async for t in eng.generate_stream(
+            p, max_tokens=ANSWER, temperature=temperature, seed=SEED)]
+
+    async def main():
+        gen = eng.generate_stream(a_prompt(6, salt=8), max_tokens=40)
+        head = [await gen.__anext__() for _ in range(2)]
+        outs = await asyncio.gather(
+            one(prompts[0], 0.0), one(prompts[1], TEMPERATURE),
+            one(prompts[2], 0.0))
+        return head + [t async for t in gen], outs
+
+    background, outs = asyncio.run(main())
+    assert [background] == greedy_reference(
+        chunk_params, [a_prompt(6, salt=8)], 40)
+    assert [outs[0][1], outs[2][1]] == greedy_reference(
+        chunk_params, [prompts[0], prompts[2]], ANSWER)
+    rid, toks = outs[1]
+    assert toks == seeded_reference(chunk_params, prompts[1], rid, len(toks))
+    # every chunk that ended a prompt: its slot decodes in the next step
+    ends = 0
+    for (width, _, _, at), (_, active, fed, _) in zip(seen, seen[1:]):
+        if width and any(at[1] + at[2] == len(p) for p in prompts):
+            ends += 1
+            assert active[at[0]] and fed[at[0]] == _engine.FED_CHUNK
+    assert ends == 3
+    stats = eng.stats()
+    assert stats["steps"] == 40 and stats["rows_dropped"] == 0
+    assert stats["steps_ahead"] == 39

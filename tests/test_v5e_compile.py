@@ -157,7 +157,8 @@ def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
         width, params, pool, pool, on_v5e((slots, 128), jnp.int32),
         on_v5e((slots,), jnp.int32), on_v5e((slots,), jnp.bool_),
         on_v5e((slots,), jnp.int32), on_v5e((slots, 2), jnp.uint32),
-        on_v5e((slots,), jnp.float32), *(chunk if width else ()),
+        on_v5e((slots,), jnp.float32), on_v5e((slots + 3,), jnp.int32),
+        on_v5e((slots,), jnp.int32), *(chunk if width else ()),
     ).lower(lowering_platforms=("tpu",)).compile()
 
 
@@ -243,7 +244,9 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
         params, *caches, on_v5e((64, 256), jnp.int32),
         on_v5e((64,), jnp.int32), on_v5e((64,), jnp.bool_),
         on_v5e((64,), jnp.int32), on_v5e((64, 2), jnp.uint32),
-        on_v5e((64,), jnp.float32), on_v5e((), jnp.int32),
+        on_v5e((64,), jnp.float32),
+        on_v5e((64 + len(_ling_steps.COUNTERS),), jnp.int32),
+        on_v5e((64,), jnp.int32), on_v5e((), jnp.int32),
     ).lower(lowering_platforms=("tpu",)).compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_decode_step")
