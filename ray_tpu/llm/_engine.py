@@ -46,6 +46,11 @@ CACHE_NAMES  the device arrays every step takes after the params, donates
     block table [layers, num_kv_blocks + 1, kv_block_size, ...] (block 0 is
     the trash block), per slot [layers, B, ...].
 alloc_cache(cfg, ecfg) -> cache, zeroed.
+step_params(cfg, params) -> the tree the decode step takes as `params`,
+    built once at the engine's start from the tree the engine was given
+    (which stays `engine.params`, under the family's published names, and is
+    what every other member below takes); the given tree itself where the
+    step wants nothing else. A leaf both trees hold is one buffer.
 make_decode_step(cfg, ecfg) -> (step, path, note). `path` names the
     attention it was built with (`stats()["decode_attention"]`); `note` says
     why a TPU was refused the kernel, or is None. The jitted step:
@@ -112,9 +117,10 @@ from ray_tpu.util import tracing
 __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 
 # the names a step set has, all of them and no other (module docstring)
-STEP_SET = ("CACHE_NAMES", "alloc_cache", "make_decode_step", "chunk_ladder",
-            "make_prefill", "check_prefill", "COUNTERS", "PROBE",
-            "SLOT_STATE", "NO_PREFIX_CACHE", "make_kv_inject", "extra_stats")
+STEP_SET = ("CACHE_NAMES", "alloc_cache", "step_params", "make_decode_step",
+            "chunk_ladder", "make_prefill", "check_prefill", "COUNTERS",
+            "PROBE", "SLOT_STATE", "NO_PREFIX_CACHE", "make_kv_inject",
+            "extra_stats")
 
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
@@ -254,12 +260,29 @@ def chunk_ladder(ecfg: EngineConfig) -> Tuple[int, ...]:
     return (widest // 2, widest)
 
 
+def _step_params(cfg: LlamaConfig, params):
+    """The decode step's tree: the given one with every layer's `wq`, `wk`
+    and `wv` packed, columns [wq | wk | wv], into one leaf `wqkv` [layers,
+    dim, (n_heads + 2 n_kv_heads) head_dim], so that the step's three input
+    projections are one matmul whose weight streams from the stack (below).
+    One concatenate on the device at the engine's start; every other leaf
+    is the given tree's own buffer."""
+    import jax.numpy as jnp
+
+    qkv = ("wq", "wk", "wv")
+    layers = {k: v for k, v in params["layers"].items() if k not in qkv}
+    layers["wqkv"] = jnp.concatenate(
+        [params["layers"][k] for k in qkv], axis=-1)
+    return {**params, "layers": layers}
+
+
 def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
     """Build the jitted whole-batch single-token decode step, which may also
-    carry one chunk of one admitting request's prompt. Returns (step, path,
-    note): which attention the decode rows were built with
-    (`paged_attention.KERNEL` or `XLA`, decided here from the backend and
-    the shapes) and, where a TPU was refused the kernel, why."""
+    carry one chunk of one admitting request's prompt; its `params` are
+    `_step_params`'. Returns (step, path, note): which attention the decode
+    rows were built with (`paged_attention.KERNEL` or `XLA`, decided here
+    from the backend and the shapes) and, where a TPU was refused the
+    kernel, why."""
     import functools
 
     import jax
@@ -299,6 +322,7 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
         last_tok, keys = feed_back(prev, fed, last_tok, keys, chunked=True)
         R = B + C
         hd = cfg.head_dim
+        nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
         ids = last_tok
         # one position a decode row, then the chunk's: two calls, so that a
         # decode step's positions stay one [B, 1] call
@@ -333,9 +357,18 @@ def _make_decode_step(cfg: LlamaConfig, ecfg: EngineConfig):
             h, kc, vc = carry
             p, l = xs
             x = rms_norm(h, p["ln1"], cfg.norm_eps)
-            q = (x @ p["wq"].astype(dt)).reshape(1, R, cfg.n_heads, hd)
-            k = (x @ p["wk"].astype(dt)).reshape(1, R, cfg.n_kv_heads, hd)
-            v = (x @ p["wv"].astype(dt)).reshape(1, R, cfg.n_kv_heads, hd)
+            # one 2-D matmul over the packed leaf, cut into heads only after
+            # it: the layer's weight then streams from the stack through the
+            # dot, as wo's and the FFN's do. A dot whose result is reshaped
+            # to heads at once has the reshape folded into it by the TPU's
+            # compiler, which then wants the weight transposed and gets it
+            # by staging the layer's slice in a buffer and copying that:
+            # three serial passes a weight (PERF.md section 6, PR 35;
+            # tests/test_v5e_compile.py holds the one-pass form)
+            qkv = x @ p["wqkv"].astype(dt)                       # [1,R,·]
+            q = qkv[..., :nq].reshape(1, R, cfg.n_heads, hd)
+            k = qkv[..., nq:nq + nkv].reshape(1, R, cfg.n_kv_heads, hd)
+            v = qkv[..., nq + nkv:].reshape(1, R, cfg.n_kv_heads, hd)
             q = _apply_rope_q(q, cos, sin).astype(dt)[0]
             k = _apply_rope_q(k, cos, sin).astype(dt)[0]
             kc = kc.at[l, phys, off].set(k)
@@ -506,7 +539,8 @@ def _make_kv_inject(cfg: LlamaConfig, ecfg: EngineConfig):
 
 LLAMA_STEPS = types.SimpleNamespace(
     CACHE_NAMES=("kc", "vc"), alloc_cache=_alloc_cache,
-    make_decode_step=_make_decode_step, chunk_ladder=chunk_ladder,
+    step_params=_step_params, make_decode_step=_make_decode_step,
+    chunk_ladder=chunk_ladder,
     make_prefill=_make_prefill, check_prefill=_check_prefill,
     COUNTERS=(), PROBE=(), SLOT_STATE=None, NO_PREFIX_CACHE=None,
     make_kv_inject=_make_kv_inject,
@@ -622,7 +656,10 @@ class PagedEngine:
         self._steps = steps = step_set(cfg)
         # the device arrays every step is given, donates and returns
         self._cache_names = steps.CACHE_NAMES
+        # the given tree, under the family's names; beside it the decode
+        # step's own (the same tree, or one that shares all it can)
         self.params = params
+        self._step_params = steps.step_params(cfg, params)
         self.eos_id = eos_id
         e = self.ecfg
         self.bs = e.kv_block_size
@@ -1282,7 +1319,7 @@ class PagedEngine:
                 host.append(self._probe_arg)
             with phase(PHASE_DISPATCH):
                 toks, *rest = self._decode(
-                    *lead, self.params, *self._cache(), *host)
+                    *lead, self._step_params, *self._cache(), *host)
                 n_cache = len(self._cache_names)
                 self._set_cache(rest[:n_cache])
             # past the caches: what a check reads, kept only while a request
@@ -1385,7 +1422,7 @@ class PagedEngine:
             chunk = (jnp.zeros((width,), jnp.int32),
                      jnp.zeros((3,), jnp.int32)) if width else ()
             toks, *caches = self._decode(
-                width, self.params, *self._cache(), *host, *chunk)
+                width, self._step_params, *self._cache(), *host, *chunk)
             self._set_cache(caches)
             toks.block_until_ready()
 
@@ -1492,6 +1529,7 @@ class PagedEngine:
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
         params = jax.tree.map(shape, self.params)
+        step_params = jax.tree.map(shape, self._step_params)
         cache = [shape(a) for a in self._cache()]
         host = self._step_inputs(shape, shape(self._toks))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
@@ -1499,7 +1537,7 @@ class PagedEngine:
             host.append(i32)          # the probed slot
         lead = (0,) if self._ladder else ()
         out = {"jit_paged_decode_step": [self._decode.lower(
-            *lead, params, *cache, *host).compile().as_text()]}
+            *lead, step_params, *cache, *host).compile().as_text()]}
         out["jit_paged_prefill"] = []
         for n in prefill_lengths:
             S = max(8, 1 << (n - 1).bit_length())

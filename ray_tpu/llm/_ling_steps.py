@@ -15,11 +15,13 @@ kinds of per-sequence state:
     tails    [kda_layers, slots, K-1, 3*H*dk]: the short convolution's last
              inputs, kept with the state
 
-All three are donated to each step and returned by it. The steps are named
-`paged_decode_step` and `paged_prefill` as every family's are, so the device
-trace's `jit_paged_*` programs mean the same whatever is served. The decode
-step takes no chunk (a chunk would have to hand the KDA state across steps):
-prompts run whole through `make_prefill`, awaited in the engine's loop.
+All three are donated to each step and returned by it; the weights every
+step takes are the given tree (`step_params` is the identity). The steps are
+named `paged_decode_step` and `paged_prefill` as every family's are, so the
+device trace's `jit_paged_*` programs mean the same whatever is served. The
+decode step takes no chunk (a chunk would have to hand the KDA state across
+steps): prompts run whole through `make_prefill`, awaited in the engine's
+loop.
 
 The decode step's first result is one int32 vector, fetched once a step: the
 sampled tokens [B], then `COUNTERS` summed over the expert layers. Its last
@@ -68,6 +70,12 @@ def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:
         jnp.zeros((cfg.kda_layers, B, H, dk, dk), jnp.float32),
         jnp.zeros((cfg.kda_layers, B, cfg.conv_kernel - 1, cfg.conv_channels),
                   cfg.dtype))
+
+
+def step_params(cfg: ling.LingConfig, params):
+    """The decode step takes the weights as `ling.init_params` lays them
+    out."""
+    return params
 
 
 def chunk_ladder(ecfg) -> Tuple[int, ...]:

@@ -31,13 +31,18 @@ CFG = LlamaConfig(
     ffn_dim=128, max_seq_len=128, dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-def test_paged_matches_dense_decode():
+# every head its own keys and values: the packed leaf is three equal thirds
+MHA = dataclasses.replace(CFG, n_kv_heads=CFG.n_heads)
+
+
+@pytest.mark.parametrize("cfg", [CFG, MHA], ids=["gqa", "mha"])
+def test_paged_matches_dense_decode(cfg):
     from ray_tpu.llm._generate import generate
 
-    params = init_params(CFG, jax.random.PRNGKey(0))
+    params = init_params(cfg, jax.random.PRNGKey(0))
     prompts = [[1, 5, 9], [3, 3, 3, 7, 2], [42]]
-    dense = generate(CFG, params, prompts, max_new_tokens=8, temperature=0.0)
-    eng = PagedEngine(CFG, params, EngineConfig(
+    dense = generate(cfg, params, prompts, max_new_tokens=8, temperature=0.0)
+    eng = PagedEngine(cfg, params, EngineConfig(
         max_num_seqs=3, kv_block_size=4, num_kv_blocks=32, max_model_len=64))
 
     async def run_one(p):
@@ -210,6 +215,55 @@ def test_chunked_admission_draws_the_seeded_tokens(served, chunk_params, mode,
     assert len(toks) == min(ANSWER, 64 - plen)
     assert toks == seeded_reference(chunk_params, a_prompt(plen), rid,
                                     len(toks))
+
+
+@pytest.mark.parametrize("cfg", [CFG, MHA], ids=["gqa", "mha"])
+def test_the_step_takes_q_k_and_v_packed_and_the_engine_keeps_the_given_tree(
+        cfg):
+    """The Llama family's `step_params`: `wqkv` is [wq | wk | wv] column for
+    column, every other leaf is the given tree's own buffer, and
+    `engine.params` still answers the names `init_params` gives (the
+    benchmark's reference check reads them there)."""
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    eng = PagedEngine(cfg, params, CHUNK_ECFG)
+    assert eng.params is params
+    given, packed = params["layers"], eng._step_params["layers"]
+    assert set(packed) == set(given) - {"wq", "wk", "wv"} | {"wqkv"}
+    hd = cfg.head_dim
+    nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    assert packed["wqkv"].shape == (cfg.n_layers, cfg.dim, nq + 2 * nkv)
+    assert packed["wqkv"].dtype == given["wq"].dtype
+    for name, lo, hi in (("wq", 0, nq), ("wk", nq, nq + nkv),
+                         ("wv", nq + nkv, nq + 2 * nkv)):
+        np.testing.assert_array_equal(packed["wqkv"][..., lo:hi], given[name])
+    shared = {**{n: (packed[n], given[n]) for n in packed if n != "wqkv"},
+              **{n: (eng._step_params[n], params[n])
+                 for n in params if n != "layers"}}
+    assert set(shared) == {"ln1", "wo", "ln2", "w1", "w3", "w2", "tok_emb",
+                           "norm", "lm_head"}
+    assert all(a is b for a, b in shared.values())
+
+
+@pytest.mark.parametrize("plen", [5, 2 * 16 + 3])
+def test_an_mha_model_admitted_in_chunks_equals_generate(plen):
+    """`n_kv_heads == n_heads` through the chunked step (a chunk beside a
+    decoding slot, then decode rows alone)."""
+    from ray_tpu.llm._generate import generate
+
+    params = init_params(MHA, jax.random.PRNGKey(1))
+    eng = PagedEngine(MHA, params, CHUNK_ECFG)
+    prompts = [a_prompt(6, salt=plen), a_prompt(plen)]
+
+    async def main():
+        first = eng.generate_stream(prompts[0], max_tokens=12)
+        head = [await first.__anext__() for _ in range(2)]
+        second = [t async for t in eng.generate_stream(
+            prompts[1], max_tokens=ANSWER)]
+        return [head + [t async for t in first], second]
+
+    got = asyncio.run(main())
+    want = generate(MHA, params, prompts, max_new_tokens=12, temperature=0.0)
+    assert got == [want[0], want[1][:ANSWER]]
 
 
 def test_slots_decoding_beside_the_admissions_are_undisturbed(served,
@@ -440,9 +494,9 @@ def test_every_family_keeps_the_step_set_and_the_engine_names_none(family):
     assert isinstance(steps.NO_PREFIX_CACHE, (str, type(None)))
     ladder = steps.chunk_ladder(EngineConfig())
     assert isinstance(ladder, tuple) and list(ladder) == sorted(set(ladder))
-    for name in ("alloc_cache", "make_decode_step", "chunk_ladder",
-                 "make_prefill", "check_prefill", "make_kv_inject",
-                 "extra_stats"):
+    for name in ("alloc_cache", "step_params", "make_decode_step",
+                 "chunk_ladder", "make_prefill", "check_prefill",
+                 "make_kv_inject", "extra_stats"):
         assert callable(getattr(steps, name))
     # the engine's code: no family, no config class, no step set by name, no
     # cache array as an attribute, no test of a config's class
@@ -542,6 +596,7 @@ def _toy_no_inject(cfg, ecfg):
 
 TOY_STEPS = types.SimpleNamespace(
     CACHE_NAMES=("bag",), alloc_cache=_toy_alloc,
+    step_params=lambda cfg, params: params,
     make_decode_step=_toy_decode_step, chunk_ladder=lambda ecfg: (),
     make_prefill=_toy_prefill, check_prefill=_toy_check_prefill,
     COUNTERS=("toy_rows",), PROBE=(), SLOT_STATE="bag",

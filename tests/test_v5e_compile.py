@@ -135,7 +135,7 @@ def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
     cells' shapes (Mistral-7B widths, 16 layers, 32 slots, blocks of 16,
     tables of 128) with a pool of `blocks` and a prompt chunk of `width`
     rows (0: none)."""
-    from ray_tpu.llm._engine import EngineConfig, _make_decode_step
+    from ray_tpu.llm._engine import LLAMA_STEPS, EngineConfig
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
@@ -144,13 +144,15 @@ def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
         ffn_dim=14336, rope_theta=1e6, max_seq_len=2048, dtype=jnp.bfloat16,
         param_dtype=jnp.bfloat16)
     slots = 32
-    step, path, note = _make_decode_step(cfg, EngineConfig(
+    step, path, note = LLAMA_STEPS.make_decode_step(cfg, EngineConfig(
         max_num_seqs=slots, kv_block_size=16, num_kv_blocks=blocks,
         max_model_len=2048))
     assert (path, note) == (pa.KERNEL, None)
+    # the tree the engine hands the step: Q, K and V packed into one leaf
     params = jax.tree.map(
         lambda x: on_v5e(x.shape, x.dtype),
-        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+        jax.eval_shape(lambda: LLAMA_STEPS.step_params(
+            cfg, init_params(cfg, jax.random.PRNGKey(0)))))
     pool = on_v5e((16, blocks + 1, 16, 8, 128))
     chunk = (on_v5e((width,), jnp.int32), on_v5e((3,), jnp.int32))
     return step.trace(
@@ -160,6 +162,31 @@ def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
         on_v5e((slots,), jnp.float32), on_v5e((slots + 3,), jnp.int32),
         on_v5e((slots,), jnp.int32), *(chunk if width else ()),
     ).lower(lowering_platforms=("tpu",)).compile()
+
+
+# an instruction of its own (a fusion's result, a copy) the size of one
+# layer's attention input weight: a slice of the stack staged in a buffer, or
+# a transposed copy of it. A `dynamic-slice` inside a matmul's fusion is the
+# weight streaming from the stack and is not one.
+STAGED_WEIGHT = re.compile(
+    r"= bf16\[1,4096,(?:4096|1024|6144)\]\S* (?:fusion|copy)\(")
+
+
+def assert_qkv_is_one_matmul_over_the_stack(hlo: str, rows: int):
+    """Q, K and V of a layer come out of one fusion `bf16[1, rows, 6144]`
+    that reads `wqkv [16, 4096, 6144]` from the stack in place, and no
+    layer's weight is staged or copied on its way to a matmul (as `wq`,
+    `wk` and `wv` each were, sliced, transposed and then used: PR 35)."""
+    assert not STAGED_WEIGHT.findall(hlo)
+    qkv = re.findall(
+        rf"%(\S+) = bf16\[1,{rows},6144\]\S* fusion\(.*kind=kOutput, "
+        r"calls=%(\S+?),", hlo)
+    assert len(qkv) == 1, qkv
+    # the fusion's own computation: the whole stack is its operand, the dot
+    # is inside
+    operands, body = hlo.split(f"%{qkv[0][1]} (", 1)[1].split(
+        "\n}", 1)[0].split("\n", 1)
+    assert "bf16[16,4096,6144]" in operands and " convolution(" in body
 
 
 def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
@@ -175,6 +202,7 @@ def test_decode_step_compiles_for_v5e_with_the_paged_kernel(on_v5e,
     assert len(kernels) == 1 and "%paged_decode_attention" in kernels[0]
     assert f"bf16[{16 * (blocks + 1)},128,128]" in kernels[0]   # pool in place
     assert not re.findall(r"(?:f32|bf16)\[32,2048,[\d,]*\]", hlo)
+    assert_qkv_is_one_matmul_over_the_stack(hlo, 32)
     m = compiled.memory_analysis()
     # the pool (2.7 GB) rides in the scan's carry and is donated: no copy
     assert m.alias_size_in_bytes > 2.6e9 and m.temp_size_in_bytes < 0.2e9
@@ -207,8 +235,24 @@ def test_decode_step_with_the_widest_chunk_compiles_for_v5e_pool_in_place(
     pool_bytes = 2 * 16 * (blocks + 1) * 16 * 8 * 128 * 2
     assert m.alias_size_in_bytes >= pool_bytes
     assert m.temp_size_in_bytes < 1e9
+    # beside the step's tree the engine keeps the leaves it was given, wq,
+    # wk and wv, under their names (`engine.params`): 0.8 GB more
+    kept = 16 * 4096 * 6144 * 2
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+            - m.alias_size_in_bytes + m.temp_size_in_bytes
+            + kept) < V5E_HBM_BYTES
+    assert_qkv_is_one_matmul_over_the_stack(hlo, 32 + width)
+
+
+def test_decode_step_with_the_narrower_chunk_projects_qkv_in_one_matmul(
+        on_v5e, monkeypatch):
+    """The ladder's other width: the third program the cells run."""
+    from ray_tpu.llm._engine import EngineConfig, chunk_ladder
+
+    width = chunk_ladder(EngineConfig(max_model_len=2048))[0]
+    assert width == 128
+    hlo = mistral_decode_step(on_v5e, monkeypatch, 2560, width).as_text()
+    assert_qkv_is_one_matmul_over_the_stack(hlo, 32 + width)
 
 
 def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
