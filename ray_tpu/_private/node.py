@@ -145,9 +145,54 @@ def start_node_daemon(
     return proc, info
 
 
+def _stat(pid) -> Optional[List[str]]:
+    """`/proc/<pid>/stat` from the state on (state, ppid, ...), or None
+    where there is no such process. The name before it may hold spaces and
+    brackets."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rsplit(")", 1)[1].split()
+    except (OSError, IndexError):
+        return None
+
+
+def _descendants(pid: int) -> List[int]:
+    """Live processes below `pid`, from /proc (none where there is no
+    /proc). A daemon's workers lead sessions of their own, so no signal to
+    the daemon's group reaches them."""
+    children: Dict[int, List[int]] = {}
+    try:
+        entries = [int(e) for e in os.listdir("/proc") if e.isdigit()]
+    except OSError:
+        return []
+    for p in entries:
+        stat = _stat(p)
+        if stat:
+            children.setdefault(int(stat[1]), []).append(p)
+    out, todo = [], [pid]
+    while todo:
+        below = children.get(todo.pop(), [])
+        out += below
+        todo += below
+    return out
+
+
+def _gone(pid: int) -> bool:
+    """No such process, or a zombie: it holds no file and no memory."""
+    stat = _stat(pid)
+    return stat is None or stat[0] == "Z"
+
+
 def kill_process(proc: subprocess.Popen, force: bool = False, timeout: float = 5.0):
+    """Stop `proc` and return when it AND every process it started is gone
+    (or `timeout` has passed twice: once for the process, once for what it
+    left). A daemon told to stop ends its workers and waits for them, chip
+    holders included, before it exits; one killed outright, or one that
+    overran `timeout`, leaves them to be swept here. So whoever returns from
+    here may start the next holder of this host's chips."""
     if proc.poll() is not None:
         return
+    below = _descendants(proc.pid)
     try:
         if force:
             os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
@@ -155,9 +200,17 @@ def kill_process(proc: subprocess.Popen, force: bool = False, timeout: float = 5
             os.killpg(os.getpgid(proc.pid), signal.SIGTERM)
             try:
                 proc.wait(timeout)
-                return
             except subprocess.TimeoutExpired:
                 os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
         proc.wait(timeout)
-    except (ProcessLookupError, PermissionError):
+    except (ProcessLookupError, PermissionError, subprocess.TimeoutExpired):
         pass
+    deadline = time.monotonic() + timeout
+    for pid in below:
+        if not _gone(pid):
+            try:
+                os.kill(pid, signal.SIGKILL)
+            except (ProcessLookupError, PermissionError):
+                pass
+    while time.monotonic() < deadline and not all(map(_gone, below)):
+        time.sleep(0.02)

@@ -171,6 +171,9 @@ class NodeDaemon:
         # the worker is spawned with TPU_VISIBLE_CHIPS restricted to them).
         self._tpu_free_chips: List[int] = list(range(int(res.get("TPU", 0))))
         self._tpu_chips_per_host = len(self._tpu_free_chips)
+        # chips of signalled workers that are not gone yet: (process, chip
+        # ids, time of the signal); `_reclaim_chips` frees them
+        self._tpu_releasing: List[Tuple[Any, Tuple[int, ...], float]] = []
         self.store_name = store_name or f"rt_{self.node_id.hex()[:12]}"
         self.store: Optional[ShmObjectStore] = None
         self.server = RpcServer(name=f"daemon-{self.node_id.hex()[:6]}")
@@ -357,8 +360,17 @@ class NodeDaemon:
             self._preempt_watcher.stop()
         for t in self._tasks:
             t.cancel()
+        killed = [w.proc for w in self.workers.values()]
         for w in list(self.workers.values()):
             self._kill_worker_proc(w, "daemon shutdown")
+        # whoever waits for this daemon's exit may take its chips next
+        deadline = time.monotonic() + GLOBAL_CONFIG.get("shutdown_timeout_s")
+        while time.monotonic() < deadline:
+            self._reclaim_chips()
+            if not self._tpu_releasing and all(
+                    p.poll() is not None for p in killed):
+                break
+            await asyncio.sleep(0.02)
         if self.control:
             await self.control.close()
         for c in self._peer_clients.values():
@@ -773,6 +785,7 @@ class NodeDaemon:
             for w in list(self.workers.values()):
                 if w.state != W_DEAD and w.proc.poll() is not None:
                     await self._on_worker_death(w)
+            self._reclaim_chips()
             # reap surplus idle workers (only genuinely idle ones — the list
             # may hold stale ids for workers that have since been leased)
             max_idle = GLOBAL_CONFIG.get("worker_pool_max_idle")
@@ -996,10 +1009,52 @@ class NodeDaemon:
             # completed task per actor ever created on this node
             self._creating_actors.pop(w.actor_id, None)
         if w.tpu_chips:
-            self._return_chips(w.tpu_chips)
+            self._tpu_releasing.append(
+                (w.proc, w.tpu_chips, time.monotonic()))
             w.tpu_chips = None
+            self._reclaim_chips()
 
-    def _alloc_chips(self, n: int) -> List[int]:
+    def _reclaim_chips(self) -> None:
+        """A chip is free when its holder is gone: a signalled worker's
+        chips go back on the free list once the process has been reaped and
+        their device files open again (the kernel takes seconds to tear
+        down a process with gigabytes mapped on its chips; until then the
+        next holder's libtpu fails on them, `Device or resource busy`). A
+        worker that died on its own is reaped already and waits for
+        nothing."""
+        if not self._tpu_releasing:
+            return
+        from ray_tpu.tpu import accelerator as tpu_accel
+        from ray_tpu.util import metrics as metrics_mod
+
+        still = []
+        for proc, chips, t0 in self._tpu_releasing:
+            waited = time.monotonic() - t0
+            held = "its holder" if proc.poll() is None else (
+                tpu_accel.busy_chip(tpu_accel.chip_device_files(chips)))
+            if held is not None and waited < tpu_accel.CHIP_ATTACH_LIMIT_S:
+                still.append((proc, chips, t0))
+                continue
+            if held is not None:
+                # somebody this daemon cannot wait for: the next worker's
+                # attach wait will name the device
+                logger.warning("chips %s still held (%s) after %.1f s: "
+                               "handed on as they are", chips, held, waited)
+            self._return_chips(chips)
+            metrics_mod.get_or_create_counter(
+                "rt_chip_release_wait_s",
+                "Seconds between signalling a chip-holding worker and its "
+                "chips being free again.").inc(waited)
+            logger.info("chips %s free: chip_release_wait_s=%.3f",
+                        ",".join(map(str, chips)), waited)
+        self._tpu_releasing = still
+
+    async def _alloc_chips(self, n: int) -> List[int]:
+        # the node's TPU count is credited when a holder is signalled; the
+        # chips themselves follow when it is gone
+        while len(self._tpu_free_chips) < n and self._tpu_releasing:
+            await asyncio.sleep(0.05)
+            self._reclaim_chips()
         if len(self._tpu_free_chips) < n:
             raise RuntimeError(
                 f"TPU chip accounting out of sync: need {n}, "
@@ -1320,7 +1375,7 @@ class NodeDaemon:
                 from ray_tpu._private.runtime_env_mgr import env_isolation_key
 
                 w = await self._spawn_worker(
-                    p.job_id, tpu_chips=self._alloc_chips(n_tpu),
+                    p.job_id, tpu_chips=await self._alloc_chips(n_tpu),
                     env_key=env_isolation_key(p.runtime_env),
                     runtime_env=p.runtime_env,
                 )
@@ -1575,7 +1630,8 @@ class NodeDaemon:
         try:
             w = await self._spawn_worker(
                 spec.job_id.binary(),
-                tpu_chips=self._alloc_chips(n_tpu) if n_tpu > 0 else None,
+                tpu_chips=(await self._alloc_chips(n_tpu)
+                           if n_tpu > 0 else None),
                 env_key=env_isolation_key(renv),
                 runtime_env=renv,
             )

@@ -252,7 +252,9 @@ def shutdown():
     _context.core_worker = None
     _context.stop_loop()
     for proc in reversed(_context.owned_processes):
-        node_mod.kill_process(proc)
+        # the daemon waits for the workers it ends (a chip holder takes
+        # seconds to be torn down): what is left of the budget, not 5 s
+        node_mod.kill_process(proc, timeout=max(5.0, budget.remaining()))
     _context.owned_processes.clear()
     _context.initialized = False
     atexit.unregister(shutdown)

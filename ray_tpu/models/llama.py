@@ -415,19 +415,72 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, learning_rate: float = 3e-4,
 
     # The (b, s, vocab) fp32 logits (and their log_softmax) are by far the
     # largest activations; computing the loss in sequence chunks under
-    # jax.checkpoint keeps only one chunk's logits live at a time in both
+    # recomputation keeps only one chunk's logits live at a time in both
     # directions (the chunk is recomputed from `h` in the backward pass).
     chunk = loss_chunk
 
-    def _chunk_nll(params, h_c, tgt_c, mask_c):
-        """Masked NLL sum over one sequence chunk. tgt -1 = no target."""
-        dt = lcfg.dtype
-        logits = (h_c @ _use(mesh, params["lm_head"].astype(dt),
-                             P(None, "tp"))).astype(jnp.float32)
+    def _head(lm_head):
+        return _use(mesh, lm_head.astype(lcfg.dtype), P(None, "tp"))
+
+    def _chunk_nll(w, h_c, tgt_c, mask_c):
+        """Masked NLL sum over one sequence chunk against the head `w`
+        (compute dtype, gathered for use). tgt -1 = no target. Leading
+        dims of `w` are batch dims shared with `h_c`."""
+        logits = jnp.einsum("...bsd,...dv->...bsv", h_c, w).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         tgt = jnp.maximum(tgt_c, 0)
         nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
         return (nll * mask_c).sum()
+
+    def _chunked_nll(lm_head, hs, ts, ms):
+        """Σ over the leading (chunk) axis of `_chunk_nll`, one chunk live at
+        a time. The head crosses the chips twice a step, not twice a chunk:
+        gathered once before the loop (and kept for the backward pass), its
+        gradient summed over the chunks in float32 by each data shard on its
+        own rows and reduced once after the loop, down to the parameter's
+        own sharding. The rows are split as (shard, rows of the shard) so
+        that a chunk's head gradient is one slice a shard, a batch dim of
+        the matmul and no contraction across chips."""
+        b = hs.shape[1]
+        shards = mesh.shape["dp"] * mesh.shape["fsdp"]
+        if b % shards:
+            shards = 1
+        part = P(BATCH_AXES, None, "tp")
+
+        def split(x):  # (chunks, b, ...) -> (chunks, shards, b / shards, ...)
+            return x.reshape(x.shape[0], shards, b // shards, *x.shape[2:])
+
+        @jax.custom_vjp
+        def total_nll(lm_head, hs, ts, ms):
+            return fwd(lm_head, hs, ts, ms)[0]
+
+        def fwd(lm_head, hs, ts, ms):
+            w = _head(lm_head)
+            w = constrain(jnp.broadcast_to(w, (shards, *w.shape)), mesh, part)
+            total = jax.lax.map(lambda htm: _chunk_nll(w, *htm), (hs, ts, ms))
+            return total.sum(), (w, hs, ts, ms)
+
+        def bwd(res, g):
+            w, hs, ts, ms = res
+
+            def body(dw, htm):
+                h_c, t_c, m_c = htm
+                _, vjp = jax.vjp(
+                    lambda w_, h_: _chunk_nll(w_, h_, t_c, m_c), w, h_c)
+                dw_c, dh_c = vjp(g)
+                return constrain(dw + dw_c.astype(jnp.float32), mesh, part), dh_c
+
+            dw0 = constrain(jnp.zeros(w.shape, jnp.float32), mesh, part)
+            dw, dhs = jax.lax.scan(body, dw0, (hs, ts, ms))
+            if shards > 1:
+                # crosses the chips in the compute dtype, half the bytes of
+                # a float32 sum: one rounding a shard a step
+                dw = dw.astype(lcfg.dtype)
+            dw = constrain(dw.sum(0), mesh, specs["lm_head"])
+            return dw.astype(lm_head.dtype), dhs, None, None
+
+        total_nll.defvjp(fwd, bwd)
+        return total_nll(lm_head, split(hs), split(ts), split(ms))
 
     def compute_loss(params, tokens):
         # forward on the FULL sequence (keeps the input length divisible by
@@ -443,10 +496,8 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, learning_rate: float = 3e-4,
             hs = h.reshape(b, s // chunk, chunk, lcfg.dim).swapaxes(0, 1)
             ts = targets.reshape(b, s // chunk, chunk).swapaxes(0, 1)
             ms = mask.reshape(b, s // chunk, chunk).swapaxes(0, 1)
-            nll_fn = jax.checkpoint(partial(_chunk_nll, params))
-            total = jax.lax.map(lambda htm: nll_fn(*htm), (hs, ts, ms)).sum()
-            return total / denom
-        return _chunk_nll(params, h, targets, mask) / denom
+            return _chunked_nll(params["lm_head"], hs, ts, ms) / denom
+        return _chunk_nll(_head(params["lm_head"]), h, targets, mask) / denom
 
     def init_state(key):
         params = init_params(cfg, key)

@@ -10,6 +10,13 @@ Key semantics:
   from device files and never loads libtpu; each granted worker is spawned
   seeing exactly its chips (`set_visible_chips_env`) and checks what JAX
   shows it against the grant (`check_granted_devices`);
+- a chip is free when its holder is GONE, not when it was signalled: the
+  kernel takes seconds to tear down a killed process that had gigabytes
+  mapped on its chips, and until then the chip's device file opens with
+  EBUSY. The daemon hands a killed worker's chips on only when they open
+  (`busy_chip`), and a granted worker waits out a holder it cannot see
+  (another cluster's, a run before this one) before it touches JAX
+  (`wait_for_chips`);
 - TPU resources are named by accelerator version ("TPU-v5e" etc.) when the
   host says which it is (`TPU_ACCELERATOR_TYPE`);
 - the FIRST host of a slice additionally exposes `TPU-{pod_type}-head: 1`, the
@@ -18,12 +25,14 @@ Key semantics:
 
 from __future__ import annotations
 
+import errno
 import glob
 import logging
 import os
 import re
+import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Callable, Dict, Iterable, List, Optional
 
 from ray_tpu._private.config import GLOBAL_CONFIG
 
@@ -39,6 +48,11 @@ TPU_NAME_ENV = "TPU_NAME"
 # granted chip ids, comma-separated (present even when the grant is the whole
 # host and TPU_VISIBLE_CHIPS is therefore left unset)
 GRANTED_CHIPS_ENV = "RT_TPU_CHIPS"
+# how long a granted worker waits for chips somebody is still letting go of
+# before it fails as it would have at once (on a v5e host a killed holder of
+# four chips with 5 GB on each was gone after 18 s); under the 120 s an
+# actor's creation may take
+CHIP_ATTACH_LIMIT_S = 60.0
 
 
 @dataclass
@@ -180,17 +194,75 @@ def granted_chips() -> List[str]:
     return [c for c in os.environ.get(GRANTED_CHIPS_ENV, "").split(",") if c]
 
 
+def chip_device_files(chips: Iterable, dev_root: str = "/dev") -> List[str]:
+    """The device files of chip ids (the daemon's numbering, which is
+    libtpu's `TPU_VISIBLE_CHIPS`): chip n is IOMMU group `/dev/vfio/<n>` on a
+    vfio-bound host (tried on a four-chip v5e host: four one-chip processes
+    made exactly their own group's file busy), `/dev/accel<n>` on an older
+    one. A file that is not there is left out."""
+    accel = bool(glob.glob(os.path.join(dev_root, "accel[0-9]*")))
+    paths = [os.path.join(dev_root, f"accel{int(c)}") if accel
+             else os.path.join(dev_root, "vfio", str(int(c))) for c in chips]
+    return [p for p in paths if os.path.exists(p)]
+
+
+def busy_chip(paths: Iterable[str],
+              opener: Callable[[str, int], int] = os.open) -> Optional[str]:
+    """The first device file that another process still holds (it opens
+    with EBUSY, which is what libtpu's start fails on), or None. Opened
+    and closed at once; any other error is for JAX to report."""
+    for path in paths:
+        try:
+            os.close(opener(path, os.O_RDWR))
+        except OSError as e:
+            if e.errno == errno.EBUSY:
+                return path
+    return None
+
+
+def wait_for_chips(paths: Iterable[str], limit_s: float = CHIP_ATTACH_LIMIT_S,
+                   opener: Callable[[str, int], int] = os.open,
+                   sleep: Callable[[float], None] = time.sleep) -> float:
+    """Wait until none of the device files is held any more; returns the
+    seconds waited (0.0 when nobody was met). Raises RuntimeError naming the
+    device that is still busy at the limit."""
+    paths, t0 = list(paths), time.monotonic()
+    held = busy_chip(paths, opener)
+    if held is None:
+        return 0.0
+    while held is not None:
+        waited = time.monotonic() - t0
+        if waited >= limit_s:
+            raise RuntimeError(
+                f"TPU chip {held} is still held by another process after "
+                f"{waited:.1f} s (chip_attach_wait_s; limit {limit_s:.0f} s)")
+        sleep(0.25)
+        held = busy_chip(paths, opener)
+    return time.monotonic() - t0
+
+
 def check_granted_devices() -> None:
     """Called by a worker that builds a model, before anything is built: a
-    worker the daemon pinned to the TPU platform must see only TPU chips,
-    exactly as many as it was granted. Raises RuntimeError otherwise. (On a
-    CPU-pinned cluster with fake TPU resources there is nothing to check,
-    and no backend is touched.)"""
+    worker the daemon pinned to the TPU platform waits until its chips'
+    last holder has let go of them (`wait_for_chips`), and must then see
+    only TPU chips, exactly as many as it was granted. Raises RuntimeError
+    otherwise. (On a CPU-pinned cluster with fake TPU resources there is
+    nothing to check, and no backend is touched.)"""
     if os.environ.get("JAX_PLATFORMS") != "tpu":
         return
+    from ray_tpu.util.metrics import get_or_create_counter
+
+    granted = granted_chips()
+    waited = wait_for_chips(chip_device_files(granted))
+    get_or_create_counter(
+        "rt_chip_attach_wait_s",
+        "Seconds granted workers waited for chips their last holder had "
+        "not let go of yet.").inc(waited)
+    logger.info("chips %s attached: chip_attach_wait_s=%.3f",
+                ",".join(granted), waited)
     import jax
 
-    devices, granted = jax.local_devices(), granted_chips()
+    devices = jax.local_devices()
     if any(d.platform != "tpu" for d in devices) or (
             len(devices) != len(granted)):
         raise RuntimeError(
