@@ -125,7 +125,7 @@ STEP_SET = ("CACHE_NAMES", "alloc_cache", "step_params", "make_decode_step",
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
 # profiler session is on; an inactive annotation is a flag check. Each is
-# opened and closed on one thread: sweep and emit on the event loop's,
+# opened and closed on one thread: sweep, emit and idle on the event loop's,
 # the others on the `asyncio.to_thread` worker that runs `_try_admit` or
 # `_run_step`. A turn is sweep, admit*, step (upload and dispatch of the
 # next step, then the wait for the one before: the device runs all through
@@ -148,9 +148,12 @@ PHASE_DISPATCH = "engine:dispatch"              # the decode step's launch
 # step, alone (outside a step) when the loop drains with nothing to dispatch
 PHASE_DEVICE_WAIT = "engine:device_wait"
 PHASE_EMIT = "engine:emit"                      # the per-slot walk
+# the wait of an engine with no request in it: in the place of a turn, so a
+# device gap under it is an empty engine and one under no phase a stalled host
+PHASE_IDLE = "engine:idle"
 PHASES = (PHASE_SWEEP, PHASE_ADMIT, PHASE_PREFIX_MATCH, PHASE_PREFILL,
           PHASE_SAMPLE_FIRST, PHASE_STEP, PHASE_UPLOAD, PHASE_DISPATCH,
-          PHASE_DEVICE_WAIT, PHASE_EMIT)
+          PHASE_DEVICE_WAIT, PHASE_EMIT, PHASE_IDLE)
 # the per-request spans on the tracing plane (util/tracing.py), three a
 # request and none a step: the control store keeps 10,000 events
 SPAN_QUEUE = "engine:queue"      # enqueue -> admission start
@@ -1245,7 +1248,15 @@ class PagedEngine:
             dispatch = chunk is not None or bool(self.active.any())
             if not dispatch and flight is None:
                 # idle, nothing in flight: block until a request arrives
-                waiting.append(await self._pending.get())
+                # (one that landed during the turn is the next sweep's). The
+                # one annotation held over an `await`: it opens and closes
+                # here, around nothing but the wait, and every other event
+                # on this thread (sweep, emit, a handler's) opens and closes
+                # inside one slice of one coroutine, so whatever runs while
+                # this waits lies whole inside it and none straddles its edge
+                if self._pending.empty():
+                    with phase(PHASE_IDLE):
+                        waiting.append(await self._pending.get())
                 t_turn = None
                 continue
             t_step = time.monotonic()

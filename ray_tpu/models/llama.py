@@ -31,6 +31,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import BATCH_AXES, MeshSpec, constrain
 
+# `jax.named_scope`s of the train step (`make_train_step`), as
+# `llm/_engine.PHASES` is for the engine loop: a component of every
+# instruction's `op_name`, which the device trace carries as `tf_op`
+# (benchmark/lib/xmeta.py reads it). Every instruction falls under one of
+# embed / layers / loss / optimizer; attn and mlp only inside layers (`_layer`,
+# shared with the served dense path). Forward, backward and recomputation need
+# no name: JAX writes `jvp(..)`, `transpose(jvp(..))`, `rematted_computation`.
+TRAIN_SCOPES = ("embed", "layers", "attn", "mlp", "loss", "optimizer")
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -271,9 +280,7 @@ def _ffn(cfg: LlamaConfig, mesh: Optional[Mesh], h, p):
     return out
 
 
-def _layer(cfg: LlamaConfig, mesh: Optional[Mesh], h, layer_params, cos, sin,
-           remat_ffn: bool = False):
-    p = layer_params
+def _attn(cfg: LlamaConfig, mesh: Optional[Mesh], h, p, cos, sin):
     hd = cfg.head_dim
     b, s, _ = h.shape
     dt = cfg.dtype
@@ -322,12 +329,18 @@ def _layer(cfg: LlamaConfig, mesh: Optional[Mesh], h, layer_params, cos, sin,
             mesh, p["wo"].astype(dt), P("tp", None))
     if mesh is not None:
         attn = constrain(attn, mesh, P(BATCH_AXES, "sp", None))
-    h = h + attn
+    return h + attn
 
+
+def _layer(cfg: LlamaConfig, mesh: Optional[Mesh], h, layer_params, cos, sin,
+           remat_ffn: bool = False):
+    with jax.named_scope("attn"):
+        h = _attn(cfg, mesh, h, layer_params, cos, sin)
     ffn = _ffn
     if remat_ffn:
         ffn = jax.checkpoint(_ffn, static_argnums=(0, 1))
-    return h + ffn(cfg, mesh, h, p)
+    with jax.named_scope("mlp"):
+        return h + ffn(cfg, mesh, h, layer_params)
 
 
 def forward(
@@ -402,16 +415,18 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, learning_rate: float = 3e-4,
 
     def backbone(params, tokens):
         dt = lcfg.dtype
-        h = _use(mesh, params["tok_emb"].astype(dt), P(None, "tp"))[tokens]
-        h = constrain(h, mesh, P(BATCH_AXES, "sp", None))
-        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-        cos, sin = rope_tables(lcfg, positions)
+        with jax.named_scope("embed"):
+            h = _use(mesh, params["tok_emb"].astype(dt), P(None, "tp"))[tokens]
+            h = constrain(h, mesh, P(BATCH_AXES, "sp", None))
+        with jax.named_scope("layers"):
+            positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+            cos, sin = rope_tables(lcfg, positions)
 
-        def body(carry, lp):
-            return layer(carry, lp, cos, sin), None
+            def body(carry, lp):
+                return layer(carry, lp, cos, sin), None
 
-        h, _ = jax.lax.scan(body, h, params["layers"])
-        return rms_norm(h, params["norm"], lcfg.norm_eps)
+            h, _ = jax.lax.scan(body, h, params["layers"])
+            return rms_norm(h, params["norm"], lcfg.norm_eps)
 
     # The (b, s, vocab) fp32 logits (and their log_softmax) are by far the
     # largest activations; computing the loss in sequence chunks under
@@ -487,17 +502,18 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, learning_rate: float = 3e-4,
         # the sp axis for sharding); position s-1 has no target and is masked
         # out instead of sliced off, so the chunking below divides evenly
         h = backbone(params, tokens)
-        b, s = tokens.shape
-        targets = jnp.concatenate(
-            [tokens[:, 1:], jnp.full((b, 1), -1, tokens.dtype)], axis=1)
-        mask = (targets >= 0).astype(jnp.float32)
-        denom = mask.sum()
-        if chunk and s % chunk == 0 and s > chunk:
-            hs = h.reshape(b, s // chunk, chunk, lcfg.dim).swapaxes(0, 1)
-            ts = targets.reshape(b, s // chunk, chunk).swapaxes(0, 1)
-            ms = mask.reshape(b, s // chunk, chunk).swapaxes(0, 1)
-            return _chunked_nll(params["lm_head"], hs, ts, ms) / denom
-        return _chunk_nll(_head(params["lm_head"]), h, targets, mask) / denom
+        with jax.named_scope("loss"):
+            b, s = tokens.shape
+            targets = jnp.concatenate(
+                [tokens[:, 1:], jnp.full((b, 1), -1, tokens.dtype)], axis=1)
+            mask = (targets >= 0).astype(jnp.float32)
+            denom = mask.sum()
+            if chunk and s % chunk == 0 and s > chunk:
+                hs = h.reshape(b, s // chunk, chunk, lcfg.dim).swapaxes(0, 1)
+                ts = targets.reshape(b, s // chunk, chunk).swapaxes(0, 1)
+                ms = mask.reshape(b, s // chunk, chunk).swapaxes(0, 1)
+                return _chunked_nll(params["lm_head"], hs, ts, ms) / denom
+            return _chunk_nll(_head(params["lm_head"]), h, targets, mask) / denom
 
     def init_state(key):
         params = init_params(cfg, key)
@@ -507,8 +523,9 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, learning_rate: float = 3e-4,
     def train_step(state, tokens):
         params, opt_state = state
         loss, grads = jax.value_and_grad(compute_loss)(params, tokens)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, opt_state), loss
 
     data_sharding = jax.sharding.NamedSharding(mesh, data_spec())

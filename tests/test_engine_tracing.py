@@ -44,10 +44,11 @@ def host_event_names(logdir):
     return {e.name for e in host_events(logdir)}
 
 
-def captured(tmp_path, eng, prompts):
-    """The engine's events, [(name, {argument: value})], while it serves
-    `prompts` one after the other, after one request outside the capture
-    (it compiles)."""
+def timed_events(tmp_path, eng, prompts, pause_s=0.05):
+    """The engine's events, [(name, {argument: value}, start_ns, end_ns)] by
+    start, while it serves `prompts` one after the other, left empty for
+    `pause_s` before each, after one request outside the capture (it
+    compiles)."""
     async def one(prompt):
         return [t async for t in eng.generate_stream(prompt, max_tokens=3)]
 
@@ -56,13 +57,21 @@ def captured(tmp_path, eng, prompts):
         jax.profiler.start_trace(str(tmp_path))
         try:
             for prompt in prompts:
+                await asyncio.sleep(pause_s)
                 await one(prompt)
         finally:
             await asyncio.to_thread(jax.profiler.stop_trace)
 
     asyncio.run(main())
-    return [(e.name, dict(e.stats)) for e in host_events(str(tmp_path))
-            if e.name.startswith("engine:")]
+    return sorted(((e.name, dict(e.stats), e.start_ns,
+                    e.start_ns + e.duration_ns)
+                   for e in host_events(str(tmp_path))
+                   if e.name.startswith("engine:")), key=lambda e: e[2])
+
+
+def captured(tmp_path, eng, prompts):
+    """`timed_events` without the times: [(name, {argument: value})]."""
+    return [(n, args) for n, args, _, _ in timed_events(tmp_path, eng, prompts)]
 
 
 # ---------------------------------------------------------------------------
@@ -111,6 +120,32 @@ def test_whole_prompt_admission_writes_every_phase(tmp_path):
             if n == _engine.PHASE_STEP} == {0}
     stats = eng.stats()
     assert stats["prefill_chunks"] == stats["steps_with_chunk"] == 0
+
+
+def test_an_empty_engines_wait_is_one_idle_phase_over_the_gap(tmp_path):
+    """Between two requests the engine is empty for `pause_s`: the loop's
+    wait for the next one is one `engine:idle` that covers the gap, and no
+    turn of the loop (no step, wait, emit, sweep or admission) overlaps an
+    idle phase: while a request is in the engine there is none. (The pause
+    before the first request may leave one too; a wait already under way
+    when a capture starts would not, an annotation is recorded only if it
+    began inside the profiler session.)"""
+    pause_s = 0.4
+    events = [(n, s, e) for n, _, s, e in timed_events(
+        tmp_path, small_engine(), [[20, 21, 22], [23, 24, 25]], pause_s)]
+    steps = [(s, e) for n, s, e in events if n == _engine.PHASE_STEP]
+    # the widest gap between two steps is the pause between the two requests
+    gap_lo, gap_hi = max(((a[1], b[0]) for a, b in zip(steps, steps[1:])),
+                         key=lambda g: g[1] - g[0])
+    idle = [e for e in events if e[0] == _engine.PHASE_IDLE]
+    between = [(s, e) for _, s, e in idle if gap_lo < s and e < gap_hi]
+    assert len(between) == 1 and len(idle) <= 2, idle
+    lo, hi = between[0]
+    assert 0.9 * pause_s <= (hi - lo) * 1e-9 <= (gap_hi - gap_lo) * 1e-9
+    for _, lo, hi in idle:
+        inside = [(n, s, e) for n, s, e in events
+                  if n != _engine.PHASE_IDLE and s < hi and e > lo]
+        assert not inside, inside
 
 
 def test_chunk_counters_account_for_every_prompt_token():

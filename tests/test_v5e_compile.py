@@ -306,3 +306,77 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
     assert m.temp_size_in_bytes < 0.2e9
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
+        on_v5e, topo):
+    """`jit_train_step` at the train cell's shapes (InternLM2-1.8B whole,
+    fsdp over the four described chips, one sequence of 4096 a chip, flash
+    kernels, `remat="dots"`): every matmul fusion of the v5e program (what
+    the device trace's `hlo_category` calls a `convolution fusion`) carries
+    one top-level name of `TRAIN_SCOPES` in its `op_name`, a layer's `attn`
+    or `mlp` under `layers`, and the recomputed ones `rematted_computation`
+    where there are any: what benchmark/lib/xmeta.py reads on the chip."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from jax.tree_util import keystr, tree_flatten_with_path
+
+    from ray_tpu.models.llama import (LlamaConfig, make_train_step,
+                                      param_specs)
+    from ray_tpu.parallel.mesh import MeshSpec, logical_to_sharding
+
+    cfg = LlamaConfig(
+        vocab_size=92544, dim=2048, n_layers=24, n_heads=16, n_kv_heads=8,
+        ffn_dim=8192, rope_theta=1e6, norm_eps=1e-5, max_seq_len=4096,
+        attention_impl="flash")
+    mesh = MeshSpec(fsdp=4).build(topo.devices)
+    init_state, _, step, data_sharding = make_train_step(
+        cfg, mesh, remat="dots")
+    shapes = jax.eval_shape(init_state, jax.random.key(0))
+    by_path = {keystr(path): s for (path, _), s in zip(
+        tree_flatten_with_path(shapes[0])[0],
+        jax.tree.leaves(logical_to_sharding(param_specs(cfg), mesh)))}
+
+    def placed(path, leaf):
+        # a moment lies as its parameter does (parallel.mesh.shard_train_state)
+        ks = keystr(path)
+        sharding = next((s for pk, s in by_path.items() if ks.endswith(pk)
+                         and leaf.ndim == len(s.spec)),
+                        NamedSharding(mesh, PartitionSpec()))
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
+
+    state = jax.tree_util.tree_map_with_path(placed, shapes)
+    tokens = jax.ShapeDtypeStruct((4, 4096), jnp.int32,
+                                  sharding=data_sharding)
+    hlo = step.trace(state, tokens).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert hlo.startswith("HloModule jit_train_step")
+    with_matmul, name = set(), None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m and not line.startswith(" "):
+            name = m.group(1)
+        elif " convolution(" in line:
+            with_matmul.add(name)
+    scopes = []
+    for line in hlo.splitlines():
+        called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        if called and called.group(1) in with_matmul:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            assert op_name, line[:200]
+            toks = [t for t in re.split(r"[/()]", op_name.group(1)) if t]
+            top = [t for t in toks if t in ("embed", "layers", "loss",
+                                           "optimizer")]
+            inner = [t for t in toks if t in ("attn", "mlp")]
+            assert len(top) == 1 and (top == ["layers"]) == (len(inner) == 1), (
+                op_name.group(1))
+            scopes.append((top[0], *inner, "transpose" in toks))
+    # a layer's seven matmuls forward, twice that backward; the head's
+    # forward, recomputed and two backward ones
+    assert len(scopes) >= 7 + 14 + 4, scopes
+    assert {("layers", "attn", False), ("layers", "attn", True),
+            ("layers", "mlp", False), ("layers", "mlp", True),
+            ("loss", False), ("loss", True)} == set(scopes)
+    # the flash kernels ride inside the scopes too
+    kernels = [line for line in hlo.splitlines() if PALLAS in line]
+    assert len(kernels) >= 3 and all(
+        re.search(r'op_name="[^"]*layers[^"]*attn', k) for k in kernels)
