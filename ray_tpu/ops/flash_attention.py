@@ -12,6 +12,15 @@ matrix in either direction:
   recompute p = exp(qkᵀ·scale − lse) from the saved logsumexp — no (s, s)
   residual is ever stored, which is what lets the surrounding model train
   without global rematerialization.
+* residuals: the backward reads five arrays, `q`, `k`, `v` (the caller's
+  projections: under a dots-saving `jax.checkpoint` policy they are saved
+  matmul outputs), the kernel's output `o` (bf16, q's shape) and its
+  logsumexp rows `lse` (float32, (b, h, s)). The last two come out of a
+  Pallas call, which is no dot, so a policy keeps them only by name:
+  `RESIDUAL_NAMES` (`jax.checkpoint_policies.save_only_these_names`).
+  Without the names a checkpointed layer runs the forward kernel a second
+  time in its backward pass; outside a `jax.checkpoint` they are the
+  identity. `models.llama.make_train_step`'s `remat="dots"` keeps both.
 
 Layout is (batch, heads, seq, head_dim) end-to-end ("bhsd"): head_dim rides
 the 128-wide lane dimension and no transposes are introduced around the
@@ -36,6 +45,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 _INTERPRET = False  # test-only: run the kernels in the Pallas interpreter
 
@@ -840,6 +850,15 @@ def flash_hop_bwd(q, k, v, g, lse, delta, causal,
 # ---------------------------------------------------------------------------
 
 
+# `jax.ad_checkpoint.checkpoint_name`s of the forward kernel's two results
+# as the backward rule reads them: what a `jax.checkpoint` policy must keep
+# (`save_only_these_names(*RESIDUAL_NAMES)`) for the backward pass not to
+# run `flash_fwd` again.
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
+RESIDUAL_NAMES = (FLASH_OUT, FLASH_LSE)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_bhsd(q, k, v, causal, block_q, block_k, bwd_block_q, bwd_block_k):
     if _kernel_path("flash_attention", q, k, block_q, block_k):
@@ -851,6 +870,10 @@ def _flash_fwd_rule(q, k, v, causal, block_q, block_k, bwd_block_q,
                     bwd_block_k):
     if _kernel_path("flash_attention", q, k, block_q, block_k):
         o, lse = _flash_fwd_tpu(q, k, v, causal, block_q, block_k)
+        # named before they part: the returned `o` and the residual `o` are
+        # then one variable, which is the one the backward reads
+        o = checkpoint_name(o, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
         return o, (q, k, v, o, lse)
     return _xla_attention_bhsd(q, k, v, causal), (q, k, v, None, None)
 

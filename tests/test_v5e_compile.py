@@ -3,6 +3,7 @@ chip's compiler shows is checked in tier-1 at no chip time. Keep every such
 test in this one file: the worker that runs it loads the TPU's library and
 holds it until it exits."""
 
+import functools
 import re
 
 import jax
@@ -308,15 +309,12 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
 
 
-def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
-        on_v5e, topo):
+@functools.lru_cache(maxsize=None)
+def train_step_for_v5e(topo, remat):
     """`jit_train_step` at the train cell's shapes (InternLM2-1.8B whole,
     fsdp over the four described chips, one sequence of 4096 a chip, flash
-    kernels, `remat="dots"`): every matmul fusion of the v5e program (what
-    the device trace's `hlo_category` calls a `convolution fusion`) carries
-    one top-level name of `TRAIN_SCOPES` in its `op_name`, a layer's `attn`
-    or `mlp` under `layers`, and the recomputed ones `rematted_computation`
-    where there are any: what benchmark/lib/xmeta.py reads on the chip."""
+    kernels) compiled for the described v5e, once a `remat` (~20 s each).
+    Call it under `on_v5e`."""
     from jax.sharding import NamedSharding, PartitionSpec
     from jax.tree_util import keystr, tree_flatten_with_path
 
@@ -330,7 +328,7 @@ def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
         attention_impl="flash")
     mesh = MeshSpec(fsdp=4).build(topo.devices)
     init_state, _, step, data_sharding = make_train_step(
-        cfg, mesh, remat="dots")
+        cfg, mesh, remat=remat)
     shapes = jax.eval_shape(init_state, jax.random.key(0))
     by_path = {keystr(path): s for (path, _), s in zip(
         tree_flatten_with_path(shapes[0])[0],
@@ -347,8 +345,27 @@ def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
     state = jax.tree_util.tree_map_with_path(placed, shapes)
     tokens = jax.ShapeDtypeStruct((4, 4096), jnp.int32,
                                   sharding=data_sharding)
-    hlo = step.trace(state, tokens).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
+    return step.trace(state, tokens).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+def flash_calls(hlo: str):
+    """[(kernel name, op_name)] of the flash kernels' custom calls in a
+    compiled module's text: the instruction is named after the kernel."""
+    return [(re.search(r"%(flash_[a-z]+)[.\w]* = ", line).group(1),
+             re.search(r'op_name="([^"]*)"', line).group(1))
+            for line in hlo.splitlines() if PALLAS in line]
+
+
+def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
+        on_v5e, topo):
+    """The train cell's step under `remat="dots"`: every matmul fusion of
+    the v5e program (what the device trace's `hlo_category` calls a
+    `convolution fusion`) carries one top-level name of `TRAIN_SCOPES` in
+    its `op_name`, a layer's `attn` or `mlp` under `layers`, and the
+    recomputed ones `rematted_computation` where there are any: what
+    benchmark/lib/xmeta.py reads on the chip."""
+    hlo = train_step_for_v5e(topo, "dots").as_text()
     assert hlo.startswith("HloModule jit_train_step")
     with_matmul, name = set(), None
     for line in hlo.splitlines():
@@ -380,3 +397,35 @@ def test_train_step_compiles_for_v5e_with_the_scopes_on_its_matmuls(
     kernels = [line for line in hlo.splitlines() if PALLAS in line]
     assert len(kernels) >= 3 and all(
         re.search(r'op_name="[^"]*layers[^"]*attn', k) for k in kernels)
+
+
+def test_train_step_under_dots_runs_each_flash_kernel_once_a_layer(
+        on_v5e, topo):
+    """`remat="dots"` keeps the forward kernel's `o` and `lse`
+    (`fa.RESIDUAL_NAMES`) through the `shard_map` around the kernel and the
+    layer scan: the v5e program holds one `flash_fwd`, one `flash_dq` and
+    one `flash_dkv` call (each once a scan), none of them recomputed, and
+    the stacked residuals (24 x 16.8 MB of `o`, 24 x 0.26 MB of `lse` a
+    chip) leave the step's temporaries under 11.0 GB (10.865; 10.451 with
+    the second `flash_fwd` in their place)."""
+    compiled = train_step_for_v5e(topo, "dots")
+    calls = flash_calls(compiled.as_text())
+    assert sorted(k for k, _ in calls) == ["flash_dkv", "flash_dq",
+                                           "flash_fwd"], calls
+    assert not [c for c in calls if "rematted_computation" in c[1]], calls
+    where = dict(calls)
+    assert "transpose(" not in where["flash_fwd"]
+    assert "transpose(" in where["flash_dq"]
+    assert "transpose(" in where["flash_dkv"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 11.0e9
+
+
+def test_train_step_under_full_remat_still_recomputes_the_forward_kernel(
+        on_v5e, topo):
+    """`remat=True` has no policy: the whole layer is recomputed in the
+    backward scan, its `flash_fwd` with it, whatever the names."""
+    calls = flash_calls(train_step_for_v5e(topo, True).as_text())
+    assert sorted(k for k, _ in calls) == ["flash_dkv", "flash_dq",
+                                           "flash_fwd", "flash_fwd"], calls
+    recomputed = [k for k, n in calls if "rematted_computation" in n]
+    assert recomputed == ["flash_fwd"], calls
