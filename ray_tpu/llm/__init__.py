@@ -30,7 +30,9 @@ MODEL_FAMILIES = {
     "llama": ("ray_tpu.models.llama", "LlamaConfig", "init_params",
               "ray_tpu.llm._engine:LLAMA_STEPS"),
     "ling": ("ray_tpu.models.ling", "LingConfig", "seeded_params",
-             "ray_tpu.llm._ling_steps")}
+             "ray_tpu.llm._ling_steps"),
+    "solar": ("ray_tpu.models.solar", "SolarConfig", "seeded_params",
+              "ray_tpu.llm._solar_steps")}
 
 
 def step_set(cfg):
@@ -71,7 +73,8 @@ class LLMConfig:
     model_id: str = "llama-tiny-random"
     # "<family>:<preset>": a model family of `MODEL_FAMILIES` and a preset
     # (a classmethod of its config class); a bare preset is the Llama
-    # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny"
+    # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny",
+    # "solar:solar_open2"
     model: str = "tiny"
     model_overrides: Dict[str, Any] = field(default_factory=dict)
     checkpoint_path: Optional[str] = None  # pickled params pytree

@@ -56,10 +56,11 @@ make_decode_step(cfg, ecfg) -> (step, path, note). `path` names the
     why a TPU was refused the kernel, or is None. The jitted step:
         paged_decode_step([C,] params, *cache, tables [B, max_blocks],
             lens [B], active [B], last_tok [B], keys [B, 2] uint32,
-            temps [B], prev [T], fed [B] [, chunk_ids [C], chunk_at [3]]
+            temps [B], prev [T], fed [B] [, chunk_ids [C], chunk_at [3 | 6]]
             [, probe_slot]) -> (toks [T], *cache [, probe])
     The static chunk width C leads iff the ladder is not empty, and the
-    chunk (`chunk_at`: slot, start position, real tokens) follows iff C > 0;
+    chunk (`chunk_at`: slot, start position, real tokens; with
+    `SNAPSHOT_STATE` three more, below) follows iff C > 0;
     `probe_slot` (a device scalar) and `probe` are there iff `PROBE` is not
     empty. `toks` is one int32 vector of one length T whatever C, fetched
     once a step: a token a slot, then `COUNTERS`, then iff the ladder is
@@ -83,13 +84,29 @@ COUNTERS  names of what the step counts on the device; `stats()` sums them.
 PROBE  keys of the dict the step returns last, fetched only while a checked
     request is in a slot (`check_routing`): "routing" [layers, B, ...] of
     every slot (the loop's prefill returns its twin, [layers, S, ...],
-    second), the others of slot `probe_slot` alone.
+    second; a step that carries a chunk its rows' as "chunk_routing" [layers,
+    C, ...]), the others of slot `probe_slot` alone, or under "chunk_<name>"
+    [layers, C, ...] of the chunk's rows.
 SLOT_STATE  by name, the cache array [layers, B, ...] a slot carries beside
     its blocks (an admission hands it to the new request; a probed request
     reads its slot's), or None where the blocks are all a sequence has.
 NO_PREFIX_CACHE  None where a block alone resumes a sequence, so the prefix
     cache may share it; else why not: what `prefix_cache=True` is refused
     with.
+SNAPSHOT_STATE  None, or by name the cache array [num_state_snapshots + 1,
+    layers, ...] that holds copies of single slots' `SLOT_STATE` (the step
+    set keeps whatever else a slot carries beside it): the family's answer
+    to "a block alone does not resume a sequence" that is not a refusal. The
+    prefix cache then owns the pool's entries (`llm/_prefix_cache.py`), a
+    match resumes at the deepest snapshot at or before its end and the
+    matched tokens behind it run again, and `chunk_at` has six numbers: the
+    three above, then the position from which the chunk's keys and values
+    are written (what lies before it is in shared blocks and runs again for
+    the state alone), the pool entry the slot's state is copied from before
+    the chunk's first row (-1: none; the step starts a prompt's position 0
+    from zeros), and the entry the slot's state is copied to after its last
+    (the last entry, which no snapshot owns, for none). A snapshot is taken
+    where a chunk ends on a multiple of the ladder's widest width.
 make_kv_inject(cfg, ecfg) -> the jitted, donating `paged_kv_inject(*cache,
     phys [nb], *blocks) -> cache` that seeds blocks `phys` from
     `generate_stream(prefilled=(*blocks, last_logits))`, one [layers, nb,
@@ -119,8 +136,8 @@ __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 # the names a step set has, all of them and no other (module docstring)
 STEP_SET = ("CACHE_NAMES", "alloc_cache", "step_params", "make_decode_step",
             "chunk_ladder", "make_prefill", "check_prefill", "COUNTERS",
-            "PROBE", "SLOT_STATE", "NO_PREFIX_CACHE", "make_kv_inject",
-            "extra_stats")
+            "PROBE", "SLOT_STATE", "NO_PREFIX_CACHE", "SNAPSHOT_STATE",
+            "make_kv_inject", "extra_stats")
 
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
@@ -164,6 +181,13 @@ SPAN_DECODE = "engine:decode"    # first token -> done
 # prompt of tens of ms), a dispatch and the rest of a decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
+# with state snapshots a request keeps, beside its two deepest, the deepest
+# on a multiple of this many widest chunks: eviction takes a leaf of every
+# idle chain a round, so a document idle between two questions can lose its
+# last few hundred tokens and both deep snapshots with them, and the next
+# question then reran the whole document (12k tokens, 2-4 times in a 51 s
+# window of the long-document cell on a v5e, PERF.md section 6, PR 46)
+SNAPSHOT_FAR = 8
 
 
 @dataclass
@@ -186,6 +210,11 @@ class EngineConfig:
     # resume a sequence (the step set's `NO_PREFIX_CACHE`) None turns it
     # off and True is refused
     prefix_cache: Optional[bool] = None
+    # entries of the pool of state snapshots the prefix cache keeps for a
+    # family whose slots carry a recurrent state (the step set's
+    # `SNAPSHOT_STATE`; its `alloc_cache` sizes an entry); fixed for the
+    # engine's life. Such a family's prefix cache needs at least two
+    num_state_snapshots: int = 0
 
 
 def sample_tokens(keys, logits, temps):
@@ -546,7 +575,7 @@ LLAMA_STEPS = types.SimpleNamespace(
     chunk_ladder=chunk_ladder,
     make_prefill=_make_prefill, check_prefill=_check_prefill,
     COUNTERS=(), PROBE=(), SLOT_STATE=None, NO_PREFIX_CACHE=None,
-    make_kv_inject=_make_kv_inject,
+    SNAPSHOT_STATE=None, make_kv_inject=_make_kv_inject,
     extra_stats=lambda cfg, cache, attn_positions_live: {})
 
 
@@ -586,6 +615,15 @@ class _Request:
     # prompt's block keys, for the cache
     cursor: int = 0
     block_keys: tuple = ()
+    # with state snapshots: where the matched blocks end (the chunks write
+    # no keys or values before it), the pool entry the first chunk starts
+    # from (-1: none; pinned until that chunk is dispatched) and the keys of
+    # the blocks whose snapshots this request took and keeps: its two
+    # deepest, oldest first, and the far one (`SNAPSHOT_FAR`)
+    cached_len: int = 0
+    restore: int = -1
+    snaps: tuple = ()
+    far_snap: Optional[bytes] = None
     # the caller's span (the `completions_stream` execution span) when the
     # request is traced; the three spans are recorded as its children
     trace_parent: Optional[dict] = None
@@ -610,10 +648,14 @@ def _request_key(req: _Request) -> Tuple[int, int]:
     return 0, (req.seed * 1000003 + req.rid) & 0xFFFFFFFF
 
 
+CHUNK_PROBE = "chunk_"     # a probe's keys that are of the chunk's rows
+
+
 def _mechanisms(probe: Dict[str, Any]) -> Dict[str, Any]:
     """Of a step's fetched probe, what it computed the probed slot's router
     and recurrence from."""
-    return {k: v for k, v in probe.items() if k != "routing"}
+    return {k: v for k, v in probe.items()
+            if k != "routing" and not k.startswith(CHUNK_PROBE)}
 
 
 @dataclass
@@ -679,10 +721,22 @@ class PagedEngine:
         self.slot_req: List[Optional[_Request]] = [None] * B
         from ray_tpu._private.config import GLOBAL_CONFIG
 
+        # the chunk widths the decode step takes. With a ladder a prompt is
+        # admitted in chunks that ride in the decode steps (`_admit_chunks`);
+        # with none whole, awaited in the loop (`_admit_whole`)
+        self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
         enabled = e.prefix_cache
-        if steps.NO_PREFIX_CACHE:
+        refusal = steps.NO_PREFIX_CACHE
+        # does a chunk say where it resumes (`chunk_at` of six)
+        self._resumes = steps.SNAPSHOT_STATE is not None
+        if self._resumes and e.num_state_snapshots < 2 and not refusal:
+            refusal = (
+                "prefix_cache=True with recurrent layers needs a pool of "
+                "state snapshots: num_state_snapshots >= 2, not "
+                f"{e.num_state_snapshots}")
+        if refusal:
             if enabled:
-                raise ValueError(steps.NO_PREFIX_CACHE)
+                raise ValueError(refusal)
             enabled = False
         if enabled is None:
             enabled = GLOBAL_CONFIG.get("llm_prefix_cache_enabled")
@@ -690,12 +744,19 @@ class PagedEngine:
         if enabled:
             from ray_tpu.llm._prefix_cache import PrefixCache
 
+            # an entry is a block: a cap under the pool's size would evict
+            # blocks that no admission needs (a 16k-token prompt is 1,024)
             self._prefix_cache = PrefixCache(
-                self.bs, GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"))
-        # the chunk widths the decode step takes. With a ladder a prompt is
-        # admitted in chunks that ride in the decode steps (`_admit_chunks`);
-        # with none whole, awaited in the loop (`_admit_whole`)
-        self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
+                self.bs, max(GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"),
+                             e.num_kv_blocks),
+                e.num_state_snapshots if self._resumes else 0)
+        # matched tokens run again because the deepest snapshot lay before
+        # the matched blocks' end; the positions a step's chunk attended (up
+        # to its end) and its query-key pairs (row i of a chunk from `at`
+        # sees at + i + 1 keys)
+        self.snapshot_rerun_tokens = 0
+        self.chunk_positions_live = 0
+        self.chunk_attn_pairs = 0
         self._alloc_device_state()
         # "paged_kernel" | "xla", fixed for the engine's life (stats())
         self._decode, self.decode_attention, self._decode_note = (
@@ -850,17 +911,31 @@ class PagedEngine:
         plen = len(req.prompt)
         hits: List[int] = []
         keys: List[bytes] = []
-        with jax.profiler.TraceAnnotation(PHASE_PREFIX_MATCH):
+        resume, restore = 0, -1
+        span = jax.profiler.TraceAnnotation(PHASE_PREFIX_MATCH)
+        with span:
+            # a check's request may ask to run from position 0 whatever is
+            # cached (`check_routing(cold=True)`)
+            cold = req.probe is not None and req.probe.get("cold")
             if cache is not None:
                 from ray_tpu.llm._prefix_cache import chain_keys
 
                 keys = chain_keys(req.prompt, self.bs)
+            if cache is not None and not cold:
                 # reuse is capped one token short of the prompt: the LAST
                 # prompt token must run through prefill locally or there
                 # are no logits to sample the first generated token from
                 hits = cache.match(keys[: (plen - 1) // self.bs])
             need_new = need - len(hits)
             fits = self._free_with_eviction(need_new)
+            resume = len(hits) * self.bs
+            if fits and self._resumes and hits:
+                # the blocks resume the sequence only from a snapshot of
+                # the slot's state: the deepest at or before their end
+                covered, restore = cache.deepest_snapshot(keys, len(hits))
+                resume = covered * self.bs
+            span.set_metadata(cached_len=len(hits) * self.bs,
+                              resume_from=resume)
         if not fits:
             if cache is not None:
                 cache.cancel_match(hits)
@@ -871,19 +946,32 @@ class PagedEngine:
         row[: need] = hits + blocks
         self.tables[slot] = row
         if self._ladder:
-            self._admit_chunks(req, slot, hits, keys)
+            self._admit_chunks(req, slot, hits, keys, resume, restore)
         else:
             self._admit_whole(req, slot, row, blocks)
         return True
 
     def _admit_chunks(self, req: _Request, slot: int, hits: List[int],
-                      keys: List[bytes]):
+                      keys: List[bytes], resume: int, restore: int):
         """With a chunk ladder an admission is bookkeeping only: the prompt
-        past the cached blocks runs as chunks of the coming steps
-        (`_next_chunk`). The slot is the request's from here, so the abort
-        sweep finds it."""
+        past the cached blocks (with state snapshots: past the snapshot
+        `restore` at position `resume`, at or before their end) runs as
+        chunks of the coming steps (`_next_chunk`). The slot is the
+        request's from here, so the abort sweep finds it."""
         req.slot, self.slot_req[slot] = slot, req
-        req.cursor, req.block_keys = len(hits) * self.bs, tuple(keys)
+        req.cursor, req.block_keys = resume, tuple(keys)
+        req.cached_len, req.restore = len(hits) * self.bs, restore
+        self.snapshot_rerun_tokens += req.cached_len - resume
+        if restore >= 0:
+            self._prefix_cache.pin_snapshot(restore)
+        if req.probe is not None:
+            req.probe["resume_from"] = resume
+            if "steps" in req.probe:
+                self._probe_admitted(req, slot)
+                state0 = req.probe["state0"]
+                req.probe["state0"] = (
+                    np.asarray(getattr(self, self._steps.SNAPSHOT_STATE)[
+                        restore]) if restore >= 0 else np.zeros_like(state0))
         self._prefilling.append(req)
         if hits:
             from ray_tpu.util.metrics import Counter
@@ -947,7 +1035,35 @@ class PagedEngine:
         n = min(len(req.prompt) - req.cursor, self._ladder[-1])
         return req, n, next(c for c in self._ladder if c >= n)
 
-    def _chunk_dispatched(self, req: _Request, n: int):
+    def _chunk_at(self, req: Optional[_Request], at: int, n: int):
+        """(the `chunk_at` a step is given for `n` tokens of `req`'s prompt
+        from position `at`, the pool entry it takes a snapshot into or -1).
+        `req` None: an idle chunk of no slot's (`warm_up`). A snapshot is
+        taken where the chunk ends on a multiple of the widest chunk, inside
+        the prompt's full blocks, at a block that has none yet."""
+        trash = self.ecfg.num_state_snapshots
+        if req is None:
+            return np.asarray(
+                [0, 0, 0] + ([0, -1, trash] if self._resumes else []),
+                np.int32), -1
+        if not self._resumes:
+            return np.asarray([req.slot, at, n], np.int32), -1
+        cache, take = self._prefix_cache, -1
+        end = at + n
+        if (cache is not None and end % self._ladder[-1] == 0
+                and end // self.bs <= len(req.block_keys)
+                and not cache.has_snapshot(req.block_keys[end // self.bs - 1])):
+            take = cache.reserve_snapshot()
+        restore = req.restore
+        if restore >= 0:
+            # the step copies it in: from here on it may be displaced
+            cache.pin_snapshot(restore, False)
+            req.restore = -1
+        return np.asarray(
+            [req.slot, at, n, req.cached_len, restore,
+             take if take >= 0 else trash], np.int32), take
+
+    def _chunk_dispatched(self, req: _Request, n: int, take: int = -1):
         """A step that carries `n` tokens of `req`'s prompt is on its way:
         move the cursor, offer the blocks it completes to the prefix cache
         (every step dispatched from here on runs after the one that writes
@@ -965,6 +1081,21 @@ class PagedEngine:
             full = req.cursor // self.bs
             self.free_blocks.extend(self._prefix_cache.register(
                 req.block_keys[:full], self.tables[slot][:full]))
+            key = req.block_keys[full - 1] if take >= 0 else None
+            if take >= 0 and self._prefix_cache.attach_snapshot(key, take):
+                # the step copies the slot's state into entry `take` after
+                # the chunk; of its own snapshots a request keeps the two
+                # deepest (one of them lies at most one widest chunk before
+                # any later prompt's shared blocks end) and the deepest on a
+                # multiple of `SNAPSHOT_FAR` widest chunks, which a tail
+                # trimmed by eviction falls back to
+                had = req.snaps + (req.far_snap,)
+                req.snaps = req.snaps[-1:] + (key,)
+                if req.cursor % (SNAPSHOT_FAR * self._ladder[-1]) == 0:
+                    req.far_snap = key
+                for k in had:
+                    if k is not None and k not in req.snaps + (req.far_snap,):
+                        self._prefix_cache.drop_snapshot(k)
         if req.cursor < len(req.prompt):
             return
         self._prefilling.popleft()
@@ -1045,6 +1176,10 @@ class PagedEngine:
                 req.probe["state"] = self._slot_state(slot)
         need = self._blocks_needed(req)
         cache = self._prefix_cache
+        if req.restore >= 0:
+            # released before its first chunk went
+            cache.pin_snapshot(req.restore, False)
+            req.restore = -1
         for b in self.tables[slot][:need]:
             b = int(b)
             if b == 0:
@@ -1307,7 +1442,8 @@ class PagedEngine:
             ends = at + n == len(admitting.prompt)
             ids = np.zeros((width,), np.int32)
             ids[:n] = admitting.prompt[at:at + n]
-            chunk_args = (ids, np.asarray([admitting.slot, at, n], np.int32))
+            chunk_at, take = self._chunk_at(admitting, at, n)
+            chunk_args = (ids, chunk_at)
             # the slot's row of keys and temperatures is idle until it
             # decodes: the step draws the request's first token with them
             self._rngs[admitting.slot] = _request_key(admitting)
@@ -1316,7 +1452,8 @@ class PagedEngine:
         lead = (width,) if self._ladder else ()
         rows = [(int(slot), self.slot_req[slot])
                 for slot in np.flatnonzero(self.active)]
-        probing = any(req.probe is not None for _, req in rows)
+        probing = (any(req.probe is not None for _, req in rows)
+                   or (chunk is not None and admitting.probe is not None))
         # the outer annotation names a device gap that straddles two of the
         # inner ones (else a Python frame and its line)
         with phase(PHASE_STEP, chunk=width):
@@ -1336,8 +1473,13 @@ class PagedEngine:
             # past the caches: what a check reads, kept only while a request
             # asks
             self._toks = toks
+            probe = rest[n_cache] if probing else None
+            if probing and self._probe_slot is None:
+                # nobody's mechanisms are recorded: the routing alone
+                probe = {k: v for k, v in probe.items()
+                         if k.endswith("routing")}
             self._flight = _Step(
-                toks, rest[n_cache] if probing else None, rows, chunk, ends,
+                toks, probe, rows, chunk, ends,
                 self._probe_slot, flight is not None,
                 int(self.lens[self.active].sum() + self.active.sum()))
             # what the host knows at dispatch it applies at dispatch
@@ -1347,7 +1489,9 @@ class PagedEngine:
             for _, req in rows:
                 self._row_dispatched(req)
             if chunk is not None:
-                self._chunk_dispatched(admitting, n)
+                self.chunk_positions_live += at + n
+                self.chunk_attn_pairs += n * at + n * (n + 1) // 2
+                self._chunk_dispatched(admitting, n, take)
             return self._fetch(flight)
 
     def _fetch(self, step: Optional[_Step]):
@@ -1389,6 +1533,14 @@ class PagedEngine:
         self.prefill_chunks += 1
         self.prefill_chunk_tokens += n
         self.prefill_chunk_pad_tokens += width - n
+        if req.probe is not None and probe is not None and not req.t_done:
+            # a checked request's prompt: its rows' routing and, for the one
+            # whose mechanisms are recorded, what the recurrence ran on
+            req.probe["routing"].append(probe[CHUNK_PROBE + "routing"][:, :n])
+            if "steps" in req.probe:
+                req.probe["chunks"].append({
+                    k[len(CHUNK_PROBE):]: v[:, :n] for k, v in probe.items()
+                    if k.startswith(CHUNK_PROBE) and k != CHUNK_PROBE + "routing"})
         if step.chunk_ends and not req.t_done:
             first, *stream = tail[len(self._step_counters):]
             if req.slot >= 0:
@@ -1429,12 +1581,14 @@ class PagedEngine:
         if not self._ladder:
             return
         host = self._step_inputs(jnp.asarray, self._toks)
+        probe = (self._probe_arg,) if self._steps.PROBE else ()
         for width in (0, *self._ladder):
             chunk = (jnp.zeros((width,), jnp.int32),
-                     jnp.zeros((3,), jnp.int32)) if width else ()
+                     jnp.asarray(self._chunk_at(None, 0, 0)[0])) if width else ()
             toks, *caches = self._decode(
-                width, self._step_params, *self._cache(), *host, *chunk)
-            self._set_cache(caches)
+                width, self._step_params, *self._cache(), *host, *chunk,
+                *probe)
+            self._set_cache(caches[:len(self._cache_names)])
             toks.block_until_ready()
 
     async def generate_stream(self, prompt_ids: List[int], *,
@@ -1493,7 +1647,8 @@ class PagedEngine:
         }
 
     async def check_routing(self, prompt_ids: List[int], max_tokens: int,
-                            mechanisms: bool = False) -> Dict[str, Any]:
+                            mechanisms: bool = False, cold: bool = False
+                            ) -> Dict[str, Any]:
         """One greedy request through the engine's loop, the timed path's
         own programs, with every expert layer's routing recorded: the tokens
         and, for each position computed (the prompt's, then one a decode
@@ -1506,23 +1661,30 @@ class PagedEngine:
         and what the slot carries beside its blocks (`SLOT_STATE`) as the
         prefill left it ("state0") and after the last step ("state"),
         [layers, ...]: a reference given the same inputs must arrive at the
-        same scores and state.
+        same scores and state. Where the prompt ran as chunks, "state0" is
+        the state its first chunk started from (a snapshot's, or zeros) and
+        "chunks" what each chunk's recurrence ran on, a dict a chunk of
+        [layers, tokens, ...].
+
+        `cold` runs the prompt from position 0 whatever the prefix cache
+        holds; "resume_from" says where it did start.
 
         A debug path beside `check_prefill`: a decode step computes these
         anyway and the loop fetches them only while such a request is in a
         slot."""
-        probe: Dict[str, Any] = {"routing": []}
+        probe: Dict[str, Any] = {"routing": [], "cold": cold}
         if mechanisms:
-            probe["steps"] = []
+            probe["steps"], probe["chunks"] = [], []
         toks = [t async for t in self.generate_stream(
             prompt_ids, max_tokens=max_tokens, probe=probe)]
-        out = {"token_ids": toks,
+        out = {"token_ids": toks, "resume_from": probe.get("resume_from", 0),
                "routing": np.concatenate(probe["routing"], axis=1)}
         if mechanisms:
             steps = probe.pop("steps")
             out.update({k: np.stack([st[k] for st in steps])
                         for k in (steps[0] if steps else ())})
             out["state0"], out["state"] = probe["state0"], probe["state"]
+            out["chunks"] = probe["chunks"]
         return out
 
     def step_hlo(self, prefill_lengths: List[int]) -> Dict[str, List[str]]:
@@ -1544,11 +1706,18 @@ class PagedEngine:
         cache = [shape(a) for a in self._cache()]
         host = self._step_inputs(shape, shape(self._toks))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
-        if self._steps.PROBE:
-            host.append(i32)          # the probed slot
-        lead = (0,) if self._ladder else ()
-        out = {"jit_paged_decode_step": [self._decode.lower(
-            *lead, step_params, *cache, *host).compile().as_text()]}
+        probe = [i32] if self._steps.PROBE else []     # the probed slot
+
+        def decode(*lead):
+            chunk = [jax.ShapeDtypeStruct((lead[0],), jnp.int32),
+                     shape(self._chunk_at(None, 0, 0)[0])] if any(lead) else []
+            return self._decode.lower(
+                *lead, step_params, *cache, *host, *chunk, *probe
+            ).compile().as_text()
+
+        # one program a chunk width, all under the one name
+        widths = [(c,) for c in (0, *self._ladder)] if self._ladder else [()]
+        out = {"jit_paged_decode_step": [decode(*w) for w in widths]}
         out["jit_paged_prefill"] = []
         for n in prefill_lengths:
             S = max(8, 1 << (n - 1).bit_length())
@@ -1610,6 +1779,14 @@ class PagedEngine:
         if self._decode_note:
             out["decode_attention_note"] = self._decode_note
         out.update(self._step_counters)
+        if self._resumes:
+            out.update({
+                name: getattr(cache, name, 0) for name in (
+                    "snapshots_taken", "snapshots_restored",
+                    "snapshots_evicted")})
+            out["snapshot_rerun_tokens"] = self.snapshot_rerun_tokens
+            out["chunk_positions_live"] = self.chunk_positions_live
+            out["chunk_attn_pairs"] = self.chunk_attn_pairs
         if not self._ladder:
             # reported where a prompt, or its bucket's compile, is awaited
             # inside a turn of the loop

@@ -58,6 +58,7 @@ NO_PREFIX_CACHE = (
     "prefix_cache=True with recurrent layers: a shared block of latents "
     "would need the recurrent state at its boundary, and nothing snapshots "
     "that state")
+SNAPSHOT_STATE = None
 
 
 def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:

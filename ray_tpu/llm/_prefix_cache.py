@@ -22,6 +22,18 @@ in the engine's device pool):
   is pinned (evicting a parent would leave unreachable children holding
   pool blocks forever).
 
+**State snapshots** (`num_snapshots` > 0; a model with recurrent layers,
+`llm/_engine`'s `SNAPSHOT_STATE`). A matched run of blocks resumes such a
+sequence only from a copy of its slot's state at or before the run's end. The
+copies live in a device pool of fixed size whose entry numbers this cache
+owns: a snapshot belongs to the cached block that *ends* at its position
+(`attach_snapshot`), `deepest_snapshot` finds the one to resume from, and it
+is evicted with that block, so it never outlives the blocks up to its
+position. When every entry is taken the least recently used snapshot gives
+its own up (`reserve_snapshot`), unless an admitted request is still to
+start from it (`pin_snapshot`). A snapshot that is gone shortens what a match
+can resume; it fails nothing.
+
 Pure host-side data structure: no asyncio, no JAX — unit-testable alone.
 All mutation happens from the engine's single admission/step context.
 """
@@ -58,15 +70,29 @@ class _Entry:
     parent: Optional[bytes] = None
     children: Set[bytes] = field(default_factory=set)
     last_use: int = 0            # LRU tick
+    snap: int = -1               # entry of the snapshot pool, -1: none
+    snap_use: int = 0            # the snapshot's own LRU tick
 
 
 class PrefixCache:
-    def __init__(self, block_size: int, max_entries: int = 4096):
+    def __init__(self, block_size: int, max_entries: int = 4096,
+                 num_snapshots: int = 0):
         self.block_size = int(block_size)
         self.max_entries = int(max_entries)
+        self.num_snapshots = int(num_snapshots)
+        self._free_snaps = list(range(self.num_snapshots))
+        self._snap_key: Dict[int, bytes] = {}   # pool entry -> its block's key
+        self._snap_pins: Dict[int, int] = {}    # pool entry -> admissions
+        self.snapshots_taken = 0
+        self.snapshots_restored = 0
+        self.snapshots_evicted = 0   # with their blocks, or displaced (LRU)
         self._entries: Dict[bytes, _Entry] = {}
         self._by_block: Dict[int, bytes] = {}
         self._tick = 0
+        # cached blocks no request holds, kept as a count: `stats()` reads
+        # it from the engine's loop while an admission, in its thread,
+        # changes the entries
+        self._idle = 0
         # counters surfaced through engine stats / the metrics plane
         self.hits = 0            # match() calls that reused >= 1 block
         self.block_hits = 0      # total blocks served from cache
@@ -86,6 +112,7 @@ class PrefixCache:
             e = self._entries.get(k)
             if e is None:
                 break
+            self._idle -= e.refs == 0
             e.refs += 1
             e.last_use = self._tick
             out.append(e.block)
@@ -99,6 +126,82 @@ class PrefixCache:
     def cancel_match(self, blocks: List[int]):
         for b in blocks:
             self.decref_block(b)
+
+    # -- state snapshots --------------------------------------------------
+
+    def deepest_snapshot(self, keys: List[bytes], n_blocks: int):
+        """(blocks, pool entry) of the deepest snapshot at or before the end
+        of the first `n_blocks` matched blocks of `keys`: the sequence can
+        resume at position `blocks * block_size` from that entry. (0, -1)
+        where none is left. Counts as a use of every snapshot on the run:
+        while a document is asked about, the shallower ones a trimmed tail
+        would fall back to stay as recent as the one that serves."""
+        self._tick += 1
+        blocks, entry = 0, -1
+        for i in range(n_blocks):
+            e = self._entries.get(keys[i])
+            if e is not None and e.snap >= 0:
+                e.snap_use = self._tick
+                blocks, entry = i + 1, e.snap
+        self.snapshots_restored += entry >= 0
+        return blocks, entry
+
+    def pin_snapshot(self, entry: int, pinned: bool = True):
+        """An admitted request will start from `entry` in a coming step:
+        until then (`pinned=False`) it is not displaced."""
+        n = self._snap_pins.get(entry, 0) + (1 if pinned else -1)
+        if n > 0:
+            self._snap_pins[entry] = n
+        else:
+            self._snap_pins.pop(entry, None)
+
+    def has_snapshot(self, key: bytes) -> bool:
+        e = self._entries.get(key)
+        return e is not None and e.snap >= 0
+
+    def reserve_snapshot(self) -> int:
+        """A pool entry to write a snapshot to: a free one, else the least
+        recently used snapshot's (never one an admission is pinned to).
+        -1 where the pool has none to give."""
+        if self._free_snaps:
+            return self._free_snaps.pop()
+        used = [(self._entries[k].snap_use, i)
+                for i, k in self._snap_key.items() if i not in self._snap_pins]
+        if not used:
+            return -1
+        entry = min(used)[1]
+        self._drop(self._snap_key[entry])
+        self.snapshots_evicted += 1
+        return self._free_snaps.pop()
+
+    def attach_snapshot(self, key: bytes, entry: int) -> bool:
+        """Pool entry `entry` (from `reserve_snapshot`) now holds the state
+        at the end of the cached block `key`. False, and the entry is free
+        again, where that block is not cached or already has one."""
+        e = self._entries.get(key)
+        if e is None or e.snap >= 0:
+            self._free_snaps.append(entry)
+            return False
+        self._tick += 1
+        e.snap, e.snap_use = entry, self._tick
+        self._snap_key[entry] = key
+        self.snapshots_taken += 1
+        return True
+
+    def drop_snapshot(self, key: bytes):
+        """Give up the snapshot of block `key`, if it has one (a request
+        recycling its own older ones). One that an admitted request is still
+        to start from stays, for `reserve_snapshot` or its block's eviction
+        to take later."""
+        e = self._entries.get(key)
+        if e is not None and e.snap >= 0 and e.snap not in self._snap_pins:
+            self._drop(key)
+
+    def _drop(self, key: bytes):
+        e = self._entries[key]
+        self._snap_key.pop(e.snap, None)
+        self._free_snaps.append(e.snap)
+        e.snap = -1
 
     # -- registration -----------------------------------------------------
 
@@ -150,6 +253,7 @@ class PrefixCache:
         if k is None:
             return False
         e = self._entries[k]
+        self._idle += e.refs == 1
         e.refs = max(0, e.refs - 1)
         return True
 
@@ -167,8 +271,8 @@ class PrefixCache:
 
     def evict(self, want: int) -> List[int]:
         """Free up to ``want`` blocks from zero-ref subtrees (LRU leaves
-        first, walking toward roots as leaves fall). Returns the physical
-        blocks for the engine's free list."""
+        first, walking toward roots as leaves fall). A block's snapshot goes
+        with it. Returns the physical blocks for the engine's free list."""
         freed: List[int] = []
         while len(freed) < want:
             leaves = self._evictable()
@@ -177,7 +281,13 @@ class PrefixCache:
             for k in leaves:
                 if len(freed) >= want:
                     break
-                e = self._entries.pop(k)
+                e = self._entries[k]
+                if e.snap >= 0:
+                    # a pinned one has a request on its block: refs > 0
+                    self._drop(k)
+                    self.snapshots_evicted += 1
+                del self._entries[k]
+                self._idle -= 1
                 self._by_block.pop(e.block, None)
                 if e.parent is not None and e.parent in self._entries:
                     self._entries[e.parent].children.discard(k)
@@ -191,6 +301,10 @@ class PrefixCache:
         blocks = [e.block for e in self._entries.values()]
         self._entries.clear()
         self._by_block.clear()
+        self._idle = 0
+        self._free_snaps = list(range(self.num_snapshots))
+        self._snap_key.clear()
+        self._snap_pins.clear()
         return blocks
 
     # -- introspection ----------------------------------------------------
@@ -203,10 +317,10 @@ class PrefixCache:
         children — i.e. every cached block no active request holds. The
         engine counts these as available capacity (repeated eviction
         rounds reach the whole zero-ref subtree)."""
-        return sum(1 for e in self._entries.values() if e.refs == 0)
+        return self._idle
 
     def stats(self) -> dict:
-        return {
+        out = {
             "entries": len(self._entries),
             "evictable": self.evictable_blocks(),
             "hits": self.hits,
@@ -214,3 +328,6 @@ class PrefixCache:
             "misses": self.misses,
             "evictions": self.evictions,
         }
+        if self.num_snapshots:
+            out["snapshots"] = len(self._snap_key)
+        return out

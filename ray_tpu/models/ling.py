@@ -23,6 +23,7 @@ the KDA gates, state and recurrence, softmax and the norms in float32.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -529,7 +530,13 @@ def ffn(cfg: LingConfig, p, x, live):
 # ---------------------------------------------------------------------------
 
 
-def balance_expert_bias(cfg: LingConfig, params, key):
+def _attention(cfg: LingConfig, p, x, live):
+    """A layer's attention on one whole sequence x [T, D] (normed)."""
+    return (kda_prefill(cfg, p, x, live)[0] if "conv" in p
+            else mla_prefill(cfg, p, x, live)[0])
+
+
+def balance_expert_bias(cfg, params, key, attention=None):
     """Set every expert layer's bias the way the published scheme trains it
     (auxiliary-loss-free balancing: after each batch, b_e moves by a fixed
     step towards the experts that got less than the mean load), on
@@ -540,7 +547,13 @@ def balance_expert_bias(cfg: LingConfig, params, key):
     the same few groups. Which groups is the seed's luck: the share of pairs
     on the experts a chip holds swung from 22 to 28% with the seed and a
     decode step's time with it (v5e, PR 29), where a trained model's router
-    is balanced by this very term."""
+    is balanced by this very term.
+
+    `attention(p, x, live)` is a layer's attention on the whole sequence:
+    this family's unless another family, whose layers carry the same router
+    and experts, gives its own (`models/solar.py`)."""
+    if attention is None:
+        attention = functools.partial(_attention, cfg)
     T = BALANCE_TOKENS
     tokens = jax.random.randint(key, (T,), 32, 127)           # printable bytes
     live = jnp.ones((T,), bool)
@@ -548,10 +561,8 @@ def balance_expert_bias(cfg: LingConfig, params, key):
     dt = cfg.dtype
     h = params["tok_emb"].astype(dt)[tokens]
     layers = []
-    for (attn, _), p in zip(cfg.kinds(), params["layers"]):
-        x = rms_norm(h, p["ln1"], cfg.norm_eps)
-        h = h + (kda_prefill(cfg, p, x, live)[0] if attn == "kda"
-                 else mla_prefill(cfg, p, x, live)[0])
+    for p in params["layers"]:
+        h = h + attention(p, rms_norm(h, p["ln1"], cfg.norm_eps), live)
         x = rms_norm(h, p["ln2"], cfg.norm_eps)
         if "router" in p:
             s = router_scores(cfg, p, x)

@@ -309,6 +309,70 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
 
 
+def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
+        on_v5e, monkeypatch):
+    """`jit_paged_decode_step` of the Solar-Open2 family at the cell's shapes
+    (published widths, one period of 4 layers, 40 held experts of 320, 16
+    slots, 32,768 blocks, 64 state snapshots) with the widest chunk: the
+    decode rows' attention is the paged kernel at 64 query heads on 8 KV
+    heads over the pool in place, the pool, the slots' state and the
+    snapshot pool are donated and not copied, the experts are grouped
+    matmuls, and the program fits the chip beside 6.6 GB of weights."""
+    from ray_tpu.llm import _solar_steps
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import solar
+
+    monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    cfg = solar.SolarConfig(
+        vocab_size=24576, n_layers=4, layer_ids=(4, 5, 6, 7), n_held=40,
+        max_seq_len=17408)
+    ecfg = EngineConfig(max_num_seqs=16, kv_block_size=16,
+                        num_kv_blocks=32768, max_model_len=17408,
+                        prefix_cache=True, num_state_snapshots=64)
+    assert cfg.kinds() == ["gqa", "kda", "kda", "kda"]
+    C = _solar_steps.chunk_ladder(ecfg)[-1]
+    assert C == 256
+    step, path, note = _solar_steps.make_decode_step(cfg, ecfg)
+    assert (path, note) == (pa.KERNEL, None)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: solar.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _solar_steps.alloc_cache(cfg, ecfg))]
+    B = 16
+    compiled = step.trace(
+        C, params, *caches, on_v5e((B, 1088), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((B,), jnp.bool_),
+        on_v5e((B,), jnp.int32), on_v5e((B, 2), jnp.uint32),
+        on_v5e((B,), jnp.float32),
+        on_v5e((B + len(_solar_steps.COUNTERS) + 3,), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((C,), jnp.int32),
+        on_v5e((6,), jnp.int32), on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines()
+               if PALLAS in line and "%paged_decode_attention" in line]
+    assert len(kernels) == 1 and "bf16[32769,128,128]" in kernels[0]
+    assert hlo.count("%ragged-dot-none") > 0
+    # neither pool nor the slots' state is copied whole
+    assert not re.findall(
+        r" copy\([^)]*(?:32769|3,16,64,128,128|65,3,64,128,128)", hlo)
+    m = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    assert 6.5e9 < weight_bytes < 6.8e9
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+    print("solar step: weights %.3f GB caches %.3f GB temp %.3f GB" % (
+        weight_bytes / 1e9, cache_bytes / 1e9, m.temp_size_in_bytes / 1e9))
+
+
 @functools.lru_cache(maxsize=None)
 def train_step_for_v5e(topo, remat):
     """`jit_train_step` at the train cell's shapes (InternLM2-1.8B whole,
