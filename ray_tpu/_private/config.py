@@ -251,7 +251,6 @@ _flag("serve_autoscale_demand_report", True, "Publish pending (unplaceable) repl
 
 # --- LLM prefix cache (llm/_prefix_cache.py; reference: vLLM automatic prefix caching / ray.llm kv_aware routing) ---
 _flag("llm_prefix_cache_enabled", True, "Block-granular prompt-prefix KV reuse in PagedEngine: full prompt blocks are content-hashed and refcounted across requests, so a shared-prefix request prefills only its suffix (the bench_llm A/B lever). Off = every request prefills from scratch.")
-_flag("llm_prefix_cache_max_entries", 4096, "Cap on cached prefix-block entries per engine (refcounted blocks in active use are never evicted; zero-ref LRU subtrees go first). Bounds host-side cache bookkeeping, not device KV memory — the paged pool itself is the real limit.")
 
 # --- serve ingress (proxy fleet; reference: Serve proxy_location) ---
 _flag("serve_proxy_location", "head", "Where serve.start() places HTTP ingress proxies when the caller passes none: 'head' = one proxy on the driver (one CPython event loop is the single-ingress SSE ceiling), 'every_node' = one 0-CPU proxy pinned per serving node (the bench_llm proxy-fleet lever: the fleet splits ingress dispatch across nodes).")

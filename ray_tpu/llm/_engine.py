@@ -744,12 +744,13 @@ class PagedEngine:
         if enabled:
             from ray_tpu.llm._prefix_cache import PrefixCache
 
-            # an entry is a block: a cap under the pool's size would evict
-            # blocks that no admission needs (a 16k-token prompt is 1,024)
             self._prefix_cache = PrefixCache(
-                self.bs, max(GLOBAL_CONFIG.get("llm_prefix_cache_max_entries"),
-                             e.num_kv_blocks),
-                e.num_state_snapshots if self._resumes else 0)
+                self.bs, e.num_state_snapshots if self._resumes else 0)
+        # requests admitted past `engine:prefix_match`, and the seconds spent
+        # inside it (hashing, matching, eviction; tries that found no room
+        # too): what an admission's bookkeeping costs, over a whole window
+        self.admissions = 0
+        self.admit_host_s = 0.0
         # matched tokens run again because the deepest snapshot lay before
         # the matched blocks' end; the positions a step's chunk attended (up
         # to its end) and its query-key pairs (row i of a chunk from `at`
@@ -913,6 +914,7 @@ class PagedEngine:
         keys: List[bytes] = []
         resume, restore = 0, -1
         span = jax.profiler.TraceAnnotation(PHASE_PREFIX_MATCH)
+        t_match = time.monotonic()
         with span:
             # a check's request may ask to run from position 0 whatever is
             # cached (`check_routing(cold=True)`)
@@ -936,11 +938,13 @@ class PagedEngine:
                 resume = covered * self.bs
             span.set_metadata(cached_len=len(hits) * self.bs,
                               resume_from=resume)
+        self.admit_host_s += time.monotonic() - t_match
         if not fits:
             if cache is not None:
                 cache.cancel_match(hits)
             return False
         req.t_admit = t_admit
+        self.admissions += 1
         blocks = [self.free_blocks.pop() for _ in range(need_new)]
         row = np.zeros((self.max_blocks,), np.int32)
         row[: need] = hits + blocks
@@ -1076,11 +1080,10 @@ class PagedEngine:
         if self._prefix_cache is not None:
             # every FULL prompt block in the pool (matched, then written by
             # the chunks so far) is cacheable; this request holds one ref on
-            # each until release. Cap-evicted zero-ref blocks return to the
-            # pool.
+            # each until release
             full = req.cursor // self.bs
-            self.free_blocks.extend(self._prefix_cache.register(
-                req.block_keys[:full], self.tables[slot][:full]))
+            self._prefix_cache.register(
+                req.block_keys[:full], self.tables[slot][:full])
             key = req.block_keys[full - 1] if take >= 0 else None
             if take >= 0 and self._prefix_cache.attach_snapshot(key, take):
                 # the step copies the slot's state into entry `take` after
@@ -1771,6 +1774,8 @@ class PagedEngine:
             "prefill_chunk_pad_tokens": self.prefill_chunk_pad_tokens,
             # one chunk a step: the same count until a step carries several
             "steps_with_chunk": self.prefill_chunks,
+            "admissions": self.admissions,
+            "admit_host_s": self.admit_host_s,
             "prefix_cache": cache.stats() if cache is not None else None,
             "attn_positions_live": self.attn_positions_live,
             "attn_positions_dense": self.attn_positions_dense,

@@ -18,9 +18,14 @@ in the engine's device pool):
   prefix survives between conversation turns) but becomes *evictable* —
   the engine reclaims LRU zero-ref blocks when the free list runs short,
   so caching never deadlocks admission.
-- eviction is leaf-first: a block whose chain-children are still cached
-  is pinned (evicting a parent would leave unreachable children holding
-  pool blocks forever).
+- eviction takes only blocks with no cached chain-child (evicting a
+  parent would leave unreachable children holding pool blocks forever),
+  the least recently used first, and a parent is next in line, at its own
+  age, the moment its last child goes: the oldest idle chain goes whole,
+  tail to head, before a more recent one loses a block. The order is kept
+  (a heap of childless zero-ref entries, at most one item an entry, its
+  age put right when it surfaces), so an eviction costs what it frees and
+  not a pass over the cache.
 
 **State snapshots** (`num_snapshots` > 0; a model with recurrent layers,
 `llm/_engine`'s `SNAPSHOT_STATE`). A matched run of blocks resumes such a
@@ -41,24 +46,28 @@ All mutation happens from the engine's single admission/step context.
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set
+import heapq
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 __all__ = ["PrefixCache", "chain_keys"]
 
 
-def chain_keys(prompt_ids: List[int], block_size: int) -> List[bytes]:
+def chain_keys(prompt_ids: Sequence[int], block_size: int) -> List[bytes]:
     """Chain hash per FULL block of the prompt: key_i commits to tokens
     [0, (i+1)*block_size) — equal keys mean equal whole prefixes, so a
-    match can splice the cached blocks in without comparing tokens."""
+    match can splice the cached blocks in without comparing tokens. The
+    ids are hashed as the bytes of one int64 array: a list, a tuple and an
+    integer array of the same ids give the same keys."""
+    raw = np.asarray(prompt_ids, np.int64).tobytes()
+    step = 8 * block_size
     keys: List[bytes] = []
     prev = b""
-    for start in range(0, len(prompt_ids) - block_size + 1, block_size):
-        chunk = prompt_ids[start:start + block_size]
-        h = hashlib.blake2b(digest_size=16)
-        h.update(prev)
-        h.update(b",".join(str(int(t)).encode() for t in chunk))
-        prev = h.digest()
+    for end in range(step, len(raw) + 1, step):
+        prev = hashlib.blake2b(
+            prev + raw[end - step:end], digest_size=16).digest()
         keys.append(prev)
     return keys
 
@@ -68,17 +77,17 @@ class _Entry:
     block: int                   # physical block id in the engine pool
     refs: int = 0                # admitted requests currently using it
     parent: Optional[bytes] = None
-    children: Set[bytes] = field(default_factory=set)
+    depth: int = 0               # blocks before it on its chain
+    children: int = 0            # cached entries whose parent it is
     last_use: int = 0            # LRU tick
+    queued: bool = False         # has its one item in the eviction heap
     snap: int = -1               # entry of the snapshot pool, -1: none
     snap_use: int = 0            # the snapshot's own LRU tick
 
 
 class PrefixCache:
-    def __init__(self, block_size: int, max_entries: int = 4096,
-                 num_snapshots: int = 0):
+    def __init__(self, block_size: int, num_snapshots: int = 0):
         self.block_size = int(block_size)
-        self.max_entries = int(max_entries)
         self.num_snapshots = int(num_snapshots)
         self._free_snaps = list(range(self.num_snapshots))
         self._snap_key: Dict[int, bytes] = {}   # pool entry -> its block's key
@@ -89,6 +98,10 @@ class PrefixCache:
         self._entries: Dict[bytes, _Entry] = {}
         self._by_block: Dict[int, bytes] = {}
         self._tick = 0
+        # the eviction order: (last_use, -depth, key) of entries that had no
+        # request and no cached child when they were put in (`_offer`), at
+        # most one item an entry; `evict` drops or re-files a stale one
+        self._heap: List[Tuple[int, int, bytes]] = []
         # cached blocks no request holds, kept as a count: `stats()` reads
         # it from the engine's loop while an admission, in its thread,
         # changes the entries
@@ -98,6 +111,8 @@ class PrefixCache:
         self.block_hits = 0      # total blocks served from cache
         self.misses = 0
         self.evictions = 0
+        self.evict_calls = 0
+        self.evict_examined = 0  # heap items popped, stale ones included
 
     # -- lookup -----------------------------------------------------------
 
@@ -205,17 +220,16 @@ class PrefixCache:
 
     # -- registration -----------------------------------------------------
 
-    def register(self, keys: List[bytes], blocks: List[int]) -> List[int]:
+    def register(self, keys: List[bytes], blocks: List[int]) -> None:
         """Cache a freshly prefilled prompt's full blocks. ``blocks[i]``
         holds the KV for chain key ``keys[i]``. Entries that already exist
         (the matched prefix, already ref'd by this request via match) are
         left alone; new tails are inserted with refs=1 — the registering
-        request's own ref. Returns blocks evicted to respect max_entries
-        (hand them back to the engine's free list)."""
-        evicted: List[int] = []
+        request's own ref. An entry is a block of the engine's pool, so the
+        pool's size is the only cap there is."""
         self._tick += 1
         prev: Optional[bytes] = None
-        for k, b in zip(keys, blocks):
+        for depth, (k, b) in enumerate(zip(keys, blocks)):
             e = self._entries.get(k)
             if e is not None:
                 # already cached (this request matched it, or an identical
@@ -231,18 +245,12 @@ class PrefixCache:
                 # the block->key map)
                 prev = None
                 continue
-            if len(self._entries) >= self.max_entries:
-                evicted.extend(self.evict(1))
-                if len(self._entries) >= self.max_entries:
-                    break  # everything left is pinned; stop caching
-            e = _Entry(block=int(b), refs=1, parent=prev,
-                       last_use=self._tick)
-            self._entries[k] = e
+            self._entries[k] = _Entry(block=int(b), refs=1, parent=prev,
+                                      depth=depth, last_use=self._tick)
             self._by_block[int(b)] = k
-            if prev is not None and prev in self._entries:
-                self._entries[prev].children.add(k)
+            if prev is not None:
+                self._entries[prev].children += 1
             prev = k
-        return evicted
 
     # -- release / eviction ----------------------------------------------
 
@@ -253,46 +261,58 @@ class PrefixCache:
         if k is None:
             return False
         e = self._entries[k]
-        self._idle += e.refs == 1
+        if e.refs == 1:
+            self._idle += 1
+            self._offer(k, e)
         e.refs = max(0, e.refs - 1)
         return True
 
     def owns_block(self, block: int) -> bool:
         return int(block) in self._by_block
 
-    def _evictable(self) -> List[bytes]:
-        """Zero-ref LEAF entries (no cached children), oldest first."""
-        out = [
-            k for k, e in self._entries.items()
-            if e.refs == 0 and not (e.children & self._entries.keys())
-        ]
-        out.sort(key=lambda k: self._entries[k].last_use)
-        return out
+    def _offer(self, k: bytes, e: _Entry) -> None:
+        """File `e` for eviction unless its item is in the heap already or a
+        cached child pins it. Called where the last request lets go of it
+        and where its last child is evicted; a match takes it back by its
+        refs alone, and `evict` sorts that out when the item surfaces."""
+        if not e.queued and not e.children:
+            e.queued = True
+            heapq.heappush(self._heap, (e.last_use, -e.depth, k))
 
     def evict(self, want: int) -> List[int]:
-        """Free up to ``want`` blocks from zero-ref subtrees (LRU leaves
-        first, walking toward roots as leaves fall). A block's snapshot goes
-        with it. Returns the physical blocks for the engine's free list."""
+        """Free up to ``want`` blocks that no request holds and no cached
+        child hangs on: least recently used first, the deeper of two of one
+        age first, and a parent in its turn, at its own age, once its last
+        child is gone (so the oldest idle chain goes whole before the next
+        loses a block). A block's snapshot goes with it. Returns the
+        physical blocks for the engine's free list. Costs the items it
+        pops: those it frees and the stale ones above them."""
+        self.evict_calls += 1
         freed: List[int] = []
-        while len(freed) < want:
-            leaves = self._evictable()
-            if not leaves:
-                break
-            for k in leaves:
-                if len(freed) >= want:
-                    break
-                e = self._entries[k]
-                if e.snap >= 0:
-                    # a pinned one has a request on its block: refs > 0
-                    self._drop(k)
-                    self.snapshots_evicted += 1
-                del self._entries[k]
-                self._idle -= 1
-                self._by_block.pop(e.block, None)
-                if e.parent is not None and e.parent in self._entries:
-                    self._entries[e.parent].children.discard(k)
-                freed.append(e.block)
-                self.evictions += 1
+        while len(freed) < want and self._heap:
+            last_use, _, k = heapq.heappop(self._heap)
+            self.evict_examined += 1
+            e = self._entries[k]
+            e.queued = False
+            if e.refs or e.children:
+                continue        # taken back since: filed again when let go
+            if e.last_use != last_use:
+                self._offer(k, e)   # used since it was filed: to its place
+                continue
+            if e.snap >= 0:
+                # a pinned one has a request on its block: refs > 0
+                self._drop(k)
+                self.snapshots_evicted += 1
+            del self._entries[k]
+            self._idle -= 1
+            del self._by_block[e.block]
+            parent = self._entries.get(e.parent)
+            if parent is not None:
+                parent.children -= 1
+                if not parent.refs:
+                    self._offer(e.parent, parent)
+            freed.append(e.block)
+            self.evictions += 1
         return freed
 
     def clear(self) -> List[int]:
@@ -301,6 +321,7 @@ class PrefixCache:
         blocks = [e.block for e in self._entries.values()]
         self._entries.clear()
         self._by_block.clear()
+        self._heap.clear()
         self._idle = 0
         self._free_snaps = list(range(self.num_snapshots))
         self._snap_key.clear()
@@ -315,8 +336,8 @@ class PrefixCache:
     def evictable_blocks(self) -> int:
         """Blocks reclaimable RIGHT NOW plus those pinned only by cached
         children — i.e. every cached block no active request holds. The
-        engine counts these as available capacity (repeated eviction
-        rounds reach the whole zero-ref subtree)."""
+        engine counts these as available capacity (one `evict` call walks
+        a zero-ref subtree from its leaves to its root)."""
         return self._idle
 
     def stats(self) -> dict:
@@ -327,6 +348,10 @@ class PrefixCache:
             "block_hits": self.block_hits,
             "misses": self.misses,
             "evictions": self.evictions,
+            # evict_examined / evictions near 1: an eviction costs what it
+            # frees
+            "evict_calls": self.evict_calls,
+            "evict_examined": self.evict_examined,
         }
         if self.num_snapshots:
             out["snapshots"] = len(self._snap_key)

@@ -46,7 +46,7 @@ def test_chain_keys_commit_to_whole_prefix():
 def test_match_increfs_and_cancel_returns():
     c = PrefixCache(block_size=4)
     keys = chain_keys([1, 2, 3, 4, 5, 6, 7, 8], 4)
-    assert c.register(keys, [10, 11]) == []
+    c.register(keys, [10, 11])
     # the registering request holds one ref per block
     assert c.evictable_blocks() == 0
     assert c.decref_block(10) and c.decref_block(11)
@@ -92,17 +92,187 @@ def test_eviction_is_leaf_first():
     c.cancel_match([7, 8])
 
 
-def test_register_cap_evicts_lru():
-    c = PrefixCache(block_size=4, max_entries=2)
-    a = chain_keys([1, 1, 1, 1], 4)
-    b = chain_keys([2, 2, 2, 2], 4)
-    d = chain_keys([3, 3, 3, 3], 4)
-    c.register(a, [10]); c.decref_block(10)
-    c.register(b, [11]); c.decref_block(11)
-    got = c.match(b); c.cancel_match(got)      # touch b: a is now LRU
-    evicted = c.register(d, [12])
-    assert evicted == [10]                     # cap held by evicting LRU a
-    assert c.owns_block(11) and c.owns_block(12)
+def _idle_chain(c, seed, n, first_block, bs=4):
+    """Chain `seed` of `n` blocks, cached in blocks first_block.., released."""
+    keys = chain_keys([seed] * (n * bs), bs)
+    blocks = list(range(first_block, first_block + n))
+    c.register(keys, blocks)
+    for b in blocks:
+        c.decref_block(b)
+    return keys, blocks
+
+
+def _touch(c, keys):
+    c.cancel_match(c.match(keys))
+
+
+@pytest.mark.parametrize("want", [3, 5, 6])
+def test_the_oldest_idle_chain_goes_whole_before_the_next_loses_a_block(want):
+    """Three idle chains; their ages are their last uses, not the order they
+    came in. Up to the oldest's length only the oldest loses blocks, tail
+    first; one more takes exactly one leaf of the second oldest."""
+    c = PrefixCache(block_size=4)
+    a, a_blocks = _idle_chain(c, 1, 4, 10)
+    b, b_blocks = _idle_chain(c, 2, 5, 20)
+    d, d_blocks = _idle_chain(c, 3, 3, 30)
+    for keys in (b, d, a):          # b is now the oldest, then d, then a
+        _touch(c, keys)
+    order = b_blocks[::-1] + d_blocks[::-1] + a_blocks[::-1]
+    assert c.evict(want) == order[:want]
+    left = 5 - min(want, 5)
+    got = c.match(b)
+    assert got == b_blocks[:left]
+    c.cancel_match(got)
+    assert len(c.match(d)) == (3 if want <= 5 else 2)
+    assert c.match(a) == a_blocks
+    # each leaf was filed at its first release: it surfaces once at that age
+    # and is filed again at its own
+    assert c.stats()["evict_examined"] == want + 3 and len(c) == 12 - want
+
+
+@pytest.mark.parametrize("want", [4, 8])
+def test_a_forks_tails_go_before_the_block_they_share(want):
+    """One document, two question tails, and a younger chain beside them:
+    both tails go (the older first, each tail first) before any shared
+    block, and the shared blocks follow in the same call once the second
+    tail has; the younger chain is not touched."""
+    c = PrefixCache(block_size=4)
+    doc = [7] * 16
+    q1 = chain_keys(doc + [1] * 8, 4)
+    q2 = chain_keys(doc + [2] * 8, 4)
+    assert q1[:4] == q2[:4] and q1[4] != q2[4]
+    c.register(q1, [0, 1, 2, 3, 4, 5])
+    got = c.match(q2)
+    assert got == [0, 1, 2, 3]
+    c.register(q2, got + [6, 7])
+    for b in [0, 1, 2, 3, 4, 5] + [0, 1, 2, 3, 6, 7]:
+        c.decref_block(b)
+    young, young_blocks = _idle_chain(c, 9, 3, 20)
+    assert c.evictable_blocks() == 11
+    assert c.evict(want) == [5, 4, 7, 6, 3, 2, 1, 0][:want]
+    assert len(c.match(q1)) == (4 if want == 4 else 0)
+    assert c.match(young) == young_blocks
+
+
+def test_a_chain_taken_back_is_never_freed_and_ages_from_its_release():
+    c = PrefixCache(block_size=4)
+    a, a_blocks = _idle_chain(c, 1, 3, 10)
+    b, b_blocks = _idle_chain(c, 2, 3, 20)
+    held = c.match(a)               # a, the older, is in use again
+    assert c.evict(1) == [22]       # its stale item is passed over
+    assert c.stats()["evict_examined"] == 2
+    c.cancel_match(held)            # idle again, younger than b now
+    assert c.evict(1) == [21]
+    held = c.match(a)
+    assert c.evict(10) == [20]      # all there is while a is held
+    assert c.evictable_blocks() == 0 and len(c) == 3
+    c.cancel_match(held)
+    assert c.evict(10) == a_blocks[::-1] and len(c) == 0
+
+
+def test_an_eviction_costs_the_blocks_it_frees_not_the_cache():
+    """40 idle chains of 800 blocks, a cache of 32,000 entries: freeing
+    1,024 looks at no more than twice that many heap items (at them and no
+    others: there is no pass over the entries), the oldest chain whole and
+    224 from the tail of the next."""
+    c = PrefixCache(block_size=2)
+    chains = [_idle_chain(c, seed, 800, 800 * seed, bs=2)
+              for seed in range(40)]
+    assert len(c) == c.evictable_blocks() == 32_000
+    freed = c.evict(1024)
+    s = c.stats()
+    assert s["evict_calls"] == 1 and s["evictions"] == 1024
+    assert 1024 <= s["evict_examined"] <= 2 * 1024
+    assert len(c) == c.evictable_blocks() == 32_000 - 1024
+    assert freed == chains[0][1][::-1] + chains[1][1][:-225:-1]
+
+
+def test_chain_keys_hash_the_ids_whatever_holds_them():
+    ids = [int(t) for t in np.random.RandomState(0).randint(0, 200_000, 70)]
+    keys = chain_keys(ids, 16)
+    assert len(keys) == 4 and len(set(keys)) == 4
+    assert all(len(k) == 16 for k in keys)
+    for same in (tuple(ids), np.asarray(ids, np.int32),
+                 np.asarray(ids, np.int64)):
+        assert chain_keys(same, 16) == keys
+    assert chain_keys(ids[:63], 16) == keys[:3]
+    assert chain_keys(ids[:15], 16) == []
+    for at in (0, 17, 40, 63):      # one id differs: every key from its block
+        other = list(ids)
+        other[at] += 1
+        got = chain_keys(other, 16)
+        first = at // 16
+        assert got[:first] == keys[:first]
+        assert all(g != k for g, k in zip(got[first:], keys[first:]))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_idle_count_and_the_leaf_rule_hold_over_random_traffic(seed):
+    """A few hundred admissions (match, take blocks, register), releases,
+    cancelled matches and evictions over prompts that share prefixes: the
+    kept count of idle blocks is the count of zero-reference entries after
+    every one, and every evicted block was idle and childless when it went.
+    """
+    rng = np.random.RandomState(seed)
+    bs = 2
+    c = PrefixCache(block_size=bs)
+    free = list(range(40))
+    live = []                       # blocks of the requests in flight
+
+    def check():
+        assert c.evictable_blocks() == sum(
+            e.refs == 0 for e in c._entries.values())
+
+    def evict(want):
+        refs = {e.block: e.refs for e in c._entries.values()}
+        kids = {}
+        for k, e in c._entries.items():
+            kids.setdefault(e.parent, set()).add(e.block)
+        key_of = dict((e.block, k) for k, e in c._entries.items())
+        parent_of = {e.block: e.parent for e in c._entries.values()}
+        idle = c.evictable_blocks()
+        freed = c.evict(want)
+        # every idle block is within reach: none is left filed nowhere
+        assert len(freed) == min(want, idle) == len(set(freed))
+        for b in freed:
+            assert refs[b] == 0 and not kids.get(key_of[b])
+            kids[parent_of[b]].discard(b)
+        free.extend(freed)
+
+    for _ in range(400):
+        op = rng.randint(5)
+        if op <= 1:                 # admit: a document, a question, a tail
+            prompt = ([int(rng.randint(4))] * (2 * bs * rng.randint(1, 5))
+                      + [10 + int(rng.randint(3))] * (bs * rng.randint(3))
+                      + [int(rng.randint(100))] * rng.randint(bs))
+            keys = chain_keys(prompt, bs)
+            hits = c.match(keys)
+            need = len(keys) + 1 - len(hits)
+            if len(free) < need:
+                evict(need - len(free))
+            if len(free) < need:
+                c.cancel_match(hits)
+            else:
+                blocks = hits + [free.pop() for _ in range(need)]
+                c.register(keys, blocks)
+                live.append(blocks)
+        elif op == 2 and live:      # a request ends
+            for b in live.pop(rng.randint(len(live))):
+                if not c.decref_block(b):
+                    free.append(b)
+        elif op == 3:               # an admission that found no room
+            c.cancel_match(c.match(chain_keys(
+                [int(rng.randint(4))] * (2 * bs * rng.randint(1, 5)), bs)))
+        elif op == 4:
+            evict(int(rng.randint(1, 12)))
+        check()
+        assert len(free) + len({b for r in live for b in r}
+                               | set(c._by_block)) == 40
+    for blocks in live:
+        for b in blocks:
+            c.decref_block(b)
+    evict(40)
+    assert len(c) == 0 and c.stats()["evictable"] == 0
 
 
 # -- engine integration -----------------------------------------------------

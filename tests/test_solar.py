@@ -374,6 +374,41 @@ def test_a_trimmed_tail_falls_back_to_the_far_snapshot(params, monkeypatch):
     assert again["token_ids"] == fresh["token_ids"]
 
 
+def test_a_new_document_takes_the_oldest_idle_one_whole(params):
+    """A pool of 22 blocks holds two documents of 10 cached blocks. The
+    third needs 12 and takes them from the first, the least recently used,
+    which goes whole; the second keeps its tail and its deep snapshot (a
+    leaf off every idle chain a round would cost it five blocks and the
+    snapshot at 128). A second question on the second and on the third then
+    resumes at 128 as on an untouched document, to a fresh engine's answer."""
+    ecfg = dataclasses.replace(ECFG, num_kv_blocks=22)
+    engine = PagedEngine(CFG, params, ecfg)
+    docs = [prompt(100 + i, 150) for i in range(3)]
+    first = [d + prompt(110 + i, 21) for i, d in enumerate(docs)]
+    again = [d + prompt(120 + i, 33) for i, d in enumerate(docs)]
+    serve(engine, first)
+    cache = engine._prefix_cache
+    assert not any(k in cache._entries for k in chain_keys(first[0], 16))
+    for p in first[1:]:
+        held = cache.match(chain_keys(p, 16))
+        assert len(held) == 10
+        cache.cancel_match(held)
+    s = engine.stats()
+    assert s["prefix_cache"]["evictions"] == 10 and s["admissions"] == 3
+    hits = s["prefix_cache"]["block_hits"]
+    warm = serve(engine, again[1:])
+    assert [w["resume_from"] for w in warm] == [128, 128]
+    s = engine.stats()
+    assert s["snapshots_restored"] == 2
+    assert s["snapshot_rerun_tokens"] == 2 * (144 - 128)
+    assert s["prefix_cache"]["block_hits"] - hits == 2 * 9
+    assert s["admissions"] == 5 and s["admit_host_s"] > 0
+    assert (s["prefix_cache"]["evict_examined"]
+            <= s["prefix_cache"]["evictions"] + s["prefix_cache"]["evict_calls"])
+    cold = serve(PagedEngine(CFG, params, ecfg), again[1:], cold=True)
+    assert [w["token_ids"] for w in warm] == [c["token_ids"] for c in cold]
+
+
 def test_the_prefix_cache_needs_a_snapshot_pool_and_ling_still_refuses(params):
     with pytest.raises(ValueError, match="num_state_snapshots"):
         PagedEngine(CFG, params, dataclasses.replace(
