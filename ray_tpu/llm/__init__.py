@@ -32,7 +32,9 @@ MODEL_FAMILIES = {
     "ling": ("ray_tpu.models.ling", "LingConfig", "seeded_params",
              "ray_tpu.llm._ling_steps"),
     "solar": ("ray_tpu.models.solar", "SolarConfig", "seeded_params",
-              "ray_tpu.llm._solar_steps")}
+              "ray_tpu.llm._solar_steps"),
+    "brumby": ("ray_tpu.models.brumby", "BrumbyConfig", "init_params",
+               "ray_tpu.llm._brumby_steps")}
 
 
 def step_set(cfg):
@@ -74,7 +76,7 @@ class LLMConfig:
     # "<family>:<preset>": a model family of `MODEL_FAMILIES` and a preset
     # (a classmethod of its config class); a bare preset is the Llama
     # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny",
-    # "solar:solar_open2"
+    # "solar:solar_open2", "brumby:brumby_14b"
     model: str = "tiny"
     model_overrides: Dict[str, Any] = field(default_factory=dict)
     checkpoint_path: Optional[str] = None  # pickled params pytree

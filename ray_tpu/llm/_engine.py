@@ -82,14 +82,16 @@ check_prefill(cfg, ecfg, prefill, params, prompt_ids) -> (got, ref): that
     forward pass's on the same prompt.
 COUNTERS  names of what the step counts on the device; `stats()` sums them.
 PROBE  keys of the dict the step returns last, fetched only while a checked
-    request is in a slot (`check_routing`): "routing" [layers, B, ...] of
-    every slot (the loop's prefill returns its twin, [layers, S, ...],
+    request is in a slot (`check_routing`): "routing" (a family with routed
+    experts) [layers, B, ...] of every slot (the loop's prefill returns its
+    twin, [layers, S, ...],
     second; a step that carries a chunk its rows' as "chunk_routing" [layers,
     C, ...]), the others of slot `probe_slot` alone, or under "chunk_<name>"
     [layers, C, ...] of the chunk's rows.
-SLOT_STATE  by name, the cache array [layers, B, ...] a slot carries beside
-    its blocks (an admission hands it to the new request; a probed request
-    reads its slot's), or None where the blocks are all a sequence has.
+SLOT_STATE  by name, the cache array [layers, B or more, ...] a slot carries
+    beside its blocks (an admission hands it to the new request; a probed
+    request reads its slot's), or None where the blocks are all a sequence
+    has.
 NO_PREFIX_CACHE  None where a block alone resumes a sequence, so the prefix
     cache may share it; else why not: what `prefix_cache=True` is refused
     with.
@@ -105,8 +107,31 @@ SNAPSHOT_STATE  None, or by name the cache array [num_state_snapshots + 1,
     the state alone), the pool entry the slot's state is copied from before
     the chunk's first row (-1: none; the step starts a prompt's position 0
     from zeros), and the entry the slot's state is copied to after its last
-    (the last entry, which no snapshot owns, for none). A snapshot is taken
-    where a chunk ends on a multiple of the ladder's widest width.
+    (the last entry, which no snapshot owns, for none).
+SNAPSHOT_WHERE  None without `SNAPSHOT_STATE`; else where a prompt leaves
+    snapshots, which follows from what an entry costs the family. "chunk":
+    an entry is small beside a prompt's blocks (Solar: 12.7 MB), so a prompt
+    leaves one wherever a chunk ends on a multiple of the ladder's widest
+    width and keeps its two deepest and a far one (`SNAPSHOT_FAR`). "match":
+    an entry is the whole of a sequence's memory (Brumby: 214 MB, what 8k
+    positions of keys and values would cost), so the pool holds a few and a
+    prompt leaves one only where prompts were seen to part: where its match
+    ended with no snapshot within a widest chunk of the end. It runs the
+    matched tokens behind the deepest one again, ends a chunk exactly on
+    the match's end and leaves the one snapshot there; every later prompt
+    behind the same blocks resumes from it with nothing to run again (a
+    resumed prompt's chunks are then cut elsewhere than a cold run's, so
+    its logits are the cold run's to rounding and not bit for bit). A
+    prompt in flight holds at most that one entry, and a match counts as a
+    use of the snapshot it resumes from alone, so what no prompt asks for
+    again is the first to be displaced. A prompt that shares blocks with
+    one still in chunks, which has not run them all yet, waits at the
+    queue's head until it has: it then matches the whole of what they
+    share and nothing is run a third time or snapshotted half way.
+    Where no array of `CACHE_NAMES` lies under the block table (Brumby), a
+    block is the prefix cache's name for a prefix and `num_kv_blocks` sizes
+    a list of integers: admission is bounded by the slots and
+    `max_model_len`.
 make_kv_inject(cfg, ecfg) -> the jitted, donating `paged_kv_inject(*cache,
     phys [nb], *blocks) -> cache` that seeds blocks `phys` from
     `generate_stream(prefilled=(*blocks, last_logits))`, one [layers, nb,
@@ -137,7 +162,7 @@ __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 STEP_SET = ("CACHE_NAMES", "alloc_cache", "step_params", "make_decode_step",
             "chunk_ladder", "make_prefill", "check_prefill", "COUNTERS",
             "PROBE", "SLOT_STATE", "NO_PREFIX_CACHE", "SNAPSHOT_STATE",
-            "make_kv_inject", "extra_stats")
+            "SNAPSHOT_WHERE", "make_kv_inject", "extra_stats")
 
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
@@ -575,7 +600,8 @@ LLAMA_STEPS = types.SimpleNamespace(
     chunk_ladder=chunk_ladder,
     make_prefill=_make_prefill, check_prefill=_check_prefill,
     COUNTERS=(), PROBE=(), SLOT_STATE=None, NO_PREFIX_CACHE=None,
-    SNAPSHOT_STATE=None, make_kv_inject=_make_kv_inject,
+    SNAPSHOT_STATE=None, SNAPSHOT_WHERE=None,
+    make_kv_inject=_make_kv_inject,
     extra_stats=lambda cfg, cache, attn_positions_live: {})
 
 
@@ -622,6 +648,9 @@ class _Request:
     # deepest, oldest first, and the far one (`SNAPSHOT_FAR`)
     cached_len: int = 0
     restore: int = -1
+    # `SNAPSHOT_WHERE` "match": the position, a block boundary, at which
+    # this prompt is to end a chunk and leave its one snapshot (0: nowhere)
+    take_at: int = 0
     snaps: tuple = ()
     far_snap: Optional[bytes] = None
     # the caller's span (the `completions_stream` execution span) when the
@@ -729,6 +758,9 @@ class PagedEngine:
         refusal = steps.NO_PREFIX_CACHE
         # does a chunk say where it resumes (`chunk_at` of six)
         self._resumes = steps.SNAPSHOT_STATE is not None
+        # does a prompt leave its snapshot where its match ended, and only
+        # there (`SNAPSHOT_WHERE`)
+        self._snap_at_match = steps.SNAPSHOT_WHERE == "match"
         if self._resumes and e.num_state_snapshots < 2 and not refusal:
             refusal = (
                 "prefix_cache=True with recurrent layers needs a pool of "
@@ -756,6 +788,8 @@ class PagedEngine:
         # to its end) and its query-key pairs (row i of a chunk from `at`
         # sees at + i + 1 keys)
         self.snapshot_rerun_tokens = 0
+        # requests that resumed from a snapshot another request's prompt left
+        self.snapshots_shared = 0
         self.chunk_positions_live = 0
         self.chunk_attn_pairs = 0
         self._alloc_device_state()
@@ -912,7 +946,7 @@ class PagedEngine:
         plen = len(req.prompt)
         hits: List[int] = []
         keys: List[bytes] = []
-        resume, restore = 0, -1
+        resume, restore, take_at = 0, -1, 0
         span = jax.profiler.TraceAnnotation(PHASE_PREFIX_MATCH)
         t_match = time.monotonic()
         with span:
@@ -923,18 +957,35 @@ class PagedEngine:
                 from ray_tpu.llm._prefix_cache import chain_keys
 
                 keys = chain_keys(req.prompt, self.bs)
-            if cache is not None and not cold:
+            # (`SNAPSHOT_WHERE` "match") what this prompt shares with one
+            # still in chunks is on its way: the head waits for it rather
+            # than run it again
+            waits = (cache is not None and not cold and self._snap_at_match
+                     and self._shared_on_its_way(keys))
+            if cache is not None and not cold and not waits:
                 # reuse is capped one token short of the prompt: the LAST
                 # prompt token must run through prefill locally or there
                 # are no logits to sample the first generated token from
                 hits = cache.match(keys[: (plen - 1) // self.bs])
             need_new = need - len(hits)
-            fits = self._free_with_eviction(need_new)
+            fits = not waits and self._free_with_eviction(need_new)
             resume = len(hits) * self.bs
             if fits and self._resumes and hits:
                 # the blocks resume the sequence only from a snapshot of
                 # the slot's state: the deepest at or before their end
-                covered, restore = cache.deepest_snapshot(keys, len(hits))
+                # ("chunk" calls it as it always was called)
+                covered, restore = (
+                    cache.deepest_snapshot(keys, len(hits), run=False)
+                    if self._snap_at_match
+                    else cache.deepest_snapshot(keys, len(hits)))
+                if restore >= 0 and cache.snapshot_owner(restore) != req.rid:
+                    self.snapshots_shared += 1
+                if (self._snap_at_match
+                        and resume - covered * self.bs > self._ladder[-1]):
+                    # prompts part at the match's end and no snapshot is
+                    # within a widest chunk of it: this one pays the rerun
+                    # and leaves one there
+                    take_at = resume
                 resume = covered * self.bs
             span.set_metadata(cached_len=len(hits) * self.bs,
                               resume_from=resume)
@@ -950,21 +1001,40 @@ class PagedEngine:
         row[: need] = hits + blocks
         self.tables[slot] = row
         if self._ladder:
-            self._admit_chunks(req, slot, hits, keys, resume, restore)
+            self._admit_chunks(req, slot, hits, keys, resume, restore,
+                               take_at)
         else:
             self._admit_whole(req, slot, row, blocks)
         return True
 
+    def _shared_on_its_way(self, keys: List[bytes]) -> bool:
+        """Is a prompt that is still in chunks yet to run blocks that the
+        prompt of `keys` shares with it? Its admission then waits (a
+        second of a closed loop's caller at most): admitted now it would
+        match what is registered so far, run the rest of the shared blocks
+        itself, and leave a snapshot where nobody parts."""
+        for r in self._prefilling:
+            if r.slot < 0:
+                continue
+            shared = next((i for i, (a, b) in enumerate(zip(keys, r.block_keys))
+                           if a != b), min(len(keys), len(r.block_keys)))
+            if shared * self.bs > r.cursor:
+                return True
+        return False
+
     def _admit_chunks(self, req: _Request, slot: int, hits: List[int],
-                      keys: List[bytes], resume: int, restore: int):
+                      keys: List[bytes], resume: int, restore: int,
+                      take_at: int = 0):
         """With a chunk ladder an admission is bookkeeping only: the prompt
         past the cached blocks (with state snapshots: past the snapshot
-        `restore` at position `resume`, at or before their end) runs as
-        chunks of the coming steps (`_next_chunk`). The slot is the
-        request's from here, so the abort sweep finds it."""
+        `restore` at position `resume`, at or before their end; `take_at`:
+        where it is to leave one of its own, 0 for nowhere) runs as chunks
+        of the coming steps (`_next_chunk`). The slot is the request's from
+        here, so the abort sweep finds it."""
         req.slot, self.slot_req[slot] = slot, req
         req.cursor, req.block_keys = resume, tuple(keys)
         req.cached_len, req.restore = len(hits) * self.bs, restore
+        req.take_at = take_at
         self.snapshot_rerun_tokens += req.cached_len - resume
         if restore >= 0:
             self._prefix_cache.pin_snapshot(restore)
@@ -1037,14 +1107,18 @@ class PagedEngine:
             return None
         req = self._prefilling[0]
         n = min(len(req.prompt) - req.cursor, self._ladder[-1])
+        if req.cursor < req.take_at:
+            # a chunk ends where the prompt is to leave its snapshot
+            n = min(n, req.take_at - req.cursor)
         return req, n, next(c for c in self._ladder if c >= n)
 
     def _chunk_at(self, req: Optional[_Request], at: int, n: int):
         """(the `chunk_at` a step is given for `n` tokens of `req`'s prompt
         from position `at`, the pool entry it takes a snapshot into or -1).
         `req` None: an idle chunk of no slot's (`warm_up`). A snapshot is
-        taken where the chunk ends on a multiple of the widest chunk, inside
-        the prompt's full blocks, at a block that has none yet."""
+        taken at a block that has none yet: by `SNAPSHOT_WHERE`, where the
+        chunk ends on a multiple of the widest chunk inside the prompt's full
+        blocks, or where it ends on the request's `take_at`."""
         trash = self.ecfg.num_state_snapshots
         if req is None:
             return np.asarray(
@@ -1054,8 +1128,10 @@ class PagedEngine:
             return np.asarray([req.slot, at, n], np.int32), -1
         cache, take = self._prefix_cache, -1
         end = at + n
-        if (cache is not None and end % self._ladder[-1] == 0
-                and end // self.bs <= len(req.block_keys)
+        here = (end == req.take_at if self._snap_at_match else
+                end % self._ladder[-1] == 0
+                and end // self.bs <= len(req.block_keys))
+        if (cache is not None and here
                 and not cache.has_snapshot(req.block_keys[end // self.bs - 1])):
             take = cache.reserve_snapshot()
         restore = req.restore
@@ -1085,7 +1161,8 @@ class PagedEngine:
             self._prefix_cache.register(
                 req.block_keys[:full], self.tables[slot][:full])
             key = req.block_keys[full - 1] if take >= 0 else None
-            if take >= 0 and self._prefix_cache.attach_snapshot(key, take):
+            if take >= 0 and self._prefix_cache.attach_snapshot(
+                    key, take, req.rid) and not self._snap_at_match:
                 # the step copies the slot's state into entry `take` after
                 # the chunk; of its own snapshots a request keeps the two
                 # deepest (one of them lies at most one widest chunk before
@@ -1525,8 +1602,9 @@ class PagedEngine:
                 self.rows_dropped += 1
                 continue
             if req.probe is not None:
-                req.probe["routing"].append(
-                    probe["routing"][:, slot:slot + 1])
+                if "routing" in probe:
+                    req.probe["routing"].append(
+                        probe["routing"][:, slot:slot + 1])
                 if slot == step.probe_slot:
                     req.probe["steps"].append(_mechanisms(probe))
             self._emit(req, int(toks[slot]))
@@ -1539,7 +1617,9 @@ class PagedEngine:
         if req.probe is not None and probe is not None and not req.t_done:
             # a checked request's prompt: its rows' routing and, for the one
             # whose mechanisms are recorded, what the recurrence ran on
-            req.probe["routing"].append(probe[CHUNK_PROBE + "routing"][:, :n])
+            if CHUNK_PROBE + "routing" in probe:
+                req.probe["routing"].append(
+                    probe[CHUNK_PROBE + "routing"][:, :n])
             if "steps" in req.probe:
                 req.probe["chunks"].append({
                     k[len(CHUNK_PROBE):]: v[:, :n] for k, v in probe.items()
@@ -1680,8 +1760,9 @@ class PagedEngine:
             probe["steps"], probe["chunks"] = [], []
         toks = [t async for t in self.generate_stream(
             prompt_ids, max_tokens=max_tokens, probe=probe)]
-        out = {"token_ids": toks, "resume_from": probe.get("resume_from", 0),
-               "routing": np.concatenate(probe["routing"], axis=1)}
+        out = {"token_ids": toks, "resume_from": probe.get("resume_from", 0)}
+        if probe["routing"]:
+            out["routing"] = np.concatenate(probe["routing"], axis=1)
         if mechanisms:
             steps = probe.pop("steps")
             out.update({k: np.stack([st[k] for st in steps])
@@ -1789,6 +1870,7 @@ class PagedEngine:
                 name: getattr(cache, name, 0) for name in (
                     "snapshots_taken", "snapshots_restored",
                     "snapshots_evicted")})
+            out["snapshots_shared"] = self.snapshots_shared
             out["snapshot_rerun_tokens"] = self.snapshot_rerun_tokens
             out["chunk_positions_live"] = self.chunk_positions_live
             out["chunk_attn_pairs"] = self.chunk_attn_pairs

@@ -83,6 +83,7 @@ class _Entry:
     queued: bool = False         # has its one item in the eviction heap
     snap: int = -1               # entry of the snapshot pool, -1: none
     snap_use: int = 0            # the snapshot's own LRU tick
+    snap_by: int = 0             # the request whose prompt left it
 
 
 class PrefixCache:
@@ -144,22 +145,32 @@ class PrefixCache:
 
     # -- state snapshots --------------------------------------------------
 
-    def deepest_snapshot(self, keys: List[bytes], n_blocks: int):
+    def deepest_snapshot(self, keys: List[bytes], n_blocks: int,
+                         run: bool = True):
         """(blocks, pool entry) of the deepest snapshot at or before the end
         of the first `n_blocks` matched blocks of `keys`: the sequence can
         resume at position `blocks * block_size` from that entry. (0, -1)
-        where none is left. Counts as a use of every snapshot on the run:
-        while a document is asked about, the shallower ones a trimmed tail
-        would fall back to stay as recent as the one that serves."""
+        where none is left. Counts as a use of every snapshot on the run
+        (`run`): while a document is asked about, the shallower ones a
+        trimmed tail would fall back to stay as recent as the one that
+        serves. Without `run`, of the one that serves alone."""
         self._tick += 1
-        blocks, entry = 0, -1
+        blocks, entry, found = 0, -1, None
         for i in range(n_blocks):
             e = self._entries.get(keys[i])
             if e is not None and e.snap >= 0:
-                e.snap_use = self._tick
-                blocks, entry = i + 1, e.snap
-        self.snapshots_restored += entry >= 0
+                if run:
+                    e.snap_use = self._tick
+                blocks, entry, found = i + 1, e.snap, e
+        if found is not None:
+            found.snap_use = self._tick
+            self.snapshots_restored += 1
         return blocks, entry
+
+    def snapshot_owner(self, entry: int) -> int:
+        """The request whose prompt left the snapshot in pool entry `entry`
+        (what `attach_snapshot` was told; 0 where it was told nothing)."""
+        return self._entries[self._snap_key[entry]].snap_by
 
     def pin_snapshot(self, entry: int, pinned: bool = True):
         """An admitted request will start from `entry` in a coming step:
@@ -189,16 +200,17 @@ class PrefixCache:
         self.snapshots_evicted += 1
         return self._free_snaps.pop()
 
-    def attach_snapshot(self, key: bytes, entry: int) -> bool:
+    def attach_snapshot(self, key: bytes, entry: int, rid: int = 0) -> bool:
         """Pool entry `entry` (from `reserve_snapshot`) now holds the state
-        at the end of the cached block `key`. False, and the entry is free
-        again, where that block is not cached or already has one."""
+        at the end of the cached block `key`, left by request `rid`. False,
+        and the entry is free again, where that block is not cached or
+        already has one."""
         e = self._entries.get(key)
         if e is None or e.snap >= 0:
             self._free_snaps.append(entry)
             return False
         self._tick += 1
-        e.snap, e.snap_use = entry, self._tick
+        e.snap, e.snap_use, e.snap_by = entry, self._tick, rid
         self._snap_key[entry] = key
         self.snapshots_taken += 1
         return True

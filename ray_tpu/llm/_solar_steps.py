@@ -65,6 +65,8 @@ SLOT_STATE = "state"
 # slot's state at or before its end: the prefix cache keeps them
 NO_PREFIX_CACHE = None
 SNAPSHOT_STATE = "snap_state"
+# an entry is 12.7 MB beside a document's blocks: one a widest chunk
+SNAPSHOT_WHERE = "chunk"
 
 
 def alloc_cache(cfg: solar.SolarConfig, ecfg) -> Tuple:

@@ -601,7 +601,7 @@ TOY_STEPS = types.SimpleNamespace(
     make_prefill=_toy_prefill, check_prefill=_toy_check_prefill,
     COUNTERS=("toy_rows",), PROBE=(), SLOT_STATE="bag",
     NO_PREFIX_CACHE="a bag of tokens is not in the blocks: none to share",
-    SNAPSHOT_STATE=None,
+    SNAPSHOT_STATE=None, SNAPSHOT_WHERE=None,
     make_kv_inject=_toy_no_inject,
     extra_stats=lambda cfg, cache, live: {"bag_bytes": cache[0].nbytes})
 TOY_ECFG = EngineConfig(max_num_seqs=2, kv_block_size=4, num_kv_blocks=16,

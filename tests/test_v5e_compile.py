@@ -373,6 +373,70 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
         weight_bytes / 1e9, cache_bytes / 1e9, m.temp_size_in_bytes / 1e9))
 
 
+def test_brumby_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
+        on_v5e, monkeypatch):
+    """`jit_paged_decode_step` of the Brumby family at the cell's shapes
+    (published widths, six layers, the whole vocabulary, 16 slots, 8 state
+    snapshots and the trash entry) with the widest chunk: the decode rows'
+    retention is the Pallas kernel `retention_step` over every slot's state
+    in place, no cache array has a block axis, the states and the snapshot
+    pool are donated and not copied, and the program fits the chip beside
+    7.1 GB of weights."""
+    from ray_tpu.llm import _brumby_steps
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import brumby
+    from ray_tpu.ops import power_retention as pr
+
+    monkeypatch.setattr(pr, "step_path", lambda: pr.KERNEL)
+    cfg = brumby.BrumbyConfig.brumby_14b(n_layers=6)
+    ecfg = EngineConfig(max_num_seqs=16, kv_block_size=16,
+                        num_kv_blocks=65536, max_model_len=32768,
+                        prefix_cache=True, num_state_snapshots=8)
+    C = _brumby_steps.chunk_ladder(ecfg)[-1]
+    assert C == 256
+    step, path, note = _brumby_steps.make_decode_step(cfg, ecfg)
+    assert (path, note) == (pr.KERNEL, None)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: brumby.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _brumby_steps.alloc_cache(cfg, ecfg))]
+    assert [c.shape for c in caches] == [
+        (6, 17, 8, 8704, 128), (6, 17, 8, 128, 128),
+        (9, 6, 8, 8704, 128), (9, 6, 8, 128, 128)]
+    B = 16
+    compiled = step.trace(
+        C, params, *caches, on_v5e((B, 2048), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((B,), jnp.bool_),
+        on_v5e((B,), jnp.int32), on_v5e((B, 2), jnp.uint32),
+        on_v5e((B,), jnp.float32),
+        on_v5e((B + len(_brumby_steps.COUNTERS) + 3,), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((C,), jnp.int32),
+        on_v5e((6,), jnp.int32), on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines()
+               if PALLAS in line and "%retention_step" in line]
+    assert len(kernels) == 1 and "f32[6,17,8,8704,128]" in kernels[0]
+    # neither the slots' states nor the snapshot pool is copied whole
+    assert not re.findall(r" copy\([^)]*(?:6,17,8,8704|9,6,8,8704)", hlo)
+    m = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    assert 7.0e9 < weight_bytes < 7.2e9 and 5.5e9 < cache_bytes < 5.7e9
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.3e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+    print("brumby step: weights %.3f GB caches %.3f GB temp %.3f GB" % (
+        weight_bytes / 1e9, cache_bytes / 1e9, m.temp_size_in_bytes / 1e9))
+
+
 @functools.lru_cache(maxsize=None)
 def train_step_for_v5e(topo, remat):
     """`jit_train_step` at the train cell's shapes (InternLM2-1.8B whole,
