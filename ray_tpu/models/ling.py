@@ -12,7 +12,7 @@ them (7 layers in the benchmark's cut): no stacked `lax.scan` as in
 **Held experts.** An expert layer is told which experts this chip holds,
 `[held_start, held_start + n_held)`. It routes over all `n_experts`, computes
 the part of the result its own experts give, for every token routed to them
-(rows sorted by expert, a grouped matmul, no capacity, no dropped token), adds
+(rows sorted by expert, a grouped SwiGLU, no capacity, no dropped token), adds
 the shared expert, and that partial sum goes on to the next layer. With every
 expert held it is the whole layer. On one chip the layer runs without its
 exchange; nothing stands in for the absent chips.
@@ -34,14 +34,13 @@ from jax import lax
 
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops import kda as kda_ops
+from ray_tpu.ops.grouped_ffn import grouped_ffn
 
 _HI = lax.Precision.HIGHEST
 NEG_INF = -1e30
 MLA_QUERY_BLOCK = 512
 BALANCE_TOKENS = 1024      # seeded tokens `balance_expert_bias` routes ...
 BALANCE_STEPS = 300        # ... and its updates of an expert layer's bias
-MOE_ROW_PIECE = 128        # rows a grouped matmul is given at once ...
-MOE_PIECES_UP_TO = 1024    # ... where a layer has at most this many pairs
 
 
 @dataclass(frozen=True)
@@ -449,37 +448,11 @@ def route(cfg: LingConfig, p, x):
 def _grouped_experts(cfg: LingConfig, p, rows, counts):
     """SwiGLU of each row's expert. rows [M, D] sorted by held expert, the
     rows of no held expert last; counts [n_held] rows per expert. Rows past
-    sum(counts) come back undefined.
-
-    The TPU's grouped matmul computes a whole row tile for every group it
-    meets in it, and tiles the rows by min(M, 512): a decode step's M = 512
-    pairs, a quarter of them held and an expert seldom holding two, paid
-    512 rows of work per touched expert (measured on v5e: 0.95 ms a matmul
-    against 0.36 ms for the touched weights' bytes). So a short batch is
-    cut into pieces of `MOE_ROW_PIECE` rows, each with its share of every
-    group, and a piece past the held rows is skipped."""
+    sum(counts) come back undefined. On a TPU one kernel that reads each
+    touched expert's weights once (`ops/grouped_ffn.py`)."""
     dt = cfg.dtype
-    w1, w3, w2 = (p[k].astype(dt) for k in ("e_w1", "e_w3", "e_w2"))
-
-    def experts(r, sizes):
-        up = jax.nn.silu(lax.ragged_dot(r, w1, sizes)) \
-            * lax.ragged_dot(r, w3, sizes)
-        return lax.ragged_dot(up, w2, sizes)
-
-    M, R = rows.shape[0], MOE_ROW_PIECE
-    if M > MOE_PIECES_UP_TO or M <= R:
-        return experts(rows, counts)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    pieces = []
-    for lo in range(0, M, R):
-        sizes = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
-        piece = rows[lo:lo + R]
-        pieces.append(lax.cond(
-            ends[-1] > lo, experts,
-            lambda r, _: jnp.zeros((r.shape[0], w2.shape[-1]), dt),
-            piece, sizes))
-    return jnp.concatenate(pieces, axis=0)
+    return grouped_ffn(rows, p["e_w1"].astype(dt), p["e_w3"].astype(dt),
+                       p["e_w2"].astype(dt), counts)
 
 
 def moe_held(cfg: LingConfig, p, x, live, shared: bool = True):
@@ -494,7 +467,7 @@ def moe_held(cfg: LingConfig, p, x, live, shared: bool = True):
         local = experts - cfg.held_start
         held = (local >= 0) & (local < n) & live[:, None]
         # rows sorted by held expert; what is not held sorts last and
-        # belongs to no group, so the grouped matmul never visits it
+        # belongs to no group, so the grouped SwiGLU never visits it
         flat = jnp.where(held, local, n).reshape(N * k)
         order = jnp.argsort(flat, stable=True)
         counts = jnp.sum(jax.nn.one_hot(flat, n, dtype=jnp.int32), axis=0)

@@ -17,6 +17,7 @@ from benchmark.lib import reference_ling as ref
 from benchmark.runners._inside_ling import ProgramWeightsLing
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
 from ray_tpu.models import ling
+from ray_tpu.ops import grouped_ffn
 from ray_tpu.ops import kda as kda_ops
 
 HP = dict(hidden_size=64, num_attention_heads=4, head_dim=16,
@@ -190,6 +191,29 @@ def test_the_four_shares_add_up(params, weights):
     want, _, _ = ref.moe(ref.spec_of(HP), x, weights.layer(2),
                          lambda lo, hi: weights.experts(2, lo, hi))
     np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("path", [grouped_ffn.XLA, grouped_ffn.KERNEL])
+def test_a_decode_steps_rows_through_the_grouped_swiglu_equal_the_reference(
+        params, weights, monkeypatch, path):
+    """64 rows, a decode step's: 128 pairs, an expert holding a handful,
+    four of the sixteen held so that most pairs sort past the held rows.
+    Through the twin, which is what runs off the TPU, and through the kernel
+    (in the interpreter)."""
+    monkeypatch.setattr(grouped_ffn, "_INTERPRET", path == grouped_ffn.KERNEL)
+    p = params["layers"][1]
+    cfg = dataclasses.replace(CFG, held_start=4, n_held=4)
+    mine = {**p, **{k: p[k][4:8] for k in ("e_w1", "e_w3", "e_w2")}}
+    assert grouped_ffn.ffn_path(mine["e_w1"]) == path
+    x = jax.random.normal(jax.random.PRNGKey(5), (64, 64))
+    live = jnp.arange(64) % 7 != 3            # a few empty slots
+    y, _, counters, _ = ling.moe_held(cfg, mine, x, live)
+    assert 0 < int(counters[1]) < int(counters[0]) == 2 * int(live.sum())
+    want, _, _ = ref.moe(ref.spec_of(HP)._replace(held_start=4, held=4), x,
+                         weights.layer(1),
+                         lambda lo, hi: weights.experts(1, 4 + lo, 4 + hi))
+    np.testing.assert_allclose(y[live], np.asarray(want)[np.asarray(live)],
+                               atol=1e-5)
 
 
 def test_engine_prefill_then_decode_equals_the_reference(engine, weights):

@@ -19,6 +19,7 @@ from benchmark.runners._inside_solar import ProgramWeightsSolar
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
 from ray_tpu.llm._prefix_cache import PrefixCache, chain_keys
 from ray_tpu.models import ling, solar
+from ray_tpu.ops import grouped_ffn
 
 HP = dict(hidden_size=64, num_attention_heads=8, num_key_value_heads=2,
           head_dim=16, linear_attn_config=dict(
@@ -149,14 +150,17 @@ def test_the_eight_shares_add_up(params, weights):
     np.testing.assert_allclose(total, want, atol=1e-5)
 
 
+@pytest.mark.parametrize("path", [grouped_ffn.XLA, grouped_ffn.KERNEL])
 def test_a_chunk_steps_rows_take_the_grouped_matmul_as_it_stands(
-        params, weights):
-    """A step of this family carries more pairs than Ling's grouped matmul
-    cuts into pieces (`ling.MOE_PIECES_UP_TO`): such rows go through it
-    whole, and the layer is the reference's."""
+        params, weights, monkeypatch, path):
+    """600 rows, more than any step of Ling's carries: the layer is the
+    reference's through the grouped SwiGLU's twin, which is what runs off the
+    TPU, and through its kernel (in the interpreter), whose rows past the
+    held ones come back undefined and are masked by `moe_held`."""
+    monkeypatch.setattr(grouped_ffn, "_INTERPRET", path == grouped_ffn.KERNEL)
     p = params["layers"][1]
+    assert grouped_ffn.ffn_path(p["e_w1"]) == path
     x = jax.random.normal(jax.random.PRNGKey(3), (600, 64))
-    assert 600 * CFG.top_k > ling.MOE_PIECES_UP_TO
     y = ling.moe_held(CFG, p, x, jnp.ones((600,), bool))[0]
     want, _, _ = rl.moe(SPEC._replace(held=16), x, weights.layer(1),
                         lambda lo, hi: weights.experts(1, lo, hi))

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops import grouped_ffn as gf
 from ray_tpu.ops import paged_attention as pa
 
 PALLAS = 'custom_call_target="tpu_custom_call"'
@@ -65,6 +66,25 @@ def pallas_calls(hlo: str):
         out[m.group(1)] = ([s for o in operands for s in o],
                            shape.findall(m.group(2)))
     return out
+
+
+def grouped_ffn_calls(hlo: str):
+    """[(operand shapes, result shapes)] of the held experts' kernel."""
+    return [shapes for name, shapes in pallas_calls(hlo).items()
+            if name.startswith("grouped_ffn")]
+
+
+def ling_cell():
+    """The Ling cell's model and engine shapes (published widths, 7 layers,
+    128 held experts, 64 slots, 16,384 blocks)."""
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import ling
+
+    return (ling.LingConfig(
+        vocab_size=39296, n_layers=7, layer_ids=(1, 6, 7, 8, 9, 10, 11),
+        first_k_dense=1, n_held=128, max_seq_len=4096),
+        EngineConfig(max_num_seqs=64, kv_block_size=16,
+                     num_kv_blocks=16384, max_model_len=4096))
 
 
 def attention_grad(spec):
@@ -263,18 +283,16 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
     the latent attention is the paged kernel over the pool in place (a latent
     640 wide in memory: at 576 the compiler laid the pool out blocks-innermost
     and copied it there and back every step, PR 29), the recurrent state and
-    the pool are donated and not copied, the experts are grouped matmuls, and
-    the program fits the chip beside 10.35 GB of weights."""
+    the pool are donated and not copied, an expert layer's held experts are
+    one call of the grouped kernel (no grouped-matmul custom call of XLA's
+    and no `conditional` around pieces of the rows is left), and the program
+    fits the chip beside 10.35 GB of weights."""
     from ray_tpu.llm import _ling_steps
-    from ray_tpu.llm._engine import EngineConfig
     from ray_tpu.models import ling
 
     monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
-    cfg = ling.LingConfig(
-        vocab_size=39296, n_layers=7, layer_ids=(1, 6, 7, 8, 9, 10, 11),
-        first_k_dense=1, n_held=128, max_seq_len=4096)
-    ecfg = EngineConfig(max_num_seqs=64, kv_block_size=16,
-                        num_kv_blocks=16384, max_model_len=4096)
+    monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    cfg, ecfg = ling_cell()
     step, path, note = _ling_steps.make_decode_step(cfg, ecfg)
     assert (path, note) == (pa.KERNEL, None)
 
@@ -298,13 +316,55 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
     kernels = [line for line in hlo.splitlines()
                if PALLAS in line and "%paged_decode_attention" in line]
     assert len(kernels) == 1 and "bf16[16385,16,640]" in kernels[0]
-    assert hlo.count("%ragged-dot-none") > 0
+    experts = grouped_ffn_calls(hlo)
+    assert len(experts) == cfg.moe_layers == 6
+    assert all(res == ["bf16[512,2560]"] and "bf16[128,2560,768]" in ops
+               for ops, res in experts)
+    assert "%ragged-dot-none" not in hlo and " conditional(" not in hlo
     # neither the pool nor the state is copied, whole or by layer
     assert not re.findall(r" copy\([^)]*(?:16385|64,32,128,128)", hlo)
     m = compiled.memory_analysis()
     cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.2e9
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+
+
+def test_ling_prefill_of_2048_tokens_compiles_for_v5e_with_the_grouped_kernel(
+        on_v5e, monkeypatch):
+    """`jit_paged_prefill` of the Ling family at the cell's widest bucket
+    (S = 2,048: 16,384 pairs a layer, the grouped kernel's widest call): the
+    kernel's blocks fit the chip's VMEM as the chip's compiler counts them,
+    every expert layer is one call, and the program fits beside the
+    weights."""
+    from ray_tpu.llm import _ling_steps
+    from ray_tpu.models import ling
+
+    monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    cfg, ecfg = ling_cell()
+    prefill = _ling_steps.make_prefill(cfg, ecfg)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: ling.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _ling_steps.alloc_cache(cfg, ecfg))]
+    S = 2048
+    compiled = prefill.trace(
+        S, params, *caches, on_v5e((256,), jnp.int32),
+        on_v5e((S,), jnp.int32), on_v5e((), jnp.int32),
+        on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_prefill")
+    experts = grouped_ffn_calls(hlo)
+    assert len(experts) == 6
+    assert all(res == [f"bf16[{S * cfg.top_k},2560]"] for _, res in experts)
+    assert "%ragged-dot-none" not in hlo
+    m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
 
@@ -316,13 +376,15 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     slots, 32,768 blocks, 64 state snapshots) with the widest chunk: the
     decode rows' attention is the paged kernel at 64 query heads on 8 KV
     heads over the pool in place, the pool, the slots' state and the
-    snapshot pool are donated and not copied, the experts are grouped
-    matmuls, and the program fits the chip beside 6.6 GB of weights."""
+    snapshot pool are donated and not copied, a layer's held experts are one
+    call of the grouped kernel over the chunk's and the slots' 2,176 pairs,
+    and the program fits the chip beside 6.6 GB of weights."""
     from ray_tpu.llm import _solar_steps
     from ray_tpu.llm._engine import EngineConfig
     from ray_tpu.models import solar
 
     monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
     cfg = solar.SolarConfig(
         vocab_size=24576, n_layers=4, layer_ids=(4, 5, 6, 7), n_held=40,
         max_seq_len=17408)
@@ -357,7 +419,11 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     kernels = [line for line in hlo.splitlines()
                if PALLAS in line and "%paged_decode_attention" in line]
     assert len(kernels) == 1 and "bf16[32769,128,128]" in kernels[0]
-    assert hlo.count("%ragged-dot-none") > 0
+    experts = grouped_ffn_calls(hlo)
+    assert len(experts) == cfg.n_layers == 4
+    assert all(res == ["bf16[2176,4096]"] and "bf16[40,4096,1280]" in ops
+               for ops, res in experts)
+    assert "%ragged-dot-none" not in hlo
     # neither pool nor the slots' state is copied whole
     assert not re.findall(
         r" copy\([^)]*(?:32769|3,16,64,128,128|65,3,64,128,128)", hlo)
