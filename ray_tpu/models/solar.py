@@ -51,10 +51,6 @@ from ray_tpu.ops import kda as kda_ops
 
 NEG_INF = -1e30
 GQA_QUERY_BLOCK = 512
-# positions `ops/kda.kda_chunked` takes at once inside a prompt chunk: at the
-# published widths 256 rows take it 1.90 ms a layer at 32, 2.27 at 64, 1.77 at
-# 16 and 4.05 at 128 (v5e, PERF.md section 6, PR 46)
-KDA_CHUNK = 32
 
 
 @dataclass(frozen=True)
@@ -252,8 +248,7 @@ def kda_block(cfg: SolarConfig, p, x, state, tail, chunk=None):
             # a padded row leaves the state as it was
             o_c, state_c = kda_ops.kda_chunked(
                 q[B:], k[B:], v[B:], jnp.where(valid[:, None, None], g[B:], 0.0),
-                jnp.where(valid[:, None], beta[B:], 0.0), state_c,
-                chunk=KDA_CHUNK)
+                jnp.where(valid[:, None], beta[B:], 0.0), state_c)
             o = jnp.concatenate([o, o_c])
             # the K-1 inputs before row n: the old tail's where n < K-1
             before = jnp.concatenate([tail_c.astype(pre.dtype), pre[B:]])

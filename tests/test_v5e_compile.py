@@ -12,6 +12,7 @@ import pytest
 
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops import grouped_ffn as gf
+from ray_tpu.ops import kda
 from ray_tpu.ops import paged_attention as pa
 
 PALLAS = 'custom_call_target="tpu_custom_call"'
@@ -72,6 +73,74 @@ def grouped_ffn_calls(hlo: str):
     """[(operand shapes, result shapes)] of the held experts' kernel."""
     return [shapes for name, shapes in pallas_calls(hlo).items()
             if name.startswith("grouped_ffn")]
+
+
+def assert_the_chunk_pass_is_the_kernel(hlo: str, calls: int, rows: int,
+                                        heads: int):
+    """The delta rule's chunk pass in a compiled program: one call of
+    `kda_chunk` a KDA layer over all its rows, from the rows as the layer's
+    matmuls leave them, and nothing of `kda_chunked_xla`: no triangular
+    solve, no `[heads, C, C, 128]` tensor of decays in HBM, no loop over
+    chunks."""
+    results = [res for name, (_, res) in pallas_calls(hlo).items()
+               if name.startswith("kda_chunk")]
+    assert results == [[f"f32[{rows},{heads},128]",
+                        f"f32[{heads},128,128]"]] * calls
+    assert "triangular-solve" not in hlo and "TriangularSolve" not in hlo
+    assert not re.findall(r"f32\[(?:\d+,)?%d,(\d+),\1,128\]" % heads, hlo)
+    assert not re.search(r'while\([^\n]*op_name="[^"]*/kda/', hlo)
+
+
+def sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in (value if isinstance(value, (list, tuple)) else [value]):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def equations(jaxpr) -> int:
+    """Equations of a traced program, those of its loops' and branches'
+    bodies counted once each."""
+    return sum(1 + sum(equations(sub) for sub in sub_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+def pallas_programs(jaxpr, name: str):
+    """The programs of the `pallas_call`s named `name` under a traced
+    program."""
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == name):
+            yield eqn.params["jaxpr"]
+        for sub in sub_jaxprs(eqn):
+            yield from pallas_programs(sub, name)
+
+
+def assert_one_lowering_of_the_chunk_pass(text: str, calls: int):
+    """A program's KDA layers share one lowering of the kernel: one private
+    function that holds the one custom call, called once a layer."""
+    assert text.count("func.func private @_kda_chunk_kernel(") == 1
+    assert len(re.findall(r"call @_kda_chunk_kernel\(", text)) == calls
+    assert text.count('kernel_name = "kda_chunk"') == 1
+
+
+# what `kda_chunk`'s traced program holds (PR 50's held 2,993, and cost every
+# program that holds it ~2.9 s of a run's set-up, cache or no cache): a count,
+# which no machine's load moves. One that grows past this is a finding.
+KDA_CHUNK_EQUATIONS = 750
+
+
+@pytest.mark.parametrize("heads,rows", [(32, 512), (64, 256)])
+def test_the_chunk_pass_is_a_small_program(heads, rows):
+    """The size pin: whatever the rows and heads, the kernel's body is
+    traced for one turn of heads and one chunk, and stays small enough to
+    trace and lower in a fraction of a second a program."""
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    traced = jax.make_jaxpr(kda._kda_chunk_kernel)(
+        *[f32(rows, heads, 128)] * 4, f32(rows, heads), f32(heads, 128, 128))
+    (program,) = pallas_programs(traced.jaxpr, "kda_chunk")
+    assert equations(program) <= KDA_CHUNK_EQUATIONS
 
 
 def ling_cell():
@@ -342,6 +411,7 @@ def test_ling_prefill_of_2048_tokens_compiles_for_v5e_with_the_grouped_kernel(
     from ray_tpu.models import ling
 
     monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    monkeypatch.setattr(kda, "chunk_path", lambda *a: kda.KERNEL)
     cfg, ecfg = ling_cell()
     prefill = _ling_steps.make_prefill(cfg, ecfg)
 
@@ -353,20 +423,24 @@ def test_ling_prefill_of_2048_tokens_compiles_for_v5e_with_the_grouped_kernel(
     caches = [spec(c) for c in jax.eval_shape(
         lambda: _ling_steps.alloc_cache(cfg, ecfg))]
     S = 2048
-    compiled = prefill.trace(
+    lowered = prefill.trace(
         S, params, *caches, on_v5e((256,), jnp.int32),
         on_v5e((S,), jnp.int32), on_v5e((), jnp.int32),
         on_v5e((), jnp.int32),
-    ).lower(lowering_platforms=("tpu",)).compile()
+    ).lower(lowering_platforms=("tpu",))
+    assert_one_lowering_of_the_chunk_pass(lowered.as_text(), 6)
+    compiled = lowered.compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_prefill")
     experts = grouped_ffn_calls(hlo)
     assert len(experts) == 6
     assert all(res == [f"bf16[{S * cfg.top_k},2560]"] for _, res in experts)
     assert "%ragged-dot-none" not in hlo
+    assert_the_chunk_pass_is_the_kernel(hlo, 6, S, 32)
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert m.temp_size_in_bytes <= 0.541e9  # what the XLA form's prefill took
 
 
 def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
@@ -385,6 +459,7 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
 
     monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
     monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    monkeypatch.setattr(kda, "chunk_path", lambda *a: kda.KERNEL)
     cfg = solar.SolarConfig(
         vocab_size=24576, n_layers=4, layer_ids=(4, 5, 6, 7), n_held=40,
         max_seq_len=17408)
@@ -405,7 +480,7 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     caches = [spec(c) for c in jax.eval_shape(
         lambda: _solar_steps.alloc_cache(cfg, ecfg))]
     B = 16
-    compiled = step.trace(
+    lowered = step.trace(
         C, params, *caches, on_v5e((B, 1088), jnp.int32),
         on_v5e((B,), jnp.int32), on_v5e((B,), jnp.bool_),
         on_v5e((B,), jnp.int32), on_v5e((B, 2), jnp.uint32),
@@ -413,7 +488,9 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
         on_v5e((B + len(_solar_steps.COUNTERS) + 3,), jnp.int32),
         on_v5e((B,), jnp.int32), on_v5e((C,), jnp.int32),
         on_v5e((6,), jnp.int32), on_v5e((), jnp.int32),
-    ).lower(lowering_platforms=("tpu",)).compile()
+    ).lower(lowering_platforms=("tpu",))
+    assert_one_lowering_of_the_chunk_pass(lowered.as_text(), 3)
+    compiled = lowered.compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_decode_step")
     kernels = [line for line in hlo.splitlines()
@@ -424,6 +501,7 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     assert all(res == ["bf16[2176,4096]"] and "bf16[40,4096,1280]" in ops
                for ops, res in experts)
     assert "%ragged-dot-none" not in hlo
+    assert_the_chunk_pass_is_the_kernel(hlo, 3, C, 64)
     # neither pool nor the slots' state is copied whole
     assert not re.findall(
         r" copy\([^)]*(?:32769|3,16,64,128,128|65,3,64,128,128)", hlo)
@@ -435,6 +513,7 @@ def test_solar_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     assert m.alias_size_in_bytes >= cache_bytes
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
+    assert m.temp_size_in_bytes <= 0.34e9   # what the XLA form's step took
     print("solar step: weights %.3f GB caches %.3f GB temp %.3f GB" % (
         weight_bytes / 1e9, cache_bytes / 1e9, m.temp_size_in_bytes / 1e9))
 
