@@ -249,9 +249,6 @@ _flag("serve_autoscale_upscale_delay_s", 0.0, "How long demand must exceed the c
 _flag("serve_autoscale_downscale_delay_s", 10.0, "Scale-down cooldown: the autoscaler only sheds replicas after demand has stayed below the current count for this long, and sizes to the PEAK demand seen inside the window — hysteresis so a sawtooth load doesn't thrash replica churn.")
 _flag("serve_autoscale_demand_report", True, "Publish pending (unplaceable) replica resource shapes through the report_demand plane so the node autoscaler launches capacity for replicas that don't fit anywhere — spike -> replicas -> nodes in one reconcile pass. Off = replicas above current cluster capacity wait for unrelated capacity to appear.")
 
-# --- LLM prefix cache (llm/_prefix_cache.py; reference: vLLM automatic prefix caching / ray.llm kv_aware routing) ---
-_flag("llm_prefix_cache_enabled", True, "Block-granular prompt-prefix KV reuse in PagedEngine: full prompt blocks are content-hashed and refcounted across requests, so a shared-prefix request prefills only its suffix (the bench_llm A/B lever). Off = every request prefills from scratch.")
-
 # --- serve ingress (proxy fleet; reference: Serve proxy_location) ---
 _flag("serve_proxy_location", "head", "Where serve.start() places HTTP ingress proxies when the caller passes none: 'head' = one proxy on the driver (one CPython event loop is the single-ingress SSE ceiling), 'every_node' = one 0-CPU proxy pinned per serving node (the bench_llm proxy-fleet lever: the fleet splits ingress dispatch across nodes).")
 

@@ -20,7 +20,7 @@ and by `max_model_len`). What a sequence has:
                 numbers; the last entry is a trash entry a step that takes no
                 snapshot writes to. An entry costs what ~8,700 positions of
                 float32 keys and values would, so one is taken only where
-                prompts were seen to diverge (`SNAPSHOT_WHERE`).
+                prompts were seen to diverge (`SNAPSHOT_POLICY`).
 
 Prompts run as chunks in the decode step (`chunk_ladder`): a chunk's rows go
 through every matmul with the slots' decode rows as one batch and run
@@ -39,6 +39,9 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
+from ray_tpu.llm._engine import (  # chunk_ladder: the step set's own name
+    chunk_ladder, feed_back, sample_tokens)
+from ray_tpu.llm._prefix_cache import SnapshotsAtMatch
 from ray_tpu.models import brumby
 from ray_tpu.models.llama import rms_norm
 
@@ -57,7 +60,7 @@ NO_PREFIX_CACHE = None
 SNAPSHOT_STATE = "snap_state"
 # an entry is the whole of a sequence's memory: taken where a match ended
 # with none near, not along every prompt
-SNAPSHOT_WHERE = "match"
+SNAPSHOT_POLICY = SnapshotsAtMatch
 
 
 def alloc_cache(cfg: brumby.BrumbyConfig, ecfg) -> Tuple:
@@ -75,13 +78,6 @@ def step_params(cfg: brumby.BrumbyConfig, params):
     """The decode step takes the weights as `brumby.init_params` lays them
     out."""
     return params
-
-
-def chunk_ladder(ecfg) -> Tuple[int, ...]:
-    """128 and 256 rows (fewer where `max_model_len` is short). PERF.md
-    section 6 (PR 48) has the step's time by width on a v5e."""
-    widest = min(256, max(8, 1 << ((ecfg.max_model_len // 4).bit_length() - 1)))
-    return (widest // 2, widest)
 
 
 def make_kv_inject(cfg: brumby.BrumbyConfig, ecfg):
@@ -106,7 +102,6 @@ def make_decode_step(cfg: brumby.BrumbyConfig, ecfg):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.llm._engine import feed_back, sample_tokens
     from ray_tpu.ops import power_retention as ret_ops
 
     path = ret_ops.step_path()
@@ -210,87 +205,3 @@ def make_decode_step(cfg: brumby.BrumbyConfig, ecfg):
         return out, state, norm, snap_state, snap_norm, probe
 
     return paged_decode_step, path, None
-
-
-def make_prefill(cfg: brumby.BrumbyConfig, ecfg):
-    """Jitted single-request prefill at a static padded length S, whole and
-    from position 0, in pieces of the ladder's narrowest width through
-    `retention_chunked`: not the loop's (prompts run as chunks in the decode
-    step), `check_prefill`'s second writing of that path. Leaves slot 0's
-    state and normaliser. Returns (last logits, caches)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.ops import power_retention as ret_ops
-
-    piece = chunk_ladder(ecfg)[0]
-
-    @functools.partial(jax.jit, static_argnums=(0,),
-                       donate_argnums=(2, 3, 4, 5))
-    def paged_prefill(S, params, state, norm, snap_state, snap_norm, table,
-                      prompt, plen):
-        del table
-        dt = cfg.dtype
-        P = min(piece, S)
-        assert S % P == 0, "prompt buckets are powers of two"
-        pos = jnp.arange(S)
-        valid = pos < plen
-        h = params["tok_emb"].astype(dt)[prompt]                 # [S, D]
-
-        def layer(carry, xs):
-            h, state, norm = carry
-            p, l = xs
-            x = rms_norm(h, p["ln1"], cfg.norm_eps)
-            with jax.named_scope("retention"):
-                q, k, v, gamma, _ = brumby.ret_inputs(cfg, p, x, pos)
-                k = jnp.where(valid[:, None, None], k, 0.0)
-                gamma = jnp.where(valid[:, None], gamma, 0.0)
-
-                def run(sz, rows):
-                    o, s, z = ret_ops.retention_chunked(
-                        *rows, sz[0], sz[1], cfg.ret_eps)
-                    return (s, z), o
-
-                pieces = tuple(a.reshape((S // P, P) + a.shape[1:])
-                               for a in (q, k, v, gamma))
-                (s, z), o = jax.lax.scan(
-                    run, (jnp.zeros_like(state[l, 0]),
-                          jnp.zeros_like(norm[l, 0])), pieces)
-                y = brumby.ret_output(cfg, p, o.reshape((S,) + o.shape[2:]))
-            h = h + y
-            h = h + brumby.ffn(cfg, p, rms_norm(h, p["ln2"], cfg.norm_eps))
-            return (h, state.at[l, 0].set(s), norm.at[l, 0].set(z)), None
-
-        (h, state, norm), _ = jax.lax.scan(
-            layer, (h, state, norm),
-            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-        h = rms_norm(h, params["norm"], cfg.norm_eps)
-        last = h[jnp.clip(plen - 1, 0, S - 1)]
-        logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
-        return logits, state, norm, snap_state, snap_norm
-
-    return paged_prefill
-
-
-def check_prefill(cfg: brumby.BrumbyConfig, ecfg, prefill, params, prompt_ids):
-    """The jitted `prefill` on caches of its own (one slot) against
-    `brumby.forward`, the quadratic form, on the same prompt: (last logits
-    of the step, of the forward pass)."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    p = list(prompt_ids) or [0]
-    plen = len(p)
-    S = max(8, 1 << (plen - 1).bit_length())
-    caches = alloc_cache(cfg, dataclasses.replace(
-        ecfg, max_num_seqs=1, num_state_snapshots=0))
-    prompt = np.zeros((S,), np.int32)
-    prompt[:plen] = p
-    got = prefill(S, params, *caches, jnp.zeros((1,), jnp.int32),
-                  jnp.asarray(prompt), jnp.int32(plen))[0]
-    ref = jax.jit(functools.partial(brumby.forward, cfg))(
-        params, jnp.asarray(prompt), jnp.int32(plen))[plen - 1]
-    return got, ref

@@ -45,6 +45,9 @@ CACHE_NAMES  the device arrays every step takes after the params, donates
     and returns, each held as the engine's attribute of that name: under the
     block table [layers, num_kv_blocks + 1, kv_block_size, ...] (block 0 is
     the trash block), per slot [layers, B, ...].
+    Where none lies under the block table, a block is the prefix cache's
+    name for a prefix and `num_kv_blocks` sizes a list of integers:
+    admission is bounded by the slots and `max_model_len`.
 alloc_cache(cfg, ecfg) -> cache, zeroed.
 step_params(cfg, params) -> the tree the decode step takes as `params`,
     built once at the engine's start from the tree the engine was given
@@ -74,12 +77,15 @@ make_decode_step(cfg, ecfg) -> (step, path, note). `path` names the
     has seen `prev`.
 chunk_ladder(ecfg) -> the widths C > 0 the step takes, ascending; () for a
     step that takes no chunk.
-make_prefill(cfg, ecfg) -> the jitted whole-prompt `paged_prefill`: with an
-    empty ladder the loop's (`_admit_whole` states its signature), else the
-    set's own business (`check_prefill`, P/D's `PrefillWorker`).
-check_prefill(cfg, ecfg, prefill, params, prompt_ids) -> (got, ref): that
-    prefill's last logits on caches of its own, and the family's reference
-    forward pass's on the same prompt.
+make_prefill, check_prefill  a pair a step set has both of or neither
+    (`WHOLE_PROMPT`): both where the ladder is empty or P/D's `PrefillWorker`
+    serves the family, neither where prompts run as chunks and nothing else
+    would call them (`check_routing` is then the family's check).
+    make_prefill(cfg, ecfg) -> the jitted whole-prompt `paged_prefill`: with
+    an empty ladder the loop's (`_admit_whole` states its signature).
+    check_prefill(cfg, ecfg, prefill, params, prompt_ids) -> (got, ref):
+    that prefill's last logits on caches of its own, and the family's
+    reference forward pass's on the same prompt.
 COUNTERS  names of what the step counts on the device; `stats()` sums them.
 PROBE  keys of the dict the step returns last, fetched only while a checked
     request is in a slot (`check_routing`): "routing" (a family with routed
@@ -108,30 +114,10 @@ SNAPSHOT_STATE  None, or by name the cache array [num_state_snapshots + 1,
     the chunk's first row (-1: none; the step starts a prompt's position 0
     from zeros), and the entry the slot's state is copied to after its last
     (the last entry, which no snapshot owns, for none).
-SNAPSHOT_WHERE  None without `SNAPSHOT_STATE`; else where a prompt leaves
-    snapshots, which follows from what an entry costs the family. "chunk":
-    an entry is small beside a prompt's blocks (Solar: 12.7 MB), so a prompt
-    leaves one wherever a chunk ends on a multiple of the ladder's widest
-    width and keeps its two deepest and a far one (`SNAPSHOT_FAR`). "match":
-    an entry is the whole of a sequence's memory (Brumby: 214 MB, what 8k
-    positions of keys and values would cost), so the pool holds a few and a
-    prompt leaves one only where prompts were seen to part: where its match
-    ended with no snapshot within a widest chunk of the end. It runs the
-    matched tokens behind the deepest one again, ends a chunk exactly on
-    the match's end and leaves the one snapshot there; every later prompt
-    behind the same blocks resumes from it with nothing to run again (a
-    resumed prompt's chunks are then cut elsewhere than a cold run's, so
-    its logits are the cold run's to rounding and not bit for bit). A
-    prompt in flight holds at most that one entry, and a match counts as a
-    use of the snapshot it resumes from alone, so what no prompt asks for
-    again is the first to be displaced. A prompt that shares blocks with
-    one still in chunks, which has not run them all yet, waits at the
-    queue's head until it has: it then matches the whole of what they
-    share and nothing is run a third time or snapshotted half way.
-    Where no array of `CACHE_NAMES` lies under the block table (Brumby), a
-    block is the prefix cache's name for a prefix and `num_kv_blocks` sizes
-    a list of integers: admission is bounded by the slots and
-    `max_model_len`.
+SNAPSHOT_POLICY  None without `SNAPSHOT_STATE`; else the class (of
+    `llm/_prefix_cache.py`, a `SnapshotPolicy`) that says where a prompt
+    leaves snapshots and which of its own it keeps. The engine builds one
+    beside its prefix cache and asks it; the why of each is with its class.
 make_kv_inject(cfg, ecfg) -> the jitted, donating `paged_kv_inject(*cache,
     phys [nb], *blocks) -> cache` that seeds blocks `phys` from
     `generate_stream(prefilled=(*blocks, last_logits))`, one [layers, nb,
@@ -158,11 +144,13 @@ from ray_tpu.util import tracing
 
 __all__ = ["EngineConfig", "PagedEngine", "PHASES"]
 
-# the names a step set has, all of them and no other (module docstring)
+# the names a step set has, all of them and no other (module docstring),
+# but for the pair it has both of or neither
 STEP_SET = ("CACHE_NAMES", "alloc_cache", "step_params", "make_decode_step",
             "chunk_ladder", "make_prefill", "check_prefill", "COUNTERS",
             "PROBE", "SLOT_STATE", "NO_PREFIX_CACHE", "SNAPSHOT_STATE",
-            "SNAPSHOT_WHERE", "make_kv_inject", "extra_stats")
+            "SNAPSHOT_POLICY", "make_kv_inject", "extra_stats")
+WHOLE_PROMPT = ("make_prefill", "check_prefill")
 
 # Host phases of the engine loop, written as `jax.profiler.TraceAnnotation`s
 # into the profiler's own trace (the device trace's clock) whenever a
@@ -206,13 +194,6 @@ SPAN_DECODE = "engine:decode"    # first token -> done
 # prompt of tens of ms), a dispatch and the rest of a decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
-# with state snapshots a request keeps, beside its two deepest, the deepest
-# on a multiple of this many widest chunks: eviction takes a leaf of every
-# idle chain a round, so a document idle between two questions can lose its
-# last few hundred tokens and both deep snapshots with them, and the next
-# question then reran the whole document (12k tokens, 2-4 times in a 51 s
-# window of the long-document cell on a v5e, PERF.md section 6, PR 46)
-SNAPSHOT_FAR = 8
 
 
 @dataclass
@@ -230,10 +211,8 @@ class EngineConfig:
     kv_block_size: int = 16        # tokens per KV block
     num_kv_blocks: int = 64        # pool size (excl. the trash block)
     max_model_len: int = 256       # prompt + generation cap per sequence
-    # None = follow the llm_prefix_cache_enabled config flag (the bench
-    # A/B lever passes an explicit bool). Where a block alone does not
-    # resume a sequence (the step set's `NO_PREFIX_CACHE`) None turns it
-    # off and True is refused
+    # None = on, unless a block alone does not resume a sequence (the step
+    # set's `NO_PREFIX_CACHE`): None then turns it off and True is refused
     prefix_cache: Optional[bool] = None
     # entries of the pool of state snapshots the prefix cache keeps for a
     # family whose slots carry a recurrent state (the step set's
@@ -312,7 +291,10 @@ def chunk_ladder(ecfg: EngineConfig) -> Tuple[int, ...]:
     cells completed 5% fewer tokens a second with 512 as the widest. Each
     width is one more program to compile and to load before traffic (~1.7 s
     of every start from a warm compile cache): a third of 64 rows would
-    save 0.8 ms on a quarter of chat's admissions, under 0.5% of a step."""
+    save 0.8 ms on a quarter of chat's admissions, under 0.5% of a step.
+    Every family whose prompts run as chunks takes these widths; PERF.md
+    section 6 has the step's time by width on a v5e for Solar (PR 46) and
+    for Brumby (PR 48)."""
     widest = min(256, max(8, 1 << ((ecfg.max_model_len // 4).bit_length() - 1)))
     return (widest // 2, widest)
 
@@ -600,7 +582,7 @@ LLAMA_STEPS = types.SimpleNamespace(
     chunk_ladder=chunk_ladder,
     make_prefill=_make_prefill, check_prefill=_check_prefill,
     COUNTERS=(), PROBE=(), SLOT_STATE=None, NO_PREFIX_CACHE=None,
-    SNAPSHOT_STATE=None, SNAPSHOT_WHERE=None,
+    SNAPSHOT_STATE=None, SNAPSHOT_POLICY=None,
     make_kv_inject=_make_kv_inject,
     extra_stats=lambda cfg, cache, attn_positions_live: {})
 
@@ -642,17 +624,16 @@ class _Request:
     cursor: int = 0
     block_keys: tuple = ()
     # with state snapshots: where the matched blocks end (the chunks write
-    # no keys or values before it), the pool entry the first chunk starts
-    # from (-1: none; pinned until that chunk is dispatched) and the keys of
-    # the blocks whose snapshots this request took and keeps: its two
-    # deepest, oldest first, and the far one (`SNAPSHOT_FAR`)
+    # no keys or values before it) and the pool entry the first chunk starts
+    # from (-1: none; pinned until that chunk is dispatched)
     cached_len: int = 0
     restore: int = -1
-    # `SNAPSHOT_WHERE` "match": the position, a block boundary, at which
-    # this prompt is to end a chunk and leave its one snapshot (0: nowhere)
+    # the snapshot policy's (`llm/_prefix_cache.SnapshotPolicy`): the
+    # position, a block boundary, at which it has this prompt end a chunk and
+    # leave a snapshot (0: nowhere), and its record of those the request
+    # took and keeps
     take_at: int = 0
-    snaps: tuple = ()
-    far_snap: Optional[bytes] = None
+    kept: Any = None
     # the caller's span (the `completions_stream` execution span) when the
     # request is traced; the three spans are recorded as its children
     trace_parent: Optional[dict] = None
@@ -748,36 +729,31 @@ class PagedEngine:
         self.fed = np.full((B,), FED_HOST, np.int32)
         self.temps = np.zeros((B,), np.float32)
         self.slot_req: List[Optional[_Request]] = [None] * B
-        from ray_tpu._private.config import GLOBAL_CONFIG
-
         # the chunk widths the decode step takes. With a ladder a prompt is
         # admitted in chunks that ride in the decode steps (`_admit_chunks`);
         # with none whole, awaited in the loop (`_admit_whole`)
         self._ladder: Tuple[int, ...] = steps.chunk_ladder(e)
-        enabled = e.prefix_cache
         refusal = steps.NO_PREFIX_CACHE
         # does a chunk say where it resumes (`chunk_at` of six)
         self._resumes = steps.SNAPSHOT_STATE is not None
-        # does a prompt leave its snapshot where its match ended, and only
-        # there (`SNAPSHOT_WHERE`)
-        self._snap_at_match = steps.SNAPSHOT_WHERE == "match"
         if self._resumes and e.num_state_snapshots < 2 and not refusal:
             refusal = (
                 "prefix_cache=True with recurrent layers needs a pool of "
                 "state snapshots: num_state_snapshots >= 2, not "
                 f"{e.num_state_snapshots}")
-        if refusal:
-            if enabled:
-                raise ValueError(refusal)
-            enabled = False
-        if enabled is None:
-            enabled = GLOBAL_CONFIG.get("llm_prefix_cache_enabled")
-        self._prefix_cache = None
-        if enabled:
+        if refusal and e.prefix_cache:
+            raise ValueError(refusal)
+        # the prefix cache and, where a block resumes a sequence only from a
+        # snapshot of its slot's state, the step set's policy beside it
+        self._prefix_cache = self._snapshots = None
+        if not refusal and e.prefix_cache is not False:
             from ray_tpu.llm._prefix_cache import PrefixCache
 
             self._prefix_cache = PrefixCache(
                 self.bs, e.num_state_snapshots if self._resumes else 0)
+            if self._resumes:
+                self._snapshots = steps.SNAPSHOT_POLICY(
+                    self._prefix_cache, self._ladder[-1])
         # requests admitted past `engine:prefix_match`, and the seconds spent
         # inside it (hashing, matching, eviction; tries that found no room
         # too): what an admission's bookkeeping costs, over a whole window
@@ -798,8 +774,15 @@ class PagedEngine:
             steps.make_decode_step(cfg, e))
         if self._decode_note:
             logging.getLogger(__name__).warning(self._decode_note)
-        # whole prompts: without a ladder in the loop; check_prefill
-        self._prefill = steps.make_prefill(cfg, e)
+        # whole prompts, where the step set has the program: without a
+        # ladder the loop's; check_prefill
+        make_prefill = getattr(steps, "make_prefill", None)
+        if make_prefill is None and not self._ladder:
+            raise ValueError(
+                "a step set with an empty chunk_ladder runs its prompts "
+                "whole and must bring make_prefill and check_prefill")
+        self._prefill = (None if make_prefill is None
+                         else make_prefill(cfg, e))
         # admitted requests whose prompts are not all in the pool yet, in
         # arrival order: the head's next chunk rides in the next step
         self._prefilling: "collections.deque[_Request]" = collections.deque()
@@ -942,7 +925,7 @@ class PagedEngine:
             return self._admit_prefilled(req, slot, need, t_admit)
         import jax
 
-        cache = self._prefix_cache
+        cache, snapshots = self._prefix_cache, self._snapshots
         plen = len(req.prompt)
         hits: List[int] = []
         keys: List[bytes] = []
@@ -957,12 +940,12 @@ class PagedEngine:
                 from ray_tpu.llm._prefix_cache import chain_keys
 
                 keys = chain_keys(req.prompt, self.bs)
-            # (`SNAPSHOT_WHERE` "match") what this prompt shares with one
-            # still in chunks is on its way: the head waits for it rather
-            # than run it again
-            waits = (cache is not None and not cold and self._snap_at_match
-                     and self._shared_on_its_way(keys))
-            if cache is not None and not cold and not waits:
+            matches = cache is not None and not cold
+            # the policy may have the head wait for what the prompts still
+            # in chunks are yet to run
+            waits = (matches and snapshots is not None
+                     and snapshots.waits(keys, self._prefilling))
+            if matches and not waits:
                 # reuse is capped one token short of the prompt: the LAST
                 # prompt token must run through prefill locally or there
                 # are no logits to sample the first generated token from
@@ -970,23 +953,12 @@ class PagedEngine:
             need_new = need - len(hits)
             fits = not waits and self._free_with_eviction(need_new)
             resume = len(hits) * self.bs
-            if fits and self._resumes and hits:
+            if fits and hits and snapshots is not None:
                 # the blocks resume the sequence only from a snapshot of
-                # the slot's state: the deepest at or before their end
-                # ("chunk" calls it as it always was called)
-                covered, restore = (
-                    cache.deepest_snapshot(keys, len(hits), run=False)
-                    if self._snap_at_match
-                    else cache.deepest_snapshot(keys, len(hits)))
+                # the slot's state, at or before their end
+                resume, restore, take_at = snapshots.resume(keys, len(hits))
                 if restore >= 0 and cache.snapshot_owner(restore) != req.rid:
                     self.snapshots_shared += 1
-                if (self._snap_at_match
-                        and resume - covered * self.bs > self._ladder[-1]):
-                    # prompts part at the match's end and no snapshot is
-                    # within a widest chunk of it: this one pays the rerun
-                    # and leaves one there
-                    take_at = resume
-                resume = covered * self.bs
             span.set_metadata(cached_len=len(hits) * self.bs,
                               resume_from=resume)
         self.admit_host_s += time.monotonic() - t_match
@@ -1006,21 +978,6 @@ class PagedEngine:
         else:
             self._admit_whole(req, slot, row, blocks)
         return True
-
-    def _shared_on_its_way(self, keys: List[bytes]) -> bool:
-        """Is a prompt that is still in chunks yet to run blocks that the
-        prompt of `keys` shares with it? Its admission then waits (a
-        second of a closed loop's caller at most): admitted now it would
-        match what is registered so far, run the rest of the shared blocks
-        itself, and leave a snapshot where nobody parts."""
-        for r in self._prefilling:
-            if r.slot < 0:
-                continue
-            shared = next((i for i, (a, b) in enumerate(zip(keys, r.block_keys))
-                           if a != b), min(len(keys), len(r.block_keys)))
-            if shared * self.bs > r.cursor:
-                return True
-        return False
 
     def _admit_chunks(self, req: _Request, slot: int, hits: List[int],
                       keys: List[bytes], resume: int, restore: int,
@@ -1107,18 +1064,15 @@ class PagedEngine:
             return None
         req = self._prefilling[0]
         n = min(len(req.prompt) - req.cursor, self._ladder[-1])
-        if req.cursor < req.take_at:
-            # a chunk ends where the prompt is to leave its snapshot
-            n = min(n, req.take_at - req.cursor)
+        if self._snapshots is not None:
+            n = self._snapshots.cut(req, n)
         return req, n, next(c for c in self._ladder if c >= n)
 
     def _chunk_at(self, req: Optional[_Request], at: int, n: int):
         """(the `chunk_at` a step is given for `n` tokens of `req`'s prompt
         from position `at`, the pool entry it takes a snapshot into or -1).
-        `req` None: an idle chunk of no slot's (`warm_up`). A snapshot is
-        taken at a block that has none yet: by `SNAPSHOT_WHERE`, where the
-        chunk ends on a multiple of the widest chunk inside the prompt's full
-        blocks, or where it ends on the request's `take_at`."""
+        `req` None: an idle chunk of no slot's (`warm_up`). Whether the
+        chunk's end is a place for a snapshot is the policy's to say."""
         trash = self.ecfg.num_state_snapshots
         if req is None:
             return np.asarray(
@@ -1126,18 +1080,12 @@ class PagedEngine:
                 np.int32), -1
         if not self._resumes:
             return np.asarray([req.slot, at, n], np.int32), -1
-        cache, take = self._prefix_cache, -1
-        end = at + n
-        here = (end == req.take_at if self._snap_at_match else
-                end % self._ladder[-1] == 0
-                and end // self.bs <= len(req.block_keys))
-        if (cache is not None and here
-                and not cache.has_snapshot(req.block_keys[end // self.bs - 1])):
-            take = cache.reserve_snapshot()
+        take = (-1 if self._snapshots is None
+                else self._snapshots.take(req, at + n))
         restore = req.restore
         if restore >= 0:
             # the step copies it in: from here on it may be displaced
-            cache.pin_snapshot(restore, False)
+            self._prefix_cache.pin_snapshot(restore, False)
             req.restore = -1
         return np.asarray(
             [req.slot, at, n, req.cached_len, restore,
@@ -1160,22 +1108,12 @@ class PagedEngine:
             full = req.cursor // self.bs
             self._prefix_cache.register(
                 req.block_keys[:full], self.tables[slot][:full])
-            key = req.block_keys[full - 1] if take >= 0 else None
-            if take >= 0 and self._prefix_cache.attach_snapshot(
-                    key, take, req.rid) and not self._snap_at_match:
+            if take >= 0:
                 # the step copies the slot's state into entry `take` after
-                # the chunk; of its own snapshots a request keeps the two
-                # deepest (one of them lies at most one widest chunk before
-                # any later prompt's shared blocks end) and the deepest on a
-                # multiple of `SNAPSHOT_FAR` widest chunks, which a tail
-                # trimmed by eviction falls back to
-                had = req.snaps + (req.far_snap,)
-                req.snaps = req.snaps[-1:] + (key,)
-                if req.cursor % (SNAPSHOT_FAR * self._ladder[-1]) == 0:
-                    req.far_snap = key
-                for k in had:
-                    if k is not None and k not in req.snaps + (req.far_snap,):
-                        self._prefix_cache.drop_snapshot(k)
+                # the chunk
+                key = req.block_keys[full - 1]
+                if self._prefix_cache.attach_snapshot(key, take, req.rid):
+                    self._snapshots.attached(req, key)
         if req.cursor < len(req.prompt):
             return
         self._prefilling.popleft()
@@ -1718,7 +1656,10 @@ class PagedEngine:
         """Prefill's last-position logits against the family's reference
         forward pass on the same prompt: the paged prefill step and the
         forward pass are two writings of one model and must agree. Runs on
-        caches of its own, so the live ones are untouched."""
+        caches of its own, so the live ones are untouched. A family whose
+        prompts run as chunks has no such program (`_no_prefill`)."""
+        if self._prefill is None:
+            raise ValueError(self._no_prefill())
         got, ref = map(np.asarray, self._steps.check_prefill(
             self.cfg, self.ecfg, self._prefill, self.params, prompt_ids))
         return {
@@ -1728,6 +1669,13 @@ class PagedEngine:
             "max_abs_ref": float(np.abs(ref).max()),
             "argmax_equal": bool(got.argmax() == ref.argmax()),
         }
+
+    def _no_prefill(self) -> str:
+        return (
+            f"{type(self.cfg).__name__}'s prompts run as chunks in the "
+            "decode step, so its step set brings no whole-prompt prefill "
+            "program to check or to lower: check_routing runs a prompt "
+            "through the served chunks and is this family's check")
 
     async def check_routing(self, prompt_ids: List[int], max_tokens: int,
                             mechanisms: bool = False, cold: bool = False
@@ -1774,13 +1722,17 @@ class PagedEngine:
     def step_hlo(self, prefill_lengths: List[int]) -> Dict[str, List[str]]:
         """The optimized HLO text of the compiled steps, by the program names
         the device trace shows: the decode step and the prefill at each
-        prompt length's bucket. Every instruction carries its `op_name`, the
+        prompt length's bucket (refused where the step set has no prefill:
+        give it no lengths). Every instruction carries its `op_name`, the
         `jax.named_scope`s it was traced under included, which the trace's
         events do not; a reader joins the two by instruction name. Compiles
         from shapes alone (a hit in the compile cache for a step that has
         run), so it may run beside the loop."""
         import jax
         import jax.numpy as jnp
+
+        if prefill_lengths and self._prefill is None:
+            raise ValueError(self._no_prefill())
 
         def shape(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)

@@ -59,7 +59,7 @@ NO_PREFIX_CACHE = (
     "would need the recurrent state at its boundary, and nothing snapshots "
     "that state")
 SNAPSHOT_STATE = None
-SNAPSHOT_WHERE = None
+SNAPSHOT_POLICY = None
 
 
 def alloc_cache(cfg: ling.LingConfig, ecfg) -> Tuple:

@@ -37,7 +37,10 @@ is evicted with that block, so it never outlives the blocks up to its
 position. When every entry is taken the least recently used snapshot gives
 its own up (`reserve_snapshot`), unless an admitted request is still to
 start from it (`pin_snapshot`). A snapshot that is gone shortens what a match
-can resume; it fails nothing.
+can resume; it fails nothing. Where a prompt leaves snapshots and which of
+its own it keeps is a `SnapshotPolicy`'s to say (below the cache): the
+family's step set names the class, the engine builds one on its cache and
+asks it.
 
 Pure host-side data structure: no asyncio, no JAX — unit-testable alone.
 All mutation happens from the engine's single admission/step context.
@@ -52,7 +55,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["PrefixCache", "chain_keys"]
+__all__ = ["PrefixCache", "SnapshotPolicy", "SnapshotsAtChunks",
+           "SnapshotsAtMatch", "chain_keys"]
 
 
 def chain_keys(prompt_ids: Sequence[int], block_size: int) -> List[bytes]:
@@ -368,3 +372,133 @@ class PrefixCache:
         if self.num_snapshots:
             out["snapshots"] = len(self._snap_key)
         return out
+
+
+class SnapshotPolicy:
+    """Where a prompt leaves snapshots of its slot's state in `cache`'s pool
+    and which of its own it keeps: the four questions the engine's loop asks
+    of a family whose step set has `SNAPSHOT_STATE`, in the order of a
+    request's life (at admission `waits`, then `resume`; `cut`; `take`,
+    which asks `here`; `attached`). `widest` is the widest prompt chunk of the engine's
+    ladder. A request is the engine's: a policy reads its `cursor` (the
+    prompt positions run so far), `block_keys` and `slot`, and owns its
+    `take_at` (as `resume` gave it) and `kept`. A subclass says `resume`;
+    as the other answers stand, a prompt ends a chunk and leaves a snapshot
+    at its `take_at` and nowhere else, and keeps what it took."""
+
+    def __init__(self, cache: PrefixCache, widest: int):
+        self.cache, self.bs, self.widest = cache, cache.block_size, widest
+
+    def waits(self, keys: List[bytes], in_chunks) -> bool:
+        """At admission, before the match: does the prompt of `keys` wait at
+        the queue's head, given the admitted requests still `in_chunks`?"""
+        return False
+
+    def resume(self, keys: List[bytes], n_blocks: int) -> Tuple[int, int, int]:
+        """At admission, with the first `n_blocks` blocks of `keys` matched
+        and room found: (the position the prompt resumes at, the pool entry
+        it resumes from or -1, the position at which it is to end a chunk
+        and leave a snapshot or 0)."""
+        raise NotImplementedError
+
+    def cut(self, req, n: int) -> int:
+        """When the next chunk of `req`'s prompt is cut: of the `n` tokens
+        it could hold, how many it does (it ends early at `take_at`)."""
+        if req.cursor < req.take_at:
+            n = min(n, req.take_at - req.cursor)
+        return n
+
+    def here(self, req, end: int) -> bool:
+        """Is `end`, where a chunk of `req`'s prompt ends, a place for a
+        snapshot?"""
+        return end == req.take_at
+
+    def take(self, req, end: int) -> int:
+        """When a chunk that ends at `end` is dispatched: the pool entry the
+        step copies the slot's state to, or -1. A block has one snapshot."""
+        if self.here(req, end) and not self.cache.has_snapshot(
+                req.block_keys[end // self.bs - 1]):
+            return self.cache.reserve_snapshot()
+        return -1
+
+    def attached(self, req, key: bytes) -> None:
+        """`req`'s snapshot at the end of block `key` is in the cache: drop
+        those of its own that it no longer keeps."""
+
+
+class SnapshotsAtChunks(SnapshotPolicy):
+    """An entry is small beside a prompt's blocks (Solar: 12.7 MB), so a
+    prompt leaves one wherever a chunk ends on a multiple of the widest
+    chunk inside its full blocks: a later prompt that shares the blocks runs
+    at most one chunk of matched tokens again. Of its own a request keeps
+    the two deepest (one of them lies at most one widest chunk before any
+    later prompt's shared blocks end) and the deepest on a multiple of
+    `SNAPSHOT_FAR` widest chunks, which a tail trimmed by eviction falls back
+    to: eviction takes an idle document's blocks from the tail, so one idle
+    between two questions can lose its last few hundred tokens and both deep
+    snapshots with them, and the next question then reran the whole
+    document (12k tokens, 2-4 times in a 51 s window of the long-document
+    cell on a v5e, PERF.md section 6, PR 46). `kept`: (the two deepest's
+    keys, oldest first; the far one's)."""
+
+    SNAPSHOT_FAR = 8
+
+    def resume(self, keys, n_blocks):
+        # a match is a use of every snapshot on the run
+        covered, restore = self.cache.deepest_snapshot(keys, n_blocks)
+        return covered * self.bs, restore, 0
+
+    def here(self, req, end):
+        return (end % self.widest == 0
+                and end // self.bs <= len(req.block_keys))
+
+    def attached(self, req, key):
+        deep, far = req.kept or ((), None)
+        had = deep + (far,)
+        deep = deep[-1:] + (key,)
+        if req.cursor % (self.SNAPSHOT_FAR * self.widest) == 0:
+            far = key
+        for k in had:
+            if k is not None and k not in deep + (far,):
+                self.cache.drop_snapshot(k)
+        req.kept = deep, far
+
+
+class SnapshotsAtMatch(SnapshotPolicy):
+    """An entry is the whole of a sequence's memory (Brumby: 214 MB, what 8k
+    positions of keys and values would cost), so the pool holds a few and a
+    prompt leaves one only where prompts were seen to part: where its match
+    ended with no snapshot within a widest chunk of the end. It runs the
+    matched tokens behind the deepest one again, ends a chunk exactly on the
+    match's end and leaves the one snapshot there; every later prompt behind
+    the same blocks resumes from it with nothing to run again (a resumed
+    prompt's chunks are then cut elsewhere than a cold run's, so its logits
+    are the cold run's to rounding and not bit for bit). A prompt in flight
+    holds at most that one entry, and a match counts as a use of the
+    snapshot it resumes from alone, so what no prompt asks for again is the
+    first to be displaced."""
+
+    def waits(self, keys, in_chunks):
+        """A prompt that shares blocks with one still in chunks, which has
+        not run them all yet, waits (a second of a closed loop's caller at
+        most): admitted now it would match what is registered so far, run
+        the rest of the shared blocks itself and leave a snapshot where
+        nobody parts. Once they are run it matches the whole of what they
+        share, and nothing is run a third time or snapshotted half way."""
+        for r in in_chunks:
+            if r.slot < 0:
+                continue        # released by the abort sweep
+            shared = next(
+                (i for i, (a, b) in enumerate(zip(keys, r.block_keys))
+                 if a != b), min(len(keys), len(r.block_keys)))
+            if shared * self.bs > r.cursor:
+                return True
+        return False
+
+    def resume(self, keys, n_blocks):
+        covered, restore = self.cache.deepest_snapshot(
+            keys, n_blocks, run=False)
+        # prompts part at the match's end; with no snapshot within a widest
+        # chunk of it this one pays the rerun and leaves one there
+        far = (n_blocks - covered) * self.bs > self.widest
+        return covered * self.bs, restore, n_blocks * self.bs if far else 0

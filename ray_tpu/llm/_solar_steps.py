@@ -43,6 +43,9 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
+from ray_tpu.llm._engine import (  # chunk_ladder: the step set's own name
+    chunk_ladder, feed_back, sample_tokens)
+from ray_tpu.llm._prefix_cache import SnapshotsAtChunks
 from ray_tpu.models import ling, solar
 from ray_tpu.models.llama import rms_norm
 
@@ -66,7 +69,7 @@ SLOT_STATE = "state"
 NO_PREFIX_CACHE = None
 SNAPSHOT_STATE = "snap_state"
 # an entry is 12.7 MB beside a document's blocks: one a widest chunk
-SNAPSHOT_WHERE = "chunk"
+SNAPSHOT_POLICY = SnapshotsAtChunks
 
 
 def alloc_cache(cfg: solar.SolarConfig, ecfg) -> Tuple:
@@ -91,15 +94,6 @@ def step_params(cfg: solar.SolarConfig, params):
     return params
 
 
-def chunk_ladder(ecfg) -> Tuple[int, ...]:
-    """128 and 256 rows (fewer where `max_model_len` is short): the widest
-    is also the distance between the state snapshots the prefix cache keeps,
-    so a resumed prompt runs at most one chunk of matched tokens again.
-    PERF.md section 6 (PR 46) has the step's time by width on a v5e."""
-    widest = min(256, max(8, 1 << ((ecfg.max_model_len // 4).bit_length() - 1)))
-    return (widest // 2, widest)
-
-
 def make_kv_inject(cfg: solar.SolarConfig, ecfg):
     raise ValueError(
         "transferred KV cannot seed a model with recurrent layers: its "
@@ -122,7 +116,6 @@ def make_decode_step(cfg: solar.SolarConfig, ecfg):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.llm._engine import feed_back, sample_tokens
     from ray_tpu.ops import paged_attention
 
     bs = ecfg.kv_block_size
@@ -241,75 +234,3 @@ def make_decode_step(cfg: solar.SolarConfig, ecfg):
                 {name: jnp.stack(a) for name, a in probe.items() if a})
 
     return paged_decode_step, path, note
-
-
-def make_prefill(cfg: solar.SolarConfig, ecfg):
-    """Jitted single-request prefill at a static padded length S, whole and
-    from position 0: not the loop's (prompts run as chunks), `check_prefill`'s
-    second writing of the chunked path. Writes the prompt's keys and values
-    into its blocks and slot 0's state and tails. Returns (last logits,
-    caches)."""
-    import jax
-    import jax.numpy as jnp
-
-    bs = ecfg.kv_block_size
-
-    @functools.partial(jax.jit, static_argnums=(0,),
-                       donate_argnums=(2, 3, 4, 5, 6, 7))
-    def paged_prefill(S, params, kc, vc, state, tails, snap_state, snap_tails,
-                      table, prompt, plen):
-        dt = cfg.dtype
-        idx = jnp.arange(S)
-        valid = idx < plen
-        phys = jnp.where(valid, table[jnp.clip(idx // bs, 0,
-                                               table.shape[0] - 1)], 0)
-        off = (idx % bs).astype(jnp.int32)
-        h = params["tok_emb"].astype(dt)[prompt]                 # [S, D]
-        i_kda = i_gqa = 0
-        for kind, p in zip(cfg.kinds(), params["layers"]):
-            x = rms_norm(h, p["ln1"], cfg.norm_eps)
-            if kind == "kda":
-                y, final, tail = solar.kda_sequence(cfg, p, x, valid)
-                state = state.at[i_kda, 0].set(final)
-                tails = tails.at[i_kda, 0].set(tail)
-                i_kda += 1
-            else:
-                _, k, v = solar.gqa_project(cfg, p, x)
-                kc = kc.at[i_gqa, phys, off].set(k)
-                vc = vc.at[i_gqa, phys, off].set(v)
-                y = solar.gqa_sequence(cfg, p, x, valid)
-                i_gqa += 1
-            h = h + y
-            h = h + ling.moe_held(
-                cfg, p, rms_norm(h, p["ln2"], cfg.norm_eps), valid)[0]
-        h = rms_norm(h, params["norm"], cfg.norm_eps)
-        last = h[jnp.clip(plen - 1, 0, S - 1)]
-        logits = (last @ params["lm_head"].astype(dt)).astype(jnp.float32)
-        return logits, kc, vc, state, tails, snap_state, snap_tails
-
-    return paged_prefill
-
-
-def check_prefill(cfg: solar.SolarConfig, ecfg, prefill, params, prompt_ids):
-    """The jitted `prefill` on caches of its own (one slot, the prompt's
-    blocks) against `solar.forward` on the same prompt: (last logits of the
-    step, of the forward pass)."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    p = list(prompt_ids) or [0]
-    plen = len(p)
-    nb = -(-plen // ecfg.kv_block_size)
-    S = max(8, 1 << (plen - 1).bit_length())
-    caches = alloc_cache(cfg, dataclasses.replace(
-        ecfg, max_num_seqs=1, num_kv_blocks=nb, num_state_snapshots=0))
-    prompt = np.zeros((S,), np.int32)
-    prompt[:plen] = p
-    got = prefill(S, params, *caches, jnp.arange(1, nb + 1, dtype=jnp.int32),
-                  jnp.asarray(prompt), jnp.int32(plen))[0]
-    ref = jax.jit(functools.partial(solar.forward, cfg))(
-        params, jnp.asarray(prompt), jnp.int32(plen))[plen - 1]
-    return got, ref

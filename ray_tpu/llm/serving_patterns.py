@@ -47,7 +47,14 @@ class PrefillWorker:
         self.config = config
         self.ecfg = EngineConfig(**(engine_config or {}))
         self.cfg, self.params = config.build_model()
-        self._prefill = step_set(self.cfg).make_prefill(self.cfg, self.ecfg)
+        steps = step_set(self.cfg)
+        if not hasattr(steps, "make_prefill"):
+            raise ValueError(
+                f"no PrefillWorker for {type(self.cfg).__name__}: its "
+                "prompts run as chunks in the decode step and its step set "
+                "brings no whole-prompt prefill program (nor could its "
+                "decode engines take transferred blocks: make_kv_inject)")
+        self._prefill = steps.make_prefill(self.cfg, self.ecfg)
         self._served = 0
 
     def prefill(self, prompt_ids: List[int]) -> Dict[str, Any]:

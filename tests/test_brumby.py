@@ -19,6 +19,7 @@ from benchmark.lib import reference_brumby as ref
 from benchmark.runners._inside_brumby import ProgramWeightsBrumby, unpack
 from ray_tpu.llm import LLMConfig, _brumby_steps, step_set
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
+from ray_tpu.llm._prefix_cache import SnapshotsAtMatch
 from ray_tpu.models import brumby
 from ray_tpu.ops import power_retention as pr
 
@@ -273,7 +274,7 @@ def test_nothing_lies_under_the_block_table_and_slots_bound_admission(params):
     steps = step_set(CFG)
     assert steps is _brumby_steps
     assert steps.SLOT_STATE == "state" and steps.SNAPSHOT_STATE == "snap_state"
-    assert steps.SNAPSHOT_WHERE == "match"
+    assert steps.SNAPSHOT_POLICY is SnapshotsAtMatch
     small, large = (steps.alloc_cache(CFG, dataclasses.replace(
         ECFG, num_kv_blocks=n)) for n in (16, 4096))
     assert [a.shape for a in small] == [a.shape for a in large]
@@ -319,8 +320,12 @@ def test_a_prompt_in_chunks_equals_the_whole_prompt_equals_the_reference(
     assert engine.stats()["prefill_chunks"] == 4
     g = gaps(weights, p, out)
     assert g["argmax_equal"] == 8 and max(g["gaps"]) < 2e-4
-    check = engine.check_prefill(p)
-    assert check["argmax_equal"] and check["max_abs_diff"] < 2e-4
+    # prompts run as chunks: no whole-prompt program is built, checked or
+    # lowered, and the refusal names the family's check
+    assert engine._prefill is None
+    for refused in (engine.check_prefill, lambda p: engine.step_hlo([len(p)])):
+        with pytest.raises(ValueError, match="no whole-prompt.*check_routing"):
+            refused(p)
     whole = brumby.forward(CFG, params, jnp.asarray(p))[199]
     assert int(jnp.argmax(whole)) == out["token_ids"][0]
     # the slot's state is the direct sum over the program's own k, v, gamma

@@ -334,12 +334,18 @@ def test_each_http_request_is_one_trace_down_to_the_engine(traced, dp_app):
         return {t: ss for t, ss in by_trace.items()
                 if any(s["name"].startswith("ingress:") for s in ss)}
 
-    deadline = time.time() + 30
+    def whole(ss):
+        """The engine's three spans are in and so is every span a parent
+        link names: each process flushes its own spans in its own time, so
+        the engine's can land before the replica's that they hang under."""
+        ids = {s["span_id"] for s in ss}
+        return ({s["name"] for s in ss} >= set(SPANS) and all(
+            s["parent_span_id"] in ids for s in ss if s["parent_span_id"]))
+
+    deadline = time.time() + 60
     while time.time() < deadline:
         traces = request_traces()
-        if len(traces) == 2 and all(
-                {s["name"] for s in ss} >= set(SPANS)
-                for ss in traces.values()):
+        if len(traces) == 2 and all(map(whole, traces.values())):
             break
         time.sleep(0.5)
     assert len(traces) == 2, {t: sorted(s["name"] for s in ss)

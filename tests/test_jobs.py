@@ -100,7 +100,12 @@ def test_quota_caps_concurrent_jobs(client):
     deadline = time.time() + 90
     max_admitted = 0
     while time.time() < deadline:
-        statuses = [client.get_job_status(s) for s in sids]
+        # one listing is one moment of the table: three reads one after
+        # another count a hand-over (A RUNNING, then A ends and B is
+        # admitted, then B PENDING) as two jobs admitted at once
+        listed = {j["submission_id"]: j["status"]
+                  for j in client.list_jobs(tenant="quota-t")}
+        statuses = [listed[s] for s in sids]
         admitted = sum(1 for s in statuses if s in (PENDING, RUNNING))
         max_admitted = max(max_admitted, admitted)
         assert admitted <= 1, f"quota breached: {statuses}"

@@ -24,6 +24,7 @@ from ray_tpu.llm import (
     EOS, MODEL_FAMILIES, LLMConfig, LLMEngine, _engine, step_set,
 )
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
+from ray_tpu.llm._prefix_cache import SnapshotPolicy
 from ray_tpu.models.llama import LlamaConfig, init_params
 
 CFG = LlamaConfig(
@@ -462,12 +463,15 @@ def test_kv_aware_router_prefix_affinity():
 
 
 def _own_public_names(steps):
-    """The names a step set defines itself: a module's imports and private
-    helpers are not its interface."""
+    """The names a step set defines itself, and those of `STEP_SET` it takes
+    from elsewhere under the interface's own name (the shared
+    `chunk_ladder`): a module's other imports and private helpers are not
+    its interface."""
     home = getattr(steps, "__name__", None)
     return {n for n, v in vars(steps).items()
             if not n.startswith("_") and not inspect.ismodule(v)
-            and (home is None or getattr(v, "__module__", home) == home)}
+            and (home is None or n in _engine.STEP_SET
+                 or getattr(v, "__module__", home) == home)}
 
 
 def _engine_code():
@@ -481,23 +485,39 @@ def _engine_code():
     return list(ast.walk(tree))
 
 
+def _holds_the_step_set(steps):
+    """Exactly the names of `STEP_SET`, the whole-prompt pair both or
+    neither: both wherever the ladder is empty (the loop's `_admit_whole`
+    runs the program), and callable."""
+    pair = set(_engine.WHOLE_PROMPT)
+    assert pair <= set(_engine.STEP_SET)
+    own = _own_public_names(steps)
+    assert own | pair == set(_engine.STEP_SET)
+    assert own & pair in (set(), pair)
+    ladder = steps.chunk_ladder(EngineConfig())
+    assert isinstance(ladder, tuple) and list(ladder) == sorted(set(ladder))
+    assert ladder or pair <= own
+    assert all(callable(getattr(steps, name)) for name in own & pair)
+
+
 @pytest.mark.parametrize("family", sorted(MODEL_FAMILIES))
 def test_every_family_keeps_the_step_set_and_the_engine_names_none(family):
     module, config_cls, _, where = MODEL_FAMILIES[family]
     cfg_cls = getattr(importlib.import_module(module), config_cls)
     steps = step_set(cfg_cls.tiny())
-    assert _own_public_names(steps) == set(_engine.STEP_SET)
+    _holds_the_step_set(steps)
     for name in ("CACHE_NAMES", "COUNTERS", "PROBE"):
         assert all(isinstance(n, str) for n in getattr(steps, name))
         assert isinstance(getattr(steps, name), tuple)
     assert steps.SLOT_STATE is None or steps.SLOT_STATE in steps.CACHE_NAMES
     assert isinstance(steps.NO_PREFIX_CACHE, (str, type(None)))
-    ladder = steps.chunk_ladder(EngineConfig())
-    assert isinstance(ladder, tuple) and list(ladder) == sorted(set(ladder))
     for name in ("alloc_cache", "step_params", "make_decode_step",
-                 "chunk_ladder", "make_prefill", "check_prefill",
-                 "make_kv_inject", "extra_stats"):
+                 "chunk_ladder", "make_kv_inject", "extra_stats"):
         assert callable(getattr(steps, name))
+    # the policy is code the engine calls, not a word it compares
+    assert (steps.SNAPSHOT_POLICY is None) == (steps.SNAPSHOT_STATE is None)
+    assert steps.SNAPSHOT_POLICY is None or issubclass(
+        steps.SNAPSHOT_POLICY, SnapshotPolicy)
     # the engine's code: no family, no config class, no step set by name, no
     # cache array as an attribute, no test of a config's class
     family_words = {family, config_cls, where.rpartition(".")[2],
@@ -601,7 +621,7 @@ TOY_STEPS = types.SimpleNamespace(
     make_prefill=_toy_prefill, check_prefill=_toy_check_prefill,
     COUNTERS=("toy_rows",), PROBE=(), SLOT_STATE="bag",
     NO_PREFIX_CACHE="a bag of tokens is not in the blocks: none to share",
-    SNAPSHOT_STATE=None, SNAPSHOT_WHERE=None,
+    SNAPSHOT_STATE=None, SNAPSHOT_POLICY=None,
     make_kv_inject=_toy_no_inject,
     extra_stats=lambda cfg, cache, live: {"bag_bytes": cache[0].nbytes})
 TOY_ECFG = EngineConfig(max_num_seqs=2, kv_block_size=4, num_kv_blocks=16,
@@ -662,6 +682,20 @@ def test_a_third_family_is_served_by_the_engine_as_it_stands(toy):
     assert not np.asarray(eng.bag).any()
     assert serve_all(eng, prompts[:1], 4) == [
         toy_reference(params, prompts[0], 4)]
+
+
+def test_the_whole_prompt_pair_is_both_or_neither_and_an_empty_ladder_needs_it(
+        toy, monkeypatch):
+    cfg, params = toy
+    _holds_the_step_set(TOY_STEPS)
+    monkeypatch.delattr(TOY_STEPS, "check_prefill")
+    with pytest.raises(AssertionError):
+        _holds_the_step_set(TOY_STEPS)          # one of the pair alone
+    monkeypatch.delattr(TOY_STEPS, "make_prefill")
+    with pytest.raises(AssertionError):
+        _holds_the_step_set(TOY_STEPS)          # neither, and no ladder
+    with pytest.raises(ValueError, match="make_prefill and check_prefill"):
+        PagedEngine(cfg, params, TOY_ECFG)
 
 
 def test_a_step_set_refuses_in_its_own_words(toy):

@@ -5,6 +5,8 @@ blocks, blocks shared while their prompt is still in chunks, and the
 client-disconnect block-leak regression."""
 
 import asyncio
+import itertools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +14,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
-from ray_tpu.llm._prefix_cache import PrefixCache, chain_keys
+from ray_tpu.llm._prefix_cache import (
+    PrefixCache, SnapshotsAtChunks, SnapshotsAtMatch, chain_keys)
 from ray_tpu.models.llama import LlamaConfig, init_params
 
 CFG = LlamaConfig(
@@ -273,6 +276,115 @@ def test_the_idle_count_and_the_leaf_rule_hold_over_random_traffic(seed):
             c.decref_block(b)
     evict(40)
     assert len(c) == 0 and c.stats()["evictable"] == 0
+
+
+# -- the snapshot policies, led as the engine's loop leads them --------------
+
+_fresh_block = itertools.count(1)
+
+
+def _life(policy, rid, prompt):
+    """One prompt's life under `policy`, with no engine: the questions in
+    the loop's order (does it wait, where does it resume, where is a chunk
+    cut, is a snapshot taken at its end, which are kept) and the loop's
+    calls into the cache between them. Returns (resume, restore, take_at)
+    as the admission had them and the chunks' ends."""
+    cache, bs = policy.cache, policy.bs
+    keys = chain_keys(prompt, bs)
+    assert not policy.waits(keys, ())
+    hits = cache.match(keys[: (len(prompt) - 1) // bs])
+    admitted = policy.resume(keys, len(hits)) if hits else (0, -1, 0)
+    resume, restore, take_at = admitted
+    if restore >= 0:
+        cache.pin_snapshot(restore)
+    blocks = hits + [next(_fresh_block) for _ in keys[len(hits):]]
+    req = types.SimpleNamespace(rid=rid, slot=0, cursor=resume, kept=None,
+                                block_keys=tuple(keys), take_at=take_at)
+    ends = []
+    while req.cursor < len(prompt):
+        n = policy.cut(req, min(len(prompt) - req.cursor, policy.widest))
+        take = policy.take(req, req.cursor + n)
+        if restore >= 0:
+            cache.pin_snapshot(restore, False)
+            restore = -1
+        req.cursor += n
+        ends.append(req.cursor)
+        full = req.cursor // bs
+        cache.register(req.block_keys[:full], blocks[:full])
+        if take >= 0 and cache.attach_snapshot(
+                req.block_keys[full - 1], take, rid):
+            policy.attached(req, req.block_keys[full - 1])
+    for b in blocks:
+        cache.decref_block(b)
+    return admitted, ends
+
+
+def _snapshots_at(cache, prompt):
+    return [i * cache.block_size
+            for i, k in enumerate(chain_keys(prompt, cache.block_size), 1)
+            if cache.has_snapshot(k)]
+
+
+def test_snapshots_at_chunks_lie_on_the_widest_chunks_multiples_two_deep_and_a_far_one_kept():
+    rng = np.random.RandomState(0)
+    cache = PrefixCache(block_size=16, num_snapshots=6)
+    policy = SnapshotsAtChunks(cache, widest=64)
+    doc = [int(t) for t in rng.randint(0, 500, 700)]
+    q1, q2 = doc + [501] * 20, doc + [502] * 25
+    admitted, ends = _life(policy, 1, q1)
+    assert admitted == (0, -1, 0)
+    # chunks of the widest width and the rest: nothing ends a chunk early
+    assert ends == [*range(64, 720, 64), 720]
+    # eleven taken, one a multiple of 64 (720 is none); kept: the deepest on
+    # a multiple of 8 x 64, and the two deepest
+    assert cache.snapshots_taken == 11 and cache.snapshots_evicted == 0
+    assert _snapshots_at(cache, q1) == [512, 640, 704]
+    assert len(cache._free_snaps) == 3
+    # the second question shares 43 blocks (688) and resumes at the deepest
+    # snapshot before their end
+    (resume, restore, take_at), ends = _life(policy, 2, q2)
+    assert (resume, take_at) == (640, 0) and restore >= 0
+    assert cache.snapshot_owner(restore) == 1
+    assert ends == [704, 725]
+    assert cache.snapshots_restored == 1
+    # it left its own at 704 (its block there is not the first question's)
+    # and keeps it; the first's stay
+    assert _snapshots_at(cache, q2) == [512, 640, 704]
+    assert cache.snapshots_taken == 12 and len(cache._free_snaps) == 2
+
+
+def test_snapshots_at_match_leave_one_where_prompts_part_and_a_prompt_waits_for_shared_blocks():
+    rng = np.random.RandomState(1)
+    cache = PrefixCache(block_size=16, num_snapshots=4)
+    policy = SnapshotsAtMatch(cache, widest=64)
+    header = [int(t) for t in rng.randint(0, 500, 150)]
+    a, b, c = (header + [501 + i] * (21 + 6 * i) for i in range(3))
+    # the first prompt behind a header leaves nothing
+    assert _life(policy, 1, a) == ((0, -1, 0), [64, 128, 171])
+    assert cache.snapshots_taken == 0
+    # the second matches nine blocks with no snapshot near their end: it
+    # runs them again, ends a chunk on the match's end and leaves one there
+    assert _life(policy, 2, b) == ((0, -1, 144), [64, 128, 144, 177])
+    assert cache.snapshots_taken == 1 and _snapshots_at(cache, b) == [144]
+    # the third resumes from it with nothing to run again, and leaves none
+    (resume, restore, take_at), ends = _life(policy, 3, c)
+    assert (resume, take_at, ends) == (144, 0, [183])
+    assert cache.snapshot_owner(restore) == 2
+    assert cache.snapshots_taken == 1 == cache.snapshots_restored
+    # a prompt behind the same header waits while one still in chunks has
+    # shared blocks yet to run, and no longer; a released one is not waited
+    # for, and a prompt that shares nothing does not wait
+    keys = chain_keys(header + [510] * 30, 16)
+    ahead = types.SimpleNamespace(slot=0, cursor=128,
+                                  block_keys=tuple(chain_keys(a, 16)))
+    assert policy.waits(keys, [ahead])
+    ahead.cursor = 144
+    assert not policy.waits(keys, [ahead])
+    ahead.cursor, ahead.slot = 64, -1
+    assert not policy.waits(keys, [ahead])
+    ahead.slot = 0
+    assert not policy.waits(chain_keys([7] * 100, 16), [ahead])
+    assert not SnapshotsAtChunks(cache, 64).waits(keys, [ahead])
 
 
 # -- engine integration -----------------------------------------------------

@@ -17,7 +17,9 @@ from benchmark.lib import reference_ling as rl
 from benchmark.lib import reference_solar as ref
 from benchmark.runners._inside_solar import ProgramWeightsSolar
 from ray_tpu.llm._engine import EngineConfig, PagedEngine
-from ray_tpu.llm._prefix_cache import PrefixCache, chain_keys
+from ray_tpu.llm import _solar_steps
+from ray_tpu.llm._prefix_cache import (
+    PrefixCache, SnapshotPolicy, SnapshotsAtChunks, chain_keys)
 from ray_tpu.models import ling, solar
 from ray_tpu.ops import grouped_ffn
 
@@ -192,8 +194,12 @@ def test_a_prompt_in_chunks_equals_the_whole_prompt_equals_the_reference(
     g = gaps(weights, p, out)
     assert g["argmax_equal"] == 8 and max(g["gaps"]) < 2e-4
     assert g["routing"]["expert_steps"] == 0.0
-    check = engine.check_prefill(p)
-    assert check["argmax_equal"] and check["max_abs_diff"] < 2e-4
+    # prompts run as chunks: no whole-prompt program is built, checked or
+    # lowered, and the refusal names the family's check
+    assert engine._prefill is None
+    for refused in (engine.check_prefill, lambda p: engine.step_hlo([len(p)])):
+        with pytest.raises(ValueError, match="no whole-prompt.*check_routing"):
+            refused(p)
     padded = np.zeros(256, np.int32)
     padded[:200] = p
     whole = solar.forward(CFG, params, jnp.asarray(padded), 200)[199]
@@ -357,9 +363,7 @@ def test_a_trimmed_tail_falls_back_to_the_far_snapshot(params, monkeypatch):
     leaves snapshots at 384 (far), 512 and 640. Eviction trims the idle
     document's tail to 432 tokens and the two deep snapshots go with their
     blocks: the next question resumes at 384, not at 0, to the same answer."""
-    from ray_tpu.llm import _engine
-
-    monkeypatch.setattr(_engine, "SNAPSHOT_FAR", 3)
+    monkeypatch.setattr(SnapshotsAtChunks, "SNAPSHOT_FAR", 3)
     ecfg = dataclasses.replace(ECFG, max_model_len=768)
     engine = PagedEngine(CFG, params, ecfg)
     doc = prompt(91, 700)
@@ -376,6 +380,54 @@ def test_a_trimmed_tail_falls_back_to_the_far_snapshot(params, monkeypatch):
     assert engine.stats()["snapshot_rerun_tokens"] == 432 - 384
     fresh, = serve(PagedEngine(CFG, params, ecfg), [q2], cold=True)
     assert again["token_ids"] == fresh["token_ids"]
+
+
+class SnapshotsAtBlocks(SnapshotPolicy):
+    """A third policy, written here: a snapshot wherever a chunk ends on a
+    block boundary of the prompt's full blocks, and all of them kept."""
+
+    def resume(self, keys, n_blocks):
+        covered, restore = self.cache.deepest_snapshot(keys, n_blocks)
+        return covered * self.bs, restore, 0
+
+    def here(self, req, end):
+        return end % self.bs == 0 and end // self.bs <= len(req.block_keys)
+
+
+def test_a_policy_written_elsewhere_is_served_by_the_engine_as_it_stands(
+        params, monkeypatch):
+    """The seam: the step set names another policy and `PagedEngine`, not
+    edited, serves by it. A document of 200 tokens leaves snapshots at 64,
+    128 and 192 and keeps all three (the family's own keeps two); a prompt
+    that parts from it at 100 resumes at 64, and its chunk of 32 tokens ends
+    on block 10 (160 tokens, no multiple of the widest chunk) and leaves
+    one there; a second question resumes at 192 with nothing to run again,
+    to a cold run's tokens."""
+    monkeypatch.setattr(_solar_steps, "SNAPSHOT_POLICY", SnapshotsAtBlocks)
+    engine = PagedEngine(CFG, params, ECFG)
+    assert type(engine._snapshots) is SnapshotsAtBlocks
+    doc = prompt(131, 200)
+    q1, q2 = doc + prompt(132, 21), doc + prompt(133, 30)
+    fork = doc[:100] + prompt(134, 60)
+    cache = engine._prefix_cache
+
+    def snapshots_of(p):
+        return [i * 16 for i, k in enumerate(chain_keys(p, 16), 1)
+                if cache.has_snapshot(k)]
+
+    serve(engine, [q1])
+    assert snapshots_of(q1) == [64, 128, 192]
+    forked, = serve(engine, [fork])
+    assert forked["resume_from"] == 64
+    assert snapshots_of(fork) == [64, 128, 160]
+    warm, = serve(engine, [q2])
+    s = engine.stats()
+    assert warm["resume_from"] == 192 and s["snapshots_restored"] == 2
+    assert s["snapshots_taken"] == 5 and s["snapshots_evicted"] == 0
+    assert s["snapshot_rerun_tokens"] == 96 - 64
+    cold, = serve(engine, [q2], cold=True)
+    assert cold["resume_from"] == 0
+    assert warm["token_ids"] == cold["token_ids"]
 
 
 def test_a_new_document_takes_the_oldest_idle_one_whole(params):
