@@ -34,7 +34,9 @@ MODEL_FAMILIES = {
     "solar": ("ray_tpu.models.solar", "SolarConfig", "seeded_params",
               "ray_tpu.llm._solar_steps"),
     "brumby": ("ray_tpu.models.brumby", "BrumbyConfig", "init_params",
-               "ray_tpu.llm._brumby_steps")}
+               "ray_tpu.llm._brumby_steps"),
+    "mellum": ("ray_tpu.models.mellum", "MellumConfig", "init_params",
+               "ray_tpu.llm._mellum_steps")}
 
 
 def step_set(cfg):
@@ -76,7 +78,7 @@ class LLMConfig:
     # "<family>:<preset>": a model family of `MODEL_FAMILIES` and a preset
     # (a classmethod of its config class); a bare preset is the Llama
     # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny",
-    # "solar:solar_open2", "brumby:brumby_14b"
+    # "solar:solar_open2", "brumby:brumby_14b", "mellum:mellum2_12b"
     model: str = "tiny"
     model_overrides: Dict[str, Any] = field(default_factory=dict)
     checkpoint_path: Optional[str] = None  # pickled params pytree

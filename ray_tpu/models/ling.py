@@ -455,15 +455,17 @@ def _grouped_experts(cfg: LingConfig, p, rows, counts):
                        p["e_w2"].astype(dt), counts)
 
 
-def moe_held(cfg: LingConfig, p, x, live, shared: bool = True):
+def moe_held(cfg: LingConfig, p, x, live, shared: bool = True, routing=None):
     """x [N, D] normed; live [N] bool (False: padding or an empty slot,
-    which is routed nowhere). Returns (y [N, D], routing [N, top_k + 1]
+    which is routed nowhere). `routing` (default `route`, this family's and
+    Solar's) is the family's router: `routing(cfg, p, x)` -> `route`'s
+    four. Returns (y [N, D], routing [N, top_k + 1]
     int32: the chosen experts and the kept-groups mask, counters [4] int32:
     pairs routed, pairs on held experts, held experts with a row, the most
     rows on one held expert, the router's scores [N, n_experts] float32)."""
     with jax.named_scope("moe"):
         N, k, n = x.shape[0], cfg.top_k, cfg.n_held
-        experts, weights, bits, scores = route(cfg, p, x)
+        experts, weights, bits, scores = (routing or route)(cfg, p, x)
         local = experts - cfg.held_start
         held = (local >= 0) & (local < n) & live[:, None]
         # rows sorted by held expert; what is not held sorts last and

@@ -26,6 +26,16 @@ against. Which one a decode step uses is decided when the step is built
 `chunk_attention` is the other reader of the pool: the rows of one prompt
 chunk that rides in a decode step, over their own sequence's table, in XLA
 on every backend.
+
+**A window** (`window`, a static number of positions, on all three): a row
+at position p sees the keys at p - window < j <= p and nothing before them.
+The table is then read as a *ring*: the block that holds position j is entry
+``(j // BS) mod max_blocks`` of the slot's row, so a table of a few blocks
+(a slot's own, fixed: `llm/_mellum_steps.py`) serves a sequence of any
+length whose writer puts position j at ``j mod (max_blocks * BS)``. The
+kernel starts at the window's first page and fetches none behind it. A
+table as long as the sequence is a ring that never wraps. Callers that
+give no window trace the programs they traced before it existed.
 """
 
 from __future__ import annotations
@@ -51,16 +61,23 @@ _MAX_GROUP_PAGES = 16
 # ---------------------------------------------------------------------------
 
 
-def xla_decode_attention(q, kc, vc, layer, tables, lengths):
+def xla_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
     """q [B, H, HD]; kc/vc [L, NB, BS, KV, HD], of which layer `layer`
     (a scalar) is read; tables [B, max_blocks]; lengths [B] (0 = inactive
     slot) → o [B, H, HD]. Gathers every block of every table row and scores
-    all ``max_blocks * BS`` positions."""
+    all ``max_blocks * BS`` positions. With a `window` the last `window`
+    positions alone count, and the table is a ring (module docstring)."""
     B, H, hd = q.shape
     kcl, vcl = kc[layer], vc[layer]
     _, bs, kvh, _ = kcl.shape
     Lmax = tables.shape[1] * bs
-    valid = jnp.arange(Lmax)[None, :] < lengths[:, None]         # [B,Lmax]
+    if window is None:
+        valid = jnp.arange(Lmax)[None, :] < lengths[:, None]     # [B,Lmax]
+    else:
+        # the position a ring index holds: the newest that lands on it
+        last = lengths[:, None] - 1
+        pos = last - jnp.mod(last - jnp.arange(Lmax)[None, :], Lmax)
+        valid = (pos >= 0) & (pos > last - window)
     # paged gather: [B, max_blocks, BS, KV, HD] → [B, Lmax, KV, HD]
     k_all = kcl[tables].reshape(B, Lmax, kvh, hd)
     v_all = vcl[tables].reshape(B, Lmax, kvh, hd)
@@ -76,7 +93,8 @@ def xla_decode_attention(q, kc, vc, layer, tables, lengths):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)[:, 0]
 
 
-def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
+def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512,
+                    window=None):
     """Causal attention of one sequence's prompt chunk over its own block
     table: q [C, H, HD] at absolute positions qpos [C]; kc/vc [L, NB, BS, KV,
     HD], of which layer `layer` (a scalar) is read after the chunk's own keys
@@ -84,13 +102,23 @@ def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
     sees the keys at positions <= qpos[i]: cached prefix, earlier chunks and
     its own chunk alike. Keys are read `tile` positions at a time up to `end`
     (the chunk's last position + 1) with an online softmax, so the scores
-    cost what the live context costs and not max_model_len. -> [C, H, HD]."""
+    cost what the live context costs and not max_model_len. With a `window`
+    row i sees qpos[i] - window < j <= qpos[i], the tiles start at the
+    block of the first row's oldest key, and `row` is a ring (module
+    docstring) that must hold window + C - 1 positions: the chunk's keys
+    are written before its rows attend. -> [C, H, HD]."""
     C, H, hd = q.shape
     L, NB, bs, kvh, _ = kc.shape
     rep = H // kvh
     blocks = max(1, min(tile, row.shape[0] * bs) // bs)   # blocks a tile
     tile = blocks * bs
-    row = jnp.pad(row, (0, -row.shape[0] % blocks)) + layer * NB
+    ring = row.shape[0]
+    first = 0                                  # the first block a tile reads
+    if window is None:
+        row = jnp.pad(row, (0, -ring % blocks)) + layer * NB
+    else:
+        row = row + layer * NB
+        first = jnp.maximum(qpos[0] - window + 1, 0) // bs
     k_pages = kc.reshape(L * NB, bs, kvh, hd)             # the same bytes
     v_pages = vc.reshape(L * NB, bs, kvh, hd)
     # a query head next to the others of its KV head: no repeat of K or V
@@ -99,12 +127,19 @@ def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
 
     def one_tile(t, carry):
         m, l, acc = carry
-        at = lax.dynamic_slice(row, (t * blocks,), (blocks,))
+        if window is None:
+            at = lax.dynamic_slice(row, (t * blocks,), (blocks,))
+        else:
+            at = row[(first + t * blocks + jnp.arange(blocks)) % ring]
         k = k_pages[at].reshape(tile, kvh, hd)
         v = v_pages[at].reshape(tile, kvh, hd)
         s = jnp.einsum("ckrd,wkd->krcw", qg, k,
                        preferred_element_type=jnp.float32) * scale
-        seen = (t * tile + jnp.arange(tile))[None, :] <= qpos[:, None]
+        if window is None:
+            seen = (t * tile + jnp.arange(tile))[None, :] <= qpos[:, None]
+        else:
+            pos = ((first + t * blocks) * bs + jnp.arange(tile))[None, :]
+            seen = (pos <= qpos[:, None]) & (pos > qpos[:, None] - window)
         s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
@@ -118,6 +153,8 @@ def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
     m0 = jnp.full((kvh, rep, C, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((kvh, rep, C, 1), jnp.float32)
     acc0 = jnp.zeros((kvh, rep, C, hd), jnp.float32)
+    if window is not None:
+        end = end - first * bs
     _, l, acc = lax.fori_loop(0, (end + tile - 1) // tile, one_tile,
                               (m0, l0, acc0))
     o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)     # [KV, rep, C, HD]
@@ -129,35 +166,50 @@ def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512):
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(tables_ref, lengths_ref, first_ref, q_ref, token_ref,
-                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, parity_ref, *,
-                   scale, group_pages, page_rows, block_size, max_blocks):
+def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
+                   group_pages, page_rows, block_size, max_blocks, window):
     """One grid step = one slot. tables_ref [B * max_blocks], lengths_ref
     [B], first_ref [1] (the layer's first page in the pool) in SMEM;
     q_ref/o_ref [1, Hp, HD]; token_ref [Hp, rows] (`_column_tokens`);
     k_hbm/v_hbm [L * NB, page_rows, HD] in HBM; k_buf/v_buf [2, rows, HD]
     with rows = group_pages * page_rows; sems [2, 2] (K/V x buffer);
     parity_ref [1]: the buffer the next group lands in, carried from slot
-    to slot."""
+    to slot. With a `window` (static) a slot's pages are counted from the
+    block of its first live position, length - window, and its table is a
+    ring: three scalar operations a page and one comparison a group more,
+    and none of them traced without one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    (q_ref, token_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+     parity_ref) = refs
     G, bs = group_pages, block_size
     b, B = pl.program_id(0), pl.num_programs(0)
 
+    def first_block(slot):
+        """The block of the slot's oldest live position."""
+        return lax.div(jnp.maximum(lengths_ref[slot] - window, 0), bs)
+
     def pages_of(slot):
-        return lax.div(lengths_ref[slot] + (bs - 1), bs)
+        pages = lax.div(lengths_ref[slot] + (bs - 1), bs)
+        return pages if window is None else pages - first_block(slot)
 
     def each_page(slot, g, buf, act):
         """`act` on the K and the V copy of every live page of group g of
         `slot`: nothing is done, and the table is not read, from the first
         page past the live length on."""
         left = pages_of(slot) - g * G
+        if window is not None:
+            first = first_block(slot) + g * G
         for i in range(G):
             @pl.when(i < left)
             def _(i=i):
-                page = first_ref[0] + tables_ref[
-                    slot * max_blocks + g * G + i]
+                if window is None:
+                    page = first_ref[0] + tables_ref[
+                        slot * max_blocks + g * G + i]
+                else:
+                    page = first_ref[0] + tables_ref[
+                        slot * max_blocks + lax.rem(first + i, max_blocks)]
                 dst = pl.ds(i * page_rows, page_rows)
                 act(pltpu.make_async_copy(
                     k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]))
@@ -185,6 +237,8 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, q_ref, token_ref,
     n_groups = jnp.maximum(lax.div(pages_of(b) + (G - 1), G), 1)
     q = q_ref[0]                                             # [Hp, HD]
     token = token_ref[...]                                   # [Hp, rows]
+    if window is not None:
+        first = first_block(b)
 
     def group(g, carry):
         m, l, acc = carry
@@ -205,7 +259,12 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, q_ref, token_ref,
         v = v_buf[buf]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        valid = token < length - g * (G * bs)
+        if window is None:
+            valid = token < length - g * (G * bs)
+        else:
+            # the group's first position, then the live ones from it
+            at = (first + g * G) * bs
+            valid = (token < length - at) & (token >= length - window - at)
         s = jnp.where(valid, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
@@ -248,7 +307,7 @@ def _group_pages(page_rows: int, max_blocks: int) -> int:
     return max(1, min(_GROUP_ROWS // page_rows, _MAX_GROUP_PAGES, max_blocks))
 
 
-def paged_decode_attention(q, kc, vc, layer, tables, lengths):
+def paged_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
     """The kernel: same arguments and result as `xla_decode_attention`.
     It is handed the whole pool and the layer's number, not the layer's
     slice: a slice of the pool as an operand is a copy of it (84 MB a layer
@@ -267,14 +326,20 @@ def paged_decode_attention(q, kc, vc, layer, tables, lengths):
     tile = _sublane_tile(q.dtype)
     Hp = -(-H // tile) * tile
     qp = q if Hp == H else jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
-    lengths = jnp.clip(lengths, 0, max_blocks * bs).astype(jnp.int32)
+    if window is None:
+        lengths = jnp.clip(lengths, 0, max_blocks * bs)
+    else:
+        # the ring holds the window, and a page's turn comes once a slot
+        assert window + bs <= max_blocks * bs, (window, bs, max_blocks)
+    lengths = lengths.astype(jnp.int32)
     # for XLA's scheduler only: the live tokens are data, a quarter of the
     # tables' reach is a guess
     live = B * max_blocks * bs // 4
 
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(hd), group_pages=G,
-        page_rows=page_rows, block_size=bs, max_blocks=max_blocks)
+        page_rows=page_rows, block_size=bs, max_blocks=max_blocks,
+        window=window)
     o = pl.pallas_call(
         kernel,
         name="paged_decode_attention",
@@ -344,6 +409,7 @@ def decode_path(n_heads: int, n_kv_heads: int, head_dim: int,
     return KERNEL, None
 
 
-def decode_attention(path: str, q, kc, vc, layer, tables, lengths):
+def decode_attention(path: str, q, kc, vc, layer, tables, lengths,
+                     window=None):
     fn = paged_decode_attention if path == KERNEL else xla_decode_attention
-    return fn(q, kc, vc, layer, tables, lengths)
+    return fn(q, kc, vc, layer, tables, lengths, window)

@@ -239,3 +239,148 @@ def test_chunk_attention_equals_dense_causal_attention(start, n, dtype):
     want = np.einsum("hcw,whd->chd", p / p.sum(-1, keepdims=True), values)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(got[:n], want[:n], atol=tol, rtol=tol)
+
+
+# --- a window over a ring -----------------------------------------------------
+
+
+def ring_case(lens, window, ring_blocks, *, heads=8, kv=2, seed=0):
+    """Slots whose last `window` positions lie in a ring of `ring_blocks`
+    blocks each (position j at ring index j mod R), float32. The pages that
+    hold a live position are finite (garbage where a position is dead), every
+    other page of the pool is NaN: a page behind the window must not be
+    fetched. -> (arguments with the NaN pool, with a clean one, the dense
+    softmax over the live positions)."""
+    rng = np.random.default_rng(seed)
+    B, R = len(lens), ring_blocks * BS
+    T = max(max(lens), 1)
+    K, V = (rng.standard_normal((B, T, kv, HD)).astype(np.float32)
+            for _ in range(2))
+    q = rng.standard_normal((B, heads, HD)).astype(np.float32)
+    pool_k = np.full((2, B * ring_blocks, BS, kv, HD), np.nan, np.float32)
+    pool_v = pool_k.copy()
+    want = np.zeros((B, heads, HD), np.float32)
+    for b, n in enumerate(lens):
+        lo = max(0, n - window)
+        for j in range(lo, n):
+            pool_k[1, b * ring_blocks + (j % R) // BS] = 7.0
+            pool_v[1, b * ring_blocks + (j % R) // BS] = -5.0
+        for j in range(lo, n):
+            pool_k[1, b * ring_blocks + (j % R) // BS, j % BS] = K[b, j]
+            pool_v[1, b * ring_blocks + (j % R) // BS, j % BS] = V[b, j]
+        for h in range(heads if n else 0):
+            g = h // (heads // kv)
+            s = K[b, lo:n, g] @ q[b, h] / np.sqrt(HD)
+            p = np.exp(s - s.max())
+            want[b, h] = (p / p.sum()) @ V[b, lo:n, g]
+    tables = (np.arange(B)[:, None] * ring_blocks
+              + np.arange(ring_blocks)[None]).astype(np.int32)
+    rest = (1, jnp.asarray(tables), jnp.asarray(lens, jnp.int32))
+    nan = (jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v)) + rest
+    clean = (jnp.asarray(q), jnp.asarray(np.nan_to_num(pool_k)),
+             jnp.asarray(np.nan_to_num(pool_v))) + rest
+    return nan, clean, want
+
+
+@pytest.mark.parametrize("lens,window,ring_blocks,heads,kv", [
+    ([5, 0, 40, 100], 32, 4, 8, 2),       # inside, inactive, across, wrapped
+    ([33, 64, 65, 1000], 32, 4, 8, 2),    # the ring's end, many wraps
+    ([31, 32, 47, 48], 32, 3, 8, 2),      # a ring of window + one block
+    ([200, 17, 16, 15], 40, 4, 2, 1),     # a window of no whole blocks
+    ([9, 130, 1, 77], 24, 3, 32, 4),      # the cell's 32 heads on 4
+], ids=["mixed", "wraps", "tight_ring", "ragged_window", "gqa_8to1"])
+def test_a_window_reads_the_ring_and_no_page_behind_it(
+        interpreted, lens, window, ring_blocks, heads, kv):
+    """The kernel in the interpreter, its XLA twin and the dense softmax
+    over the last `window` positions agree, on a pool whose pages behind the
+    window are NaN for the kernel."""
+    nan, clean, want = ring_case(lens, window, ring_blocks, heads=heads, kv=kv)
+    twin = np.asarray(pa.xla_decode_attention(*clean, window))
+    np.testing.assert_allclose(twin, want, atol=2e-5)
+    got = np.asarray(jax.jit(pa.paged_decode_attention, static_argnums=(6,))(
+        *nan, window))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert not got[np.asarray(lens) == 0].any()
+
+
+def test_a_window_longer_than_every_sequence_is_no_window(interpreted):
+    """A table as long as the sequences is a ring that never wraps."""
+    rng = np.random.default_rng(5)
+    lens = [40, 0, 17, 100]
+    (k, v), _, tables = pool_and_tables(rng, lens, kv=2, max_blocks=8,
+                                        dtype=jnp.float32)
+    q = jnp.asarray(rng.standard_normal((4, 8, HD)), jnp.float32)
+    lengths = jnp.asarray(lens, jnp.int32)
+    live = np.asarray(lens) > 0
+    plain = np.asarray(pa.xla_decode_attention(q, k, v, 1, tables, lengths))
+    for fn in (pa.xla_decode_attention, jax.jit(
+            pa.paged_decode_attention, static_argnums=(6,))):
+        np.testing.assert_allclose(
+            np.asarray(fn(q, k, v, 1, tables, lengths, 112))[live],
+            plain[live], atol=2e-5)
+    # and a window of 30 on the same pool reads the last 30 alone
+    short = np.asarray(pa.xla_decode_attention(q, k, v, 1, tables, lengths, 30))
+    kern = np.asarray(jax.jit(pa.paged_decode_attention, static_argnums=(6,))(
+        q, k, v, 1, tables, lengths, 30))
+    np.testing.assert_allclose(kern[live], short[live], atol=2e-5)
+    assert np.abs(short[0] - plain[0]).max() > 1e-3      # 40 > 30
+    np.testing.assert_allclose(short[2], plain[2], atol=2e-5)   # 17 < 30
+
+
+@pytest.mark.parametrize("start,n", [(0, 20), (30, 32), (50, 7), (200, 32)])
+def test_a_chunks_rows_see_their_window_of_the_ring(start, n):
+    """`chunk_attention` with a window over a ring of window + C positions,
+    the chunk's keys written first: row i sees start + i - window < j <=
+    start + i, whatever the ring's wrap; padded rows write nothing."""
+    C, window, kv, heads = 32, 24, 2, 8
+    ring_blocks = -(-(window + C) // BS)
+    R = ring_blocks * BS
+    rng = np.random.default_rng(start)
+    T = start + n
+    K, V = (rng.standard_normal((T, kv, HD)).astype(np.float32)
+            for _ in range(2))
+    q = rng.standard_normal((C, heads, HD)).astype(np.float32)
+    pool_k = rng.standard_normal((1, ring_blocks, BS, kv, HD)).astype(np.float32)
+    pool_v = pool_k.copy()
+    for j in range(max(0, T - R), T):
+        pool_k[0, (j % R) // BS, j % BS] = K[j]
+        pool_v[0, (j % R) // BS, j % BS] = V[j]
+    qpos = start + np.arange(C)
+    got = np.asarray(pa.chunk_attention(
+        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v), 0,
+        jnp.arange(ring_blocks, dtype=jnp.int32), jnp.asarray(qpos, jnp.int32),
+        jnp.int32(T), tile=2 * BS, window=window))
+    for i in range(n):
+        lo = max(0, qpos[i] - window + 1)
+        for h in range(heads):
+            g = h // (heads // kv)
+            s = K[lo:qpos[i] + 1, g] @ q[i, h] / np.sqrt(HD)
+            p = np.exp(s - s.max())
+            np.testing.assert_allclose(
+                got[i, h], (p / p.sum()) @ V[lo:qpos[i] + 1, g], atol=2e-5)
+
+
+def kernel_equations(window):
+    from tests.test_v5e_compile import equations, pallas_programs
+
+    spec = jax.ShapeDtypeStruct
+    traced = jax.make_jaxpr(
+        lambda q, k, v, t, l: pa.paged_decode_attention(q, k, v, 1, t, l, window)
+    )(spec((4, 32, HD), jnp.bfloat16), spec((2, 64, 32, 4, HD), jnp.bfloat16),
+      spec((2, 64, 32, 4, HD), jnp.bfloat16), spec((4, 40), jnp.int32),
+      spec((4,), jnp.int32))
+    (program,) = pallas_programs(traced.jaxpr, "paged_decode_attention")
+    return equations(program)
+
+
+def test_the_window_costs_callers_without_one_nothing():
+    """One body: without a window it is the program it was (455 equations at
+    eight pages a group, counted on the tree before the window existed), and
+    the window is a few scalar operations a page and one comparison a group
+    more, not a second body beside it."""
+    assert kernel_equations(None) == KERNEL_EQUATIONS
+    assert KERNEL_EQUATIONS < kernel_equations(1024) <= KERNEL_EQUATIONS + 80
+
+
+KERNEL_EQUATIONS = 455

@@ -217,7 +217,9 @@ def test_flash_kernels_compile_for_v5e_under_their_names(on_v5e, build,
         assert found == [signature], (name, calls)
 
 
-V5E_HBM_BYTES = 15.75e9    # what the runtime leaves a program of 16 GiB
+# 15.75 GiB written as GB: 1.16e9 under the chip's own limit
+# (`V5E_BYTES_LIMIT` below), so the tests held to it are held the tighter
+V5E_HBM_BYTES = 15.75e9
 
 
 def mistral_decode_step(on_v5e, monkeypatch, blocks, width):
@@ -580,6 +582,87 @@ def test_brumby_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
             - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_HBM_BYTES
     print("brumby step: weights %.3f GB caches %.3f GB temp %.3f GB" % (
         weight_bytes / 1e9, cache_bytes / 1e9, m.temp_size_in_bytes / 1e9))
+
+
+# the chip's own `bytes_limit` (PERF.md section 7, PR 44), in bytes: what
+# `V5E_HBM_BYTES` above rounds down, having written GiB as GB
+V5E_BYTES_LIMIT = 16_909_334_528
+
+
+def test_mellum_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
+        on_v5e, monkeypatch):
+    """`jit_paged_decode_step` of the Mellum 2 family at the cell's shapes
+    (published widths, two periods of 8 layers, all 64 experts, 46 slots,
+    12,288 blocks of 32, tables of 1,056) with the widest chunk: the decode
+    rows' attention is the paged kernel in every layer, over the block pool
+    in the two full layers and over the slots' rings, seen as a pool, in the
+    six window layers; pool and rings are donated and not copied; a layer's
+    experts are one call of the grouped kernel over the chunk's and the
+    slots' 2,416 pairs (19 row tiles); caches of 1.61 + 0.72 GB; and
+    weights, caches and temporaries fit under the chip's own limit."""
+    from ray_tpu.llm import _mellum_steps
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import mellum
+
+    monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    cfg = mellum.MellumConfig(
+        n_layers=8, layer_ids=tuple(range(8)), max_seq_len=33792)
+    ecfg = EngineConfig(max_num_seqs=46, kv_block_size=32,
+                        num_kv_blocks=12288, max_model_len=33792,
+                        prefix_cache=False)
+    assert cfg.kinds() == ["window"] * 3 + ["full"] + ["window"] * 3 + ["full"]
+    C = _mellum_steps.chunk_ladder(ecfg)[-1]
+    assert C == 256 and _mellum_steps._ring_positions(cfg, ecfg) == 1280
+    step, path, note = _mellum_steps.make_decode_step(cfg, ecfg)
+    assert (path, note) == (pa.KERNEL, None)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: mellum.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _mellum_steps.alloc_cache(cfg, ecfg))]
+    B = 46
+    compiled = step.trace(
+        C, params, *caches, on_v5e((B, 1056), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((B,), jnp.bool_),
+        on_v5e((B,), jnp.int32), on_v5e((B, 2), jnp.uint32),
+        on_v5e((B,), jnp.float32),
+        on_v5e((B + len(_mellum_steps.COUNTERS) + 3,), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((C,), jnp.int32),
+        on_v5e((3,), jnp.int32), on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines()
+               if PALLAS in line and "%paged_decode_attention" in line]
+    assert len(kernels) == 8
+    # the full layers read the block pool, the window layers the rings as
+    # 46 slots x 40 blocks a layer
+    assert sum("bf16[24578,128,128]" in k for k in kernels) == 2
+    assert sum("bf16[11040,128,128]" in k for k in kernels) == 6
+    experts = grouped_ffn_calls(hlo)
+    assert len(experts) == 8
+    # 2,416 pairs, padded by the kernel's caller to 19 row tiles of 128
+    assert all(res == ["bf16[2432,2304]"] and "bf16[64,2304,896]" in ops
+               for ops, res in experts)
+    # neither the pool nor the rings is copied whole
+    assert not re.findall(r" copy\([^)]*(?:12289|6,46,1280)", hlo)
+    m = compiled.memory_analysis()
+    pool_bytes = sum(c.size * c.dtype.itemsize for c in caches[:2])
+    ring_bytes = sum(c.size * c.dtype.itemsize for c in caches[2:])
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    assert 1.60e9 < pool_bytes < 1.62e9 and 0.72e9 < ring_bytes < 0.73e9
+    assert 7.58e9 < weight_bytes < 7.60e9
+    assert m.alias_size_in_bytes >= pool_bytes + ring_bytes
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_BYTES_LIMIT
+    print("mellum step: weights %.3f GB pool %.3f GB rings %.3f GB temp "
+          "%.3f GB" % (weight_bytes / 1e9, pool_bytes / 1e9, ring_bytes / 1e9,
+                       m.temp_size_in_bytes / 1e9))
 
 
 @functools.lru_cache(maxsize=None)
