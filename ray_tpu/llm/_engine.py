@@ -20,7 +20,10 @@ module is the scheduler, and serves any model family of
   prefix-cache match); the prompt then runs as chunks of a few static
   widths, one chunk of one request a step, in the same program and the same
   weight matmuls as the running slots' tokens, so nobody waits for a
-  prefill. The request's first token is sampled in the step that holds its
+  prefill. Chunk steps alternate between the oldest prompt in chunks and the
+  one with the least left to run (`_next_chunk`): a short prompt does not
+  wait out every long one, and the oldest has every second chunk step at
+  least. The request's first token is sampled in the step that holds its
   prompt's last token and the slot decodes from the next step on. Where the
   ladder is empty each prompt runs whole through the step set's prefill,
   awaited in the loop. The ladder is the one thing the scheduler's path
@@ -784,8 +787,10 @@ class PagedEngine:
         self._prefill = (None if make_prefill is None
                          else make_prefill(cfg, e))
         # admitted requests whose prompts are not all in the pool yet, in
-        # arrival order: the head's next chunk rides in the next step
+        # arrival order, and whose turn the next chunk step is: the oldest's,
+        # else that of the one with the least left to run (`_next_chunk`)
         self._prefilling: "collections.deque[_Request]" = collections.deque()
+        self._oldest_turn = True
         self._pending: "asyncio.Queue[_Request]" = None  # type: ignore
         self._inject = None  # the step set's KV scatter, at first use (P/D)
         self._loop_task = None
@@ -804,6 +809,8 @@ class PagedEngine:
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
         self.prefill_chunk_pad_tokens = 0
+        # chunk steps given to a request that was not the oldest in chunks
+        self.chunk_overtakes = 0
         # positions decode attention had to read (each active slot's context,
         # the current token's included) and what scoring max_model_len
         # positions of every slot reads: their ratio is the live share
@@ -1054,15 +1061,25 @@ class PagedEngine:
         self._activate_slot(req, slot, tok)
 
     def _next_chunk(self):
-        """The oldest admitted request whose prompt is not all in the pool,
-        and what of it the next step runs: (request, tokens, width), the
-        width the narrowest of the ladder that holds the tokens; None when
-        no prompt is waiting."""
-        while self._prefilling and self._prefilling[0].slot < 0:
-            self._prefilling.popleft()      # released by the abort sweep
+        """The admitted request whose prompt the next step runs a chunk of,
+        and what of it: (request, tokens, width), the width the narrowest of
+        the ladder that holds the tokens; None when no prompt is waiting.
+        Chunk steps alternate between the oldest request in chunks and the
+        one with the fewest prompt tokens left (ties to the older): a short
+        prompt passes the long ones ahead of it, and the oldest still
+        advances on every second chunk step at least, so no prompt starves
+        and none takes more than twice the steps it takes alone. The rule
+        reads only what the queue holds; with one request in chunks, or both
+        choices the same, it is the oldest's chunk every step."""
+        if any(r.slot < 0 for r in self._prefilling):
+            # released by the abort sweep, wherever they stood
+            self._prefilling = collections.deque(
+                r for r in self._prefilling if r.slot >= 0)
         if not self._prefilling:
             return None
         req = self._prefilling[0]
+        if not self._oldest_turn:
+            req = min(self._prefilling, key=lambda r: len(r.prompt) - r.cursor)
         n = min(len(req.prompt) - req.cursor, self._ladder[-1])
         if self._snapshots is not None:
             n = self._snapshots.cut(req, n)
@@ -1101,6 +1118,9 @@ class PagedEngine:
         step leaves on the device (`FED_CHUNK`)."""
         slot = req.slot
         req.cursor += n
+        # a dispatched chunk spends the turn, whoever it went to
+        self.chunk_overtakes += req is not self._prefilling[0]
+        self._oldest_turn = not self._oldest_turn
         if self._prefix_cache is not None:
             # every FULL prompt block in the pool (matched, then written by
             # the chunks so far) is cacheable; this request holds one ref on
@@ -1116,7 +1136,7 @@ class PagedEngine:
                     self._snapshots.attached(req, key)
         if req.cursor < len(req.prompt):
             return
-        self._prefilling.popleft()
+        self._prefilling.remove(req)
         if req.admitted_mid_decode:
             self.mid_decode_admissions += 1
         self.lens[slot] = len(req.prompt)
@@ -1415,8 +1435,8 @@ class PagedEngine:
             t_step = time.monotonic()
             try:
                 # one hop to a thread: the next step out (a token for every
-                # active slot and, riding along, the next chunk of the
-                # oldest admitted prompt), then the wait for the one before
+                # active slot and, riding along, the next chunk of an
+                # admitted prompt), then the wait for the one before
                 fetched = await asyncio.to_thread(
                     self._run_step, dispatch, chunk, flight)
             except Exception as e:  # noqa: BLE001 — decode step failed
@@ -1807,6 +1827,8 @@ class PagedEngine:
             "prefill_chunk_pad_tokens": self.prefill_chunk_pad_tokens,
             # one chunk a step: the same count until a step carries several
             "steps_with_chunk": self.prefill_chunks,
+            # of the chunks dispatched, those not the oldest prompt's
+            "chunk_overtakes": self.chunk_overtakes,
             "admissions": self.admissions,
             "admit_host_s": self.admit_host_s,
             "prefix_cache": cache.stats() if cache is not None else None,

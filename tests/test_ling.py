@@ -282,7 +282,8 @@ def test_a_llama_engine_allocates_no_state_and_keeps_its_stats():
         "mid_decode_admissions", "prefix_cache", "attn_positions_live",
         "attn_positions_dense", "decode_attention", "prefill_chunks",
         "prefill_chunk_tokens", "prefill_chunk_pad_tokens", "steps_with_chunk",
-        "steps_ahead", "rows_dropped", "admissions", "admit_host_s"}
+        "chunk_overtakes", "steps_ahead", "rows_dropped", "admissions",
+        "admit_host_s"}
 
 
 def test_llm_config_resolves_the_family():

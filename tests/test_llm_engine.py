@@ -999,3 +999,185 @@ def test_a_prompts_last_chunk_and_first_decode_row_ride_consecutive_steps(
     stats = eng.stats()
     assert stats["steps"] == 40 and stats["rows_dropped"] == 0
     assert stats["steps_ahead"] == 39
+
+
+# ---------------------------------------------------------------------------
+# which admitted prompt a step's one chunk goes to
+# ---------------------------------------------------------------------------
+
+# widest chunk 16 at a length of 127: a prompt of up to eight chunks
+ORDER_ECFG = EngineConfig(max_num_seqs=5, kv_block_size=4, num_kv_blocks=160,
+                          max_model_len=127, prefix_cache=False)
+WIDEST = 16
+
+
+def chunks_of(n):
+    return -(-n // WIDEST)
+
+
+def order_engine(chunk_params):
+    """An engine that notes every dispatched step: (slot, start, tokens) of
+    the chunk it carries, or None."""
+    eng = PagedEngine(CFG, chunk_params, ORDER_ECFG)
+    step, seen = eng._decode, []
+
+    def recording(width, *args):
+        seen.append(tuple(int(x) for x in np.asarray(args[-1]))
+                    if width else None)
+        return step(width, *args)
+
+    eng._decode = recording
+    return eng, seen
+
+
+async def first_token(eng, prompt):
+    return [t async for t in eng.generate_stream(prompt, max_tokens=1)]
+
+
+PASSED = (115, 5, 20, 9)
+
+
+@pytest.fixture(scope="module")
+def passed(chunk_params):
+    """One prompt of eight chunks and three of one, two and one admitted
+    behind it in the same turn, a token each: (the tokens, which prompt each
+    chunk step went to, the engine's stats). Admitted in list order, so
+    prompt i holds slot i."""
+    eng, seen = order_engine(chunk_params)
+    prompts = [a_prompt(n, salt=21) for n in PASSED]
+    outs = serve_all(eng, prompts, 1)
+    assert outs == greedy_reference(chunk_params, prompts, 1)
+    return [at[0] for at in seen], eng.stats()
+
+
+def test_short_prompts_pass_a_long_one_which_runs_on_every_second_chunk_step(
+        passed):
+    """The chunk steps alternate between the oldest and the one with the
+    least left, so each short prompt's last chunk runs within twice the
+    chunks of itself and of the shorter ones ahead of it, and the long one
+    runs on every second chunk step until they are through."""
+    order, stats = passed
+    assert order == [0, 1, 0, 3, 0, 2, 0, 2, 0, 0, 0, 0]
+    for i in (1, 2, 3):
+        ahead = sum(chunks_of(n) for j, n in enumerate(PASSED)
+                    if j and (n, j) <= (PASSED[i], i))
+        assert max(k for k, who in enumerate(order, 1) if who == i) <= 2 * ahead
+    # never two chunk steps in a row without the oldest
+    assert all(0 in pair for pair in zip(order, order[1:]))
+    assert stats["prefill_chunks"] == len(order) == stats["steps"]
+
+
+def test_chunk_overtakes_counts_the_chunk_steps_not_given_to_the_oldest(
+        passed):
+    order, stats = passed
+    left = [chunks_of(n) for n in PASSED]
+    overtakes = 0
+    for who in order:
+        overtakes += who != next(i for i, n in enumerate(left) if n)
+        left[who] -= 1
+    assert not any(left)
+    assert stats["chunk_overtakes"] == overtakes == 4
+    assert stats["steps_with_chunk"] == stats["prefill_chunks"] == 12
+
+
+def test_a_long_prompt_finishes_under_an_endless_supply_of_short_ones(
+        chunk_params):
+    """Three callers send one-chunk prompts back to back for as long as the
+    long prompt is in chunks: it still gets every second chunk step, and its
+    first token comes within twice its own chunks plus one."""
+    eng, seen = order_engine(chunk_params)
+    long = a_prompt(120, salt=22)
+    done = asyncio.Event()
+
+    async def caller(i):
+        k = 0
+        while not done.is_set():
+            k += 1
+            await first_token(eng, a_prompt(3 + (i + k) % 9, salt=100 * i + k))
+        return k
+
+    async def main():
+        eng._pending = eng._loop_task = None
+        first = asyncio.ensure_future(first_token(eng, long))
+        # the long prompt is the oldest: admitted before any short one
+        while not eng._prefilling:
+            await asyncio.sleep(0)
+        callers = [asyncio.ensure_future(caller(i)) for i in range(3)]
+        tok = await first
+        at = len(seen)
+        done.set()
+        return tok, at, sum(await asyncio.gather(*callers))
+
+    tok, at, sent = asyncio.run(main())
+    assert [tok] == greedy_reference(chunk_params, [long], 1)
+    assert sent > chunks_of(len(long)) // 2
+    # admitted first, so in slot 0: all its chunks within the bound
+    assert sum(1 for c in seen[:at] if c and c[0] == 0) == chunks_of(len(long))
+    assert at <= 2 * chunks_of(len(long)) + 1
+    stats = eng.stats()
+    assert 0 < stats["chunk_overtakes"] <= stats["prefill_chunks"] // 2 + 1
+
+
+@pytest.mark.parametrize("beside", [0, 2], ids=["idle", "beside_two_decoding"])
+def test_one_prompt_in_chunks_runs_as_it_always_did(chunk_params, beside):
+    """With a single request in chunks both choices name it: consecutive
+    steps carry its chunks in order, the widest until the last, as when the
+    oldest took every chunk step; nobody is overtaken."""
+    eng, seen = order_engine(chunk_params)
+    long = a_prompt(115, salt=23)
+
+    async def main():
+        eng._pending = eng._loop_task = None
+        gens = [eng.generate_stream(a_prompt(5, salt=30 + i), max_tokens=40)
+                for i in range(beside)]
+        for g in gens:
+            await g.__anext__()
+        while eng._prefilling:
+            await asyncio.sleep(0)
+        at = len(seen)
+        tok = await first_token(eng, long)
+        for g in gens:
+            await g.aclose()
+        return at, tok
+
+    at, tok = asyncio.run(main())
+    assert [tok] == greedy_reference(chunk_params, [long], 1)
+    slot = beside
+    want = [(slot, 16 * i, min(16, 115 - 16 * i)) for i in range(8)]
+    assert seen[at:at + 8] == want
+    assert eng.stats()["chunk_overtakes"] == 0
+
+
+@pytest.mark.parametrize("b_len", [90, 40], ids=["not_started", "half_run"])
+def test_an_aborted_request_in_the_middle_of_the_queue_is_skipped(
+        chunk_params, b_len):
+    """Three prompts in chunks and the caller of the middle one walks away,
+    before its first chunk (it is neither the oldest nor the shortest) or
+    after it (it is the shortest): the sweep releases its slot and blocks
+    once, the scheduler passes over it wherever it stands, and the other two
+    get the reference's tokens."""
+    eng, seen = order_engine(chunk_params)
+    a, b, c = (a_prompt(n, salt=24) for n in (120, b_len, 60))
+
+    async def main():
+        eng._pending = eng._loop_task = None
+        first = asyncio.ensure_future(first_token(eng, a))
+        while not eng._prefilling:
+            await asyncio.sleep(0)
+        gone = asyncio.ensure_future(first_token(eng, b))
+        last = asyncio.ensure_future(first_token(eng, c))
+        # until the shortest of the three has had a chunk
+        while not any(at and at[0] == (1 if b_len == 40 else 2) for at in seen):
+            await asyncio.sleep(0)
+        assert [len(r.prompt) for r in eng._prefilling] == [120, b_len, 60]
+        gone.cancel()
+        return await first, await last
+
+    outs = asyncio.run(main())
+    assert list(outs) == greedy_reference(chunk_params, [a, c], 1)
+    run = [sum(n for slot, _, n in filter(None, seen) if slot == k)
+           for k in range(3)]
+    assert run[0] == 120 and run[2] == 60
+    assert run[1] == 0 if b_len == 90 else 0 < run[1] < 40
+    assert not eng._prefilling and eng.slot_req == [None] * 5
+    assert sorted(eng.free_blocks) == list(range(1, 161))

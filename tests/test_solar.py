@@ -286,14 +286,26 @@ def test_requests_in_one_batch_do_not_touch_each_other(engine):
 
 def test_a_request_admitted_mid_prompt_keeps_the_snapshot_it_starts_from(
         params):
-    """B shares A's first blocks and is admitted while A is still in chunks:
-    it waits behind A with A's first snapshot pinned. A goes on to take three
-    more and recycles its oldest, which is B's: the entry must not be handed
-    out and overwritten before B's first chunk has copied it in."""
+    """B shares A's first blocks and is admitted while A is still in chunks,
+    with A's first snapshot pinned. B has more of its prompt left than A, so
+    A is both the oldest and the one with the least left and B waits behind
+    it (a B with less left would run its first chunk between A's, and copy
+    the snapshot in at once). A goes on to take three more and recycles its
+    oldest, which is B's: the entry must not be handed out and overwritten
+    before B's first chunk has copied it in (422 tokens on, B's answer no
+    longer shows the state it started from: the entry itself is read)."""
     ecfg = dataclasses.replace(ECFG, max_model_len=640)
     engine = PagedEngine(CFG, params, ecfg)
     a = prompt(55, 540)
-    b = a[:140] + prompt(56, 30)
+    b = a[:140] + prompt(56, 410)
+    inner, copied_in = engine._chunk_at, []
+
+    def chunk_at(req, at, n):
+        if req is not None and req.restore >= 0:
+            copied_in.append(np.asarray(engine.snap_state[req.restore]))
+        return inner(req, at, n)
+
+    engine._chunk_at = chunk_at
 
     async def go():
         engine._pending = engine._loop_task = None
@@ -301,12 +313,18 @@ def test_a_request_admitted_mid_prompt_keeps_the_snapshot_it_starts_from(
         while engine.stats()["snapshots_taken"] < 1:
             await asyncio.sleep(0)
         assert engine._prefilling[0].cursor == 128
-        second = await engine.check_routing(b, 8)
+        second = await engine.check_routing(b, 8, mechanisms=True)
         return await first, second
 
     out_a, out_b = asyncio.run(go())
     assert out_b["resume_from"] == 128
-    assert engine.stats()["snapshots_taken"] == 4
+    # what B's first chunk started from is what the entry held at admission
+    assert len(copied_in) == 1 and copied_in[0].any()
+    assert np.array_equal(copied_in[0], out_b["state0"])
+    stats = engine.stats()
+    # A's four, then B's three: B never ran a chunk between A's
+    assert stats["snapshots_taken"] == 7 and stats["chunk_overtakes"] == 0
+    assert stats["snapshots_restored"] == 1
     fresh = PagedEngine(CFG, params, ecfg)
     assert out_b["token_ids"] == serve(fresh, [b], cold=True)[0]["token_ids"]
     assert out_a["token_ids"] == serve(fresh, [a], cold=True)[0]["token_ids"]
