@@ -13,7 +13,11 @@ Everything that belongs to one cell is data, found by name:
     layer_metrics/<name>.py   one reader per per-layer metric
 
 The last line of stdout is the result: one JSON object with `correct`,
-`attempted`, `failed`, `metrics`, `device` (and `breakdown` with --trace 1).
+`attempted`, `failed`, `metrics`, `device` (and `breakdown` with --trace 1),
+then `problems` (why the run is not `correct`, each text with the numbers it
+was held to; empty when it is) and, last, `held` (each number compared beside
+its limit, under a short name, in every run of a serve cell). The same two
+are the last lines of stderr.
 With --trace 0 the metrics are the cell's end-to-end metrics; with --trace 1
 its per-layer metrics, from a run with tracing and a profiler window on.
 Progress goes to stderr. Without a TPU (or with fewer chips than the cell
@@ -227,11 +231,12 @@ def main(argv=None) -> int:
                 art["problems"].append(f"no value for {name}")
                 continue
             metrics[name] = {"value": values[name], "unit": units[name]}
-    for p in art["problems"]:
-        ctx.log(f"NOT CORRECT: {p}")
     summary = {k: art.get(k) for k in ("check", "engine_init_s", "flash_bound",
                                        "stats_open", "stats_close", "load",
-                                       "counter_tokens_per_s", "retried")}
+                                       "counter_tokens_per_s", "retried",
+                                       "close_read_late_s", "close_read_took_s",
+                                       "count_covers_s",
+                                       "capture_returned_after_close_s")}
     if art.get("gen_late_s"):
         summary["gen_late_max_ms"] = max(art["gen_late_s"]) * 1e3
     summary["trace"] = {k: v for k, v in (art.get("trace") or {}).items()
@@ -244,10 +249,20 @@ def main(argv=None) -> int:
         # rate under a device metric's name
         ctx.log(f"rehearsal metrics (not reported): {json.dumps(metrics)}")
         metrics = {}
-    result = {"correct": not art["problems"], "attempted": art["attempted"],
+    # why and by what numbers, last on stderr: the driver's record of a run
+    # that is not correct keeps the end of it and nothing else
+    held = f" [held to: {art['held']}]" if art.get("held") else ""
+    problems = [f"{p}{held}" for p in art["problems"]]
+    if not problems and held:
+        ctx.log(f"held to: {art['held']}")
+    for p in problems:
+        ctx.log(f"NOT CORRECT: {p}")
+    result = {"correct": not problems, "attempted": art["attempted"],
               "failed": art["failed"], "metrics": metrics, "device": device}
     if breakdown is not None and on_chip:
         result["breakdown"] = breakdown
+    result["problems"] = problems
+    result["held"] = art.get("held_numbers", {})
     print(json.dumps(result), flush=True)
     return 0
 

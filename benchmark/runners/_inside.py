@@ -63,7 +63,9 @@ def profile_options():
 
 
 async def engine_trace(actor, logdir: str, seconds: float) -> Dict[str, Any]:
-    """Trace this process, the one that holds the chip, for `seconds`."""
+    """Trace this process, the one that holds the chip, for `seconds`:
+    captured from `t0` to `t1`, written by `t2` (this process's
+    `time.monotonic()`, which on one host is the runner's too)."""
     import jax
 
     jax.profiler.start_trace(logdir, profiler_options=profile_options())
@@ -73,7 +75,7 @@ async def engine_trace(actor, logdir: str, seconds: float) -> Dict[str, Any]:
     finally:
         t1 = time.monotonic()
         await asyncio.to_thread(jax.profiler.stop_trace)
-    return {"logdir": logdir, "t0": t0, "t1": t1}
+    return {"logdir": logdir, "t0": t0, "t1": t1, "t2": time.monotonic()}
 
 
 def engine_memory(actor) -> Dict[str, Any]:

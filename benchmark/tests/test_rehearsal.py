@@ -17,7 +17,11 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the contract's five and, last, why a run is not `correct` and every number
+# it was held to beside its limit (PR 58; the driver ignores keys it does
+# not read)
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "problems", "held"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -52,6 +56,11 @@ def test_tiny_cell_runs_end_to_end_on_the_cpu(cell, trace, seed):
     assert set(result["device"]) == DEVICE_KEYS
     assert result["correct"] is True, proc.stderr[-3000:]
     assert result["failed"] == 0 and result["attempted"] > 0
+    assert result["problems"] == [] and list(result)[-2:] == ["problems", "held"]
+    if cell != "tiny-pretrain-fsdp4":
+        assert result["held"]["requests_failed"] == {"value": 0, "limit": 0}
+        assert proc.stderr.strip().splitlines()[-1].split("] ", 1)[1].startswith(
+            "held to: 0 of ")
     assert result["device"]["platform"] == "cpu"
     assert result["device"]["count"] == load("workloads", f"{cell}.json")["chips"]
     # a CPU run prints counts and `correct`, never a rate, a time or a
@@ -84,6 +93,27 @@ def test_a_host_fault_in_the_first_window_is_followed_by_one_more(cell, trace):
     assert summary["end_to_end"]["setup_s"] < second_ramp_at - 5.0
     if trace:
         assert sum("tracing the engine's process" in ln for ln in log) == 2
+
+
+def test_a_run_that_is_not_correct_ends_with_its_reasons():
+    """tests/forced_fault.py plants a fault of the system's: no second
+    window, exit code 0, `correct` false, and the reasons with every number
+    held beside its limit are the last lines of stderr and the result's
+    `problems`: the driver's record keeps the end of each and nothing else."""
+    proc = run_cell("tiny-docqa-closed", 0,
+                    script=os.path.join("tests", "forced_fault.py"))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert set(result) == RESULT_KEYS and result["correct"] is False
+    assert list(result)[-2:] == ["problems", "held"]
+    assert len(result["problems"]) == 1
+    assert "planted by tests/forced_fault.py [held to: 0 of " in result["problems"][0]
+    assert "client's rate / engine's count - 1 = " in result["problems"][0]
+    assert result["held"]["client_rate_over_engine_count_minus_1"]["limit"] == 0.5
+    log = [ln for ln in proc.stderr.splitlines() if ln.startswith("[bench")]
+    assert "summary: " in log[-3] and "rehearsal metrics" in log[-2]
+    assert log[-1].split("] ", 1)[1] == "NOT CORRECT: " + result["problems"][0]
+    assert not any("one more window" in ln for ln in log)
 
 
 def test_a_real_cell_refuses_to_run_without_a_chip():

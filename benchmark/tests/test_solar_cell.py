@@ -28,6 +28,8 @@ CONFIG = load("configs", "solar-open2-250b-l4-ep8.json")
 TRAFFIC = load("traffic", "longdocqa-closed.json")
 CELL = "solaropen2-longdocqa-closed"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+NEW = ["chunk_step_share", "snapshot_rerun_share", "gqa_device_share",
+       "solar_step_hbm_roofline", "solar_step_mfu"]
 
 
 def test_the_configuration_keeps_every_published_number_but_the_three_cuts():
@@ -82,13 +84,21 @@ def test_the_cell_and_its_traffic_are_the_issues_to_the_number():
     assert 16384 + 256 + 128 <= e["max_model_len"]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["file"].endswith("solar-open2-250b-l4-ep8.json")
+    # found by name, not by place: later PRs append behind these
+    entry = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert entry["why"] == cell["why"] and entry["chips"] == 1
+    config = next(c for c in bench["configs"]
+                  if c["name"] == "solar-open2-250b-l4-ep8")
+    assert config["file"].endswith("solar-open2-250b-l4-ep8.json")
     listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", ())}
     assert listed == set(cell["per_layer"]) and len(listed) == 23
-    for m in bench["per_layer"][-5:]:
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tokens_per_s"
+    # the five readers the cell brought (PR 46); later cells list the
+    # chunks' and the snapshots' beside their own
+    new = [m for m in bench["per_layer"] if m["name"] in NEW]
+    assert sorted(m["name"] for m in new) == sorted(NEW)
+    for m in new:
+        assert CELL in m["workloads"] and m["moves"] == "out_tokens_per_s"
         mod = __import__(f"benchmark.layer_metrics.{m['name']}",
                          fromlist=["x"])
         assert (mod.UNIT, mod.LAYER, mod.SOURCE, mod.MOVES) == (

@@ -305,3 +305,171 @@ def test_no_second_window_that_would_outlast_the_runs_limit():
     assert seeds == [7] and settled == [1]
     assert art["faults"] == {"late"} and art["retried"] is None
     assert any("no second window" in m for m in ctx.logged)
+
+
+# --------------------------------------------------------------------------
+# a window's edges: the count is read at the close, whatever the capture
+# takes to be written (PR 58)
+# --------------------------------------------------------------------------
+
+
+def drive_edges(capture, window_s=0.2, lead_s=0.05, second_window=False):
+    """`serve_dp.read_edges` over a short window with a fake engine and a
+    fake capture: the art, and when (from the close) each thing happened."""
+    import time
+
+    events = []
+
+    async def go():
+        t_close = 0.0
+
+        def engine_stats():
+            events.append(("stats", time.monotonic() - t_close))
+            return {"tokens_out": 1000 + len(events)}
+
+        def traced():
+            events.append(("capture starts", time.monotonic() - t_close))
+            try:
+                return capture()
+            finally:
+                events.append(("capture returns", time.monotonic() - t_close))
+
+        async def window(seed):
+            nonlocal t_close
+            t_open = time.monotonic() + lead_s
+            t_close = t_open + window_s
+            art = {"t_open": t_open, "window_s": window_s}
+            await serve_dp.read_edges(
+                art, engine_stats, lambda: 3, traced if capture else None)
+            events.append(("window returns", time.monotonic() - t_close))
+            return {**art, "records": closed_records(
+                t_open=t_open, every=0.05, callers=2)}
+
+        if not second_window:
+            return await window(7)
+
+        async def settle():
+            events.append(("settle", time.monotonic() - t_close))
+
+        # the first verdict has a host's fault: `judged_windows` opens the
+        # second window only when the first has returned
+        real = serve_dp.finish
+
+        def finish(ctx, art):
+            art = real(ctx, art)
+            if "faults" in art and not any(e[0] == "settle" for e in events):
+                art["faults"].add("late")
+                art["problems"].append("the generator ran late: planted")
+            return art
+
+        serve_dp.finish = finish
+        try:
+            return await serve_dp.judged_windows(
+                ctx_of("closed", rehearsal=True), {"ok": True}, window, settle)
+        finally:
+            serve_dp.finish = real
+
+    return asyncio.run(go()), events
+
+
+def slow_capture(after_close_s=0.3, window_s=0.2):
+    """A capture that starts a quarter into the window and returns
+    `after_close_s` after its close."""
+    import time
+
+    def capture():
+        time.sleep((1.0 - serve_dp.TRACE_FROM) * window_s + after_close_s)
+        return {"logdir": "/nowhere", "t0": 0.0, "t1": 1.0, "t2": 2.0}
+
+    return capture
+
+
+def test_the_count_is_read_at_the_close_and_the_capture_awaited_after_it():
+    art, events = drive_edges(slow_capture())
+    when = dict(events)                   # the last of each kind
+    reads = [t for kind, t in events if kind == "stats"]
+    # two readings: at the opening and within 50 ms of the close, while the
+    # capture is still being written
+    assert len(reads) == 2 and reads[0] == pytest.approx(-0.2, abs=0.05)
+    assert 0.0 <= reads[1] < 0.05 < 0.25 < when["capture returns"]
+    assert when["capture starts"] == pytest.approx(
+        -(1.0 - serve_dp.TRACE_FROM) * 0.2, abs=0.05)
+    # the window returns only when the capture has, with its call in `art`
+    assert when["window returns"] >= when["capture returns"]
+    assert art["trace_call"]["logdir"] == "/nowhere"
+    assert art["capture_returned_after_close_s"] == pytest.approx(0.3, abs=0.06)
+    # and says when the count was read and what it covers
+    assert 0.0 <= art["close_read_late_s"] < 0.05
+    assert 0.0 <= art["close_read_took_s"] < 0.05
+    assert art["count_covers_s"] == pytest.approx(0.2, abs=0.02)
+    assert (art["cache_files_open"], art["cache_files_close"]) == (3, 3)
+
+
+def test_an_untraced_window_reads_its_edges_and_waits_for_no_capture():
+    art, events = drive_edges(None)
+    assert [kind for kind, _ in events] == ["stats", "stats", "window returns"]
+    assert "trace_call" not in art and dict(events)["window returns"] < 0.1
+    assert 0.0 <= art["close_read_late_s"] < 0.05
+
+
+def test_a_second_window_never_opens_while_a_capture_is_open():
+    art, events = drive_edges(slow_capture(), second_window=True)
+    kinds = [kind for kind, _ in events]
+    # two windows, each: capture starts, the close's reading, capture
+    # returns, window returns; the engine settles between them
+    assert kinds.count("capture starts") == kinds.count("capture returns") == 2
+    assert kinds.index("settle") > kinds.index("capture returns")
+    open_ = 0
+    for kind in kinds:
+        open_ += {"capture starts": 1, "capture returns": -1}.get(kind, 0)
+        assert open_ in (0, 1)
+        if kind in ("settle", "window returns"):
+            assert open_ == 0
+    assert "the generator ran late: planted" in art["retried"]
+
+
+def test_a_capture_that_raises_fails_the_run():
+    def capture():
+        raise RuntimeError("the profiler failed")
+
+    with pytest.raises(RuntimeError, match="the profiler failed"):
+        drive_edges(capture)
+
+
+def late_art(late_s, tail_tokens):
+    """A window whose count was read `late_s` after the close, by when the
+    engine had generated `tail_tokens` more for the callers that straddled
+    it."""
+    art = art_of(closed_records(), tokens_out=COUNT + tail_tokens)
+    art.update(close_read_late_s=late_s, close_read_took_s=0.004,
+               count_covers_s=WINDOW + late_s)
+    return art
+
+
+def test_a_late_reading_is_divided_by_what_it_covers_and_says_so():
+    # read on time: today's text, today's arithmetic, and the line says when
+    ctx = ctx_of("closed")
+    art = serve_dp.finish(ctx, late_art(0.002, 0))
+    assert art["problems"] == []
+    assert art["counter_tokens_per_s"] == pytest.approx(COUNT / (WINDOW + 0.002))
+    assert any("read +0.002 s from the close in 0.004 s" in m
+               for m in ctx.logged)
+    assert art["held_numbers"]["client_rate_over_engine_count_minus_1"][
+        "limit"] == 0.03
+    # the parent's fault: 3.2% more tokens, counted 10 s after the close,
+    # against 51 s. Now a 61 s count is divided by 61 s: still the host's
+    # fault (one more window), and the text names the reading, not the traffic
+    art = serve_dp.finish(ctx_of("closed"), late_art(10.0, round(0.032 * COUNT)))
+    assert art["faults"] == {"counter"}
+    assert art["counter_tokens_per_s"] == pytest.approx(
+        (COUNT + round(0.032 * COUNT)) / 61.0)
+    assert "read 10.0 s after the close, covers 61.0 s" in art["problems"][0]
+    assert "does not hold for this traffic" not in art["problems"][0]
+    # a disagreement read on time is the traffic's, as it was
+    art = serve_dp.finish(ctx_of("closed"),
+                          late_art(0.002, round(0.06 * COUNT)))
+    assert art["faults"] == {"counter"}
+    assert "does not hold for this traffic" in art["problems"][0]
+    # a step or two late and in agreement: nothing to say
+    art = serve_dp.finish(ctx_of("closed"), late_art(0.2, 2))
+    assert art["problems"] == []
