@@ -36,7 +36,9 @@ MODEL_FAMILIES = {
     "brumby": ("ray_tpu.models.brumby", "BrumbyConfig", "init_params",
                "ray_tpu.llm._brumby_steps"),
     "mellum": ("ray_tpu.models.mellum", "MellumConfig", "init_params",
-               "ray_tpu.llm._mellum_steps")}
+               "ray_tpu.llm._mellum_steps"),
+    "joyai": ("ray_tpu.models.joyai", "JoyAIConfig", "seeded_params",
+              "ray_tpu.llm._joyai_steps")}
 
 
 def step_set(cfg):
@@ -78,7 +80,8 @@ class LLMConfig:
     # "<family>:<preset>": a model family of `MODEL_FAMILIES` and a preset
     # (a classmethod of its config class); a bare preset is the Llama
     # family's. "tiny", "llama3_8b", "ling:ling3_flash", "ling:tiny",
-    # "solar:solar_open2", "brumby:brumby_14b", "mellum:mellum2_12b"
+    # "solar:solar_open2", "brumby:brumby_14b", "mellum:mellum2_12b",
+    # "joyai:joyai_llm_flash"
     model: str = "tiny"
     model_overrides: Dict[str, Any] = field(default_factory=dict)
     checkpoint_path: Optional[str] = None  # pickled params pytree

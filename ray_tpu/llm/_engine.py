@@ -687,6 +687,7 @@ class _Step:
     probe_slot: Optional[int]       # whose mechanisms `probe` holds
     ahead: bool                     # dispatched with a step still unfetched
     live: int                       # positions its decode rows attended
+    shared: int                     # of those, in blocks several requests hold
 
 
 class PagedEngine:
@@ -816,6 +817,10 @@ class PagedEngine:
         # positions of every slot reads: their ratio is the live share
         self.attn_positions_live = 0
         self.attn_positions_dense = 0
+        # of the live positions, those in blocks that more than one admitted
+        # request holds (the prefix cache's counts): what reading a shared
+        # prefix once a step, and not once a row, would save
+        self.attn_positions_shared = 0
         # what the decode step counts on the device and returns behind its
         # tokens, summed over the steps
         self._step_counters = {name: 0 for name in steps.COUNTERS}
@@ -1007,9 +1012,11 @@ class PagedEngine:
             if "steps" in req.probe:
                 self._probe_admitted(req, slot)
                 state0 = req.probe["state0"]
-                req.probe["state0"] = (
-                    np.asarray(getattr(self, self._steps.SNAPSHOT_STATE)[
-                        restore]) if restore >= 0 else np.zeros_like(state0))
+                if state0 is not None:
+                    req.probe["state0"] = (
+                        np.asarray(getattr(self, self._steps.SNAPSHOT_STATE)[
+                            restore]) if restore >= 0
+                        else np.zeros_like(state0))
         self._prefilling.append(req)
         if hits:
             from ray_tpu.util.metrics import Counter
@@ -1184,7 +1191,11 @@ class PagedEngine:
         req.probe["state0"] = self._slot_state(slot)
 
     def _slot_state(self, slot: int):
-        return np.asarray(getattr(self, self._steps.SLOT_STATE)[:, slot])
+        """What slot `slot` carries beside its blocks; None where the
+        blocks are all a sequence has."""
+        name = self._steps.SLOT_STATE
+        return None if name is None else np.asarray(
+            getattr(self, name)[:, slot])
 
     def _release(self, req: _Request):
         """The request is over (its last token emitted, aborted or failed):
@@ -1516,10 +1527,13 @@ class PagedEngine:
                 # nobody's mechanisms are recorded: the routing alone
                 probe = {k: v for k, v in probe.items()
                          if k.endswith("routing")}
+            shared = (0 if self._prefix_cache is None else self.bs
+                      * self._prefix_cache.shared_blocks(
+                          self.tables[self.active]))
             self._flight = _Step(
                 toks, probe, rows, chunk, ends,
                 self._probe_slot, flight is not None,
-                int(self.lens[self.active].sum() + self.active.sum()))
+                int(self.lens[self.active].sum() + self.active.sum()), shared)
             # what the host knows at dispatch it applies at dispatch
             self._rngs[:, 1] += 1  # fresh fold per step
             self.lens[self.active] += 1
@@ -1549,6 +1563,7 @@ class PagedEngine:
         self.steps += 1
         self.steps_ahead += step.ahead
         self.attn_positions_live += step.live
+        self.attn_positions_shared += step.shared
         self.attn_positions_dense += (
             self.ecfg.max_num_seqs * self.ecfg.max_model_len)
         # behind the tokens: the step's counters, then the chunk's
@@ -1834,6 +1849,7 @@ class PagedEngine:
             "prefix_cache": cache.stats() if cache is not None else None,
             "attn_positions_live": self.attn_positions_live,
             "attn_positions_dense": self.attn_positions_dense,
+            "attn_positions_shared": self.attn_positions_shared,
             "decode_attention": self.decode_attention,
         }
         if self._decode_note:

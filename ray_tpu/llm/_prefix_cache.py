@@ -102,6 +102,9 @@ class PrefixCache:
         self.snapshots_evicted = 0   # with their blocks, or displaced (LRU)
         self._entries: Dict[bytes, _Entry] = {}
         self._by_block: Dict[int, bytes] = {}
+        # every cached entry's `refs` by its block's number, for
+        # `shared_blocks`: grown as blocks are seen
+        self._block_refs = np.zeros((1024,), np.int32)
         self._tick = 0
         # the eviction order: (last_use, -depth, key) of entries that had no
         # request and no cached child when they were put in (`_offer`), at
@@ -134,6 +137,7 @@ class PrefixCache:
                 break
             self._idle -= e.refs == 0
             e.refs += 1
+            self._block_refs[e.block] += 1
             e.last_use = self._tick
             out.append(e.block)
         if out:
@@ -264,6 +268,10 @@ class PrefixCache:
             self._entries[k] = _Entry(block=int(b), refs=1, parent=prev,
                                       depth=depth, last_use=self._tick)
             self._by_block[int(b)] = k
+            if int(b) >= len(self._block_refs):
+                self._block_refs = np.pad(
+                    self._block_refs, (0, max(len(self._block_refs), int(b))))
+            self._block_refs[int(b)] = 1
             if prev is not None:
                 self._entries[prev].children += 1
             prev = k
@@ -281,7 +289,15 @@ class PrefixCache:
             self._idle += 1
             self._offer(k, e)
         e.refs = max(0, e.refs - 1)
+        self._block_refs[e.block] = e.refs
         return True
+
+    def shared_blocks(self, tables: np.ndarray) -> int:
+        """How many entries of `tables` (block numbers; 0 is no block) are
+        cached blocks that more than one admitted request holds."""
+        refs = self._block_refs
+        known = tables[tables < len(refs)]
+        return int(np.count_nonzero(refs[known] > 1))
 
     def owns_block(self, block: int) -> bool:
         return int(block) in self._by_block
@@ -338,6 +354,7 @@ class PrefixCache:
         self._entries.clear()
         self._by_block.clear()
         self._heap.clear()
+        self._block_refs[:] = 0
         self._idle = 0
         self._free_snaps = list(range(self.num_snapshots))
         self._snap_key.clear()

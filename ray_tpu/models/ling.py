@@ -63,6 +63,9 @@ class LingConfig:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 6e6
+    # False: the rotary turns channel i with i + rope/2 (`rope_half`); True:
+    # the adjacent pair (2i, 2i + 1) (`rope_pairs`)
+    rope_interleave: bool = False
     norm_eps: float = 1e-6
     ffn_dim: int = 6144            # dense layers
     moe_ffn_dim: int = 768         # each routed expert and the shared one
@@ -217,6 +220,19 @@ def rope_half(x, positions, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
+def rope_pairs(x, positions, theta: float):
+    """`rope_half`'s arguments, the published `rope_interleave`: channel 2i
+    turns with 2i + 1 by pos * theta^(-2i/hd). The result is laid out in
+    halves, [the pairs' first members | their second], on queries and keys
+    alike, so no score sees the permutation."""
+    return rope_half(jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1),
+                     positions, theta)
+
+
+def _rope(cfg):
+    return rope_pairs if cfg.rope_interleave else rope_half
+
+
 def _l2(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
@@ -302,11 +318,17 @@ def kda_decode(cfg: LingConfig, p, x, state, tail):
 
 def _mla_q(cfg: LingConfig, p, x, positions):
     """x [N, D], positions [N] -> q_nope [N, H, nope], q_rope [N, H, rope]
-    (roped, dtype)."""
+    (roped, dtype). Where the layer has a low-rank query (`wqa`), q = W_qb
+    RMSNorm(W_qa x); else q = W_q x."""
     N, H = x.shape[0], cfg.n_heads
-    q = (x @ p["wq"].astype(cfg.dtype)).reshape(
-        N, H, cfg.qk_nope_dim + cfg.qk_rope_dim)
-    q_r = rope_half(q[..., cfg.qk_nope_dim:], positions, cfg.rope_theta)
+    if "wqa" in p:
+        c_q = rms_norm(x @ p["wqa"].astype(cfg.dtype), p["q_norm"],
+                       cfg.norm_eps)
+        q = c_q @ p["wqb"].astype(cfg.dtype)
+    else:
+        q = x @ p["wq"].astype(cfg.dtype)
+    q = q.reshape(N, H, cfg.qk_nope_dim + cfg.qk_rope_dim)
+    q_r = _rope(cfg)(q[..., cfg.qk_nope_dim:], positions, cfg.rope_theta)
     return q[..., : cfg.qk_nope_dim], q_r.astype(cfg.dtype)
 
 
@@ -315,8 +337,8 @@ def mla_latents(cfg: LingConfig, p, x, positions):
     latent c and the roped shared key k_r."""
     ckr = x @ p["wkva"].astype(cfg.dtype)
     c = rms_norm(ckr[:, : cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_r = rope_half(ckr[:, None, cfg.kv_lora_rank:], positions,
-                    cfg.rope_theta)[:, 0]
+    k_r = _rope(cfg)(ckr[:, None, cfg.kv_lora_rank:], positions,
+                     cfg.rope_theta)[:, 0]
     return jnp.concatenate([c.astype(cfg.dtype), k_r.astype(cfg.dtype)], -1)
 
 
@@ -328,7 +350,10 @@ def _wkvb(cfg: LingConfig, p):
 
 
 def _mla_output(cfg: LingConfig, p, x, o):
-    o = (o.astype(jnp.float32) * _head_gate(cfg, x, p)[..., None])
+    """o [N, H, v] -> [N, D]: the head-wise gate where the layer has one
+    (`wg`), then W_o."""
+    if "wg" in p:
+        o = o.astype(jnp.float32) * _head_gate(cfg, x, p)[..., None]
     return o.astype(cfg.dtype).reshape(o.shape[0], -1) @ p["wo"].astype(cfg.dtype)
 
 
@@ -418,9 +443,12 @@ def router_scores(cfg: LingConfig, p, x):
 def select_experts(cfg: LingConfig, s, bias):
     """Scores s [N, E] and the expert bias [E] -> (experts [N, top_k], kept
     [N, n_group] bool): selection on score + bias, a group scored by the sum
-    of its two best, `top_k` experts among the `topk_group` kept groups'."""
+    of its two best, `top_k` experts among the `topk_group` kept groups'.
+    One group (`n_group` 1) is no grouping: the `top_k` best of all."""
     N, E, G = s.shape[0], cfg.n_experts, cfg.n_group
     sb = s + bias
+    if G == 1:
+        return lax.top_k(sb, cfg.top_k)[1], jnp.ones((N, 1), bool)
     gs = lax.top_k(sb.reshape(N, G, E // G), 2)[0].sum(-1)    # [N, G]
     kept_idx = lax.top_k(gs, cfg.topk_group)[1]
     kept = jnp.zeros((N, G), bool).at[
@@ -468,11 +496,16 @@ def moe_held(cfg: LingConfig, p, x, live, shared: bool = True, routing=None):
         experts, weights, bits, scores = (routing or route)(cfg, p, x)
         local = experts - cfg.held_start
         held = (local >= 0) & (local < n) & live[:, None]
+        # the experts' leaves may hold several layers' experts on their
+        # leading axis, this layer's the n from `e_first` on (a scan over
+        # stacked layers: the grouped kernel then reads an expert where it
+        # lies, and a layer's slice of the stack is never copied out)
+        first, groups = p.get("e_first", 0), p["e_w1"].shape[0]
         # rows sorted by held expert; what is not held sorts last and
         # belongs to no group, so the grouped SwiGLU never visits it
-        flat = jnp.where(held, local, n).reshape(N * k)
+        flat = jnp.where(held, first + local, groups).reshape(N * k)
         order = jnp.argsort(flat, stable=True)
-        counts = jnp.sum(jax.nn.one_hot(flat, n, dtype=jnp.int32), axis=0)
+        counts = jnp.sum(jax.nn.one_hot(flat, groups, dtype=jnp.int32), axis=0)
         dt = cfg.dtype
         out = _grouped_experts(cfg, p, x[order // k], counts)
         w_sorted = jnp.where(held, weights, 0.0).reshape(N * k)[order]
@@ -511,6 +544,30 @@ def _attention(cfg: LingConfig, p, x, live):
             else mla_prefill(cfg, p, x, live)[0])
 
 
+def balance_tokens(key):
+    """The seeded tokens the balancing routes: printable bytes."""
+    return jax.random.randint(key, (BALANCE_TOKENS,), 32, 127)
+
+
+def balanced_bias(cfg, p, x):
+    """An expert layer's bias after `BALANCE_STEPS` updates on its normed
+    inputs x [T, D]: after each, b_e moves by a fixed step towards the
+    experts that got less than the mean load."""
+    s = router_scores(cfg, p, x)
+    mean_load = x.shape[0] * cfg.top_k / cfg.n_experts
+
+    def step(i, b):
+        experts, _ = select_experts(cfg, s, b)
+        load = jnp.sum(jax.nn.one_hot(
+            experts.reshape(-1), cfg.n_experts, dtype=jnp.float32), 0)
+        # the step shrinks from a tenth of the scores' range to a
+        # thousandth, as a learning rate would
+        rate = 0.05 * 0.01 ** (i / (BALANCE_STEPS - 1))
+        return b + rate * jnp.sign(mean_load - load)
+
+    return lax.fori_loop(0, BALANCE_STEPS, step, p["router_bias"])
+
+
 def balance_expert_bias(cfg, params, key, attention=None):
     """Set every expert layer's bias the way the published scheme trains it
     (auxiliary-loss-free balancing: after each batch, b_e moves by a fixed
@@ -530,9 +587,8 @@ def balance_expert_bias(cfg, params, key, attention=None):
     if attention is None:
         attention = functools.partial(_attention, cfg)
     T = BALANCE_TOKENS
-    tokens = jax.random.randint(key, (T,), 32, 127)           # printable bytes
+    tokens = balance_tokens(key)
     live = jnp.ones((T,), bool)
-    mean_load = T * cfg.top_k / cfg.n_experts
     dt = cfg.dtype
     h = params["tok_emb"].astype(dt)[tokens]
     layers = []
@@ -540,19 +596,7 @@ def balance_expert_bias(cfg, params, key, attention=None):
         h = h + attention(p, rms_norm(h, p["ln1"], cfg.norm_eps), live)
         x = rms_norm(h, p["ln2"], cfg.norm_eps)
         if "router" in p:
-            s = router_scores(cfg, p, x)
-
-            def step(i, b):
-                experts, _ = select_experts(cfg, s, b)
-                load = jnp.sum(jax.nn.one_hot(
-                    experts.reshape(-1), cfg.n_experts, dtype=jnp.float32), 0)
-                # the step shrinks from a tenth of the scores' range to a
-                # thousandth, as a learning rate would
-                rate = 0.05 * 0.01 ** (i / (BALANCE_STEPS - 1))
-                return b + rate * jnp.sign(mean_load - load)
-
-            p = {**p, "router_bias": lax.fori_loop(
-                0, BALANCE_STEPS, step, p["router_bias"])}
+            p = {**p, "router_bias": balanced_bias(cfg, p, x)}
         h = h + ffn(cfg, p, x, live)[0]
         layers.append(p)
     return {**params, "layers": layers}

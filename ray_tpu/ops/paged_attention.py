@@ -25,7 +25,8 @@ against. Which one a decode step uses is decided when the step is built
 
 `chunk_attention` is the other reader of the pool: the rows of one prompt
 chunk that rides in a decode step, over their own sequence's table, in XLA
-on every backend.
+on every backend; `chunk_latent_attention` is the same for a pool of latents
+(`llm/_joyai_steps.py`).
 
 **A window** (`window`, a static number of positions, on all three): a row
 at position p sees the keys at p - window < j <= p and nothing before them.
@@ -159,6 +160,60 @@ def chunk_attention(q, kc, vc, layer, row, qpos, end, tile: int = 512,
                               (m0, l0, acc0))
     o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)     # [KV, rep, C, HD]
     return o.transpose(2, 0, 1, 3).reshape(C, H, hd)
+
+
+def chunk_latent_attention(q, pool, layer, row, qpos, end, rank: int,
+                           tile: int = 1024):
+    """`chunk_attention` for latent attention in its absorbed form: the rows
+    of one prompt chunk over their sequence's cached latents, each a key of
+    all heads whose value is its first `rank` columns. q [C, H, W] (the
+    absorbed query, scaled, zeros past rank + rope) at absolute positions
+    qpos [C]; pool [L, NB, BS, 1, W], of which layer `layer` (a scalar) is
+    read after the chunk's own latents were scattered into it; row
+    [max_blocks] the sequence's table row. Row i sees the latents at
+    positions <= qpos[i]: blocks another sequence left, earlier chunks and its
+    own chunk alike, `tile` positions at a time up to `end` with an online
+    softmax. Every head's query is a row of one [C * H, W] x [W, tile]
+    matmul: 2 C H (W + rank) operations a position read. -> [C, H, rank].
+
+    On a v5e at C = 256, H = 32, W = 640, rank = 512 over 12,000 positions a
+    layer takes 1.59 ms with tiles of 512, 1.54 with 1,024, 1.50 with 2,048
+    (134 to 142 TFLOP/s of these operations); the expanded form, keys and
+    values of every head made from each tile's latents (2 x 512 x 8,192
+    operations a position, then 2 C H 320), 1.54 and 1.45 with tiles of 512
+    and 1,024 (PERF.md section 6, PR 60): within a tenth, so the chunk's rows
+    share the decode rows' absorbed query and output."""
+    C, H, W = q.shape
+    L, NB, bs = pool.shape[:3]
+    blocks = max(1, min(tile, row.shape[0] * bs) // bs)   # blocks a tile
+    tile = blocks * bs
+    row = jnp.pad(row, (0, -row.shape[0] % blocks)) + layer * NB
+    pages = pool.reshape(L * NB, bs, W)                   # the same bytes
+    qf = q.reshape(C * H, W)
+    qrow = jnp.repeat(qpos, H)[:, None]                   # [C * H, 1]
+
+    def one_tile(t, carry):
+        m, l, acc = carry
+        at = lax.dynamic_slice(row, (t * blocks,), (blocks,))
+        lat = pages[at].reshape(tile, W)
+        s = jnp.einsum("qw,kw->qk", qf, lat,
+                       preferred_element_type=jnp.float32)
+        seen = (t * tile + jnp.arange(tile))[None, :] <= qrow
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.dot(p.astype(lat.dtype), lat[:, :rank],
+                                    preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((C * H, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((C * H, 1), jnp.float32)
+    acc0 = jnp.zeros((C * H, rank), jnp.float32)
+    _, l, acc = lax.fori_loop(0, (end + tile - 1) // tile, one_tile,
+                              (m0, l0, acc0))
+    return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype).reshape(C, H, rank)
 
 
 # ---------------------------------------------------------------------------

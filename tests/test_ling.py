@@ -280,7 +280,8 @@ def test_a_llama_engine_allocates_no_state_and_keeps_its_stats():
     assert set(eng.stats()) == {
         "steps", "tokens_out", "free_blocks", "blocks_in_use", "active_slots",
         "mid_decode_admissions", "prefix_cache", "attn_positions_live",
-        "attn_positions_dense", "decode_attention", "prefill_chunks",
+        "attn_positions_dense", "attn_positions_shared", "decode_attention",
+        "prefill_chunks",
         "prefill_chunk_tokens", "prefill_chunk_pad_tokens", "steps_with_chunk",
         "chunk_overtakes", "steps_ahead", "rows_dropped", "admissions",
         "admit_host_s"}

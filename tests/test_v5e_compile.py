@@ -665,6 +665,78 @@ def test_mellum_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
                        m.temp_size_in_bytes / 1e9))
 
 
+def test_joyai_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
+        on_v5e, monkeypatch):
+    """`jit_paged_decode_step` of the JoyAI-LLM-Flash family at the cell's
+    shapes (published widths, all 40 layers and the whole vocabulary, 16 of
+    256 experts held, 16 slots, 6,144 blocks of 16, tables of 1,024) with
+    the widest chunk, 256 rows: 4,776.5M parameters; the decode rows'
+    attention is the paged kernel over the latent pool as one 640-wide head,
+    once in the unrolled dense layer and once in the scanned expert layers'
+    body; the held experts are the grouped kernel at 2,048 -> 768 over the
+    39 layers' stack in place; the pool is donated and not copied; weights, pool and temporaries fit under the
+    chip's own limit."""
+    from ray_tpu.llm import _joyai_steps
+    from ray_tpu.llm._engine import EngineConfig
+    from ray_tpu.models import joyai
+
+    monkeypatch.setattr(pa, "decode_path", lambda *a: (pa.KERNEL, None))
+    monkeypatch.setattr(gf, "ffn_path", lambda *a: gf.KERNEL)
+    cfg = joyai.JoyAIConfig(n_held=16)
+    ecfg = EngineConfig(max_num_seqs=16, kv_block_size=16, num_kv_blocks=6144,
+                        max_model_len=16384, prefix_cache=True)
+    C = _joyai_steps.chunk_ladder(ecfg)[-1]
+    assert C == 256
+    step, path, note = _joyai_steps.make_decode_step(cfg, ecfg)
+    assert (path, note) == (pa.KERNEL, None)
+
+    def spec(x):
+        return on_v5e(x.shape, x.dtype)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: joyai.init_params(cfg, jax.random.PRNGKey(0))))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert n_params == 4_776_521_472
+    caches = [spec(c) for c in jax.eval_shape(
+        lambda: _joyai_steps.alloc_cache(cfg, ecfg))]
+    B = 16
+    compiled = step.trace(
+        C, params, *caches, on_v5e((B, 1024), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((B,), jnp.bool_),
+        on_v5e((B,), jnp.int32), on_v5e((B, 2), jnp.uint32),
+        on_v5e((B,), jnp.float32),
+        on_v5e((B + len(_joyai_steps.COUNTERS) + 3,), jnp.int32),
+        on_v5e((B,), jnp.int32), on_v5e((C,), jnp.int32),
+        on_v5e((3,), jnp.int32), on_v5e((), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    kernels = [line for line in hlo.splitlines()
+               if PALLAS in line and "%paged_decode_attention" in line]
+    # the dense layer's call and the scanned body's
+    assert len(kernels) == 2
+    assert all("bf16[245800,16,640]" in k for k in kernels)
+    experts = grouped_ffn_calls(hlo)
+    assert len(experts) == 1
+    # every layer's experts as one stack, read where they lie: no layer's
+    # slice of it is copied out for the kernel
+    assert all("bf16[624,2048,768]" in ops for ops, res in experts)
+    assert "bf16[16,2048,768]" not in hlo
+    # the pool is not copied whole
+    assert not re.findall(r" copy\([^)]*40,6145", hlo)
+    m = compiled.memory_analysis()
+    pool_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    assert 5.03e9 < pool_bytes < 5.04e9
+    assert 9.59e9 < weight_bytes < 9.60e9      # the routers are float32
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes) < V5E_BYTES_LIMIT
+    print("joyai step: weights %.3f GB pool %.3f GB temp %.3f GB" % (
+        weight_bytes / 1e9, pool_bytes / 1e9, m.temp_size_in_bytes / 1e9))
+
+
 @functools.lru_cache(maxsize=None)
 def train_step_for_v5e(topo, remat):
     """`jit_train_step` at the train cell's shapes (InternLM2-1.8B whole,
