@@ -17,8 +17,9 @@ through every matmul and the experts with the slots' decode rows as one
 batch, write their latents to the slot's blocks and attend them in the
 absorbed form (`ops/paged_attention.chunk_latent_attention`): over blocks
 another sequence's prompt left, earlier chunks and their own rows alike. The
-decode rows attend through `ops/paged_attention`'s kernel on a TPU, the
-latents as one KV head that is its own value. The leading dense layers are
+decode rows attend through `ops/paged_attention.paged_latent_attention` on a
+TPU: the kernel over the pool as its one operand, a live page fetched once
+and its first `rank` columns the value. The leading dense layers are
 unrolled; the expert layers are one `lax.scan` over their stacked weights,
 the layer's number traced into the whole pool, which rides in the carry and
 is written in place (`_engine._make_decode_step`).
@@ -97,8 +98,6 @@ def make_decode_step(cfg: joyai.JoyAIConfig, ecfg):
     chunk of one admitting prompt. Returns (step, path, note): which
     attention the decode rows were built with and, where a TPU was refused
     the kernel, why."""
-    import math
-
     import jax
     import jax.numpy as jnp
 
@@ -147,16 +146,14 @@ def make_decode_step(cfg: joyai.JoyAIConfig, ecfg):
 
         def attention(latents, layer):
             """`ling.mla_decode`'s `attend` over layer `layer` of the pool:
-            the decode rows each over their slot's live latents, the
-            chunk's rows over their sequence's up to their own positions."""
+            the decode rows each over their slot's live latents (the paged
+            kernel, which reads the pool once; a gathered context off-TPU),
+            the chunk's rows over their sequence's up to their own
+            positions."""
             def attend(q, scale):
                 if path == paged_attention.KERNEL:
-                    # the kernel scales by its head width
-                    qd = jnp.pad(
-                        q[:B] * jnp.asarray(scale * math.sqrt(W), q.dtype),
-                        ((0, 0), (0, 0), (0, W - q.shape[-1])))
-                    o = paged_attention.paged_decode_attention(
-                        qd, latents, latents, layer, tables, live)[..., :rank]
+                    o = paged_attention.paged_latent_attention(
+                        q[:B], scale, latents, layer, tables, live, rank)
                 else:
                     context = latents[layer][tables].reshape(
                         B, max_blocks * bs, W)

@@ -101,12 +101,10 @@ def make_decode_step(cfg: ling.LingConfig, ecfg):
     """The jitted whole-batch single-token step. Returns (step, path, note):
     which latent attention it was built with and, where a TPU was refused
     the kernel, why. On a TPU the latent attention is
-    `ops/paged_attention`'s kernel over the block table, the latents as one
-    KV head that is its own value (the kernel scales by its head width, so
-    the query is pre-scaled to the model's 1/sqrt(nope + rope)); elsewhere
-    an XLA gather of the table's blocks."""
-    import math
-
+    `ops/paged_attention.paged_latent_attention`: the kernel over the block
+    table with the pool as its one operand, a live page fetched once and
+    its first `kv_lora_rank` columns the value; elsewhere an XLA gather of
+    the table's blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -125,14 +123,8 @@ def make_decode_step(cfg: ling.LingConfig, ecfg):
                 tables.shape[0], max_blocks * bs, W)
             return ling.attend_latents(cfg, context, live)
 
-        def attend(q, scale):
-            q = jnp.pad(q * jnp.asarray(scale * math.sqrt(W), q.dtype),
-                        ((0, 0), (0, 0), (0, W - q.shape[-1])))
-            return paged_attention.paged_decode_attention(
-                q, latents, latents, layer, tables, live
-            )[..., : cfg.kv_lora_rank]
-
-        return attend
+        return lambda q, scale: paged_attention.paged_latent_attention(
+            q, scale, latents, layer, tables, live, cfg.kv_lora_rank)
 
     def paged_decode_step(params, latents, state, tails, tables, lens, active,
                           last_tok, keys, temps, prev, fed, probe_slot):

@@ -28,6 +28,14 @@ chunk that rides in a decode step, over their own sequence's table, in XLA
 on every backend; `chunk_latent_attention` is the same for a pool of latents
 (`llm/_joyai_steps.py`).
 
+**A pool of latents** (`paged_latent_attention`, the decode rows of
+`llm/_joyai_steps.py` and `llm/_ling_steps.py`): one cached row a position,
+the key of every head, whose first `rank` columns are the value. The same
+kernel with the pool as its one HBM operand (`value_dim`, static): a live
+page moves to VMEM once, one DMA and one buffer, and the weighted sum reads
+the value where the key lies. Callers with keys and values in two arrays
+trace the program they traced before it existed.
+
 **A window** (`window`, a static number of positions, on all three): a row
 at position p sees the keys at p - window < j <= p and nothing before them.
 The table is then read as a *ring*: the block that holds position j is entry
@@ -222,7 +230,8 @@ def chunk_latent_attention(q, pool, layer, row, qpos, end, rank: int,
 
 
 def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
-                   group_pages, page_rows, block_size, max_blocks, window):
+                   group_pages, page_rows, block_size, max_blocks, window,
+                   value_dim):
     """One grid step = one slot. tables_ref [B * max_blocks], lengths_ref
     [B], first_ref [1] (the layer's first page in the pool) in SMEM;
     q_ref/o_ref [1, Hp, HD]; token_ref [Hp, rows] (`_column_tokens`);
@@ -232,12 +241,19 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
     to slot. With a `window` (static) a slot's pages are counted from the
     block of its first live position, length - window, and its table is a
     ring: three scalar operations a page and one comparison a group more,
-    and none of them traced without one."""
+    and none of them traced without one. With a `value_dim` (static) the
+    pool is its own value: there is no v_hbm and no v_buf, sems is [1, 2],
+    a page moves once, a row's value is its first `value_dim` columns where
+    they lie in k_buf, and o_ref is [1, Hp, value_dim]; without one the
+    body is the two-array one, equation for equation."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (q_ref, token_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-     parity_ref) = refs
+    if value_dim is None:
+        (q_ref, token_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+         parity_ref) = refs
+    else:
+        q_ref, token_ref, k_hbm, o_ref, k_buf, sems, parity_ref = refs
     G, bs = group_pages, block_size
     b, B = pl.program_id(0), pl.num_programs(0)
 
@@ -250,9 +266,10 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
         return pages if window is None else pages - first_block(slot)
 
     def each_page(slot, g, buf, act):
-        """`act` on the K and the V copy of every live page of group g of
-        `slot`: nothing is done, and the table is not read, from the first
-        page past the live length on."""
+        """`act` on the K and the V copy (the one copy of a pool that is
+        its own value) of every live page of group g of `slot`: nothing is
+        done, and the table is not read, from the first page past the live
+        length on."""
         left = pages_of(slot) - g * G
         if window is not None:
             first = first_block(slot) + g * G
@@ -268,8 +285,9 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
                 dst = pl.ds(i * page_rows, page_rows)
                 act(pltpu.make_async_copy(
                     k_hbm.at[page], k_buf.at[buf, dst], sems.at[0, buf]))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]))
+                if value_dim is None:
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[page], v_buf.at[buf, dst], sems.at[1, buf]))
 
     def start(slot, g, buf):
         each_page(slot, g, buf, lambda copy: copy.start())
@@ -282,7 +300,8 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
         # rows that no DMA fills are masked out of the scores, and their
         # probability 0 must meet a finite V: no stale NaN in the buffers
         k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        if value_dim is None:
+            v_buf[...] = jnp.zeros_like(v_buf)
         parity_ref[0] = 0
         start(0, 0, 0)
 
@@ -311,7 +330,7 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
 
         wait(b, g, buf)
         k = k_buf[buf]                                       # [rows, HD]
-        v = v_buf[buf]
+        v = v_buf[buf] if value_dim is None else k[:, :value_dim]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         if window is None:
@@ -331,10 +350,10 @@ def _decode_kernel(tables_ref, lengths_ref, first_ref, *refs, scale,
         parity_ref[0] = nxt
         return m_new, l, acc
 
-    Hp, hd = q_ref.shape[1:]
+    Hp, vd = o_ref.shape[1:]
     m0 = jnp.full((Hp, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hp, 1), jnp.float32)
-    acc0 = jnp.zeros((Hp, hd), jnp.float32)
+    acc0 = jnp.zeros((Hp, vd), jnp.float32)
     _, l, acc = lax.fori_loop(0, n_groups, group, (m0, l0, acc0))
     # an inactive slot (and a padded query head) has l == 0 and acc == 0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -367,6 +386,30 @@ def paged_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
     It is handed the whole pool and the layer's number, not the layer's
     slice: a slice of the pool as an operand is a copy of it (84 MB a layer
     for K and again for V at the benchmark's shapes)."""
+    return _paged_call(q, kc, vc, layer, tables, lengths, window, None)
+
+
+def paged_latent_attention(q, scale, pool, layer, tables, lengths, rank: int):
+    """The kernel for latent attention in its absorbed form, the decode
+    rows' twin of `chunk_latent_attention`: q [B, H, <= W] the absorbed
+    queries, which score at `scale`; pool [L, NB, BS, 1, W], each cached
+    row the key of all heads whose value is its first `rank` columns (a
+    whole number of lanes); tables, lengths as `paged_decode_attention`'s.
+    The pool is the kernel's one operand in HBM: a live page moves to VMEM
+    once and serves as key and as value. -> [B, H, rank], bit for bit the
+    first `rank` columns of `paged_decode_attention(q', pool, pool, ...)`
+    on the query made here."""
+    W = pool.shape[-1]
+    # the kernel scales by its head width
+    q = jnp.pad(q * jnp.asarray(scale * math.sqrt(W), q.dtype),
+                ((0, 0), (0, 0), (0, W - q.shape[-1])))
+    return _paged_call(q, pool, None, layer, tables, lengths, None, rank)
+
+
+def _paged_call(q, kc, vc, layer, tables, lengths, window, value_dim):
+    """The Pallas call of both entries: keys and values in two arrays, or
+    (`vc` None) a pool whose rows hold their value in their first
+    `value_dim` columns."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -394,25 +437,24 @@ def paged_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / math.sqrt(hd), group_pages=G,
         page_rows=page_rows, block_size=bs, max_blocks=max_blocks,
-        window=window)
+        window=window, value_dim=value_dim)
+    vd = value_dim or hd
+    pools = [kc] if vc is None else [kc, vc]
     o = pl.pallas_call(
         kernel,
         name="paged_decode_attention",
-        out_shape=jax.ShapeDtypeStruct((B, Hp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, vd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, Hp, hd), lambda b, *_: (b, 0, 0)),
                 pl.BlockSpec((Hp, rows), lambda b, *_: (0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, Hp, hd), lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, rows, hd), kc.dtype),
-                pltpu.VMEM((2, rows, hd), vc.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=pl.BlockSpec((1, Hp, vd), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, rows, hd), x.dtype)
+                            for x in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
@@ -421,8 +463,8 @@ def paged_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
-            flops=int(2 * 2 * Hp * kvh * live * hd),
-            bytes_accessed=int(2 * live * kvh * hd * kc.dtype.itemsize
+            flops=int(2 * Hp * kvh * live * (hd + vd)),
+            bytes_accessed=int(len(pools) * live * kvh * hd * kc.dtype.itemsize
                                + 2 * q.size * q.dtype.itemsize),
             transcendentals=int(Hp * kvh * live),
         ),
@@ -430,7 +472,7 @@ def paged_decode_attention(q, kc, vc, layer, tables, lengths, window=None):
     )(tables.reshape(-1).astype(jnp.int32), lengths,
       (jnp.asarray(layer, jnp.int32) * NB).reshape(1), qp,
       _column_tokens(Hp, rows, H, kvh),
-      kc.reshape(L * NB, page_rows, hd), vc.reshape(L * NB, page_rows, hd))
+      *(x.reshape(L * NB, page_rows, hd) for x in pools))
     return o if Hp == H else o[:, :H]
 
 

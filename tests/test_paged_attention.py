@@ -2,6 +2,7 @@
 against the XLA formulation it replaces, and the choice between them."""
 
 import asyncio
+import math
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ def interpreted(monkeypatch):
 
 
 def pool_and_tables(rng, lens, *, kv, max_blocks, layers=2, dtype=jnp.bfloat16,
-                    order="shuffled"):
+                    order="shuffled", hd=HD):
     """A pool whose layer 1 holds the slots' blocks (owned blocks drawn
     without order from 1..NB-1, block 0 the trash block) and whose every
     other block, and all of layer 0, is NaN: nothing but the live pages of
@@ -38,7 +39,7 @@ def pool_and_tables(rng, lens, *, kv, max_blocks, layers=2, dtype=jnp.bfloat16,
         used += n
     owned = np.zeros(nb, bool)
     owned[tables[tables > 0]] = True
-    shape = (layers, nb, BS, kv, HD)
+    shape = (layers, nb, BS, kv, hd)
     k, v = (rng.standard_normal(shape).astype(np.float32) for _ in range(2))
     # a copy made here: x goes on, and on the CPU a float32 array may share
     # its buffer with the device's (the NaN then showed in `clean` now and
@@ -70,19 +71,72 @@ def check(lens, *, heads, kv, max_blocks=8, dtype=jnp.bfloat16,
     assert not got[~live].any()
 
 
+# a pool of latents, each the key of all heads and in its first `rank`
+# columns the value: (width in memory, rank, heads). 640 / 512 on 32 heads is
+# the JoyAI and the Ling cell's; 256 / 128 on 8 pads the heads to a tile
+LATENTS = {"latents_640_512": (640, 512, 32), "latents_256_128": (256, 128, 8)}
+FORMS = ["two_arrays", *LATENTS]
+
+
+def check_latents(lens, form, *, max_blocks=8, order="shuffled", seed=0):
+    """`paged_latent_attention` against `ling.attend_latents` on the
+    gathered context, on a pool whose every page that is not live is NaN;
+    and bit for bit against the call it replaced, the pool handed to
+    `paged_decode_attention` as keys and as values."""
+    from ray_tpu.models import ling
+
+    width, rank, heads = LATENTS[form]
+    cfg = ling.LingConfig(kv_lora_rank=rank, n_heads=heads)
+    assert cfg.latent_width == width and cfg.latent_dim < width
+    rng = np.random.default_rng(seed)
+    (pool, _), (pool_nan, _), tables = pool_and_tables(
+        rng, lens, kv=1, max_blocks=max_blocks, order=order, hd=width)
+    q = jnp.asarray(rng.standard_normal((len(lens), heads, cfg.latent_dim)),
+                    jnp.bfloat16)
+    lengths = jnp.asarray(lens, jnp.int32)
+    layer = pool.shape[0] - 1
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    context = pool[layer][tables].reshape(len(lens), max_blocks * BS, width)
+    want = np.asarray(ling.attend_latents(cfg, context, lengths)(q, scale),
+                      np.float32)
+    got = jax.jit(pa.paged_latent_attention, static_argnums=(1, 6))(
+        q, scale, pool_nan, layer, tables, lengths, rank)
+    assert got.shape == (len(lens), heads, rank)
+    # the query as both step sets wrote it out before the entry held it
+    qd = jnp.pad(q * jnp.asarray(scale * math.sqrt(width), q.dtype),
+                 ((0, 0), (0, 0), (0, width - cfg.latent_dim)))
+    two = jax.jit(pa.paged_decode_attention)(
+        qd, pool_nan, pool_nan, layer, tables, lengths)[..., :rank]
+    got, two = (np.asarray(x, np.float32) for x in (got, two))
+    assert np.isfinite(got).all()
+    assert (got == two).all()
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-2, rtol=2e-2)
+    assert not got[~live].any()
+
+
+def check_form(form, lens, **kw):
+    if form in LATENTS:
+        check_latents(lens, form, **kw)
+    else:
+        check(lens, heads=8, kv=2, **kw)
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("lens", [
     [40, 0, 17, 100],            # ragged, with an inactive slot (trash row)
     [1, BS - 1, BS, BS + 1],     # round a block's edge
     [8 * BS, 8 * BS - 1, 2 * BS, 2 * BS + 1],   # max_model_len, group edges
     [0, 0, 0, 0],                # nothing active at all
 ], ids=["ragged_inactive", "block_edge", "max_model_len", "all_inactive"])
-def test_kernel_matches_xla_over_lengths(interpreted, lens):
-    check(lens, heads=8, kv=2)
+def test_kernel_matches_xla_over_lengths(interpreted, lens, form):
+    check_form(form, lens)
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("order", ["shuffled", "descending"])
-def test_block_tables_neither_contiguous_nor_ordered(interpreted, order):
-    check([70, 33, 0, 128], heads=8, kv=2, order=order, seed=3)
+def test_block_tables_neither_contiguous_nor_ordered(interpreted, order, form):
+    check_form(form, [70, 33, 0, 128], order=order, seed=3)
 
 
 @pytest.mark.parametrize("heads,kv", [(32, 8), (2, 1), (4, 4)],
@@ -374,6 +428,45 @@ def kernel_equations(window):
     return equations(program)
 
 
+def latent_program(pool_once: bool):
+    """The kernel's traced program at the JoyAI cell's shapes (16 rows, 32
+    heads, a pool of 40 x 6,145 blocks of 16 latents 640 wide, tables of
+    1,024), and the arrays the call is handed: through the latent entry, or
+    the pool as keys and as values as both step sets called it before."""
+    spec = jax.ShapeDtypeStruct
+    if pool_once:
+        def fn(q, pool, t, l):
+            return pa.paged_latent_attention(
+                q[..., :576], 192 ** -0.5, pool, 1, t, l, 512)
+    else:
+        def fn(q, pool, t, l):
+            return pa.paged_decode_attention(q, pool, pool, 1, t, l)[..., :512]
+    traced = jax.make_jaxpr(fn)(
+        spec((16, 32, 640), jnp.bfloat16),
+        spec((40, 6145, 16, 1, 640), jnp.bfloat16),
+        spec((16, 1024), jnp.int32), spec((16,), jnp.int32))
+    (call,) = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "paged_decode_attention"
+    return (call.params["jaxpr"], [v.aval.shape for v in call.invars],
+            call.outvars[0].aval)
+
+
+def test_a_pool_that_is_its_own_value_is_one_operand_and_a_smaller_body():
+    """One body here too: with keys and values in two arrays it is the
+    program it was, and over a pool of latents it holds one operand in HBM,
+    one buffer, one copy and one wait a page, which is fewer equations than
+    the two-operand body both latent families ran (815)."""
+    from tests.test_v5e_compile import equations
+
+    assert kernel_equations(None) == KERNEL_EQUATIONS
+    pages = (40 * 6145, 16, 640)
+    twice, shapes, out = latent_program(pool_once=False)
+    assert shapes.count(pages) == 2 and out.shape == (16, 32, 640)
+    once, shapes, out = latent_program(pool_once=True)
+    assert shapes.count(pages) == 1 and out.shape == (16, 32, 512)
+    assert equations(once) <= LATENT_EQUATIONS < equations(twice)
+
+
 def test_the_window_costs_callers_without_one_nothing():
     """One body: without a window it is the program it was (455 equations at
     eight pages a group, counted on the tree before the window existed), and
@@ -384,3 +477,5 @@ def test_the_window_costs_callers_without_one_nothing():
 
 
 KERNEL_EQUATIONS = 455
+# the latent body at the JoyAI cell's sixteen pages a group (PR 61)
+LATENT_EQUATIONS = 749

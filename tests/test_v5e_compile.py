@@ -69,6 +69,12 @@ def pallas_calls(hlo: str):
     return out
 
 
+def latent_attention_calls(hlo: str):
+    """[(operand shapes, result shapes)] of the decode kernel's calls."""
+    return [shapes for name, shapes in pallas_calls(hlo).items()
+            if name.startswith("paged_decode_attention")]
+
+
 def grouped_ffn_calls(hlo: str):
     """[(operand shapes, result shapes)] of the held experts' kernel."""
     return [shapes for name, shapes in pallas_calls(hlo).items()
@@ -384,9 +390,11 @@ def test_ling_decode_step_compiles_for_v5e_without_copying_its_caches(
     ).lower(lowering_platforms=("tpu",)).compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_decode_step")
-    kernels = [line for line in hlo.splitlines()
-               if PALLAS in line and "%paged_decode_attention" in line]
-    assert len(kernels) == 1 and "bf16[16385,16,640]" in kernels[0]
+    # the pool is the kernel's one operand in HBM (PR 61): its value is not
+    # a second one, and what comes back is as wide as the value
+    (ops, res), = latent_attention_calls(hlo)
+    assert ops.count("bf16[16385,16,640]") == 1
+    assert res == ["bf16[64,32,512]"]
     experts = grouped_ffn_calls(hlo)
     assert len(experts) == cfg.moe_layers == 6
     assert all(res == ["bf16[512,2560]"] and "bf16[128,2560,768]" in ops
@@ -671,8 +679,9 @@ def test_joyai_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     shapes (published widths, all 40 layers and the whole vocabulary, 16 of
     256 experts held, 16 slots, 6,144 blocks of 16, tables of 1,024) with
     the widest chunk, 256 rows: 4,776.5M parameters; the decode rows'
-    attention is the paged kernel over the latent pool as one 640-wide head,
-    once in the unrolled dense layer and once in the scanned expert layers'
+    attention is the paged kernel over the latent pool as one 640-wide head
+    that is its own value (one operand, fetched once), once in the unrolled
+    dense layer and once in the scanned expert layers'
     body; the held experts are the grouped kernel at 2,048 -> 768 over the
     39 layers' stack in place; the pool is donated and not copied; weights, pool and temporaries fit under the
     chip's own limit."""
@@ -711,11 +720,14 @@ def test_joyai_decode_step_with_the_widest_chunk_compiles_for_v5e_in_place(
     ).lower(lowering_platforms=("tpu",)).compile()
     hlo = compiled.as_text()
     assert hlo.startswith("HloModule jit_paged_decode_step")
-    kernels = [line for line in hlo.splitlines()
-               if PALLAS in line and "%paged_decode_attention" in line]
-    # the dense layer's call and the scanned body's
-    assert len(kernels) == 2
-    assert all("bf16[245800,16,640]" in k for k in kernels)
+    # the dense layer's call and the scanned body's; each is handed the pool
+    # once (PR 61: it was the keys and the values, and every live page moved
+    # twice) and returns the value's 512 columns
+    calls = latent_attention_calls(hlo)
+    assert len(calls) == 2
+    for ops, res in calls:
+        assert ops.count("bf16[245800,16,640]") == 1
+        assert res == ["bf16[16,32,512]"]
     experts = grouped_ffn_calls(hlo)
     assert len(experts) == 1
     # every layer's experts as one stack, read where they lie: no layer's
