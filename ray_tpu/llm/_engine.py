@@ -177,7 +177,8 @@ PHASE_SAMPLE_FIRST = "engine:sample_first"      # waits for the prefill
 PHASE_STEP = "engine:step"
 PHASE_UPLOAD = "engine:upload"                  # the step's host arrays
 PHASE_DISPATCH = "engine:dispatch"              # the decode step's launch
-# np.asarray(toks) of the step dispatched a turn earlier: one a fetched
+# np.asarray(toks) of the step dispatched a turn earlier (argument `chunk`:
+# the width of the chunk the fetched step carried, 0 for none): one a fetched
 # step, alone (outside a step) when the loop drains with nothing to dispatch
 PHASE_DEVICE_WAIT = "engine:device_wait"
 PHASE_EMIT = "engine:emit"                      # the per-slot walk
@@ -197,6 +198,12 @@ SPAN_DECODE = "engine:decode"    # first token -> done
 # prompt of tens of ms), a dispatch and the rest of a decode step:
 # one that takes longer than this is counted as a stall (stats())
 STALL_TURN_S = 1.0
+# a fetch that returns sooner than this found its tokens ready: the device
+# had finished the step before the loop asked, so the host set that turn's
+# pace (stats()["turns_unwaited"]). Above what the copy of ready tokens to
+# the host takes (0.41-0.68 ms on a v5e, a step running behind it or none;
+# microseconds on the CPU), below any wait for a step
+UNWAITED_S = 1e-3
 
 
 @dataclass
@@ -689,6 +696,11 @@ class _Step:
     live: int                       # positions its decode rows attended
     shared: int                     # of those, in blocks several requests hold
 
+    @property
+    def width(self) -> int:
+        """The width of the chunk it carried, 0 for none."""
+        return self.chunk[2] if self.chunk is not None else 0
+
 
 class PagedEngine:
     """The continuous-batching scheduler around a family's jitted steps.
@@ -833,6 +845,25 @@ class PagedEngine:
         # before the dispatch, and when the last one ended (unix time)
         self._stalls = {"loop_stalls": 0, "loop_stall_s": 0.0,
                         "loop_stall_admit_s": 0.0, "loop_stall_last_at": 0.0}
+        # the loop's account of its own time, on the same clock reads, with
+        # a profiler or none (`_account_turn`): the seconds of the turns that
+        # fetched a step (`loop_turn_s`), of those the seconds inside the
+        # fetch (`loop_wait_s`: `engine:device_wait`'s edges), the turns
+        # whose fetch found its tokens ready and their seconds, and the
+        # seconds an empty engine waited (`loop_idle_s`: `engine:idle`'s
+        # edges, added when the wait ends). A turn is filed under the chunk
+        # width of the step it fetched (`steps_w<w>`, `turn_s_w<w>`): where
+        # the device sets the pace that is what that kind of step takes,
+        # where the host does, the host's turn. The sums over the widths are
+        # `loop_turn_s` and `steps`; `loop_wait_s` <= `loop_turn_s`; and
+        # `loop_turn_s` + `loop_idle_s` is all of the loop's time but the
+        # sweeps that found the engine empty, before each idle wait.
+        self._account = {"loop_turn_s": 0.0, "loop_wait_s": 0.0,
+                         "loop_idle_s": 0.0, "turns_unwaited": 0,
+                         "turn_unwaited_s": 0.0}
+        for width in (0, *self._ladder):
+            self._account[f"steps_w{width}"] = 0
+            self._account[f"turn_s_w{width}"] = 0.0
         self._ttfts = collections.deque(maxlen=256)
         self._queue_waits = collections.deque(maxlen=256)
 
@@ -1439,8 +1470,10 @@ class PagedEngine:
                 # inside one slice of one coroutine, so whatever runs while
                 # this waits lies whole inside it and none straddles its edge
                 if self._pending.empty():
+                    t_idle = time.monotonic()
                     with phase(PHASE_IDLE):
                         waiting.append(await self._pending.get())
+                    self._account["loop_idle_s"] += time.monotonic() - t_idle
                 t_turn = None
                 continue
             t_step = time.monotonic()
@@ -1461,22 +1494,40 @@ class PagedEngine:
                     self._fail(self._pending.get_nowait(), e)
                 raise
             if flight is not None:
+                toks, probe, wait_s = fetched
                 with phase(PHASE_EMIT):
-                    self._emit_step(flight, *fetched)
+                    self._emit_step(flight, toks, probe)
                 now = time.monotonic()
-                if now - t_turn > STALL_TURN_S:
-                    self._stalls["loop_stalls"] += 1
-                    self._stalls["loop_stall_s"] += now - t_turn
-                    self._stalls["loop_stall_admit_s"] += t_step - t_turn
-                    self._stalls["loop_stall_last_at"] = time.time()
+                self._account_turn(flight.width, now - t_turn,
+                                   t_step - t_turn, wait_s)
                 t_turn = now
             await asyncio.sleep(0)  # let admissions interleave
+
+    def _account_turn(self, width: int, turn_s: float, admit_s: float,
+                      wait_s: float) -> None:
+        """File a turn that fetched a step of chunk width `width`: its wall
+        `turn_s`, of that the seconds before the dispatch and the seconds
+        inside the fetch."""
+        acc = self._account
+        acc["loop_turn_s"] += turn_s
+        acc["loop_wait_s"] += wait_s
+        acc[f"steps_w{width}"] += 1
+        acc[f"turn_s_w{width}"] += turn_s
+        if wait_s < UNWAITED_S:
+            acc["turns_unwaited"] += 1
+            acc["turn_unwaited_s"] += turn_s
+        if turn_s > STALL_TURN_S:
+            self._stalls["loop_stalls"] += 1
+            self._stalls["loop_stall_s"] += turn_s
+            self._stalls["loop_stall_admit_s"] += admit_s
+            self._stalls["loop_stall_last_at"] = time.time()
 
     def _run_step(self, dispatch: bool, chunk, flight: Optional[_Step]):
         """On the loop's thread hop: dispatch the next step (`dispatch`;
         `chunk` is `_next_chunk`'s) and apply what the host knows without
         its tokens, then fetch the tokens of `flight`, the step dispatched
-        a turn earlier: (tokens, probe or None), or None without one."""
+        a turn earlier: (tokens, probe or None, the seconds the fetch
+        waited), or None without one."""
         import jax
         import jax.numpy as jnp
 
@@ -1551,9 +1602,12 @@ class PagedEngine:
 
         if step is None:
             return None
-        with jax.profiler.TraceAnnotation(PHASE_DEVICE_WAIT):
-            return np.asarray(step.toks), (
+        t_wait = time.monotonic()
+        with jax.profiler.TraceAnnotation(PHASE_DEVICE_WAIT,
+                                          chunk=step.width):
+            toks, probe = np.asarray(step.toks), (
                 None if step.probe is None else jax.device_get(step.probe))
+        return toks, probe, time.monotonic() - t_wait
 
     def _emit_step(self, step: _Step, toks, probe):
         """Hand out a fetched step's tokens as the slots stood when it was
@@ -1864,10 +1918,8 @@ class PagedEngine:
             out["snapshot_rerun_tokens"] = self.snapshot_rerun_tokens
             out["chunk_positions_live"] = self.chunk_positions_live
             out["chunk_attn_pairs"] = self.chunk_attn_pairs
-        if not self._ladder:
-            # reported where a prompt, or its bucket's compile, is awaited
-            # inside a turn of the loop
-            out.update(self._stalls)
+        out.update(self._stalls)
+        out.update(self._account)
         out.update(self._steps.extra_stats(
             self.cfg, self._cache(), self.attn_positions_live))
         if ttfts:

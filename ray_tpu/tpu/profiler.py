@@ -3,9 +3,10 @@
 Reference surface: the dashboard's JAX capture endpoint
 (dashboard/modules/reporter/jax_profile_manager.py:11). Capture writes an
 XPlane/perfetto trace directory the driver can fetch or inspect, either
-from a fresh task pinned to a node (`capture_on_node`) or from inside a
-live actor's own process (`capture_in_actor`): only the process that
-holds a chip can trace it.
+from a fresh task pinned to a node (`node_capture_task`, the dashboard's
+route: a new worker's own idle runtime) or from inside a live actor's own
+process (`capture_in_actor`): only the process that holds a chip can
+trace it.
 """
 
 from __future__ import annotations
@@ -63,28 +64,16 @@ async def _capture_in_actor(_instance, logdir: Optional[str],
 
 
 def node_capture_task(node_id_hex: str):
-    """The capture task pinned to `node_id_hex` (shared by capture_on_node
-    and the dashboard's /api/jax_profile)."""
+    """The capture task pinned to `node_id_hex` (the dashboard's
+    /api/jax_profile). It runs in a NEW worker on that node: it sees that
+    worker's own (idle) JAX runtime, and no chip that another process
+    holds. To trace an engine or a train worker, use `capture_in_actor`."""
     from ray_tpu._private.protocol import SchedulingStrategy
 
     return _capture_task.options(
         scheduling_strategy=SchedulingStrategy(
             kind="NODE_AFFINITY", node_id=node_id_hex, soft=False),
     )
-
-
-def capture_on_node(node_id_hex: str, logdir: Optional[str] = None,
-                    duration_s: float = 2.0) -> List[str]:
-    """Capture a JAX profile on a specific node (reference: the dashboard
-    agent's per-node capture). Returns trace file paths on that node.
-
-    The capture runs in a NEW worker on that node: it sees that worker's
-    own (idle) JAX runtime, and no chip that another process holds. To
-    trace an engine or a train worker, use `capture_in_actor`."""
-    _dir, files = ray_tpu.get(
-        node_capture_task(node_id_hex).remote(logdir, duration_s),
-        timeout=duration_s + 120)
-    return files
 
 
 def capture_in_actor(actor, logdir: Optional[str] = None,
@@ -99,5 +88,4 @@ def capture_in_actor(actor, logdir: Optional[str] = None,
     return files
 
 
-__all__ = ["capture_in_actor", "capture_local", "capture_on_node",
-           "node_capture_task"]
+__all__ = ["capture_in_actor", "capture_local", "node_capture_task"]

@@ -5,10 +5,12 @@ request, and the profiler hook inside an actor's own process."""
 import asyncio
 import glob
 import os
+import re
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import ray_tpu
@@ -24,10 +26,14 @@ CFG = LlamaConfig(
 SPANS = (_engine.SPAN_QUEUE, _engine.SPAN_PREFILL, _engine.SPAN_DECODE)
 
 
+def small_ecfg(**kw):
+    return EngineConfig(max_num_seqs=2, kv_block_size=4, num_kv_blocks=32,
+                        max_model_len=64, **kw)
+
+
 def small_engine(**kw):
     return PagedEngine(CFG, init_params(CFG, jax.random.PRNGKey(0)),
-                       EngineConfig(max_num_seqs=2, kv_block_size=4,
-                                    num_kv_blocks=32, max_model_len=64, **kw))
+                       small_ecfg(**kw))
 
 
 def host_events(logdir):
@@ -44,16 +50,18 @@ def host_event_names(logdir):
     return {e.name for e in host_events(logdir)}
 
 
-def timed_events(tmp_path, eng, prompts, pause_s=0.05):
+def timed_events(tmp_path, eng, prompts, pause_s=0.05, outside=True):
     """The engine's events, [(name, {argument: value}, start_ns, end_ns)] by
     start, while it serves `prompts` one after the other, left empty for
     `pause_s` before each, after one request outside the capture (it
-    compiles)."""
+    compiles; without `outside` the engine's loop starts inside the
+    capture)."""
     async def one(prompt):
         return [t async for t in eng.generate_stream(prompt, max_tokens=3)]
 
     async def main():
-        await one(prompts[0][:-1] + [1])
+        if outside:
+            await one(prompts[0][:-1] + [1])
         jax.profiler.start_trace(str(tmp_path))
         try:
             for prompt in prompts:
@@ -169,6 +177,153 @@ def test_chunk_counters_account_for_every_prompt_token():
     assert stats["steps_with_chunk"] <= stats["steps"] == 7 + 3 * len(prompts)
     # 25 = 16 + 9 -> 16; 3 -> 8; 37 = 16 + 16 + 5 -> 8; 1 -> 8
     assert stats["prefill_chunk_pad_tokens"] == 7 + 5 + 3 + 7
+
+
+# ---------------------------------------------------------------------------
+# the loop's own account of its time (stats())
+# ---------------------------------------------------------------------------
+
+ACCOUNT = ("loop_turn_s", "loop_wait_s", "loop_idle_s", "turns_unwaited",
+           "turn_unwaited_s")
+LADDER = _engine.chunk_ladder(small_ecfg())     # (8, 16)
+
+
+def serve_in_turn(eng, prompts, pause_s=0.0, marks=None):
+    """Serve `prompts` one after the other on one event loop, the engine
+    left empty for `pause_s` before each; `marks`, where given, gets
+    (time.monotonic(), stats()) as each request's last token arrives."""
+    async def main():
+        for prompt in prompts:
+            await asyncio.sleep(pause_s)
+            assert len([t async for t in eng.generate_stream(
+                prompt, max_tokens=3)]) == 3
+            if marks is not None:
+                marks.append((time.monotonic(), eng.stats()))
+
+    asyncio.run(main())
+
+
+def slow_to_fetch(eng, nap_s):
+    """Make every step's tokens take `nap_s` to fetch, as a device that is
+    still computing them does: beside such a wait what an annotation costs
+    (microseconds, as long as the tiny step's own wait on the CPU) is
+    nothing, as it is on the chip."""
+    step = eng._decode
+
+    class Toks:
+        def __init__(self, value):
+            self.value = value
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(nap_s)
+            return np.asarray(self.value)
+
+    def decode(*args):
+        # the step before's result is among the inputs (`feed_back`)
+        toks, *rest = step(*(a.value if isinstance(a, Toks) else a
+                             for a in args))
+        return Toks(toks), *rest
+
+    eng._decode = decode
+
+
+@pytest.fixture(scope="module")
+def accounted():
+    """The counters of an engine that ran a prompt of one narrow chunk and
+    one of two widest chunks and a narrow one."""
+    eng = small_engine()
+    serve_in_turn(eng, [[20, 21, 22], list(range(30, 30 + 40))])
+    return eng.stats()
+
+
+@pytest.mark.parametrize("width", (0, *LADDER))
+def test_a_turn_is_filed_under_the_width_of_the_step_it_fetched(
+        accounted, width):
+    """Every width of the step has its count and its seconds, every fetched
+    step is under exactly one, and the turns' seconds are the widths'
+    seconds: the wait is a part of them, the unwaited turns some of them."""
+    s = accounted
+    assert {k for k in s if re.fullmatch(r"(steps|turn_s)_w\d+", k)} == {
+        f"{name}{w}" for name in ("steps_w", "turn_s_w") for w in (0, *LADDER)}
+    assert s[f"steps_w{width}"] > 0 and s[f"turn_s_w{width}"] > 0
+    # 3 tokens a request: a chunk of 8; two of 16 and one of 8; the rest
+    assert [s[f"steps_w{w}"] for w in (0, *LADDER)] == [s["steps"] - 4, 2, 2]
+    assert sum(s[f"steps_w{w}"] for w in (0, *LADDER)) == s["steps"]
+    assert sum(s[f"turn_s_w{w}"] for w in (0, *LADDER)) == pytest.approx(
+        s["loop_turn_s"], rel=1e-9)
+    assert 0 < s["loop_wait_s"] <= s["loop_turn_s"]
+    assert 0 <= s["turns_unwaited"] <= s["steps"]
+    assert 0 <= s["turn_unwaited_s"] <= s["loop_turn_s"]
+
+
+def test_turns_and_idle_waits_cover_the_loops_time():
+    """From one request's last token to a later one's, with the engine
+    left empty in between: the turns' seconds and the idle waits' add up to
+    the interval, but for each sweep that found the engine empty."""
+    eng, marks = small_engine(), []
+    serve_in_turn(eng, [[20, 21, 22]] + [[23, 24, 25]] * 3, 0.1, marks)
+    (t0, a), (t1, b) = marks[0], marks[-1]
+    covered = sum(b[k] - a[k] for k in ("loop_turn_s", "loop_idle_s"))
+    assert b["loop_idle_s"] - a["loop_idle_s"] >= 3 * 0.09
+    assert covered == pytest.approx(t1 - t0, rel=0.03, abs=0.04)
+
+
+def test_an_empty_engine_adds_its_wait_to_the_idle_seconds_and_no_turn():
+    """An engine left empty 0.4 s: when the next request ends the wait, the
+    whole of it is in `loop_idle_s`, and until a step is fetched nothing is
+    in `loop_turn_s`."""
+    eng = small_engine()
+
+    async def main():
+        assert len([t async for t in eng.generate_stream(
+            [20, 21, 22], max_tokens=3)]) == 3
+        before = eng.stats()
+        await asyncio.sleep(0.4)
+        gen = eng.generate_stream([23, 24, 25], max_tokens=3)
+        first = asyncio.ensure_future(gen.__anext__())
+        while eng.stats()["loop_idle_s"] == before["loop_idle_s"]:
+            await asyncio.sleep(0)
+        woken = eng.stats()
+        await first
+        await gen.aclose()
+        return before, woken
+
+    before, woken = asyncio.run(main())
+    assert woken["loop_idle_s"] - before["loop_idle_s"] >= 0.35
+    assert woken["loop_turn_s"] == before["loop_turn_s"]
+    assert woken["steps"] == before["steps"]
+
+
+def test_the_counters_are_the_annotations_sums_and_each_wait_names_its_width(
+        tmp_path):
+    """A profiler session held over a whole run (the loop starts inside it):
+    `loop_wait_s` is the sum of the `engine:device_wait`s and `loop_idle_s`
+    that of the `engine:idle`s, each pair on the same two edges, and every
+    wait carries the chunk width of the step it fetched: the widths that
+    were dispatched, each fetched once."""
+    eng = small_engine()
+    eng.warm_up()
+    slow_to_fetch(eng, 0.005)
+    events = timed_events(tmp_path, eng, [
+        [20, 21, 22], list(range(30, 30 + 40)), [23, 24, 25]],
+        pause_s=0.1, outside=False)
+    stats = eng.stats()
+
+    def total_s(name):
+        return sum(e - s for n, _, s, e in events if n == name) * 1e-9
+
+    assert total_s(_engine.PHASE_DEVICE_WAIT) == pytest.approx(
+        stats["loop_wait_s"], rel=0.02)
+    assert stats["loop_idle_s"] >= 2 * 0.09
+    assert total_s(_engine.PHASE_IDLE) == pytest.approx(
+        stats["loop_idle_s"], rel=0.02)
+    waits = [args for n, args, _, _ in events
+             if n == _engine.PHASE_DEVICE_WAIT]
+    assert len(waits) == stats["steps"] and all("chunk" in a for a in waits)
+    assert sorted(a["chunk"] for a in waits) == sorted(
+        args["chunk"] for n, args, _, _ in events if n == _engine.PHASE_STEP)
+    for w in (0, *LADDER):
+        assert sum(a["chunk"] == w for a in waits) == stats[f"steps_w{w}"]
 
 
 def test_tracing_off_records_no_engine_span(monkeypatch):

@@ -15,7 +15,7 @@ import pytest
 
 from benchmark.lib import reference_ling as ref
 from benchmark.runners._inside_ling import ProgramWeightsLing
-from ray_tpu.llm._engine import EngineConfig, PagedEngine
+from ray_tpu.llm._engine import EngineConfig, PagedEngine, chunk_ladder
 from ray_tpu.models import ling
 from ray_tpu.ops import grouped_ffn
 from ray_tpu.ops import kda as kda_ops
@@ -250,14 +250,21 @@ def test_prefix_cache_is_refused_with_recurrent_layers(params):
         PagedEngine(CFG, params, dataclasses.replace(ECFG, prefix_cache=True))
 
 
+# the loop's stalls and its account of its own time: every family's engine
+LOOP_KEYS = ("loop_stalls", "loop_stall_s", "loop_stall_admit_s",
+             "loop_stall_last_at", "loop_turn_s", "loop_wait_s", "loop_idle_s",
+             "turns_unwaited", "turn_unwaited_s")
+
+
 def test_stats_count_the_experts(engine):
     serve(engine, [prompt(41, 80)], max_tokens=8)
     s = engine.stats()
     for key in ("moe_pairs_routed", "moe_pairs_held", "moe_experts_touched",
                 "moe_load_max", "state_bytes", "latent_positions_live",
-                "loop_stalls", "loop_stall_s", "loop_stall_admit_s",
-                "loop_stall_last_at"):
+                *LOOP_KEYS, "steps_w0", "turn_s_w0"):
         assert key in s
+    # no chunk ladder: every fetched step is a decode step
+    assert s["steps_w0"] == s["steps"] and s["turn_s_w0"] == s["loop_turn_s"]
     assert 0 < s["moe_pairs_held"] <= s["moe_pairs_routed"]
     assert s["moe_experts_touched"] <= s["moe_pairs_held"]
     assert s["state_bytes"] == engine.state.nbytes + engine.tails.nbytes
@@ -284,7 +291,9 @@ def test_a_llama_engine_allocates_no_state_and_keeps_its_stats():
         "prefill_chunks",
         "prefill_chunk_tokens", "prefill_chunk_pad_tokens", "steps_with_chunk",
         "chunk_overtakes", "steps_ahead", "rows_dropped", "admissions",
-        "admit_host_s"}
+        "admit_host_s", *LOOP_KEYS,
+        *(f"{name}{w}" for name in ("steps_w", "turn_s_w")
+          for w in (0, *chunk_ladder(ECFG)))}
 
 
 def test_llm_config_resolves_the_family():

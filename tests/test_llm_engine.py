@@ -11,6 +11,7 @@ import importlib
 import inspect
 import re
 import textwrap
+import time
 import types
 from typing import Any
 
@@ -534,6 +535,31 @@ def test_every_family_keeps_the_step_set_and_the_engine_names_none(family):
             and getattr(n.func, "id", "") == "isinstance"} <= {"Exception"}
 
 
+STALL_KEYS = {"loop_stalls", "loop_stall_s", "loop_stall_admit_s",
+              "loop_stall_last_at"}
+ACCOUNT_KEYS = {"loop_turn_s", "loop_wait_s", "loop_idle_s", "turns_unwaited",
+                "turn_unwaited_s"}
+
+
+@pytest.mark.parametrize("family", sorted(MODEL_FAMILIES))
+def test_every_familys_engine_reports_the_loops_account_and_its_stalls(family):
+    """`stats()` of any family's engine holds the loop's account of its
+    time, a count and the seconds for every chunk width its step takes (0
+    alone without a ladder), and the stall counters: all zero before a
+    request, and no other key of those shapes."""
+    cfg, params = LLMConfig(model=f"{family}:tiny").build_model()
+    ecfg = EngineConfig(max_num_seqs=2, kv_block_size=16, num_kv_blocks=16,
+                        max_model_len=64, prefix_cache=False)
+    stats = PagedEngine(cfg, params, ecfg).stats()
+    widths = (0, *step_set(cfg).chunk_ladder(ecfg))
+    by_width = {f"{name}{w}" for name in ("steps_w", "turn_s_w")
+                for w in widths}
+    assert STALL_KEYS | ACCOUNT_KEYS | by_width <= set(stats)
+    assert {k for k in stats
+            if re.fullmatch(r"(steps|turn_s)_w\d+", k)} == by_width
+    assert not any(stats[k] for k in STALL_KEYS | ACCOUNT_KEYS | by_width)
+
+
 @dataclasses.dataclass(frozen=True)
 class ToyConfig:
     """A third family, served by the engine as it stands: a bag of tokens.
@@ -947,6 +973,54 @@ def test_a_recording_step_set_sees_each_dispatch_before_the_fetch_ahead_of_it(
     assert steps > 0
     assert at["late"]["steps_ahead"] - at["early"]["steps_ahead"] == steps
     assert eng._flight is None and eng.stats()["rows_dropped"] == 0
+
+
+@pytest.mark.parametrize("slow", ["fetch", "dispatch"])
+def test_a_turn_is_unwaited_where_its_tokens_were_ready_at_the_fetch(
+        toy, monkeypatch, slow):
+    """The toy family with a decode step whose tokens take a while to fetch
+    (a device that sets the pace: every turn waits) or whose dispatch takes
+    a while and returns tokens that are ready (a host that does: no turn
+    waits, and the unwaited turns' seconds are all of the turns'). The
+    limit is raised from its millisecond so that a test machine that takes
+    the thread away between two clock reads changes nothing."""
+    cfg, params = toy
+    inner, _, _ = _toy_decode_step(cfg, TOY_ECFG)
+    nap_s = 0.03
+    monkeypatch.setattr(_engine, "UNWAITED_S", nap_s / 3)
+
+    class Toks:
+        def __init__(self, value):
+            self.value = value
+
+        def __array__(self, dtype=None, copy=None):
+            if slow == "fetch":
+                time.sleep(nap_s)
+            return self.value
+
+    def paced(params, bag, *slots):
+        *slots, prev, fed = slots
+        toks, bag = inner(params, bag, *slots, getattr(prev, "value", prev),
+                          fed)
+        value = np.asarray(toks)          # the step has run
+        if slow == "dispatch":
+            time.sleep(nap_s)
+        return Toks(value), bag
+
+    eng = PagedEngine(cfg, params, TOY_ECFG)
+    eng._decode = paced
+    assert serve_all(eng, [[5, 6, 7]], 8) == [toy_reference(
+        params, [5, 6, 7], 8)]
+    s = eng.stats()
+    assert s["steps"] == s["steps_w0"] >= 7
+    assert s["turn_s_w0"] == s["loop_turn_s"] >= (s["steps"] - 1) * nap_s
+    if slow == "fetch":
+        assert s["turns_unwaited"] == 0 and s["turn_unwaited_s"] == 0.0
+        assert s["steps"] * nap_s <= s["loop_wait_s"] <= s["loop_turn_s"]
+    else:
+        assert s["turns_unwaited"] == s["steps"]
+        assert s["turn_unwaited_s"] == s["loop_turn_s"]
+        assert s["loop_wait_s"] < s["steps"] * nap_s / 3
 
 
 def test_a_prompts_last_chunk_and_first_decode_row_ride_consecutive_steps(

@@ -136,14 +136,24 @@ def test_placement_group_listing(ray_init):
 
 
 def test_jax_profiler_capture(ray_init, tmp_path):
-    """JAX profiler capture on a cluster node writes an XPlane trace
-    (reference: jax_profile_manager.py capture + util/tpu.py profiler)."""
-    from ray_tpu.tpu.profiler import capture_on_node
+    """JAX profiler capture inside a live actor's own process, the path
+    the benchmark and an operator use to trace a process that holds a
+    chip, writes an XPlane trace (reference: jax_profile_manager.py
+    capture + util/tpu.py profiler)."""
+    from ray_tpu.tpu.profiler import capture_in_actor
 
-    node = state.list_nodes()[0]
+    @ray_tpu.remote
+    class Holder:
+        def ping(self):
+            return 1
 
-    files = capture_on_node(node["node_id"], str(tmp_path / "prof"),
-                            duration_s=0.5)
+    actor = Holder.remote()
+    assert ray_tpu.get(actor.ping.remote(), timeout=60) == 1
+    try:
+        files = capture_in_actor(actor, str(tmp_path / "prof"),
+                                 duration_s=0.5)
+    finally:
+        ray_tpu.kill(actor)
     assert files, "no trace files produced"
     assert any(f.endswith(".xplane.pb") or "trace" in f for f in files), files
 
